@@ -1,4 +1,4 @@
-"""BERT-base finetune throughput (BASELINE.md row 3)."""
+"""BERT-base finetune throughput (BASELINE.json row 3)."""
 
 from __future__ import annotations
 
@@ -19,7 +19,9 @@ def main():
 
     if os.environ.get("PADDLE_TPU_BENCH_CPU"):
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    from paddle_tpu._core import compile_cache
+
+    compile_cache.enable()
     on_accel = jax.devices()[0].platform != "cpu"
 
     import paddle_tpu as paddle
@@ -105,8 +107,9 @@ def main():
         tokens_per_sec = measure(B)
 
     # vs_baseline: peak-normalized chip-efficiency parity against the
-    # written-down A100 reference point (BASELINE.md "A100 reference
-    # points"): BERT-base AMP S=128 1xA100 = 139,264 tok/s (1,088 seq/s).
+    # written-down A100 reference point (NVIDIA DeepLearningExamples
+    # BERT-base phase-1 AMP, ~8.7k seq/s on 8xA100): S=128 1xA100 =
+    # 139,264 tok/s (1,088 seq/s).
     from paddle_tpu.device.peaks import A100_PEAK_TFLOPS, device_peak_tflops
 
     d = jax.devices()[0]

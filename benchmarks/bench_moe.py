@@ -1,4 +1,4 @@
-"""ERNIE-MoE-shaped semi-auto training throughput (BASELINE.md stretch row).
+"""ERNIE-MoE-shaped semi-auto training throughput (BASELINE.json stretch row).
 
 Prints ONE JSON line like bench.py.  vs_baseline is 0.0 ("track" level).
 Single-chip runs exercise the dense expert compute + gating; the EP
@@ -21,7 +21,9 @@ def main():
 
     if os.environ.get("PADDLE_TPU_BENCH_CPU"):
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    from paddle_tpu._core import compile_cache
+
+    compile_cache.enable()
     on_accel = jax.devices()[0].platform != "cpu"
 
     import paddle_tpu as paddle

@@ -1,7 +1,7 @@
-"""PP-OCR-class recognizer training throughput (BASELINE.md row 4).
+"""PP-OCR-class recognizer training throughput (BASELINE.json row 4).
 
 Prints ONE JSON line like bench.py.  vs_baseline is 0.0 ("track" level —
-BASELINE.md records no written-down A100 reference point for this row)."""
+BASELINE.json records no written-down A100 reference point for this row)."""
 
 from __future__ import annotations
 
@@ -19,7 +19,9 @@ def main():
 
     if os.environ.get("PADDLE_TPU_BENCH_CPU"):
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    from paddle_tpu._core import compile_cache
+
+    compile_cache.enable()
     on_accel = jax.devices()[0].platform != "cpu"
 
     import paddle_tpu as paddle

@@ -1,8 +1,8 @@
-"""ResNet-50 ImageNet-shape training throughput (BASELINE.md row 2).
+"""ResNet-50 ImageNet-shape training throughput (BASELINE.json row 2).
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline"} like
 bench.py; vs_baseline tracks images/sec against the Paddle-on-A100
-reference point once recorded (none published in-repo — BASELINE.md)."""
+reference point once recorded (none published in-repo — BASELINE.json)."""
 
 from __future__ import annotations
 
@@ -21,7 +21,9 @@ def main():
 
     if os.environ.get("PADDLE_TPU_BENCH_CPU"):
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    from paddle_tpu._core import compile_cache
+
+    compile_cache.enable()
     on_accel = jax.devices()[0].platform != "cpu"
 
     import paddle_tpu as paddle
@@ -114,8 +116,9 @@ def main():
         images_per_sec = measure(B, iters)
 
     # vs_baseline: peak-normalized chip-efficiency parity against the
-    # written-down A100 reference point (BASELINE.md "A100 reference
-    # points"): ResNet-50 AMP 1xA100 = 2,900 img/s.
+    # written-down A100 reference point (NVIDIA DeepLearningExamples
+    # PaddlePaddle ResNet-50 AMP, ~23.2k img/s on 8xA100): 1xA100 =
+    # 2,900 img/s.
     # vs_baseline = (ours/our_peak) / (2900/A100_peak).
     from paddle_tpu.device.peaks import A100_PEAK_TFLOPS, device_peak_tflops
 

@@ -1,7 +1,7 @@
 """PP-YOLO-class detection + PP-OCR-class recognition walkthrough.
 
 Run (CPU): python examples/detect_and_ocr.py
-Shows the BASELINE.md row-4 model families end to end: a detector forward
+Shows the BASELINE.json row-4 model families end to end: a detector forward
 with yolo_box decode, and a CRNN recognizer trained with CTC until its
 greedy decode emits the target sequence.
 """

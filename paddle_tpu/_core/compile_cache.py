@@ -15,15 +15,26 @@ or at runtime with ``paddle.set_flags({"FLAGS_compilation_cache_dir":
 "/path"})`` — the flags listener applies it immediately.  Pair with
 ``jit.TrainStep.warmup(sample_batch)`` to pay the (first-run) compile before
 traffic.
+
+WHERE the cache lives follows one rule, for every process of this repo
+(tests, benches, tools, cluster workers, chip_smoke.py): if
+``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and code sets
+NO directory; otherwise ``enable()`` uses the flag's value and, failing
+that, the fixed ``<checkout>/.jax_cache``.  Fixed, because the directory is
+part of jax's cache key: a cache that moves never hits.
 """
 
 from __future__ import annotations
 
+import os
 import threading
 
 from . import flags
 
-__all__ = ["configure", "compile_stats", "reset_compile_stats"]
+__all__ = ["configure", "enable", "default_dir", "compile_stats",
+           "reset_compile_stats"]
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 _lock = threading.Lock()
 _listeners_installed = False
@@ -77,27 +88,42 @@ def _install_listeners():
         _listeners_installed = True
 
 
+def default_dir() -> str:
+    """``<checkout>/.jax_cache`` (listed in .gitignore)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
+
+
+def enable() -> str:
+    """Turn the persistent cache on by the one rule (module docstring) and
+    return the directory in use."""
+    return configure(str(flags.flag("FLAGS_compilation_cache_dir") or "")
+                     or default_dir())
+
+
 def configure(cache_dir: str | None = None):
     """Point jax's persistent compilation cache at ``cache_dir`` (default:
     the FLAGS_compilation_cache_dir value; empty disables).  Safe to call
-    repeatedly; re-pointing resets jax's in-memory view of the cache."""
+    repeatedly; re-pointing resets jax's in-memory view of the cache.
+    Where JAX_COMPILATION_CACHE_DIR is set, that directory stands and none
+    is set here, whatever ``cache_dir`` says."""
     global _configured_dir
     _install_listeners()
+    import jax
+
+    env_dir = os.environ.get(ENV_VAR)
     if cache_dir is None:
         cache_dir = str(flags.flag("FLAGS_compilation_cache_dir") or "")
-    cache_dir = cache_dir or None
+    cache_dir = env_dir or cache_dir or None
     if cache_dir == _configured_dir:
         return cache_dir
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
+    if not env_dir:
+        from jax.experimental.compilation_cache import compilation_cache as cc
 
-    try:
         # drop the once-per-task "is the cache in use" decision so a dir set
         # AFTER the first compile still takes effect
         cc.reset_cache()
-    except Exception:
-        pass
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     if cache_dir is not None:
         jax.config.update("jax_enable_compilation_cache", True)
         # default min-compile-time gate (1s) would skip exactly the small

@@ -10,8 +10,9 @@ by source hash) and exposes:
 - ShmRing — process-shared ring buffer (DataLoader worker transport)
 - HostEventRecorder — low-overhead profiler span buffer
 
-If no compiler is available the attribute `AVAILABLE` is False and callers
-fall back to pure-Python equivalents.
+If the build fails (no compiler, a compile error) the attribute `AVAILABLE`
+is False, `BUILD_ERROR` says why, and callers fall back to pure-Python
+equivalents.
 """
 
 from __future__ import annotations
@@ -28,6 +29,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src")
 
 AVAILABLE = False
+BUILD_ERROR: str | None = None  # why AVAILABLE is False, when a build failed
 _lib = None
 
 
@@ -47,9 +49,13 @@ def _build() -> str | None:
     tmp = f"{out}.{os.getpid()}.tmp"  # per-process name: concurrent first
     # builds (multi-rank launch) must not interleave writes to one file
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread", *srcs, "-o", tmp, "-lrt"]
+    global BUILD_ERROR
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", None) or b""
+        BUILD_ERROR = (f"{type(e).__name__}: {e} "
+                       f"{stderr.decode(errors='replace')[-400:]}").strip()
         return None
     os.replace(tmp, out)
     return out

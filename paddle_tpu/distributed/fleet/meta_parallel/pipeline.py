@@ -685,8 +685,11 @@ class PipelineStack(Layer):
 
         plan = _schedules.get_schedule(self._schedule).engine_plan(S, M)
         T, TB = plan["T"], plan["TB"]
-        b_tick = jnp.asarray(plan["b_tick"], jnp.int32)
-        w_tick = jnp.asarray(plan["w_tick"], jnp.int32)
+        # host arrays: this runs under the caller's trace and the engine is
+        # cached on the object, so a jnp constant made here would be a
+        # tracer of a trace that has ended by the next call
+        b_tick = np.asarray(plan["b_tick"], np.int32)
+        w_tick = np.asarray(plan["w_tick"], np.int32)
         ring = [(i, (i + 1) % S) for i in range(S)]
         ring_rev = [(i, (i - 1) % S) for i in range(S)]
 
@@ -903,11 +906,14 @@ class PipelineStack(Layer):
         # produce stage-varying values from replicated inputs (the same
         # reason the 2-D-mesh path rides the partial-manual fallback) — the
         # mesh lint, not the rep checker, owns collective congruence here.
+        # jitted: an EAGER call (ShardedTrainStep's step 0) would run jax's
+        # eager shard_map, which in jax 0.9 refuses partial-manual axes
+        # with a pp-sharded output ("out_specs refers to 'dp'")
         def fwd_sm(*vals):
-            return shard_map(
+            return jax.jit(shard_map(
                 pipe_fwd, mesh=jmesh, in_specs=in_specs,
                 out_specs=(PartitionSpec(), PartitionSpec(pp)),
-                axis_names={pp}, check_vma=False)(*vals)
+                axis_names={pp}, check_vma=False))(*vals)
 
         @jax.custom_vjp
         def zb(*vals):
@@ -927,11 +933,11 @@ class PipelineStack(Layer):
                        + sum(bc_diff))
             grad_specs = tuple(PartitionSpec(pp) for _ in range(n_keys)) + \
                 tuple(PartitionSpec() for _ in range(n_grads - n_keys))
-            grads = shard_map(
+            grads = jax.jit(shard_map(
                 pipe_bwd, mesh=jmesh,
                 in_specs=in_specs + (PartitionSpec(pp), PartitionSpec()),
                 out_specs=grad_specs,
-                axis_names={pp}, check_vma=False)(*vals, store, g)
+                axis_names={pp}, check_vma=False))(*vals, store, g)
             grads = list(grads)
             out = []
             for i, v in enumerate(vals):

@@ -1,57 +1,21 @@
-"""shard_map across jax versions.
-
-jax>=0.6 exposes ``jax.shard_map(f, mesh=, in_specs=, out_specs=,
-axis_names=, check_vma=)``; older jax ships
-``jax.experimental.shard_map.shard_map`` which takes ``check_rep`` instead
-of ``check_vma`` and spells partial-manual as ``auto`` (the complement of
-the manual axes) instead of ``axis_names``.  This adapter translates the
-new-style kwargs the callers in this package use, so a jax<0.6 runtime
-runs them instead of failing at import or with an opaque TypeError.
+"""`jax.shard_map` and `jax.lax.axis_size` under the names this package
+imports them by (pyproject.toml pins jax>=0.7).  The one adaptation left:
+callers pass ``axis_names=None`` / ``check_vma=None`` for "jax's default".
 """
 
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map_new
-
-    _NEW_API = True
-except ImportError:  # jax<0.6
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    _NEW_API = False
+import jax
+from jax.lax import axis_size
 
 __all__ = ["shard_map", "axis_size"]
 
 
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` across jax versions: jax<0.6 has no axis_size;
-    ``psum(1, axis)`` constant-folds to the mapped axis size there."""
-    import jax.lax as _lax
-
-    if hasattr(_lax, "axis_size"):
-        return _lax.axis_size(axis_name)
-    return _lax.psum(1, axis_name)
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=None, **kwargs):
-    if _NEW_API:
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
-    if axis_names is not None and set(axis_names) != set(mesh.axis_names):
-        # The old API spells partial-manual as `auto` = the complement set,
-        # but its partial-auto tracing has no autodiff rules (jvp raises
-        # NotImplementedError), so callers that differentiate through the
-        # region (pipeline 1F1B) cannot use it.  Full-manual is semantically
-        # safe here instead: specs never mention the would-be-auto axes, so
-        # inputs replicate and outputs are per-rank identical over them —
-        # at worst duplicated compute on those axes, never wrong values.
-        kwargs["check_rep"] = False
-    elif check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

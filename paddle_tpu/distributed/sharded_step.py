@@ -24,6 +24,7 @@ traced into one XLA program over a jax.sharding.Mesh:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import numpy as np
@@ -155,6 +156,26 @@ class ShardedTrainStep(TrainStep):
             else:
                 sh = self._acc_sharding(acc._value, psh)
             acc._bind(jax.device_put(acc._value, sh))
+        # whatever else rides in the state (the learning-rate scalar, a
+        # GradScaler's counters) is replicated: left on one device it came
+        # back from the first compiled step as a mesh array, and the second
+        # call compiled the whole step again for that one new input sharding
+        replicated = NamedSharding(self.mesh.jax_mesh, PartitionSpec())
+        for t in self._state:
+            if not isinstance(t._value.sharding, NamedSharding):
+                t._bind(jax.device_put(t._value, replicated))
+        # what _pin_state holds the traced step's outputs to
+        self._placed = [t._value.sharding for t in self._state]
+
+    def _pin_state(self, vals):
+        """Every state output keeps the sharding `_place_state` gave its
+        input.  Left to propagation, a parameter came out of the step with
+        its fp32 master weight's ZeRO sharding (dp x mp instead of mp): the
+        second call then saw new input shardings and compiled again, the
+        parameter buffers were no longer donated in place, and every later
+        step re-gathered each weight before using it."""
+        return [jax.lax.with_sharding_constraint(v, sh)
+                for v, sh in zip(vals, self._placed)]
 
     # ------------------------------------------------- comm/compute overlap
     def _post_backward(self):
@@ -190,10 +211,20 @@ class ShardedTrainStep(TrainStep):
                 out.append(shard_batch(self.mesh, b, self.batch_spec))
         return tuple(out)
 
+    def _mesh_scope(self):
+        """The legacy `with mesh:` resolves bare PartitionSpecs; jax.set_mesh
+        makes the mesh readable (jax.sharding.get_abstract_mesh) to code
+        traced under it — ops.use_pallas() keeps Mosaic kernels, which GSPMD
+        cannot partition, out of the sharded program."""
+        stack = contextlib.ExitStack()
+        stack.enter_context(self.mesh.jax_mesh)
+        stack.enter_context(jax.set_mesh(self.mesh.jax_mesh))
+        return stack
+
     def __call__(self, *batch):
         batch = self._shard_batch_tensors(batch)
         if self._compiled is None:
-            with self.mesh.jax_mesh:
+            with self._mesh_scope():
                 loss = self._eager_step(*batch)
                 self._state = self._collect_state()
                 self._place_state()
@@ -204,5 +235,5 @@ class ShardedTrainStep(TrainStep):
                 # error here, never an 8-device rendezvous hang
                 self._maybe_mesh_lint(batch)
             return loss
-        with self.mesh.jax_mesh:
+        with self._mesh_scope():
             return super().__call__(*batch)

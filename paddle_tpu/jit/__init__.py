@@ -41,7 +41,7 @@ __all__ = ["to_static", "TrainStep", "not_to_static", "save", "load", "ignore_mo
 
 def _host_device():
     """default_device(cpu) context, or a no-op if no cpu backend exists
-    (jax_platforms pinned to an accelerator plugin only)."""
+    (JAX_PLATFORMS naming an accelerator only)."""
     import contextlib
 
     try:
@@ -280,6 +280,11 @@ class TrainStep:
         traced program — ShardedTrainStep's comm/compute overlap rewrites
         gradients here (grad-sync decomposition, docs/PIPELINE.md)."""
 
+    def _pin_state(self, vals):
+        """Hook on the state the traced step returns — ShardedTrainStep
+        pins every output to the sharding its input was placed with."""
+        return vals
+
     def _eager_step(self, *batch):
         loss = self.loss_fn(self.model, *batch)
         if self.scaler is not None and self.scaler.is_enable():
@@ -294,13 +299,14 @@ class TrainStep:
     def _ensure_built(self):
         if self._compiled is None:
             # Materialize optimizer accumulators WITHOUT an eager
-            # forward/backward (which would dispatch hundreds of per-op XLA
-            # compiles — ruinous on remote-attached TPUs).  The zero-grad
-            # journaled step runs on the host CPU backend (only effective for
-            # host-built, uncommitted params — state already device_put to an
-            # accelerator stays there); the compiled step transfers fresh
-            # state to the accelerator on first call.  GradScaler state is
-            # device tensors (amp/__init__.py) and joins the state list.
+            # forward/backward (hundreds of per-op XLA compiles, and every
+            # activation live at once).  The zero-grad journaled step runs
+            # on the host CPU backend (uncommitted params follow it there —
+            # state already device_put to an accelerator stays); the
+            # compiled step moves the fresh state to the accelerator on its
+            # first call, where chip_smoke.py checks that it arrived.
+            # GradScaler state is device tensors (amp/__init__.py) and joins
+            # the state list.
             params = [p for p in self.optimizer._parameter_list if not p.stop_gradient]
             with _host_device():
                 self.optimizer._journaled_step(params)
@@ -422,7 +428,7 @@ class TrainStep:
                         self._post_backward()
                         optimizer.step()
                     optimizer.clear_grad()
-                new_vals = [t._value for t in state]
+                new_vals = self._pin_state([t._value for t in state])
                 return new_vals, loss._value
             finally:
                 for t, v, g in zip(state, originals, grads_saved):

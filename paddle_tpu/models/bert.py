@@ -1,6 +1,6 @@
 """BERT / ERNIE encoder family.
 
-Capability target: the BASELINE.md north-star finetune configs (BERT-base +
+Capability target: the BASELINE.json north-star finetune configs (BERT-base +
 ERNIE-3.0 data-parallel finetune) — reference model definitions live in
 PaddleNLP on top of the framework; here the family is built on this
 framework's nn stack the same way (nn.TransformerEncoder).  ERNIE 1.0/3.0

@@ -1,7 +1,7 @@
 """LLaMA-family decoder (flagship model).
 
 Capability target: the reference trains LLaMA-2 via PaddleNLP on fleet hybrid
-parallel (BASELINE.md north star).  Architecture built on this framework's nn
+parallel (BASELINE.json north star).  Architecture built on this framework's nn
 API; TPU-first choices:
 - bfloat16 parameters/activations by default, fp32 RMSNorm statistics;
 - rotary embeddings computed once and gathered (no per-step trig);

@@ -12,7 +12,13 @@ ships two implementations:
   interpreter mode against it).
 
 Dispatch is `use_pallas()`: TPU backend by default, overridable via the flag
-`FLAGS_use_pallas` (paddle_tpu.set_flags) for A/B benchmarking.
+`FLAGS_use_pallas` (paddle_tpu.set_flags) for A/B benchmarking.  Under a
+multi-device mesh (`jax.set_mesh`, which ShardedTrainStep enters) the default
+is the XLA implementation: GSPMD refuses to split a Mosaic kernel ("Mosaic
+kernels cannot be automatically partitioned") and libtpu registers no custom
+partitioner, so until the kernels are shard_mapped the partitioned program
+keeps plain XLA ops.  The mesh is part of jax's trace key, so one process can
+run both.
 
 This library also plays the role of the reference's KPS tier
 (paddle/phi/kernels/primitive/, Backend::KPS — the "write once, run
@@ -27,6 +33,7 @@ from __future__ import annotations
 import jax
 
 from paddle_tpu._core import flags as _flags
+from paddle_tpu.ops import _pl_utils
 
 _flags.define_flag("FLAGS_use_pallas", "auto", "auto|true|false — Pallas kernel dispatch")
 _flags.define_flag("FLAGS_flash_block_q", 0,
@@ -48,7 +55,7 @@ def use_pallas() -> bool:
         return True
     if v in ("false", "0"):
         return False
-    return jax.default_backend() == "tpu"
+    return _pl_utils.on_tpu() and jax.sharding.get_abstract_mesh().size <= 1
 
 
 from .flash_attention import flash_attention, flash_attention_reference  # noqa: E402,F401

@@ -10,7 +10,22 @@ enforced by the jaxpr scan in tests/test_ops_pallas.py.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+
+def on_tpu() -> bool:
+    """True when jax's default backend is a TPU.  The one place the ops
+    tier asks: `use_pallas()` defaults to it, and `interpret()` hands its
+    negation to every pallas_call.  Tests that compile kernels for a
+    described (not attached) chip monkeypatch THIS function."""
+    return jax.default_backend() == "tpu"
+
+
+def interpret() -> bool:
+    """pallas_call's `interpret=`: Mosaic on a TPU, the Pallas interpreter
+    everywhere else."""
+    return not on_tpu()
 
 
 def imap(fn):

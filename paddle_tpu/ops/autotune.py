@@ -161,6 +161,12 @@ class AutotuneCache:
         return None
 
     # ------------------------------------------------------------- access
+    @property
+    def runtime_entries(self) -> int:
+        """How many entries came from OUTSIDE the committed seed table (the
+        user fallback dir, FLAGS_autotune_cache_dir, or this process)."""
+        return sum(len(v) for v in self._runtime.values())
+
     def get(self, kernel: str, key: dict):
         entry = self._data.get(kernel, {}).get(_key_str(key))
         return dict(entry["config"]) if entry else None
@@ -511,7 +517,8 @@ def tune_matmul_epilogue(m=4096, k=4096, n=4096, dtype="bfloat16", **kw):
 
     def build(cfg):
         tiles = (cfg["bm"], cfg["bk"], cfg["bn"])
-        return jax.jit(lambda a, ww, bb: me._fused_2d(a, ww, bb, "gelu",
+        # tanh-GELU: the heaviest epilogue Mosaic lowers (exact erf is XLA's)
+        return jax.jit(lambda a, ww, bb: me._fused_2d(a, ww, bb, "gelu_tanh",
                                                       tiles=tiles))
 
     return tune_kernel("matmul_epilogue", key, build,

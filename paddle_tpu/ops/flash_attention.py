@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops import _pl_utils
 from paddle_tpu.ops._pl_utils import imap
 from jax.experimental.pallas import tpu as pltpu
 
@@ -89,6 +90,35 @@ def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False):
     return _fallback(seq_q), _fallback(seq_k)
 
 
+# Mosaic's own stack scratch on top of the pipeline's blocks (observed
+# <= 16 KiB when compiling for a v5e; the dkv kernel at exactly 16 MiB of
+# blocks was refused at "16.01M").
+_MOSAIC_SCRATCH = 32 << 10
+
+
+def _require_vmem(kernel, seq_name, seq, row_bytes, tile_bytes):
+    """Mosaic branch only: these kernels keep one head's WHOLE sequence
+    resident in VMEM (K and V in the forward and dq kernels; Q, dO, lse
+    and delta in the dk/dv kernel) and Pallas double-buffers every block,
+    so past a length the chip's compiler refuses the kernel with a
+    scoped-VMEM allocation dump.  Name the limit instead.  No switch to
+    the O(S^2) reference: a caller that needs longer sequences shards
+    them (context_parallel_llama) until a streaming-K/V kernel exists."""
+    from paddle_tpu.ops.autotune import _VMEM_BUDGET
+
+    need = 2 * (seq * row_bytes + tile_bytes) + _MOSAIC_SCRATCH
+    if need <= _VMEM_BUDGET:
+        return
+    longest = ((_VMEM_BUDGET - _MOSAIC_SCRATCH) // 2 - tile_bytes) // row_bytes
+    raise ValueError(
+        f"flash_attention ({kernel} kernel): {seq_name}={seq} needs "
+        f"{need / (1 << 20):.2f} MiB of VMEM for its whole-sequence blocks "
+        f"(double-buffered) but Mosaic's scoped-VMEM limit is "
+        f"{_VMEM_BUDGET >> 20} MiB; the longest {seq_name} this kernel "
+        f"compiles for at these widths is {longest // 128 * 128}. "
+        "Shard the sequence (context parallelism) or shorten it.")
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -151,6 +181,11 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     num_kv_heads, seq_k = k.shape[1], k.shape[2]
     group = num_heads // num_kv_heads
     grid = (batch, num_heads, seq_q // block_q)
+    interpret = _pl_utils.interpret()
+    if not interpret:
+        isz = q.dtype.itemsize
+        _require_vmem("forward", "seq_k", seq_k, 2 * head_dim * isz,
+                      2 * block_q * head_dim * isz + block_q * 128 * 4)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=block_k),
@@ -168,7 +203,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, 128), jnp.float32),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -270,7 +305,15 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)  # [B,N,Sq]
     lse_b = jnp.broadcast_to(lse[..., None], (*lse.shape, 128)).astype(jnp.float32)
     delta_b = jnp.broadcast_to(delta[..., None], (*delta.shape, 128)).astype(jnp.float32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _pl_utils.interpret()
+    if not interpret:
+        isz = q.dtype.itemsize
+        lane_f32 = 128 * 4  # one lse / delta row, lane-padded
+        _require_vmem("backward dq", "seq_k", seq_k, 2 * head_dim * isz,
+                      3 * block_q * head_dim * isz + 2 * block_q * lane_f32)
+        _require_vmem("backward dk/dv", "seq_q", seq_q,
+                      2 * head_dim * isz + 2 * lane_f32,
+                      4 * block_k * head_dim * isz)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, block_k=block_k),
@@ -342,21 +385,24 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, res, do):
 _flash_bnsh.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _pad_seq(x, block):
-    s = x.shape[2]
-    pad = (-s) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    return x, pad
+def _ragged(seq_q, seq_k, block_q, block_k):
+    """Lengths the kernels cannot tile: not a multiple of the block, or a
+    (short-sequence) block off the 8-row sublane grid, which Mosaic refuses
+    ("cannot statically prove that index ... is a multiple of 8")."""
+    return bool(seq_q % block_q or seq_k % block_k
+                or block_q % 8 or block_k % 8)
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None):
     """Blockwise flash attention.  q/k/v: [B, S, N, H] (paddle layout).
 
-    Non-multiple-of-block sequence lengths are zero-padded; for the non-causal
-    case padded keys are masked out by construction only when causal — so for
-    safety arbitrary lengths take the padded-causal path or mask via the
-    reference; practical training shapes are multiples of the block size.
+    Lengths the kernels cannot tile (see _ragged): causal self-attention
+    (Sq == Sk, e.g. a prompt of any length) is zero-padded to a multiple of
+    128 and sliced back — exact, since a padded key sits after every real
+    query and the causal mask hides it.  Padding keys would change a
+    non-causal softmax, and padding a cross-length chunk would move its
+    bottom-right alignment, so those fall back to the full-softmax
+    reference with a warning.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -364,11 +410,21 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     seq_q, seq_k = qt.shape[2], kt.shape[2]
-    block_q, block_k = _block_sizes(
-        seq_q, seq_k, head_dim=qt.shape[-1], dtype=qt.dtype, causal=causal)
-    if seq_q % block_q or seq_k % block_k:
-        # padding keys changes non-causal softmax; fall back to the full
-        # O(S^2)-memory reference — fine for tests, a cliff in real use
+
+    def blocks(sq, sk):
+        return _block_sizes(sq, sk, head_dim=qt.shape[-1], dtype=qt.dtype,
+                            causal=causal)
+
+    block_q, block_k = blocks(seq_q, seq_k)
+    pad = 0
+    if _ragged(seq_q, seq_k, block_q, block_k) and causal and seq_q == seq_k:
+        pad = -seq_q % 128
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+        qt, kt, vt = (jnp.pad(x, widths) for x in (qt, kt, vt))
+        block_q, block_k = blocks(seq_q + pad, seq_k + pad)
+    if _ragged(seq_q + pad, seq_k + pad, block_q, block_k):
+        # the full O(S^2)-memory reference — fine for tests, a cliff in
+        # real use
         import warnings
 
         warnings.warn(
@@ -380,7 +436,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
         )
         return flash_attention_reference(q, k, v, causal=causal, scale=scale)
     out = _flash_bnsh(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
-    return jnp.swapaxes(out, 1, 2)
+    return jnp.swapaxes(out[:, :, :seq_q], 1, 2)
 
 
 def flash_attention_reference(q, k, v, *, causal=False, scale=None):

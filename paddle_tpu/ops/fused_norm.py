@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops import _pl_utils
 from paddle_tpu.ops._pl_utils import imap
 
 
@@ -69,7 +70,7 @@ def _pallas_rows(kernel, x2d, params, out_dtype, rows_block=None):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, hidden), imap(lambda i: (i, 0))),
         out_shape=jax.ShapeDtypeStruct((rows, hidden), out_dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_pl_utils.interpret(),
     )(x2d, *params)
 
 

@@ -12,7 +12,9 @@ activation never round-trips HBM.  Tiles come from the measured autotune
 cache (ops/autotune.py, kernel "matmul_epilogue") with VMEM-safe analytic
 defaults; shapes the grid cannot tile cleanly fall back to plain XLA
 (which fuses simple epilogues well — the kernel exists for the cases it
-does not, and for tile control).
+does not, and for tile control).  So does exact-erf GELU: Mosaic has no
+lowering for erf, so that epilogue is XLA's on every backend and
+MatmulEpiloguePattern does not fuse it.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops import _pl_utils
 from paddle_tpu.ops._pl_utils import imap
 from jax.experimental.pallas import tpu as pltpu
 
@@ -80,15 +83,26 @@ def _pick_tiles(M, K, N, dtype):
     return bm, bk, bn
 
 
+# The epilogues the kernel takes.  Exact-erf GELU is not among them: the
+# TPU compiler cannot lower it ("Unimplemented primitive in Pallas TPU
+# lowering: erfc"), so it is XLA's on every backend.  The one place that
+# knows; MatmulEpiloguePattern asks here before it fuses.
+FUSIBLE_ACTS = frozenset(_ACTS) - {"gelu"}
+
+
 def _fused_2d(x2d, w, bias, act, tiles=None):
     M, K = x2d.shape
     N = w.shape[1]
+    if act not in FUSIBLE_ACTS:
+        return None
     tiles = tiles or _pick_tiles(M, K, N, x2d.dtype)
     if tiles is None:
         return None
     bm, bk, bn = tiles
     has_bias = bias is not None
-    b = bias if has_bias else jnp.zeros((N,), x2d.dtype)
+    # the bias rides as [1, N]: Mosaic refuses a 1-D operand blocked at
+    # bn ("XLA layout does not match Mosaic layout")
+    b = (bias if has_bias else jnp.zeros((N,), x2d.dtype)).reshape(1, N)
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
         functools.partial(_kernel, act=act, k_steps=grid[2], has_bias=has_bias),
@@ -96,12 +110,12 @@ def _fused_2d(x2d, w, bias, act, tiles=None):
         in_specs=[
             pl.BlockSpec((bm, bk), imap(lambda i, j, k: (i, k))),
             pl.BlockSpec((bk, bn), imap(lambda i, j, k: (k, j))),
-            pl.BlockSpec((bn,), imap(lambda i, j, k: (j,))),
+            pl.BlockSpec((1, bn), imap(lambda i, j, k: (0, j))),
         ],
         out_specs=pl.BlockSpec((bm, bn), imap(lambda i, j, k: (i, j))),
         out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=jax.default_backend() != "tpu",
+        interpret=_pl_utils.interpret(),
     )(x2d, w, b)
 
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops import _pl_utils
 from paddle_tpu.ops._pl_utils import imap
 
 
@@ -56,7 +57,7 @@ def _swiglu_apply(x2d, y2d, rows_block=None, cols_block=None):
         ],
         out_specs=pl.BlockSpec((br, bc), imap(lambda i, j: (i, j))),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x2d.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_pl_utils.interpret(),
     )(x2d, y2d)
 
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import time
+import warnings
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
@@ -167,7 +168,9 @@ def reset_lora_stats():
 # accepted = engines whose compiled macro-step adopted a fused config;
 # disabled = engines that kept the unfused ops (measured loss, cache
 # verdict, a failed cache-config parity re-gate, or a mesh-lint
-# violation on the sharded kernel); mesh_fused = the accepted subset
+# violation on the sharded kernel); build_errors = engines whose chain
+# kernel RAISED at build/compile/run (a Mosaic refusal on the chip — kept
+# apart from disabled, which is a verdict); mesh_fused = the accepted subset
 # whose engine is TP-sharded (the shard_map chain over the mesh);
 # mesh_skipped = TP-sharded engines whose pools ride REPLICATED (head
 # counts the mp axis doesn't divide) — no head-local layout to fuse
@@ -178,11 +181,13 @@ _SCHED_DECODE_STATS = {
     "decode_chains_found": 0,
     "decode_chains_accepted": 0,
     "decode_chains_disabled": 0,
+    "decode_chains_build_errors": 0,
     "decode_chains_mesh_skipped": 0,
     "decode_chains_mesh_fused": 0,
     "prefill_chains_found": 0,
     "prefill_chains_accepted": 0,
     "prefill_chains_disabled": 0,
+    "prefill_chains_build_errors": 0,
 }
 
 
@@ -2052,6 +2057,10 @@ class GenerationEngine:
                         cfg["_mesh"] = mesh
                         cfg["_mp_axis"] = self._mp_axis
                         _SCHED_DECODE_STATS["decode_chains_mesh_fused"] += 1
+                elif decision.status == "build_error":
+                    _SCHED_DECODE_STATS["decode_chains_build_errors"] += 1
+                    warnings.warn("decode chain kernel failed to build; "
+                                  f"serving unfused: {decision.error}")
                 else:
                     _SCHED_DECODE_STATS["decode_chains_disabled"] += 1
         self._decode_chain_cfg = cfg
@@ -2095,6 +2104,10 @@ class GenerationEngine:
             if decision.accepted:
                 cfg = dict(decision.config)
                 _SCHED_DECODE_STATS["prefill_chains_accepted"] += 1
+            elif decision.status == "build_error":
+                _SCHED_DECODE_STATS["prefill_chains_build_errors"] += 1
+                warnings.warn("prefill chain kernel failed to build; "
+                              f"serving unfused: {decision.error}")
             else:
                 _SCHED_DECODE_STATS["prefill_chains_disabled"] += 1
         self._prefill_chain_cfg = cfg

@@ -53,21 +53,19 @@ import threading
 def _bootstrap_jax():
     """Same pinning as tests/conftest.py / run_tier1's worker bootstrap:
     CPU platform, exact matmuls, shared persistent compile cache.  The
-    cache is configured through _core/compile_cache.configure — NOT raw
-    jax.config.update calls — so worker processes get the shared helper's
-    exact semantics: gate-zeroing (every small CPU-smoke compile
-    persists), the jax.monitoring hit/miss counters the readiness report
-    carries, and the FLAGS_compilation_cache_dir listener."""
+    cache comes up through _core/compile_cache.enable — NOT raw
+    jax.config.update calls — so worker processes find the directory every
+    other process of the repo uses and get the shared helper's exact
+    semantics: gate-zeroing (every small CPU-smoke compile persists), the
+    jax.monitoring hit/miss counters the readiness report carries, and the
+    FLAGS_compilation_cache_dir listener."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_default_matmul_precision", "highest")
     from paddle_tpu._core import compile_cache
-    from paddle_tpu._core import flags as _flags
 
-    cache = (str(_flags.flag("FLAGS_compilation_cache_dir") or "")
-             or os.environ.get("PADDLE_TPU_TEST_CACHE_DIR", "/tmp/jax_cache"))
-    compile_cache.configure(cache)
+    compile_cache.enable()
 
 
 def _load_factory(spec: str):

@@ -529,8 +529,8 @@ class MatmulEpiloguePattern(RewritePattern):
     (ops/matmul_epilogue.py — the epilogue runs on the f32 accumulator in
     VMEM; the pre-activation never round-trips HBM).
 
-    Anchored at the activation (gelu/silu/relu) whose single input is the
-    single-use output of a linear/matmul op."""
+    Anchored at the activation (tanh-gelu/silu/relu) whose single input is
+    the single-use output of a linear/matmul op."""
 
     name = "matmul_epilogue_fuse"
     root_type = None  # three root types; filtered in match
@@ -586,6 +586,12 @@ class MatmulEpiloguePattern(RewritePattern):
         act = base
         if base == "gelu" and op.kwargs.get("approximate"):
             act = "gelu_tanh"
+        from paddle_tpu.ops.matmul_epilogue import FUSIBLE_ACTS
+
+        if act not in FUSIBLE_ACTS:
+            # leave linear+act to XLA rather than emit a kernel the chip
+            # refuses (exact-erf GELU)
+            return False
 
         entries = [x_entry, w_entry] + ([b_entry] if b_entry is not None else [])
         var_vids, rebuild = _mixed(entries)
@@ -806,6 +812,7 @@ class GenericElementwiseFusionPass:
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
 
+        from paddle_tpu.ops import _pl_utils
         from paddle_tpu.ops._pl_utils import imap
 
         def chain_body(*vals):
@@ -861,7 +868,7 @@ class GenericElementwiseFusionPass:
                           for _ in flat],
                 out_specs=pl.BlockSpec((br, bc), imap(lambda i, j: (i, j))),
                 out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
-                interpret=jax.default_backend() != "tpu",
+                interpret=_pl_utils.interpret(),
             )(*flat)
             return out.reshape(shape)
 

@@ -1,4 +1,4 @@
-"""PP-YOLOE-class single-stage detector (BASELINE.md row: PP-YOLOE).
+"""PP-YOLOE-class single-stage detector (BASELINE.json row: PP-YOLOE).
 
 Reference lineage: the PP-YOLO family served from the reference's vision
 stack — CSP backbone blocks + FPN neck + per-level heads decoded by the

@@ -1,4 +1,4 @@
-"""PP-OCR-class text recognizer (BASELINE.md row: PP-OCRv4).
+"""PP-OCR-class text recognizer (BASELINE.json row: PP-OCRv4).
 
 Reference lineage: the PP-OCR recognition pipeline served from the
 reference's vision/text stack — a conv feature extractor squeezed to a

@@ -172,6 +172,12 @@ def test_v5p_readiness_geometry_and_peaks(tmp_cache):
 
     assert device_peak_tflops("TPU v5p", "tpu") == 459.0
     assert device_peak_tflops("TPU v5 lite", "tpu") == 197.0
+    assert device_peak_tflops("cpu", "cpu") == 0.0
+    # an unknown accelerator is an error, never a default peak — and a bare
+    # "v5" is not a v5p
+    for kind in ("TPU v5", "TPU v9", "Tesla X"):
+        with pytest.raises(ValueError, match="no published peak"):
+            device_peak_tflops(kind, "tpu")
 
     # candidates at training shapes are all VMEM-valid
     for seq in (2048, 4096):

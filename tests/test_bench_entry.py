@@ -1,6 +1,6 @@
-"""The driver's bench entry point (bench.py parent->probe->child) must
-stay runnable — a syntax/import/harness regression here forfeits the
-round's one driver-recorded measurement."""
+"""bench.py must stay runnable — a syntax/import/harness regression here
+forfeits a round's measurement.  Its refusal to measure without an
+accelerator is tested (fast) in tests/test_chip_smoke.py."""
 
 import json
 import os
@@ -24,22 +24,9 @@ def test_bench_smoke_flag_asserts_payload_fields():
     line = next(ln for ln in reversed(out.stdout.splitlines())
                 if ln.startswith("{"))
     payload = json.loads(line)
+    # a CPU run, labelled as one: never a device metric
+    assert payload["platform"] == "cpu" and payload["mfu"] is None
+    assert payload["metric"] == "cpu_smoke_tokens_per_sec"
     assert payload["configs"] and all("mfu" in c for c in payload["configs"])
     sch = payload["detail"]["pipeline"]["schedules"]
     assert sch["ZB-H1"] < sch["1F1B"]
-
-
-@pytest.mark.slow
-def test_bench_parent_harness_cpu_smoke():
-    env = dict(os.environ, PADDLE_TPU_BENCH_CPU="1")
-    out = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py")],
-        capture_output=True, text=True, timeout=840, env=env)
-    assert out.returncode == 0, out.stderr[-500:]
-    line = next(ln for ln in reversed(out.stdout.splitlines())
-                if ln.startswith("{"))
-    payload = json.loads(line)
-    assert payload["metric"] == "llama_pretrain_tokens_per_sec_per_chip"
-    assert payload["value"] > 0
-    assert payload["config"] == "cpu_smoke"

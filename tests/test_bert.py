@@ -1,4 +1,4 @@
-"""BERT/ERNIE family (BASELINE.md finetune north-stars) on the nn stack."""
+"""BERT/ERNIE family (BASELINE.json finetune north-stars) on the nn stack."""
 
 import numpy as np
 import pytest

@@ -1,0 +1,101 @@
+"""chip_smoke.py off the chip: it must refuse to run, and its phase functions
+must pass at a tiny size on the CPU (on-chip-measurement guide, section 2,
+rehearsals 1 and 2) — wrong paths, arguments and sharding rules are found
+here, at no chip time.  Plus the one rule for where the compile cache lives.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+import chip_smoke  # noqa: E402
+from paddle_tpu._core import compile_cache  # noqa: E402
+from paddle_tpu.models.llama import llama_7b, llama_tiny  # noqa: E402
+
+
+@pytest.mark.parametrize("script,args", [
+    ("chip_smoke.py", []), ("chip_smoke.py", ["--four-chips"]),
+    ("bench.py", [])])  # bench.py's measuring path; --smoke is its CPU twin
+def test_no_accelerator_no_result(script, args):
+    """Run where jax finds no TPU, the script exits nonzero and prints no
+    success line: no `"ok": true`, no metric payload, nothing on stdout."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(_REPO, script), *args],
+                         capture_output=True, text=True, timeout=300, env=env)
+    assert out.returncode != 0, out.stdout[-500:]
+    assert out.stdout.strip() == "", out.stdout[-500:]
+    assert "TPU" in out.stderr or "accelerator" in out.stderr
+
+
+def test_train_and_serve_phases_on_cpu_tiny():
+    dev = jax.devices()[0]
+    cfg = llama_tiny(dtype="bfloat16")
+    train = chip_smoke.train_phase(cfg, batch=2, seq=64, steps=4, seed=0,
+                                   device=dev)
+    assert train["losses"][-1] < train["losses"][0]
+    # no Pallas on the CPU: the step IS its plain-jnp twin
+    assert train["losses"][0] == train["twin"]
+    serve = chip_smoke.serve_phase(cfg, prompt_lens=(5, 16, 37, 64, 100),
+                                   max_new_tokens=12, seed=0, device=dev,
+                                   num_blocks=64)
+    assert sorted(serve["results"]) == ["r0", "r1", "r2", "r3", "r4"]
+
+
+def test_a_failed_check_raises():
+    """No try/except around a phase: a check that fails ends the run."""
+    cfg = llama_tiny(dtype="bfloat16")
+    wrong = jax.devices()[1]  # nothing of the step lives there
+    with pytest.raises(RuntimeError, match="chip_smoke check failed"):
+        chip_smoke.train_phase(cfg, batch=2, seq=64, steps=2, seed=0,
+                               device=wrong)
+
+
+def test_four_chip_phase_on_virtual_devices():
+    out = chip_smoke.four_chip_phase(llama_tiny(dtype="bfloat16"), batch=2,
+                                     seq=64, steps=3, seed=0,
+                                     devices=jax.devices())
+    assert len(out["losses"]) == len(out["reference"]) == 3
+
+
+def test_depth_is_what_sixteen_gigabytes_force():
+    """llama_7b widths on a 16 GB chip: AdamW + fp32 master weights leave
+    room for two layers; bf16 serving is capped, not forced."""
+    limit = int(15.75 * 2**30)
+    depth, why = chip_smoke.choose_depth("train", llama_7b(), limit, batch=1,
+                                         seq=2048)
+    assert depth == 2 and "3 would need" in why
+    assert chip_smoke.choose_depth("serve", llama_7b(), limit,
+                                   ceiling=8)[0] == 8
+    with pytest.raises(RuntimeError, match="even one layer"):
+        chip_smoke.choose_depth("train", llama_7b(), 2**30, batch=1, seq=2048)
+
+
+def test_compile_cache_directory_rule(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set -> code sets no directory, whatever it is
+    asked; unset -> the fixed <checkout>/.jax_cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setattr(cc, "reset_cache", lambda: None)
+
+    monkeypatch.setattr(compile_cache, "_configured_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert compile_cache.configure("/somewhere/else") == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in dict(calls)
+
+    calls.clear()
+    monkeypatch.setattr(compile_cache, "_configured_dir", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(_REPO, ".jax_cache")
+    assert compile_cache.default_dir() == want
+    assert compile_cache.enable() == want
+    assert ("jax_compilation_cache_dir", want) in calls

@@ -234,7 +234,8 @@ def test_respawned_worker_boots_with_persistent_cache_hits(
                                             reset_cluster_stats)
 
     cache = tmp_path / "fresh_cache"
-    monkeypatch.setenv("PADDLE_TPU_TEST_CACHE_DIR", str(cache))
+    # workers inherit it; jax reads it itself and code sets no directory
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
     reset_cluster_stats()
     c = EngineCluster(_MODEL_SPEC, num_replicas=1, num_prefill=0,
                       engine_kwargs=_EKW, workdir=str(tmp_path / "wd"),

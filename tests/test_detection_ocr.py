@@ -1,4 +1,4 @@
-"""PP-YOLOE-class detector + PP-OCR-class recognizer (BASELINE.md rows).
+"""PP-YOLOE-class detector + PP-OCR-class recognizer (BASELINE.json rows).
 
 Reference lineage: the PP-YOLO family (yolo_box decode,
 paddle/phi/kernels/gpu/yolo_box_kernel.cu) and the PP-OCR recognition

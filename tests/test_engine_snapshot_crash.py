@@ -30,10 +30,10 @@ jax.config.update("jax_platforms", "cpu")
 # pinned like tests/conftest.py and run_tier1's worker bootstrap
 jax.config.update("jax_default_matmul_precision", "highest")
 
-cache = os.environ.get("PADDLE_TPU_TEST_CACHE_DIR", "/tmp/jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache)
+from paddle_tpu._core import compile_cache
+
+compile_cache.enable()  # the suite's one cache (tests/conftest.py)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
@@ -98,7 +98,6 @@ def _run_server(tmp_path, snap_dir, out, kill_point="", kill_at=0,
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo_root + os.pathsep + os.environ.get("PYTHONPATH", ""))
-    env.setdefault("PADDLE_TPU_TEST_CACHE_DIR", "/tmp/jax_cache")
     cmd = [sys.executable, str(script), str(snap_dir), str(out),
            kill_point, str(kill_at), mode]
     if popen:

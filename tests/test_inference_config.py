@@ -131,7 +131,9 @@ def test_config_switches_work_or_warn(tmp_path):
     # the TRT precision request DID carry over
     assert cfg._precision == "float16"
     # working switches do their thing quietly
-    cfg.set_optim_cache_dir("/tmp/jax_cache")
+    from paddle_tpu._core import compile_cache
+
+    cfg.set_optim_cache_dir(compile_cache.enable())  # the suite's own cache
     cfg.disable_glog_info()
     with pytest.raises(ValueError):
         cfg.set_precision("int3")

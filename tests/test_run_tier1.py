@@ -127,8 +127,8 @@ def test_crash_in_one_shard_reported_siblings_complete(tmp_path):
         """)
     s_crash = Shard(name="crashy", files=[crash])
     s_good = Shard(name="goody", files=[good])
-    run_shard(s_crash, cache_dir=str(tmp_path / "cache"), timeout=120)
-    run_shard(s_good, cache_dir=str(tmp_path / "cache"), timeout=120)
+    run_shard(s_crash, timeout=120)
+    run_shard(s_good, timeout=120)
     # the crash is contained and NAMED...
     assert s_crash.crashed and s_crash.signal == signal.SIGSEGV
     assert not s_crash.ok
@@ -146,7 +146,7 @@ def test_plain_failure_parsed_not_crash(tmp_path):
             assert False, "genuine failure"
         """)
     shard = Shard(name="mixed", files=[mixed])
-    run_shard(shard, cache_dir=str(tmp_path / "cache"), timeout=120)
+    run_shard(shard, timeout=120)
     assert shard.rc == 1 and not shard.crashed
     assert shard.counts.get("passed") == 1
     assert shard.counts.get("failed") == 1
@@ -167,7 +167,7 @@ def test_isolated_shard_retries_intermittent_crash(tmp_path):
             assert True
         """)
     shard = Shard(name="iso:flaky", files=[flaky], isolated=True)
-    run_shard(shard, cache_dir=str(tmp_path / "cache"), timeout=120,
+    run_shard(shard, timeout=120,
               retry_crashed=1)
     assert shard.ok and shard.retries == 1
     assert shard.counts.get("passed") == 1
@@ -176,7 +176,7 @@ def test_isolated_shard_retries_intermittent_crash(tmp_path):
     # the known communicator modules, not a blanket flake-hider
     os.remove(str(tmp_path / "ran_once"))
     shard2 = Shard(name="flaky2", files=[flaky], isolated=False)
-    run_shard(shard2, cache_dir=str(tmp_path / "cache"), timeout=120,
+    run_shard(shard2, timeout=120,
               retry_crashed=1)
     assert shard2.crashed and shard2.retries == 0
 
@@ -189,28 +189,31 @@ def test_always_crashing_isolated_shard_exhausts_retries(tmp_path):
             os.kill(os.getpid(), signal.SIGKILL)
         """)
     shard = Shard(name="iso:hard", files=[hard], isolated=True)
-    run_shard(shard, cache_dir=str(tmp_path / "cache"), timeout=120,
+    run_shard(shard, timeout=120,
               retry_crashed=1)
     assert shard.crashed and shard.signal == signal.SIGKILL
     assert shard.retries == 1  # retried once, then reported honestly
 
 
-def test_cache_dir_env_reaches_shard(tmp_path):
+def test_shards_find_the_cache_by_the_one_rule(tmp_path, monkeypatch):
+    """A shard is told no cache directory: it inherits
+    JAX_COMPILATION_CACHE_DIR where the parent has it, and the retired
+    test-cache variable is never exported."""
     probe = _write(tmp_path, "test_probe_env.py", """\
         import os
 
         def test_cache_env():
-            assert os.environ["PADDLE_TPU_TEST_CACHE_DIR"] == \\
+            assert "PADDLE_TPU_TEST_CACHE_DIR" not in os.environ
+            assert os.environ["JAX_COMPILATION_CACHE_DIR"] == \\
                 os.environ["_EXPECTED_CACHE"]
         """)
     cache = str(tmp_path / "shared_cache")
-    os.environ["_EXPECTED_CACHE"] = cache
-    try:
-        shard = Shard(name="env", files=[probe])
-        run_shard(shard, cache_dir=cache, timeout=120)
-        assert shard.ok and shard.counts.get("passed") == 1
-    finally:
-        del os.environ["_EXPECTED_CACHE"]
+    monkeypatch.setenv("_EXPECTED_CACHE", cache)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    monkeypatch.delenv("PADDLE_TPU_TEST_CACHE_DIR", raising=False)
+    shard = Shard(name="env", files=[probe])
+    run_shard(shard, timeout=120)
+    assert shard.ok and shard.counts.get("passed") == 1
 
 
 # -------------------------------------------- in-suite isolation helper

@@ -52,10 +52,10 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "highest")
-cache = os.environ.get("PADDLE_TPU_TEST_CACHE_DIR", "/tmp/jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache)
+from paddle_tpu._core import compile_cache
+
+compile_cache.enable()  # the suite's one cache (tests/conftest.py)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 from paddle_tpu.serving.cluster import EngineCluster, cluster_stats
 
@@ -121,7 +121,6 @@ def _run_driver(tmp_path, workdir, out, router_kill="", worker_role="",
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo_root + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
-    env.setdefault("PADDLE_TPU_TEST_CACHE_DIR", "/tmp/jax_cache")
     cmd = [sys.executable, str(script), str(workdir), str(out),
            _MODEL_SPEC, router_kill, worker_role, worker_kill,
            str(snapshot_interval), str(standby), str(wait_standby),

@@ -1,0 +1,174 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler
+(Mosaic) at llama_7b widths for a DESCRIBED v5e chip — no chip attached,
+no chip time (on-chip-measurement guide, section 2, rehearsal 3).
+
+Interpret-mode tests cannot see what Mosaic refuses (a primitive without
+a TPU lowering, a block that overflows VMEM); these can, in a second or
+two each.  A compile that passes is not a chip run: nothing executes.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and under xdist every
+worker imports every test file.  All compiles run in this process, and
+all of them live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops import _pl_utils
+
+# llama_7b widths (models.llama.llama_7b)
+HIDDEN, HEADS, HEAD_DIM, FFN = 4096, 32, 128, 11008
+ROWS = 4096  # tokens per step: batch 2 x seq 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Steer the ops tier to its TPU branch (Mosaic, not the interpreter)
+    and keep the persistent compile cache out of it: an executable for a
+    described chip can be written there but never read back here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(_pl_utils, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # conftest pins fp32-exact matmuls for the CPU numerics tests; the chip
+    # runs the MXU default, and Mosaic refuses bf16 operands at "highest"
+    with jax.default_matmul_precision("default"):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes, sharding, dtype=jnp.bfloat16):
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=sharding) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "kernel not in the program"
+    return compiled
+
+
+def _flash(q, k, v):
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=True)
+
+
+def _flash_loss(q, k, v):
+    return _flash(q, k, v).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("batch,seq", [(2, 2048), (1, 4096)])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(one_chip, mosaic, batch, seq, direction):
+    fn = _flash if direction == "fwd" else jax.grad(_flash_loss, (0, 1, 2))
+    shape = (batch, seq, HEADS, HEAD_DIM)
+    _compile(fn, shape, shape, shape, sharding=one_chip)
+
+
+@pytest.mark.parametrize("seq", [5, 37, 200])
+def test_flash_attention_compiles_for_any_prompt_length(one_chip, mosaic, seq):
+    """The serving engine prefills prompts as they come.  A 37-row block is
+    refused by Mosaic ("cannot statically prove that index in dimension 2
+    is a multiple of 8"); causal self-attention pads to the block instead."""
+    shape = (1, seq, HEADS, HEAD_DIM)
+    _compile(_flash, shape, shape, shape, sharding=one_chip)
+
+
+@pytest.mark.parametrize("direction,seq,longest", [
+    ("fwd", 16384, 16000), ("bwd", 8192, 5248)])
+def test_flash_attention_names_its_length_limit(one_chip, mosaic, direction,
+                                                seq, longest):
+    """Past these lengths Mosaic refuses the kernels (whole-sequence K/V,
+    resp. Q/dO/lse/delta, of one head resident in VMEM).  The error is ours
+    and names the limit; there is no switch to the O(S^2) reference."""
+    fn = _flash if direction == "fwd" else jax.grad(_flash_loss, (0, 1, 2))
+    shape = (1, seq, HEADS, HEAD_DIM)
+    with pytest.raises(ValueError, match=f"longest .* is {longest}"):
+        _compile(fn, shape, shape, shape, sharding=one_chip)
+
+
+def test_flash_attention_limit_is_the_compilers(one_chip, mosaic):
+    """The stated limit is tight: the longest length the guard admits
+    compiles in a program where XLA cannot relieve the kernel (it moves
+    operands of a bare kernel into VMEM itself, which hides the limit)."""
+    def loss(x, w):
+        b, s, d = x.shape
+        q, k, v = ((x @ w).reshape(b, s, HEADS, HEAD_DIM) for _ in range(3))
+        return _flash_loss(q, k, v)
+
+    x = jax.ShapeDtypeStruct((1, 5248, HIDDEN), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((HIDDEN, HIDDEN), jnp.bfloat16, sharding=one_chip)
+    jax.jit(jax.grad(loss, 1)).lower(x, w).compile()
+
+
+@pytest.mark.parametrize("case", ["fwd", "residual", "bwd"])
+def test_fused_rms_norm_compiles(one_chip, mosaic, case):
+    from paddle_tpu.ops import fused_rms_norm
+
+    x, w = (ROWS, HIDDEN), (HIDDEN,)
+    if case == "fwd":
+        _compile(lambda x, w: fused_rms_norm(x, w), x, w, sharding=one_chip)
+    elif case == "residual":
+        _compile(lambda x, r, w: fused_rms_norm(x, w, residual=r), x, x, w,
+                 sharding=one_chip)
+    else:
+        # the backward is analytic jnp; the kernel rides its forward rule,
+        # which stays in the program only if the loss needs the output
+        _compile(jax.grad(lambda x, w: (fused_rms_norm(x, w)
+                                        .astype(jnp.float32) ** 2).sum(),
+                          (0, 1)),
+                 x, w, sharding=one_chip)
+
+
+def test_swiglu_compiles(one_chip, mosaic):
+    from paddle_tpu.ops import swiglu
+
+    _compile(swiglu, (ROWS, FFN), (ROWS, FFN), sharding=one_chip)
+
+
+# Every activation MatmulEpiloguePattern can hand the kernel.  The models in
+# paddle_tpu/models reach the pattern with exact-erf GELU (bert, gpt) and
+# with silu (llama, where SwiGLUPattern takes it first); exact GELU is
+# declined by the pattern — see the next test — so what remains fusible is:
+@pytest.mark.parametrize("act", ["relu", "silu", "gelu_tanh"])
+def test_matmul_bias_act_compiles(one_chip, mosaic, act):
+    from paddle_tpu.ops import matmul_bias_act
+
+    _compile(lambda x, w, b: matmul_bias_act(x, w, b, act),
+             (ROWS, HIDDEN), (HIDDEN, FFN), (FFN,), sharding=one_chip)
+
+
+def test_matmul_bias_act_exact_gelu_stays_with_xla(one_chip, mosaic):
+    """Mosaic has no lowering for erf ("Unimplemented primitive in Pallas
+    TPU lowering: erfc"), so exact GELU never enters the kernel: the op
+    computes it with plain XLA, numerics unchanged."""
+    from paddle_tpu.ops import matmul_bias_act
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((ROWS, HIDDEN), (HIDDEN, FFN), (FFN,))]
+    compiled = jax.jit(
+        lambda x, w, b: matmul_bias_act(x, w, b, "gelu")).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

@@ -17,9 +17,11 @@ leaking into `slow`.  This runner fixes both mechanically:
   shard by default, with one automatic retry on signal-death (the crash
   is intermittent infra, not an assertion failure; genuine test failures
   never retry).
-- **Shared compile cache**: every shard points at ONE persistent XLA
-  compile-cache dir (tests/conftest.py honors PADDLE_TPU_TEST_CACHE_DIR),
-  so repeated model compiles are warm across shards and across runs.
+- **Shared compile cache**: every shard finds ONE persistent XLA
+  compile-cache dir by the rule in paddle_tpu/_core/compile_cache.py
+  (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache — applied by
+  tests/conftest.py), so repeated model compiles are warm across shards
+  and across runs.
 
 Usage:
   python tools/run_tier1.py                 # full tier-1, default shards
@@ -89,8 +91,6 @@ ISOLATED_DEFAULT = (
     # the same containment.
     "test_zb_schedules.py",
 )
-
-DEFAULT_CACHE_DIR = "/tmp/jax_cache"
 
 _PYTEST_BASE = ["-q", "--continue-on-collection-errors",
                 "-p", "no:cacheprovider", "-p", "no:xdist",
@@ -164,15 +164,14 @@ def _parse_counts(output):
     return counts
 
 
-def run_shard(shard, marker="not slow", cache_dir=DEFAULT_CACHE_DIR,
-              timeout=1800, extra_args=(), retry_crashed=1, python=None):
+def run_shard(shard, marker="not slow", timeout=1800, extra_args=(),
+              retry_crashed=1, python=None):
     """Run one shard in a subprocess; fills the Shard's result fields.
     Signal-deaths of ISOLATED shards retry up to retry_crashed times —
     the 8-device communicator crash is intermittent infra, and a retry
     that passes means the tests pass; assertion failures never retry."""
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PADDLE_TPU_TEST_CACHE_DIR"] = cache_dir
     cmd = [python or sys.executable, "-m", "pytest", *shard.files,
            *_PYTEST_BASE, "-m", marker, *extra_args]
     attempts = 1 + (retry_crashed if shard.isolated else 0)
@@ -217,10 +216,6 @@ def main(argv=None):
                     default=max(1, min(6, (os.cpu_count() or 2) // 4)),
                     help="concurrent shard subprocesses")
     ap.add_argument("-m", "--marker", default="not slow")
-    ap.add_argument("--cache-dir", default=DEFAULT_CACHE_DIR,
-                    help="persistent XLA compile cache shared by all "
-                         "shards (tests/conftest.py reads "
-                         "PADDLE_TPU_TEST_CACHE_DIR)")
     ap.add_argument("--timeout", type=int, default=1800,
                     help="per-shard wall clock limit (seconds)")
     ap.add_argument("--retry-crashed", type=int, default=1,
@@ -243,15 +238,14 @@ def main(argv=None):
                   f"{' '.join(os.path.basename(f) for f in shard.files)}")
         return 0
 
-    os.makedirs(args.cache_dir, exist_ok=True)
     print(f"run_tier1: {len(plan)} shards "
           f"({sum(s.isolated for s in plan)} isolated), jobs={args.jobs}, "
-          f"marker={args.marker!r}, cache={args.cache_dir}")
+          f"marker={args.marker!r}")
     t0 = time.monotonic()
     with concurrent.futures.ThreadPoolExecutor(args.jobs) as pool:
         futures = [
             pool.submit(run_shard, shard, marker=args.marker,
-                        cache_dir=args.cache_dir, timeout=args.timeout,
+                        timeout=args.timeout,
                         extra_args=tuple(args.pytest_args),
                         retry_crashed=args.retry_crashed)
             for shard in plan
@@ -299,16 +293,15 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "highest")
-jax.config.update("jax_compilation_cache_dir", {cache_dir!r})
+from paddle_tpu._core import compile_cache
+compile_cache.enable()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 import importlib
 getattr(importlib.import_module({module!r}), {func!r})()
 """
 
 
-def run_isolated_test(module, func, retries=2, timeout=300,
-                      cache_dir=None):
+def run_isolated_test(module, func, retries=2, timeout=300):
     """Run `module.func()` in a bootstrapped subprocess (8 virtual CPU
     devices, persistent compile cache — the tests/conftest.py environment)
     and raise AssertionError on failure.  A signal-death retries up to
@@ -316,10 +309,7 @@ def run_isolated_test(module, func, retries=2, timeout=300,
     intermittent infra, while an assertion failure (rc > 0) fails
     immediately.  This is how a SIGSEGV-prone payload runs INSIDE tier-1
     without being able to kill the suite process."""
-    cache_dir = cache_dir or os.environ.get("PADDLE_TPU_TEST_CACHE_DIR",
-                                            DEFAULT_CACHE_DIR)
-    code = _WORKER_BOOTSTRAP.format(cache_dir=cache_dir, module=module,
-                                    func=func)
+    code = _WORKER_BOOTSTRAP.format(module=module, func=func)
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     last_rc, last_out = None, ""
