@@ -242,6 +242,15 @@ def apply(name, fn, *args, n_outputs=None, **kwargs):
     return _apply_impl(name, fn, *args, n_outputs=n_outputs, **kwargs)
 
 
+_funnel_calls = 0  # every op that went through _apply_impl, this process
+
+
+def funnel_calls() -> int:
+    """Ops executed through the funnel so far: a caller counts the eager
+    dispatches of a region by the difference (serving's admit_eager_ops)."""
+    return _funnel_calls
+
+
 def _apply_impl(name, fn, *args, n_outputs=None, **kwargs):
     """Execute op `fn` over Tensor/raw args, recording a grad node if needed.
 
@@ -260,6 +269,8 @@ def _apply_impl(name, fn, *args, n_outputs=None, **kwargs):
     cached jax.jit of fn, the grad path a cached jitted jax.vjp pair — the
     per-op Python retrace cost is paid once per signature, not per call.
     """
+    global _funnel_calls
+    _funnel_calls += 1
     args = _maybe_amp_cast(name, args)
     tensors = [a for a in args if isinstance(a, Tensor)]
     if _state.touch_recorders:

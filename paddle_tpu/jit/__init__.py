@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from paddle_tpu._core import random as rng_mod
 from paddle_tpu._core.autograd import no_grad
 from paddle_tpu._core.tensor import Parameter, Tensor
+from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["to_static", "TrainStep", "not_to_static", "save", "load", "ignore_module"]
 
@@ -155,7 +156,7 @@ class _StaticFunction:
                 proto[i] = v
 
             @jax.jit
-            def compiled(state_vals, t_vals, kw_vals, key):
+            def static_function(state_vals, t_vals, kw_vals, key):
                 originals = [t._value for t in state]
                 try:
                     for t, v in zip(state, state_vals):
@@ -180,7 +181,7 @@ class _StaticFunction:
                 sv = list(vals[:n_s])
                 tv = list(vals[n_s:n_s + n_t])
                 kv = list(vals[n_s + n_t:])
-                out = compiled(sv, tv, kv, _key)
+                out = static_function(sv, tv, kv, _key)
                 flat_out, out_tree = jax.tree_util.tree_flatten(out)
                 holder["tree"] = out_tree
                 return tuple(flat_out) if len(flat_out) != 1 else flat_out[0]
@@ -308,9 +309,10 @@ class TrainStep:
             # GradScaler state is device tensors (amp/__init__.py) and joins
             # the state list.
             params = [p for p in self.optimizer._parameter_list if not p.stop_gradient]
-            with _host_device():
-                self.optimizer._journaled_step(params)
-            self._state = self._collect_state()
+            with RecordEvent("jit.train_step.build.optimizer_state"):
+                with _host_device():
+                    self.optimizer._journaled_step(params)
+                self._state = self._collect_state()
             self._build()
 
     @staticmethod
@@ -341,10 +343,21 @@ class TrainStep:
         lint_train_step(self, *batch, raise_on_error=True)
 
     def __call__(self, *batch):
-        first_build = self._compiled is None
-        self._ensure_built()
-        if first_build:
-            self._maybe_mesh_lint(batch)
+        """One step.  Spans: `jit.train_step` around the call; on the call
+        that builds the step, `jit.train_step.build` with its two halves —
+        `.build.optimizer_state` (the accumulators, made on the host CPU
+        backend) and `.build.trace` (the first call of the jitted step:
+        jax traces, lowers and compiles or reads the cache there, then
+        enqueues); on every later call `jit.train_step.dispatch`."""
+        with RecordEvent("jit.train_step"):
+            if self._compiled is not None:
+                return self._dispatch(batch, "jit.train_step.dispatch")
+            with RecordEvent("jit.train_step.build"):
+                self._ensure_built()
+                self._maybe_mesh_lint(batch)
+                return self._dispatch(batch, "jit.train_step.build.trace")
+
+    def _dispatch(self, batch, span):
         batch_vals = jax.tree_util.tree_map(_unwrap, batch, is_leaf=lambda x: isinstance(x, Tensor))
         key = rng_mod.next_key()
         if self.optimizer._lr_scheduler is not None:
@@ -354,7 +367,8 @@ class TrainStep:
         # the plain path stays free of per-step flatten cost
         step_fn = (self._aot.get(self._batch_sig(batch_vals), self._compiled)
                    if self._aot else self._compiled)
-        new_state, loss_val = step_fn(state_vals, batch_vals, key)
+        with RecordEvent(span):
+            new_state, loss_val = step_fn(state_vals, batch_vals, key)
         for t, v in zip(self._state, new_state):
             t._bind(v)
         return Tensor(loss_val)
@@ -405,8 +419,10 @@ class TrainStep:
         model, optimizer, loss_fn, scaler = self.model, self.optimizer, self.loss_fn, self.scaler
         state = self._state
 
+        # the function's name is the program's: `jit_train_step` on the
+        # trace's XLA Modules line and in PjitFunction(...) host events
         @functools.partial(jax.jit, donate_argnums=(0,))
-        def compiled(state_vals, batch_vals, key):
+        def train_step(state_vals, batch_vals, key):
             originals = [t._value for t in state]
             grads_saved = [getattr(t, "grad", None) for t in state]
             try:
@@ -436,7 +452,7 @@ class TrainStep:
                     t.grad = g
                     t._grad_node = None
 
-        self._compiled = compiled
+        self._compiled = train_step
 
 
 def save(layer, path, input_spec=None, **configs):

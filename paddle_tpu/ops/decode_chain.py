@@ -682,6 +682,7 @@ def _wrap_call(spec, kernel, grid, in_specs, out_specs, out_shape, aliases):
             out_shape=out_shape,
             input_output_aliases=aliases,
             interpret=_pl_utils.interpret(),
+            name="decode_chain",
         )(*pool_leaves, q, kn, vn, tables, lens)
         if int8:
             o, kd, ks, vd, vs = outs
@@ -962,6 +963,7 @@ def _build_prefill(spec, config):
             out_specs=qtile((1, s, n, h)),
             out_shape=jax.ShapeDtypeStruct((1, s, n, h), dt),
             interpret=_pl_utils.interpret(),
+            name="prefill_chain",
         )(q, k, v)
 
     return fused

@@ -204,6 +204,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -329,6 +330,7 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
         out_specs=pl.BlockSpec((None, None, block_q, head_dim), imap(lambda b, n, i: (b, n, i, 0))),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k_rep, v_rep, do, lse_b, delta_b)
 
     dk_rep, dv_rep = pl.pallas_call(
@@ -351,6 +353,7 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
             jax.ShapeDtypeStruct(v_rep.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k_rep, v_rep, do, lse_b, delta_b)
 
     if group > 1:

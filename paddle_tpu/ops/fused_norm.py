@@ -71,6 +71,7 @@ def _pallas_rows(kernel, x2d, params, out_dtype, rows_block=None):
         out_specs=pl.BlockSpec((br, hidden), imap(lambda i: (i, 0))),
         out_shape=jax.ShapeDtypeStruct((rows, hidden), out_dtype),
         interpret=_pl_utils.interpret(),
+        name="rms_norm_fwd",
     )(x2d, *params)
 
 

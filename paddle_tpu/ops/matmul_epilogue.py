@@ -116,6 +116,7 @@ def _fused_2d(x2d, w, bias, act, tiles=None):
         out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=_pl_utils.interpret(),
+        name="matmul_epilogue",
     )(x2d, w, b)
 
 

@@ -58,6 +58,7 @@ def _swiglu_apply(x2d, y2d, rows_block=None, cols_block=None):
         out_specs=pl.BlockSpec((br, bc), imap(lambda i, j: (i, j))),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x2d.dtype),
         interpret=_pl_utils.interpret(),
+        name="swiglu_fwd",
     )(x2d, y2d)
 
 

@@ -5,8 +5,12 @@ HostTracer + CudaTracer/CUPTI, chrome-trace export, statistics tables,
 schedules) over paddle/fluid/platform/profiler/.
 
 TPU-native composition:
-- **Host tracer**: RecordEvent instrumentation (used by the op funnel when a
-  profiler is active) collecting ns-resolution host spans.
+- **Host tracer**: RecordEvent, the program's one span primitive.  Every span
+  is a `jax.profiler.TraceAnnotation`, so it lands on the clock of whatever
+  trace session is open (this module's Profiler or a caller's own
+  `jax.profiler.start_trace`) and costs about a microsecond when none is;
+  while a Profiler records, spans also go to its host buffer with their
+  parent and arguments.  SPAN_NAMES lists every name the program emits.
 - **Device tracer**: jax.profiler start/stop_trace — XLA's XPlane/TensorBoard
   trace IS the CUPTI analog (per-kernel device timeline compiled in by XLA).
 - Export: chrome trace JSON from host spans (device timeline lives in the
@@ -15,12 +19,12 @@ TPU-native composition:
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
+import tempfile
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from enum import Enum
 
 import jax
@@ -33,7 +37,23 @@ __all__ = [
     "make_scheduler",
     "export_chrome_tracing",
     "load_profiler_result",
+    "SPAN_NAMES",
 ]
+
+# Every span name the program emits, layer by layer (PERF.md section 3 says
+# which metric reads each).  `op::<name>` (one per eager op, only while a
+# Profiler records) is the one family not listed.
+SPAN_NAMES = (
+    # entry points: jit.TrainStep.__call__
+    "jit.train_step", "jit.train_step.build",
+    "jit.train_step.build.optimizer_state", "jit.train_step.build.trace",
+    "jit.train_step.dispatch",
+    # admission, scheduler, cache manager: serving.GenerationEngine
+    "serving.admit", "serving.admit.match", "serving.admit.prefill",
+    "serving.admit.first_token", "serving.admit.pour",
+    "serving.step", "serving.step.schedule", "serving.step.dispatch",
+    "serving.step.sync", "serving.step.retire",
+)
 
 _active_profiler = None  # checked by the op funnel (cheap global)
 _last_profiler = None  # most recent stopped Profiler (export_protobuf)
@@ -60,6 +80,8 @@ class _Span:
     end_ns: int
     tid: int
     category: str = "host"
+    parent: str | None = None  # the span open on this thread when it began
+    args: dict = field(default_factory=dict)  # e.g. rid: one id per request
 
 
 class _HostEventBuffer:
@@ -72,33 +94,54 @@ class _HostEventBuffer:
             self.spans.append(span)
 
 
-class RecordEvent:
-    """Host span (reference platform/profiler RecordEvent).  Also annotates
-    the XLA device trace via jax.profiler.TraceAnnotation so host spans line
-    up with device kernels in TensorBoard."""
+_open_spans = threading.local()  # .stack: this thread's open RecordEvents
 
-    def __init__(self, name: str, event_type=None):
+
+class RecordEvent:
+    """Host span (reference platform/profiler RecordEvent): name, start,
+    end, the span that caused it, and keyword arguments (spans of one
+    request carry its `rid`).
+
+    `begin()` always enters a `jax.profiler.TraceAnnotation(name, **args)`:
+    with no trace session open that costs about a microsecond and records
+    nothing; with one open — a Profiler's or the caller's own
+    `jax.profiler.start_trace` — the span is on the trace's clock beside
+    the device's operations, its arguments are the event's stats, and its
+    parent is the event that contains it on the same thread line.  While a
+    Profiler records, `end()` also appends the span to its host buffer."""
+
+    def __init__(self, name: str, event_type=None, **args):
         self.name = name
+        self.args = args
+        self.parent = None
         self._ann = None
         self._t0 = None
 
     def begin(self):
-        prof = _active_profiler
+        stack = getattr(_open_spans, "stack", None)
+        if stack is None:
+            stack = _open_spans.stack = []
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
-        if prof is not None:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
         return self
 
     def end(self):
+        if self._ann is None:  # never begun, or ended twice
+            return
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        stack = getattr(_open_spans, "stack", ())
+        if self in stack:  # not the top when begin()/end() pairs cross
+            stack.remove(self)
         prof = _active_profiler
-        if prof is not None and self._t0 is not None:
-            prof._buffer.add(
-                _Span(self.name, self._t0, time.perf_counter_ns(), threading.get_ident())
-            )
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        if prof is not None:
+            prof._buffer.add(_Span(self.name, self._t0, t1,
+                                   threading.get_ident(), parent=self.parent,
+                                   args=self.args))
 
     __enter__ = begin
 
@@ -141,13 +184,28 @@ class Profiler:
         self._buffer = _HostEventBuffer()
         self._step = 0
         self._recording = False
-        self._xplane_dir = None
+        # where start() put the device trace: jax writes
+        # <trace_dir>/plugins/profile/<time>/*.xplane.pb (None: timer_only
+        # or no TPU target)
+        self.trace_dir = None
+        self._tracing = False
         self._step_spans = []
         self._step_t0 = None
 
     # ---------------------------------------------------------------- state
     def start(self):
+        """Open the device trace, then start recording host spans.  The
+        xplane goes under the directory the `on_trace_ready` exporter names
+        (`export_chrome_tracing(dir_name)`), else a new temporary one; either
+        way `self.trace_dir` says where.  A `jax.profiler.start_trace` that
+        fails (another session is open) raises: a Profiler that silently
+        traced nothing is worse than none."""
         global _active_profiler
+        if not self.timer_only and ProfilerTarget.TPU in self.targets:
+            self.trace_dir = (getattr(self.on_trace_ready, "dir_name", None)
+                              or tempfile.mkdtemp(prefix="paddle_tpu_profile_"))
+            jax.profiler.start_trace(self.trace_dir)
+            self._tracing = True
         if self.scheduler is not None:
             state = self.scheduler(0)
             _active_profiler = (
@@ -156,28 +214,17 @@ class Profiler:
         else:
             _active_profiler = self
         self._recording = True
-        if not self.timer_only and ProfilerTarget.TPU in self.targets:
-            self._xplane_dir = os.path.abspath("profiler_log/xplane")
-            os.makedirs(self._xplane_dir, exist_ok=True)
-            try:
-                jax.profiler.start_trace(self._xplane_dir)
-            except Exception:
-                self._xplane_dir = None
         self._step_t0 = time.perf_counter_ns()
         return self
 
     def stop(self):
-        global _active_profiler
-        if self._xplane_dir is not None:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._xplane_dir = None
+        global _active_profiler, _last_profiler
         self._recording = False
         _active_profiler = None
-        global _last_profiler
         _last_profiler = self
+        if self._tracing:
+            self._tracing = False
+            jax.profiler.stop_trace()
         if self.on_trace_ready is not None:
             self.on_trace_ready(self)
 
@@ -264,7 +311,22 @@ class Profiler:
         return out
 
 
-def export_chrome_tracing(profiler: Profiler, path: str):
+def export_chrome_tracing(profiler, path: str | None = None):
+    """Write `profiler`'s host spans to `path` as chrome-trace JSON; each
+    event's `args` hold its parent span and its keyword arguments (`rid`).
+
+    Called with a directory name instead (reference
+    profiler.export_chrome_tracing(dir_name)), returns an `on_trace_ready`
+    handler that writes `<dir_name>/host_spans.json` when the Profiler
+    stops; the Profiler puts its device trace under the same directory."""
+    if isinstance(profiler, (str, os.PathLike)):
+        dir_name = os.fspath(profiler)
+
+        def handle(prof):
+            export_chrome_tracing(prof, os.path.join(dir_name, "host_spans.json"))
+
+        handle.dir_name = dir_name
+        return handle
     events = []
     for s in profiler._buffer.spans:
         events.append(
@@ -276,11 +338,12 @@ def export_chrome_tracing(profiler: Profiler, path: str):
                 "dur": (s.end_ns - s.start_ns) / 1e3,
                 "pid": 0,
                 "tid": s.tid % 10_000,
+                "args": {"parent": s.parent, **s.args},
             }
         )
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(path, "w") as f:
-        json.dump({"traceEvents": events}, f)
+        json.dump({"traceEvents": events}, f, default=str)  # a rid may be any object
     return path
 
 
@@ -318,10 +381,11 @@ class SummaryView:
 
 
 def export_protobuf(path=None):
-    """reference: profiler export to protobuf dump.  The host-span tree
-    exports via the chrome-trace JSON (load_profiler_result-compatible);
-    protobuf adds no information on this runtime, so this writes the same
-    payload with the requested extension."""
+    """reference: profiler export to protobuf dump.  Despite the name (kept:
+    API surface) this writes the chrome-trace JSON of the host spans
+    (load_profiler_result reads it back) under the requested file name; the
+    protobuf of this runtime is the device trace's `.xplane.pb` under
+    `Profiler.trace_dir`."""
     prof = _active_profiler or _last_profiler
     if prof is None:
         raise RuntimeError("export_protobuf: no active/finished Profiler")
@@ -373,6 +437,12 @@ def decode_stats(reset: bool = False) -> dict:
     parking traffic), parked_requests (a GAUGE of the live parking lot,
     preserved across resets like the LoRA slot gauges), and the
     per-SLO-class admitted_/completed_{high,normal,low} breakdown.
+    The admission split (committed atomic admissions only): admissions,
+    admit_seconds and its phases admit_{match,prefill,first_token,pour}
+    _seconds — each taken at the boundary its `serving.admit.*` span
+    marks — admit_eager_ops (op-funnel calls of the prefill forward), and
+    queued_admissions / queue_wait_seconds (submit -> the attempt that
+    committed, for requests that waited in the pending queue).
     Zeros when no engine ran.  Serving owns the counters — one schema,
     no drift."""
     from paddle_tpu import serving

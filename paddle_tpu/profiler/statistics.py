@@ -193,14 +193,17 @@ def dispatch_cache_line(stats: dict) -> str:
 
 def decode_line(stats: dict) -> str:
     """One-line rendering of the serving decode counters for
-    Profiler.summary(); empty when no engine dispatched this process.
+    Profiler.summary(); empty when no engine dispatched or admitted this
+    process.  With committed admissions, a line splits their host time into
+    the four phases the `serving.admit.*` spans time.
     With the prefix cache or capacity counters active, a second line
     reports hits/misses/avoided-prefill-tokens/evictions and pool bytes
     per resident request (the int8-KV capacity metric)."""
-    if not stats.get("dispatches"):
+    adm = stats.get("admissions", 0)
+    if not stats.get("dispatches") and not adm:
         return ""
     toks = stats.get("tokens", 0)
-    disp = stats["dispatches"]
+    disp = stats.get("dispatches", 0)
     line = (
         "Serving decode: tokens=%d dispatches=%d (%.1f tok/dispatch, "
         "last chunk D=%d) tokens/s=%.1f sync=%.3fs of %.3fs"
@@ -208,6 +211,22 @@ def decode_line(stats: dict) -> str:
            stats.get("last_chunk", 0), stats.get("tokens_per_sec", 0.0),
            stats.get("sync_seconds", 0.0), stats.get("step_seconds", 0.0))
     )
+    if adm:
+        # the admission split: where the host time of a committed atomic
+        # admission went (docs/DECODE.md "Reading an admission")
+        ms = lambda k: 1e3 * stats.get(k, 0.0) / adm  # noqa: E731
+        line += (
+            "\nAdmission split: %d admitted, %.1f ms each = match %.1f + "
+            "prefill %.1f (%.0f eager ops) + first token %.1f + pour %.1f"
+            % (adm, ms("admit_seconds"), ms("admit_match_seconds"),
+               ms("admit_prefill_seconds"),
+               stats.get("admit_eager_ops", 0) / adm,
+               ms("admit_first_token_seconds"), ms("admit_pour_seconds"))
+        )
+        queued = stats.get("queued_admissions", 0)
+        if queued:
+            line += "; %d waited %.1f ms in the queue" % (
+                queued, 1e3 * stats.get("queue_wait_seconds", 0.0) / queued)
     lookups = stats.get("prefix_hits", 0) + stats.get("prefix_misses", 0)
     if lookups or stats.get("resident_peak"):
         line += (

@@ -29,7 +29,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu._core import autograd as _autograd
 from paddle_tpu._core import flags as _flags
+from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["GenerationEngine", "RadixPrefixCache", "decode_stats",
            "reset_decode_stats", "lora_stats", "reset_lora_stats",
@@ -85,7 +87,38 @@ _DECODE_STATS = {
     "completed_high": 0,
     "completed_normal": 0,
     "completed_low": 0,
+    # admission split (docs/DECODE.md "Reading an admission"): COMMITTED
+    # atomic admissions (_try_admit) and their host seconds — the whole
+    # attempt and its four phases, each beside the `serving.admit.*` span
+    # of the same boundary; admit_eager_ops counts op-funnel calls made
+    # by the prefill forward (the eager dispatches an admission costs).
+    # queue_wait_seconds: submit -> the attempt that committed, for the
+    # queued_admissions that waited in the pending queue
+    "admissions": 0,
+    "admit_seconds": 0.0,
+    "admit_match_seconds": 0.0,
+    "admit_prefill_seconds": 0.0,
+    "admit_first_token_seconds": 0.0,
+    "admit_pour_seconds": 0.0,
+    "admit_eager_ops": 0,
+    "queued_admissions": 0,
+    "queue_wait_seconds": 0.0,
 }
+
+_ADMIT_PHASES = ("match", "prefill", "first_token", "pour")
+
+
+@contextlib.contextmanager
+def _admit_phase(name, acc):
+    """One phase of an admission attempt: a `serving.admit.<name>` span,
+    and its host seconds added to the attempt's own tally `acc` (which
+    reaches _DECODE_STATS only if the attempt commits)."""
+    t = time.perf_counter()
+    try:
+        with RecordEvent("serving.admit." + name):
+            yield
+    finally:
+        acc[name] += time.perf_counter() - t
 
 
 def decode_stats(reset: bool = False) -> dict:
@@ -606,6 +639,7 @@ class GenerationEngine:
             np.asarray(self._scratch, np.int32)[:, None],
             (1, self._max_blocks_per_seq)))
         self._req_counter = 0
+        self._queued_at: dict = {}  # rid -> perf_counter when add_request queued it
         self._state = list(model.state_dict().values())
         # ---- fault-tolerance tier (serving/snapshot.py) -----------------
         self._macro_steps = 0          # boundary count; snapshot step tags
@@ -933,6 +967,15 @@ class GenerationEngine:
                     self._prefix is None or not self._prefix.holds(b)):
                 self._free.append(b)
 
+    def _back_out(self, fresh, matched):
+        """Undo a failed admission attempt's allocation: prefill and pour
+        only ever wrote the fresh pages, so returning them (and the prefix
+        references) restores the allocator exactly."""
+        for b in fresh:
+            self._ref[b] = 0
+            self._free.append(b)
+        self._unref(matched)
+
     def _release(self, slot):
         self._unref(slot.blocks)
         if self._pack is not None:
@@ -1058,6 +1101,8 @@ class GenerationEngine:
         # by (class, submit order); all-default-priority traffic is FIFO —
         # the original contract)
         if self._pending or not self._try_admit(req):
+            # the wait _try_admit's commit reports as queue_wait_seconds
+            self._queued_at[rid] = time.perf_counter()
             self._pending.append(req)
             return None
         return self._results[rid][0]
@@ -1134,13 +1179,42 @@ class GenerationEngine:
         """One admission attempt: prefix-match, allocate, prefill the
         suffix, pour, occupy a slot.  Returns False (with ALL state backed
         out — no leaked blocks, no occupied slot, no stolen references) on
-        transient shortage; real errors back out and re-raise."""
-        import paddle_tpu as paddle
-        from paddle_tpu.models.llama import _model_forward_cached
+        transient shortage; real errors back out and re-raise.
 
+        An attempt that finds a free slot is one `serving.admit` span
+        carrying the request's rid; its phases are child spans whose host
+        seconds reach the admit_* counters only when the attempt COMMITS
+        (as prefix_hits does): a backed-out attempt must not inflate the
+        split."""
+        t0 = time.perf_counter()
         slot = next((s for s in self._slots if not s.active), None)
         if slot is None:
             return False
+        acc = dict.fromkeys(_ADMIT_PHASES, 0.0)
+        acc["eager_ops"] = 0
+        with RecordEvent("serving.admit", rid=req["rid"],
+                         prompt_len=req["prompt"].shape[1],
+                         blocks=req["n_blocks"]):
+            if not self._admit(req, slot, acc):
+                return False
+        st = _DECODE_STATS
+        st["admissions"] += 1
+        st["admit_seconds"] += time.perf_counter() - t0
+        for name in _ADMIT_PHASES:
+            st["admit_" + name + "_seconds"] += acc[name]
+        st["admit_eager_ops"] += acc["eager_ops"]
+        queued_at = self._queued_at.pop(req["rid"], None)
+        if queued_at is not None:
+            st["queued_admissions"] += 1
+            st["queue_wait_seconds"] += t0 - queued_at
+        return True
+
+    def _admit(self, req, slot, acc):
+        """_try_admit's body, into the free `slot`; `acc` tallies this
+        attempt's phases."""
+        import paddle_tpu as paddle
+        from paddle_tpu.models.llama import _model_forward_cached
+
         # ---- adapter residency: the request's adapter must hold a pack
         # slot before prefill (adapted projections feed the K/V it pours).
         # Transient slot exhaustion — every slot serving in-flight
@@ -1163,30 +1237,35 @@ class GenerationEngine:
         ns = ((ad_slot, self._slot_epochs[ad_slot])
               if self._pack is not None else None)
         toks = matched = None
-        if self._prefix is not None:
-            # token list cached across retries (the prompt is immutable);
-            # the match itself re-walks each attempt on purpose — the
-            # LRU touch keeps a waiting request's pages warm for its
-            # retry instead of letting pressure evict them
-            toks = req.setdefault("toks", [int(t) for t in prompt[0]])
-            matched = self._prefix.match(toks, max_blocks=(s0 - 1) // bs,
-                                         ns=ns)
-            for b in matched:
-                self._ref[b] += 1
-        matched = matched or []
+        with _admit_phase("match", acc):
+            if self._prefix is not None:
+                # token list cached across retries (the prompt is
+                # immutable); the match itself re-walks each attempt on
+                # purpose — the LRU touch keeps a waiting request's pages
+                # warm for its retry instead of letting pressure evict them
+                toks = req.setdefault("toks", [int(t) for t in prompt[0]])
+                matched = self._prefix.match(
+                    toks, max_blocks=(s0 - 1) // bs, ns=ns)
+                for b in matched:
+                    self._ref[b] += 1
+            matched = matched or []
+            try:
+                fresh = self._alloc(req["n_blocks"] - len(matched))
+            except _PoolExhausted:
+                self._unref(matched)
+                return False
+            blocks = matched + fresh
+            m_len = len(matched) * bs
+            model = self.model
+            try:
+                caches = self._prefix_or_empty(
+                    self._kpools, self._vpools, matched, m_len,
+                    self._n_layers, self._nkv, self._head_dim,
+                    model.config.dtype)
+            except BaseException:
+                self._back_out(fresh, matched)
+                raise
         try:
-            fresh = self._alloc(req["n_blocks"] - len(matched))
-        except _PoolExhausted:
-            self._unref(matched)
-            return False
-        blocks = matched + fresh
-        m_len = len(matched) * bs
-
-        model = self.model
-        try:
-            caches = self._prefix_or_empty(
-                self._kpools, self._vpools, matched, m_len, self._n_layers,
-                self._nkv, self._head_dim, model.config.dtype)
             # adapter requests prefill THROUGH their adapter: forward-post
             # hooks add each target projection's (x A)(B) s delta, so the
             # poured K/V matches what the adapted model would cache
@@ -1199,61 +1278,43 @@ class GenerationEngine:
             else:
                 prefill_ctx = contextlib.nullcontext()
             with prefill_ctx, paddle.no_grad():
-                if (self.prefill_chunk is None
-                        or s0 - m_len <= self.prefill_chunk):
-                    h, caches = _model_forward_cached(
-                        model.model, paddle.to_tensor(prompt[:, m_len:]),
-                        caches, m_len)
-                else:
-                    # chunked prefill: fixed-size chunks through the cached
-                    # forward (bottom-right-aligned cross-length attention)
-                    # cap the peak activation footprint for long prompts.
-                    # An accepted prefill-chain config routes each
-                    # DIVISIBLE chunk's attention core through the fused
-                    # K-tiled kernel (schedule search; PrefillChainSpec)
-                    from paddle_tpu.models.llama import prefill_chain_scope
-
-                    pf_cfg = self._resolve_prefill_chain()
-                    with prefill_chain_scope(pf_cfg):
-                        off = m_len
-                        while off < s0:
-                            chunk = prompt[:, off:off + self.prefill_chunk]
-                            h, caches = _model_forward_cached(
-                                model.model, paddle.to_tensor(chunk),
-                                caches, off)
-                            off += chunk.shape[1]
-                logits_last = model._logits(h[:, -1:, :])._value[0, -1, :]
-                first = int(np.asarray(jnp.argmax(logits_last)))
+                with _admit_phase("prefill", acc):
+                    ops0 = _autograd.funnel_calls()
+                    h, caches = self._prefill_suffix(prompt, caches, m_len)
+                    acc["eager_ops"] = _autograd.funnel_calls() - ops0
+                # the read-back is the admission's one device sync: the
+                # host waits here for everything the prefill enqueued
+                with _admit_phase("first_token", acc):
+                    logits_last = model._logits(
+                        h[:, -1:, :])._value[0, -1, :]
+                    first = int(np.asarray(jnp.argmax(logits_last)))
 
             # pour the suffix K/V into this request's exclusive pages
             # (matched prefix pages are shared and immutable)
-            self._pour(self._kpools, self._vpools, caches, blocks, s0,
-                       self._nkv, self._head_dim,
-                       sharding=self._pool_sharding, start_tok=m_len)
+            with _admit_phase("pour", acc):
+                self._pour(self._kpools, self._vpools, caches, blocks, s0,
+                           self._nkv, self._head_dim,
+                           sharding=self._pool_sharding, start_tok=m_len)
             if self.draft_model is not None:
                 # draft prefill over the same suffix into the draft pools
                 # (cached pages were poured to BOTH pool sets at insert
                 # time, so a matched prefix covers the draft too)
-                d_caches = self._prefix_or_empty(
-                    self._d_kpools, self._d_vpools, matched, m_len,
-                    self._d_layers, self._d_nkv, self._d_hd,
-                    self.draft_model.config.dtype)
-                with paddle.no_grad():
+                with _admit_phase("prefill", acc), paddle.no_grad():
+                    d_caches = self._prefix_or_empty(
+                        self._d_kpools, self._d_vpools, matched, m_len,
+                        self._d_layers, self._d_nkv, self._d_hd,
+                        self.draft_model.config.dtype)
                     _, d_caches = _model_forward_cached(
                         self.draft_model.model,
                         paddle.to_tensor(prompt[:, m_len:]), d_caches, m_len)
-                self._pour(self._d_kpools, self._d_vpools, d_caches, blocks,
-                           s0, self._d_nkv, self._d_hd,
-                           sharding=self._d_pool_sharding, start_tok=m_len)
+                with _admit_phase("pour", acc):
+                    self._pour(self._d_kpools, self._d_vpools, d_caches,
+                               blocks, s0, self._d_nkv, self._d_hd,
+                               sharding=self._d_pool_sharding,
+                               start_tok=m_len)
                 slot.d_seq_len = s0
         except BaseException:
-            # back out cleanly: pour only ever wrote the fresh pages, so
-            # returning them (and the prefix references) restores the
-            # allocator exactly
-            for b in fresh:
-                self._ref[b] = 0
-                self._free.append(b)
-            self._unref(matched)
+            self._back_out(fresh, matched)
             raise
 
         slot.rid = req["rid"]
@@ -1308,6 +1369,32 @@ class GenerationEngine:
             self._finish(slot)
         return True
 
+    def _prefill_suffix(self, prompt, caches, m_len):
+        """The target model's eager forward over prompt[:, m_len:] on top
+        of `caches` (the matched prefix, or empties): (hidden, caches)."""
+        import paddle_tpu as paddle
+        from paddle_tpu.models.llama import (_model_forward_cached,
+                                             prefill_chain_scope)
+
+        model, s0 = self.model.model, prompt.shape[1]
+        if self.prefill_chunk is None or s0 - m_len <= self.prefill_chunk:
+            return _model_forward_cached(
+                model, paddle.to_tensor(prompt[:, m_len:]), caches, m_len)
+        # chunked prefill: fixed-size chunks through the cached forward
+        # (bottom-right-aligned cross-length attention) cap the peak
+        # activation footprint for long prompts.  An accepted
+        # prefill-chain config routes each DIVISIBLE chunk's attention
+        # core through the fused K-tiled kernel (schedule search;
+        # PrefillChainSpec)
+        with prefill_chain_scope(self._resolve_prefill_chain()):
+            off = m_len
+            while off < s0:
+                chunk = prompt[:, off:off + self.prefill_chunk]
+                h, caches = _model_forward_cached(
+                    model, paddle.to_tensor(chunk), caches, off)
+                off += chunk.shape[1]
+        return h, caches
+
     # ------------------------------------- interleaved prefill (PREFILLING)
     def _begin_prefill(self, req):
         """Interleaved admission, reservation half: claim a slot, adapter
@@ -1351,10 +1438,7 @@ class GenerationEngine:
                 self._kpools, self._vpools, matched, m_len, self._n_layers,
                 self._nkv, self._head_dim, self.model.config.dtype)
         except BaseException:
-            for b in fresh:
-                self._ref[b] = 0
-                self._free.append(b)
-            self._unref(matched)
+            self._back_out(fresh, matched)
             raise
         slot.rid = req["rid"]
         slot.blocks = matched + fresh
@@ -2146,8 +2230,9 @@ class GenerationEngine:
         # phase 2; docs/SCHEDULE_SEARCH.md)
         chain_cfg = self._resolve_decode_chain()
 
-        def step(state_vals, kpools, vpools, tokens, tables, scratch_tables,
-                 lens, max_lens, done0, temps, keys, steps, *lora_args):
+        def decode_macro_step(state_vals, kpools, vpools, tokens, tables,
+                              scratch_tables, lens, max_lens, done0, temps,
+                              keys, steps, *lora_args):
             if has_pack:
                 ad_slots, pack_ab, pack_scaling = lora_args
                 row_scale = jnp.take(pack_scaling, ad_slots)  # [B]
@@ -2218,7 +2303,9 @@ class GenerationEngine:
                 for t, v in zip(state, originals):
                     t._bind(v)
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        # the function's name is the program's: `jit_decode_macro_step` on
+        # the trace's XLA Modules line and in PjitFunction(...) host events
+        return jax.jit(decode_macro_step, donate_argnums=(1, 2))
 
     def _step_avals(self):
         """ShapeDtypeStruct mirror of step()'s exact dispatch signature,
@@ -2330,7 +2417,7 @@ class GenerationEngine:
         model = self.draft_model
         state = self._d_state
 
-        def dstep(state_vals, kpools, vpools, tokens, tables, lens):
+        def draft_step(state_vals, kpools, vpools, tokens, tables, lens):
             originals = [t._value for t in state]
             try:
                 for t, v in zip(state, state_vals):
@@ -2350,7 +2437,7 @@ class GenerationEngine:
                 for t, v in zip(state, originals):
                     t._bind(v)
 
-        return jax.jit(dstep)
+        return jax.jit(draft_step)
 
     def _build_verify(self):
         from paddle_tpu._core.autograd import no_grad
@@ -2361,8 +2448,8 @@ class GenerationEngine:
         state = self._state
         has_pack = self._pack is not None
 
-        def verify(state_vals, kpools, vpools, tokens, tables, lens,
-                   *lora_args):
+        def verify_step(state_vals, kpools, vpools, tokens, tables, lens,
+                        *lora_args):
             """tokens [B, K+1]; lens INCLUDING the whole chunk; returns
             preds [B, K+1] (greedy next token after each chunk position)
             plus the written pools.  On adapter engines the extra args
@@ -2396,7 +2483,7 @@ class GenerationEngine:
                 for t, v in zip(state, originals):
                     t._bind(v)
 
-        return jax.jit(verify)
+        return jax.jit(verify_step)
 
     def _spec_step(self):
         """One speculative tick: the draft proposes K tokens per live slot
@@ -2527,6 +2614,10 @@ class GenerationEngine:
         PENDING QUEUE this step always maps to a list, led by its
         prefill-produced first token (the one add_request returned None
         instead of)."""
+        with RecordEvent("serving.step"):
+            return self._step()
+
+    def _step(self):
         if not self.has_work():
             # an idle engine is still at a boundary: a pending SIGTERM
             # preemption (or an overdue interval) must commit its final
@@ -2540,14 +2631,15 @@ class GenerationEngine:
         # step's output — always as a list for those rids, even at D=1.
         # A draining engine admits NOTHING: its queue was handed off in
         # the drain snapshot and will be served by the restore target.
-        admitted = [] if self._draining else self._admit_pending()
-        # interleaved chunked prefill: grant this boundary's budget of
-        # block-sized chunks (deadline pressure orders the PREFILLING
-        # slots); prompts whose final chunk landed activate NOW and
-        # their first token joins this step's output like any queued
-        # admission (drain() demoted prefilling slots, so this is a
-        # no-op on a lame duck)
-        admitted.extend(self._advance_prefills())
+        with RecordEvent("serving.step.schedule"):
+            admitted = [] if self._draining else self._admit_pending()
+            # interleaved chunked prefill: grant this boundary's budget of
+            # block-sized chunks (deadline pressure orders the PREFILLING
+            # slots); prompts whose final chunk landed activate NOW and
+            # their first token joins this step's output like any queued
+            # admission (drain() demoted prefilling slots, so this is a
+            # no-op on a lame duck)
+            admitted.extend(self._advance_prefills())
         if not any(s.active for s in self._slots):
             # an admitted request may have finished AT admission
             # (EOS / max_new_tokens=1): its first token still surfaces.
@@ -2569,83 +2661,86 @@ class GenerationEngine:
             self._macro_steps += 1
             self.maybe_snapshot()  # boundary: no-op without a snapshot dir
             return out
-        D = self._effective_chunk()
-        step_fn = self._step_fns.get(D)
-        if step_fn is None:
-            step_fn = self._step_fns[D] = self._build_step(D)
+        with RecordEvent("serving.step.dispatch"):
+            D = self._effective_chunk()
+            step_fn = self._step_fns.get(D)
+            if step_fn is None:
+                step_fn = self._step_fns[D] = self._build_step(D)
 
-        B, W = self.max_batch, self._max_blocks_per_seq
-        tokens = np.zeros((B, 1), np.int32)
-        tables = np.zeros((B, W), np.int32)
-        lens = np.ones((B,), np.int32)
-        max_lens = np.zeros((B,), np.int32)
-        done0 = np.ones((B,), bool)
-        temps = np.zeros((B,), np.float32)
-        keys = np.zeros((B, 2), np.uint32)
-        steps = np.zeros((B,), np.uint32)
-        ad_slots = np.zeros((B,), np.int32)
-        for i, s in enumerate(self._slots):
-            if s.active:
-                tokens[i, 0] = s.last_token
-                row = list(s.blocks) + [s.blocks[-1]] * (W - len(s.blocks))
-                tables[i] = row
-                lens[i] = s.seq_len + 1  # includes the token being decoded
-                max_lens[i] = s.max_len
-                done0[i] = False
-                temps[i] = s.temperature
-                keys[i] = s.key
-                steps[i] = len(s.generated)  # fold index for this request
-                ad_slots[i] = s.adapter_slot
-            else:
-                tables[i] = self._scratch[i]  # park masked lanes off-pool
-                lens[i] = 1
+            B, W = self.max_batch, self._max_blocks_per_seq
+            tokens = np.zeros((B, 1), np.int32)
+            tables = np.zeros((B, W), np.int32)
+            lens = np.ones((B,), np.int32)
+            max_lens = np.zeros((B,), np.int32)
+            done0 = np.ones((B,), bool)
+            temps = np.zeros((B,), np.float32)
+            keys = np.zeros((B, 2), np.uint32)
+            steps = np.zeros((B,), np.uint32)
+            ad_slots = np.zeros((B,), np.int32)
+            for i, s in enumerate(self._slots):
+                if s.active:
+                    tokens[i, 0] = s.last_token
+                    row = list(s.blocks) + [s.blocks[-1]] * (W - len(s.blocks))
+                    tables[i] = row
+                    lens[i] = s.seq_len + 1  # includes the token being decoded
+                    max_lens[i] = s.max_len
+                    done0[i] = False
+                    temps[i] = s.temperature
+                    keys[i] = s.key
+                    steps[i] = len(s.generated)  # fold index for this request
+                    ad_slots[i] = s.adapter_slot
+                else:
+                    tables[i] = self._scratch[i]  # park masked lanes off-pool
+                    lens[i] = 1
 
-        lora_args = ()
-        if self._pack is not None:
-            # pack contents ride as ARGUMENTS (not closed-over constants):
-            # register_adapter's scatter produces new arrays of identical
-            # shape, so a swap changes values only and this same compiled
-            # step serves every tenant mix
-            lora_args = (jnp.asarray(ad_slots), self._pack.ab,
-                         self._pack.scaling)
-            _LORA_STATS["gather_dispatches"] += 1
-        nxt, new_k, new_v = step_fn(
-            [t._value for t in self._state],
-            list(self._kpools), list(self._vpools),
-            jnp.asarray(tokens), jnp.asarray(tables),
-            self._scratch_tables, jnp.asarray(lens),
-            jnp.asarray(max_lens), jnp.asarray(done0),
-            jnp.asarray(temps), jnp.asarray(keys), jnp.asarray(steps),
-            *lora_args,
-        )
+            lora_args = ()
+            if self._pack is not None:
+                # pack contents ride as ARGUMENTS (not closed-over constants):
+                # register_adapter's scatter produces new arrays of identical
+                # shape, so a swap changes values only and this same compiled
+                # step serves every tenant mix
+                lora_args = (jnp.asarray(ad_slots), self._pack.ab,
+                             self._pack.scaling)
+                _LORA_STATS["gather_dispatches"] += 1
+            nxt, new_k, new_v = step_fn(
+                [t._value for t in self._state],
+                list(self._kpools), list(self._vpools),
+                jnp.asarray(tokens), jnp.asarray(tables),
+                self._scratch_tables, jnp.asarray(lens),
+                jnp.asarray(max_lens), jnp.asarray(done0),
+                jnp.asarray(temps), jnp.asarray(keys), jnp.asarray(steps),
+                *lora_args,
+            )
         self._kpools = list(new_k)
         self._vpools = list(new_v)
         t_sync = time.perf_counter()
-        nxt = np.asarray(nxt)  # [B, D] — the one device sync per chunk
+        with RecordEvent("serving.step.sync"):
+            nxt = np.asarray(nxt)  # [B, D] — the one device sync per chunk
         _DECODE_STATS["dispatches"] += 1
         _DECODE_STATS["macro_steps"] += 1
         _DECODE_STATS["last_chunk"] = D
         _DECODE_STATS["sync_seconds"] += time.perf_counter() - t_sync
 
-        out = {}
-        for i, s in enumerate(self._slots):
-            if not s.active:
-                continue
-            rid = s.rid  # _finish() clears the slot's rid on retirement
-            emitted = []
-            for j in range(D):
-                tok = int(nxt[i, j])
-                s.seq_len += 1
-                s.last_token = tok
-                s.generated.append(tok)
-                emitted.append(tok)
-                if (self.eos_token_id is not None
-                        and tok == self.eos_token_id) or (
-                            s.seq_len + 1 >= s.max_len):
-                    self._finish(s)
-                    break
-            out[rid] = emitted if D > 1 else emitted[0]
-            _DECODE_STATS["tokens"] += len(emitted)
+        with RecordEvent("serving.step.retire"):
+            out = {}
+            for i, s in enumerate(self._slots):
+                if not s.active:
+                    continue
+                rid = s.rid  # _finish() clears the slot's rid on retirement
+                emitted = []
+                for j in range(D):
+                    tok = int(nxt[i, j])
+                    s.seq_len += 1
+                    s.last_token = tok
+                    s.generated.append(tok)
+                    emitted.append(tok)
+                    if (self.eos_token_id is not None
+                            and tok == self.eos_token_id) or (
+                                s.seq_len + 1 >= s.max_len):
+                        self._finish(s)
+                        break
+                out[rid] = emitted if D > 1 else emitted[0]
+                _DECODE_STATS["tokens"] += len(emitted)
         _DECODE_STATS["step_seconds"] += time.perf_counter() - t_start
         self._merge_admitted(out, admitted)
         self._macro_steps += 1

@@ -172,3 +172,39 @@ def test_matmul_bias_act_exact_gelu_stays_with_xla(one_chip, mosaic):
     compiled = jax.jit(
         lambda x, w, b: matmul_bias_act(x, w, b, "gelu")).lower(*args).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_kernel_names_reach_the_hlo_instruction_names(one_chip, mosaic):
+    """`name=` on each `pl.pallas_call` becomes the custom call's HLO
+    instruction name — what the trace's `XLA Ops` line prints.  The three
+    flash kernels are told apart from the norm and swiglu by name, and the
+    regex of `perfbench/layer_metrics/flash_roofline_share.json` matches
+    exactly them."""
+    import json
+    import os
+    import re
+
+    from paddle_tpu.ops import fused_rms_norm, swiglu
+
+    def loss(q, k, v, x, w, gate, up):
+        return (_flash_loss(q, k, v)
+                + fused_rms_norm(x, w).astype(jnp.float32).sum()
+                + swiglu(gate, up).astype(jnp.float32).sum())
+
+    qkv, x, ffn = (1, 4096, HEADS, HEAD_DIM), (ROWS, HIDDEN), (ROWS, FFN)
+    # value_and_grad: the loss keeps the forward kernels from being dropped
+    text = _compile(jax.value_and_grad(loss, (0, 1, 2, 3, 5)), qkv, qkv, qkv, x,
+                    (HIDDEN,), ffn, ffn, sharding=one_chip).as_text()
+    names = {re.sub(r"\.\d+$", "", m) for m in re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rms_norm_fwd",
+               "swiglu_fwd")
+    for kernel in kernels:
+        assert any(kernel in n for n in names), (kernel, names)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "perfbench", "layer_metrics",
+                           "flash_roofline_share.json")) as f:
+        pat = re.compile(json.load(f)["reader"]["over"]["op_match"])
+    # the trace reduction names an operation "<instruction> <opcode> -> ..."
+    matched = {n for n in names if pat.search(n + " custom-call -> ")}
+    assert matched == {n for n in names if "flash_" in n} and len(matched) == 3
