@@ -195,7 +195,8 @@ def decode_line(stats: dict) -> str:
     """One-line rendering of the serving decode counters for
     Profiler.summary(); empty when no engine dispatched or admitted this
     process.  With committed admissions, a line splits their host time into
-    the four phases the `serving.admit.*` spans time.
+    the four phases the `serving.admit.*` spans time, and gives the prefill
+    programs' hit share (calls that found their program built) beside it.
     With the prefix cache or capacity counters active, a second line
     reports hits/misses/avoided-prefill-tokens/evictions and pool bytes
     per resident request (the int8-KV capacity metric)."""
@@ -223,6 +224,19 @@ def decode_line(stats: dict) -> str:
                stats.get("admit_eager_ops", 0) / adm,
                ms("admit_first_token_seconds"), ms("admit_pour_seconds"))
         )
+        calls = stats.get("prefill_program_calls", 0)
+        if calls or stats.get("prefill_eager_fallbacks"):
+            # compiled prefill: how many admissions found their (bucket,
+            # prefix length) program ready, and how many stayed eager
+            line += (
+                "; prefill programs: %d calls, %.0f%% ready (%d built), "
+                "%d pad tokens, %d eager fallbacks"
+                % (calls,
+                   100.0 * (1 - stats.get("prefill_programs_built", 0)
+                            / calls) if calls else 0.0,
+                   stats.get("prefill_programs_built", 0),
+                   stats.get("prefill_pad_tokens", 0),
+                   stats.get("prefill_eager_fallbacks", 0)))
         queued = stats.get("queued_admissions", 0)
         if queued:
             line += "; %d waited %.1f ms in the queue" % (

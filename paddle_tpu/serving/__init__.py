@@ -90,8 +90,10 @@ _DECODE_STATS = {
     # admission split (docs/DECODE.md "Reading an admission"): COMMITTED
     # atomic admissions (_try_admit) and their host seconds — the whole
     # attempt and its four phases, each beside the `serving.admit.*` span
-    # of the same boundary; admit_eager_ops counts op-funnel calls made
-    # by the prefill forward (the eager dispatches an admission costs).
+    # of the same boundary; admit_eager_ops counts the host dispatches of
+    # the prefill phase: op-funnel calls EXECUTED eagerly plus one per
+    # compiled-program call (funnel calls made while a program is traced
+    # are its build, which prefill_programs_built counts).
     # queue_wait_seconds: submit -> the attempt that committed, for the
     # queued_admissions that waited in the pending queue
     "admissions": 0,
@@ -103,9 +105,22 @@ _DECODE_STATS = {
     "admit_eager_ops": 0,
     "queued_admissions": 0,
     "queue_wait_seconds": 0.0,
+    # compiled prefill (docs/DECODE.md "The prefill program"): admissions
+    # whose prefill ran as `jit_prefill_program`, how many of those calls
+    # were the first use of their (bucket, prefix length) program, the
+    # right-padding tokens the buckets cost, and admissions that stayed on
+    # the eager forward (chunked / interleaved prefill, adapter requests)
+    "prefill_program_calls": 0,
+    "prefill_programs_built": 0,
+    "prefill_pad_tokens": 0,
+    "prefill_eager_fallbacks": 0,
 }
 
 _ADMIT_PHASES = ("match", "prefill", "first_token", "pour")
+# per-attempt counts that reach _DECODE_STATS only when the attempt commits
+_ADMIT_COUNTS = ("admit_eager_ops", "prefill_program_calls",
+                 "prefill_programs_built", "prefill_pad_tokens",
+                 "prefill_eager_fallbacks")
 
 
 @contextlib.contextmanager
@@ -252,11 +267,48 @@ _CHAIN_UNSET = object()
 def _invalidate_decode_steps(_changed):
     for eng in list(_ENGINES):
         eng._step_fns.clear()
+        eng._prefill_fns.clear()
         eng._draft_fn = eng._verify_fn = None
         # flags govern whether (and which) fused decode-chain schedule the
         # rebuilt steps may consume — re-resolve with the steps
         eng._decode_chain_cfg = _CHAIN_UNSET
         eng._prefill_chain_cfg = _CHAIN_UNSET
+
+
+def _cache_blocks(caches, start_tok, s0, bs):
+    """Naive prefill caches ([1, S, Nkv, H] K and V per layer, Tensors or
+    raw arrays) -> the pool's block layout for tokens [start_tok, s0): per
+    layer [n, Nkv, bs, H], the last block's tail zero-padded.  start_tok is
+    block-aligned (it skips the prefix-matched region: the caches hold the
+    FULL logical sequence).  The ONE shaper: eager admissions call it on
+    the host, the prefill program inside its trace."""
+    n = -(-(s0 - start_tok) // bs)
+    pad = start_tok + n * bs - s0
+
+    def shape(t):
+        kv = jnp.moveaxis(getattr(t, "_value", t), 1, 2)
+        kv = kv[0, :, start_tok:s0]                          # [Nkv, S', H]
+        if pad:
+            kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0)))
+        nkv, _, head_dim = kv.shape
+        return kv.reshape(nkv, n, bs, head_dim).swapaxes(0, 1)
+
+    return [shape(k) for k, _ in caches], [shape(v) for _, v in caches]
+
+
+@jax.jit
+def _pour_new_blocks(pool, blocks, idx):
+    """`paged_pour_blocks` of a request's pages `idx` [n_t]: `blocks`
+    [n, Nkv, bs, H] cut or zero-extended to n_t blocks.  The caller's blocks
+    are all-zero past the request's real tokens (a bucket's padding), so
+    cutting drops nothing; extending zeroes the future decode pages.  One
+    compiled scatter per (pool, n, n_t), in place of six eager calls."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    n_t = idx.shape[0]
+    zeros = jnp.zeros((n_t,) + blocks.shape[1:], blocks.dtype)
+    return pa.paged_pour_blocks(
+        pool, jnp.concatenate([blocks, zeros])[:n_t], idx)
 
 
 # SLO classes for add_request(priority=): admission order is (class, submit
@@ -630,6 +682,8 @@ class GenerationEngine:
             raise ValueError("decode_chunk must be >= 1")
         self._decode_chunk = None if decode_chunk is None else int(decode_chunk)
         self._step_fns: dict = {}  # macro-step executables, keyed by D
+        # admission prefill programs, keyed by (padded suffix, prefix length)
+        self._prefill_fns: dict = {}
         self._decode_chain_cfg = _CHAIN_UNSET  # lazy (_resolve_decode_chain)
         self._prefill_chain_cfg = _CHAIN_UNSET  # lazy (_resolve_prefill_chain)
         # masked lanes' block tables (every page is the slot's scratch
@@ -1191,7 +1245,7 @@ class GenerationEngine:
         if slot is None:
             return False
         acc = dict.fromkeys(_ADMIT_PHASES, 0.0)
-        acc["eager_ops"] = 0
+        acc.update(dict.fromkeys(_ADMIT_COUNTS, 0))
         with RecordEvent("serving.admit", rid=req["rid"],
                          prompt_len=req["prompt"].shape[1],
                          blocks=req["n_blocks"]):
@@ -1202,7 +1256,8 @@ class GenerationEngine:
         st["admit_seconds"] += time.perf_counter() - t0
         for name in _ADMIT_PHASES:
             st["admit_" + name + "_seconds"] += acc[name]
-        st["admit_eager_ops"] += acc["eager_ops"]
+        for name in _ADMIT_COUNTS:
+            st[name] += acc[name]
         queued_at = self._queued_at.pop(req["rid"], None)
         if queued_at is not None:
             st["queued_admissions"] += 1
@@ -1257,11 +1312,30 @@ class GenerationEngine:
             blocks = matched + fresh
             m_len = len(matched) * bs
             model = self.model
+            # the atomic base-model prefill runs as ONE compiled program
+            # per (suffix bucket, prefix length); what stays eager is
+            # selected on what this attempt observes: a suffix longer than
+            # prefill_chunk (chunked prefill, and with it an accepted
+            # prefill-chain schedule), and an adapter request (its
+            # forward-post hooks close over the pack's arrays, which a
+            # trace would freeze).  Interleaved prefill never comes here.
+            compiled = not ad_slot and (
+                self.prefill_chunk is None
+                or s0 - m_len <= self.prefill_chunk)
             try:
-                caches = self._prefix_or_empty(
-                    self._kpools, self._vpools, matched, m_len,
-                    self._n_layers, self._nkv, self._head_dim,
-                    model.config.dtype)
+                if not compiled:
+                    caches = self._prefix_or_empty(
+                        self._kpools, self._vpools, matched, m_len,
+                        self._n_layers, self._nkv, self._head_dim,
+                        model.config.dtype)
+                elif m_len:
+                    prefix = [(k._value, v._value) for k, v in
+                              self._gather_prefix(
+                                  self._kpools, self._vpools, matched,
+                                  m_len, self._nkv, self._head_dim,
+                                  model.config.dtype)]
+                else:
+                    prefix = None    # no 2N empty tensors for a program
             except BaseException:
                 self._back_out(fresh, matched)
                 raise
@@ -1279,22 +1353,31 @@ class GenerationEngine:
                 prefill_ctx = contextlib.nullcontext()
             with prefill_ctx, paddle.no_grad():
                 with _admit_phase("prefill", acc):
-                    ops0 = _autograd.funnel_calls()
-                    h, caches = self._prefill_suffix(prompt, caches, m_len)
-                    acc["eager_ops"] = _autograd.funnel_calls() - ops0
+                    if compiled:
+                        logits_last, k_new, v_new = self._prefill_compiled(
+                            prompt, prefix, m_len, acc)
+                    else:
+                        ops0 = _autograd.funnel_calls()
+                        h, caches = self._prefill_suffix(prompt, caches,
+                                                         m_len)
+                        acc["admit_eager_ops"] = (_autograd.funnel_calls()
+                                                  - ops0)
+                        acc["prefill_eager_fallbacks"] = 1
                 # the read-back is the admission's one device sync: the
                 # host waits here for everything the prefill enqueued
                 with _admit_phase("first_token", acc):
-                    logits_last = model._logits(
-                        h[:, -1:, :])._value[0, -1, :]
+                    if not compiled:
+                        logits_last = model._logits(
+                            h[:, -1:, :])._value[0, -1, :]
                     first = int(np.asarray(jnp.argmax(logits_last)))
 
             # pour the suffix K/V into this request's exclusive pages
             # (matched prefix pages are shared and immutable)
             with _admit_phase("pour", acc):
-                self._pour(self._kpools, self._vpools, caches, blocks, s0,
-                           self._nkv, self._head_dim,
-                           sharding=self._pool_sharding, start_tok=m_len)
+                if not compiled:
+                    k_new, v_new = _cache_blocks(caches, m_len, s0, bs)
+                self._pour(self._kpools, self._vpools, k_new, v_new,
+                           fresh, sharding=self._pool_sharding)
             if self.draft_model is not None:
                 # draft prefill over the same suffix into the draft pools
                 # (cached pages were poured to BOTH pool sets at insert
@@ -1308,10 +1391,9 @@ class GenerationEngine:
                         self.draft_model.model,
                         paddle.to_tensor(prompt[:, m_len:]), d_caches, m_len)
                 with _admit_phase("pour", acc):
-                    self._pour(self._d_kpools, self._d_vpools, d_caches,
-                               blocks, s0, self._d_nkv, self._d_hd,
-                               sharding=self._d_pool_sharding,
-                               start_tok=m_len)
+                    self._pour(self._d_kpools, self._d_vpools,
+                               *_cache_blocks(d_caches, m_len, s0, bs),
+                               fresh, sharding=self._d_pool_sharding)
                 slot.d_seq_len = s0
         except BaseException:
             self._back_out(fresh, matched)
@@ -1394,6 +1476,89 @@ class GenerationEngine:
                     model, paddle.to_tensor(chunk), caches, off)
                 off += chunk.shape[1]
         return h, caches
+
+    # ------------------------------------------- the prefill program
+    def _prefill_bucket(self, s, m_len) -> int:
+        """Padded length of an `s`-token suffix behind `m_len` prefix
+        tokens: the next power of two, at least one pool block, clipped so
+        that m_len + bucket stays inside the rope table.  One program per
+        bucket serves every real length in it."""
+        rope_len = int(self.model.model.rope_cos.shape[0])
+        return min(max(self.block_size, 1 << (s - 1).bit_length()),
+                   rope_len - m_len)
+
+    def _prefill_program(self, s_pad, m_len):
+        """The compiled model work of one atomic admission, built once per
+        (padded suffix length, prefix length) and kept in `_prefill_fns`:
+        (state_vals, ids[1, s_pad], n_real, prefix_caches) ->
+        (logits_last[vocab], k_blocks, v_blocks).  The forward over the
+        suffix on top of the gathered prefix (None when m_len == 0), the
+        logits of position n_real - 1 (a traced scalar: one program serves
+        every real length of its bucket), and every layer's new K/V already
+        in the pool's block layout, [ceil(s_pad / bs), Nkv, bs, H] a layer.
+        Right padding is invisible to the real positions under the causal
+        bottom-right-aligned mask; positions at or past n_real are ZEROED
+        before the blocks are shaped, so a partial block's tail (and every
+        block past it) holds what the eager pour's jnp.pad leaves there —
+        an int8 pool takes its per-block scale from the whole block.
+        Weights enter as arguments and are bound under the trace, as in
+        _build_step; the pools stay outside."""
+        fn = self._prefill_fns.get((s_pad, m_len))
+        if fn is not None:
+            return fn
+        from paddle_tpu._core.autograd import no_grad
+        from paddle_tpu._core.tensor import Tensor
+        from paddle_tpu.models.llama import (_empty_caches,
+                                             _model_forward_cached)
+
+        model, state, bs = self.model, self._state, self.block_size
+
+        def prefill_program(state_vals, ids, n_real, prefix_caches):
+            originals = [t._value for t in state]
+            try:
+                for t, v in zip(state, state_vals):
+                    t._bind(v)
+                with no_grad():
+                    caches = (_empty_caches(model.config, 1) if not m_len
+                              else [(Tensor(k), Tensor(v))
+                                    for k, v in prefix_caches])
+                    h, caches = _model_forward_cached(
+                        model.model, Tensor(ids), caches, m_len)
+                    last = jax.lax.dynamic_slice_in_dim(
+                        h._value, n_real - 1, 1, axis=1)
+                    logits_last = model._logits(Tensor(last))._value[0, -1]
+                real = (jnp.arange(m_len + s_pad)
+                        < m_len + n_real)[None, :, None, None]
+                k_blocks, v_blocks = _cache_blocks(
+                    [(jnp.where(real, k._value, 0),
+                      jnp.where(real, v._value, 0)) for k, v in caches],
+                    m_len, m_len + s_pad, bs)
+                return logits_last, k_blocks, v_blocks
+            finally:
+                for t, v in zip(state, originals):
+                    t._bind(v)
+
+        # `jit_prefill_program` on the trace's XLA Modules line, beside
+        # `jit_decode_macro_step`
+        fn = self._prefill_fns[(s_pad, m_len)] = jax.jit(prefill_program)
+        return fn
+
+    def _prefill_compiled(self, prompt, prefix, m_len, acc):
+        """Run the suffix prompt[:, m_len:] through its bucket's program:
+        (logits_last, k_blocks, v_blocks), the blocks of the padded bucket
+        (all-zero past the real tokens).  Nothing is read back here."""
+        suffix = prompt[:, m_len:]
+        s = suffix.shape[1]
+        s_pad = self._prefill_bucket(s, m_len)
+        acc["prefill_programs_built"] = int(
+            (s_pad, m_len) not in self._prefill_fns)
+        fn = self._prefill_program(s_pad, m_len)
+        ids = np.zeros((1, s_pad), np.int32)
+        ids[:, :s] = suffix
+        out = fn([t._value for t in self._state], ids, np.int32(s), prefix)
+        acc["prefill_program_calls"] = acc["admit_eager_ops"] = 1
+        acc["prefill_pad_tokens"] = s_pad - s
+        return out
 
     # ------------------------------------- interleaved prefill (PREFILLING)
     def _begin_prefill(self, req):
@@ -1598,9 +1763,10 @@ class GenerationEngine:
             logits_last = self.model._logits(
                 st.h[:, -1:, :])._value[0, -1, :]
         first = int(np.asarray(jnp.argmax(logits_last)))
-        self._pour(self._kpools, self._vpools, st.caches, slot.blocks, s0,
-                   self._nkv, self._head_dim, sharding=self._pool_sharding,
-                   start_tok=st.poured * bs)
+        self._pour(self._kpools, self._vpools,
+                   *_cache_blocks(st.caches, st.poured * bs, s0, bs),
+                   slot.blocks[st.poured:], sharding=self._pool_sharding)
+        _DECODE_STATS["prefill_eager_fallbacks"] += 1
         slot.active = True
         slot.prefill = None
         slot.seq_len = s0
@@ -1765,34 +1931,17 @@ class GenerationEngine:
                         Tensor(jnp.moveaxis(vv, 1, 2).astype(dt))))
         return out
 
-    def _pour(self, kpools, vpools, caches, blocks, s0, nkv, head_dim,
-              sharding=None, start_tok=0):
-        """Scatter naive prefill caches into a request's pool pages.
-
-        start_tok (always block-aligned) skips the prefix-matched region:
-        `caches` hold the FULL logical sequence (gathered prefix +
-        computed suffix) but only blocks[start_tok//bs:] — the request's
-        exclusively owned pages — are written.  Quantized pools get fresh
-        per-block-per-head scales here (paged_pour_blocks)."""
-        from paddle_tpu.ops import paged_attention as pa
-
-        bs = self.block_size
-        b0 = start_tok // bs
-        tgt = blocks[b0:]
-        n_t = len(tgt)
-        pad = b0 * bs + n_t * bs - s0
-        idx = jnp.asarray(tgt, jnp.int32)
-        for li, (k, v) in enumerate(caches):
-            kv = jnp.moveaxis(k._value, 1, 2)[:, :, start_tok:]  # [1,Nkv,S',H]
-            vv = jnp.moveaxis(v._value, 1, 2)[:, :, start_tok:]
-            if pad:
-                kv = jnp.pad(kv, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                vv = jnp.pad(vv, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            # [1, Nkv, n_t*bs, H] -> n_t x [Nkv, bs, H]
-            kv = kv.reshape(nkv, n_t, bs, head_dim).swapaxes(0, 1)
-            vv = vv.reshape(nkv, n_t, bs, head_dim).swapaxes(0, 1)
-            kpools[li] = pa.paged_pour_blocks(kpools[li], kv, idx)
-            vpools[li] = pa.paged_pour_blocks(vpools[li], vv, idx)
+    def _pour(self, kpools, vpools, k_blocks, v_blocks, pages,
+              sharding=None):
+        """Scatter per-layer blocks (`_cache_blocks` layout) into `pages`,
+        a request's exclusively owned pool pages in order: one
+        `paged_pour_blocks` per pool.  Pages past the given blocks — the
+        request's future decode pages — are poured with zeros, which on a
+        quantized pool also resets a recycled page's stale scale."""
+        idx = jnp.asarray(pages, jnp.int32)
+        for li in range(len(kpools)):
+            kpools[li] = _pour_new_blocks(kpools[li], k_blocks[li], idx)
+            vpools[li] = _pour_new_blocks(vpools[li], v_blocks[li], idx)
             if sharding is not None:
                 # keep the pool committed to its head-sharded layout so the
                 # decode executable's input shardings stay stable
@@ -2359,11 +2508,13 @@ class GenerationEngine:
         effective chunk).  There is no separate tail program to warm: the
         macro-step's done-mask design parks rows that finish mid-chunk on
         their scratch pages in-device, so the one D-token executable IS
-        the tail executable.  `prefill=True` additionally runs the
-        admission prefill forward for a single-block prompt over empty
-        caches (the eager dispatch path keys on prompt length, so this
-        warms the one length every full-block admission dispatches;
-        longer prompts still compile lazily).  `adopt=True` round-trips
+        the tail executable.  `prefill=True` additionally builds (and
+        runs once, on a dummy prompt) the admission prefill program of a
+        single-block prompt over no prefix: programs are keyed by the
+        suffix length rounded up to a power of two (at least one block)
+        and the matched prefix length, so this readies every admission of
+        at most block_size tokens; longer buckets still compile lazily,
+        once each, at their first admission.  `adopt=True` round-trips
         one scratch page through pool_get_blocks/pool_set_blocks — the
         page-shipping adopt path's gather/scatter programs.
 
@@ -2385,16 +2536,11 @@ class GenerationEngine:
                                          .compile())
                 warmed.append(D)
         if prefill:
-            import paddle_tpu as paddle
-            from paddle_tpu.models.llama import _model_forward_cached
-
-            caches = self._prefix_or_empty(
-                self._kpools, self._vpools, [], 0, self._n_layers,
-                self._nkv, self._head_dim, self.model.config.dtype)
-            dummy = np.zeros((1, self.block_size), np.int32)
-            with paddle.no_grad():
-                _model_forward_cached(self.model.model,
-                                      paddle.to_tensor(dummy), caches, 0)
+            s_pad = self._prefill_bucket(self.block_size, 0)
+            jax.block_until_ready(self._prefill_program(s_pad, 0)(
+                [t._value for t in self._state],
+                np.zeros((1, s_pad), np.int32), np.int32(self.block_size),
+                None))
         if adopt and self._prefix is not None and self.draft_model is None \
                 and self._pack is None:
             from paddle_tpu.ops import paged_attention as pa

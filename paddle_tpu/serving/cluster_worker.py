@@ -436,16 +436,20 @@ def _standby_loop(spec, model, ring_in, out, killer):
         # the flags listener clears EVERY engine's compiled steps on ANY
         # set_flags — hold the warm executables across the snapshot-dir
         # arm and reinstall them
-        step_fns = dict(eng._step_fns)
+        step_fns, prefill_fns = dict(eng._step_fns), dict(eng._prefill_fns)
         paddle.set_flags({
             "FLAGS_engine_snapshot_dir": snap_dir,
             "FLAGS_engine_snapshot_interval": interval})
         eng._step_fns.update(step_fns)
+        eng._prefill_fns.update(prefill_fns)
     store = EngineSnapshot(snap_dir) if snap_dir else None
     if store is not None and store.latest_step() is not None:
         restored = store.restore(model)
         if _carries_executables(eng, store.config()):
             restored._step_fns.update(eng._step_fns)
+            # prefill programs close over the model alone (bucket, prefix
+            # length and block size are their key and the geometry)
+            restored._prefill_fns.update(eng._prefill_fns)
         eng = restored
         tracked = _claimed_rids(eng)
     out.push({"t": "resume", "rids": sorted(tracked, key=str),

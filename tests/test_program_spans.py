@@ -230,6 +230,7 @@ def test_jitted_programs_are_named_for_what_they_are(tiny_model):
     assert step._compiled.__name__ == "train_step"
     eng = _engine(tiny_model)
     assert eng._build_step(8).__name__ == "decode_macro_step"
+    assert eng._prefill_program(16, 0).__name__ == "prefill_program"
 
 
 # ------------------------------------------------------ the admission counters
