@@ -4,6 +4,9 @@
     python chip_smoke.py               # one TPU chip: train phase, serve phase
     python chip_smoke.py --four-chips  # four chips: the sharded train step and
                                        # the one-chip step it is compared with
+    python chip_smoke.py --mla-moe-logits  # one chip: the latent-attention /
+                                       # routed-expert model's LOGITS through
+                                       # the engine against its plain reference
 
 Drives the two entry points users of this framework call — the compiled train
 step (`paddle_tpu.jit.TrainStep`) and the serving engine
@@ -48,6 +51,39 @@ TRAIN_STATE_BYTES_PER_PARAM = 14
 # rest is for the program itself, the batch, the copy of the state that
 # arrives from the host on the first call, and fragmentation.
 MEMORY_SHARE = 0.8
+
+
+# The latent-attention / routed-expert logit comparison (--mla-moe-logits;
+# PERF.md section 6, PR 27, has the readings these were set from).  The error
+# of a row of logits is max |program - reference| over the vocabulary in units
+# of the reference row's standard deviation.
+# A token whose k-th and (k+1)-th router logits lie within ROUTER_TIE_TAU of
+# each other, one of the two experts held here, is a NEAR TIE: rounding in the
+# program's bfloat16 hidden state may hand it another expert's output, a
+# different result and not a less precise one.  Near ties are counted and
+# compared against the looser MLA_MOE_TIE_TOL; every other row against
+# MLA_MOE_LOGIT_TOL.
+MLA_MOE_LOGIT_TOL = 0.08
+MLA_MOE_TIE_TOL = 0.6
+ROUTER_TIE_TAU = 0.05
+# Rows of logits are five layers of bfloat16 activations away from the float32
+# reference (an rms of 0.012 sigma), which hides what one type inside the
+# router or the softmax does.  So those two are ALSO compared on the same
+# inputs, where nothing upstream differs (`precision_probes`):
+# the share of (token, expert layer) pairs for which the program's router,
+# handed the reference's router inputs, chooses the reference's experts
+# (float32 at highest precision: every pair but an exact tie; bfloat16: about
+# 0.7, CPU rehearsal at the published router's widths) ...
+ROUTE_AGREEMENT_MIN = 0.95
+# ... and the rms error of the program's decode attention over a paged pool
+# against a float32 softmax on the same bfloat16 queries and rows, relative to
+# the output's rms, with scores spread as a trained model's are (standard
+# deviation SOFTMAX_SCORE_SPREAD; the seeded weights give about 0.3, where
+# thousands of near-equal probabilities average any rounding away).  CPU
+# rehearsal at the cell's sizes: float32 softmax 0.0012-0.0016 (what rounding
+# the probabilities to the rows' type costs), bfloat16 softmax 0.016-0.018.
+SOFTMAX_SCORE_SPREAD = 4.0
+SOFTMAX_RMS_TOL = 0.005
 
 
 def say(msg: str):
@@ -345,6 +381,250 @@ def serve_phase(cfg, *, prompt_lens, max_new_tokens, seed, device,
     return {"results": results, "exact_first_tokens": exact}
 
 
+# ------------------------------------- latent attention, routed experts ----
+
+def _row_error(got, want):
+    import numpy as np
+
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / want.std())
+
+
+def mla_moe_logits_phase(cfg_file, *, seed, device, prompt_lens, decoded,
+                         block_size) -> dict:
+    """The configuration `cfg_file` (a perfbench configuration of the
+    `mla_moe` family) served by GenerationEngine: one prompt of each length,
+    and LOGITS, not tokens, against the plain float32 reference's full forward
+    pass at three places each — the prefill program's last position, and the
+    decode step through the paged latent pool after `decoded[0]` and
+    `decoded[1]` decoded tokens, teacher-forced on the engine's own tokens;
+    then `precision_probes`.
+
+    The logits are the engine's own: the prefill program is the executable the
+    admission ran (`_prefill_fns`), and the decode logits are the contract's
+    decode step — what the macro-step scans — over the engine's RESIDENT pools
+    at that boundary; each is tied to the stream by its argmax being the token
+    the engine then emitted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import mla_moe
+    from paddle_tpu.serving import GenerationEngine
+    from perfbench import reference_mla_moe as ref
+    from perfbench.families import mla_moe as fam
+
+    chunk = 8    # FLAGS_decode_chunk's default: tokens a macro-step
+    assert all(d % chunk == 0 for d in decoded), "whole macro-steps"
+    model = fam.build(cfg_file, seed, training=False)
+    cfg = model.config
+    jax.block_until_ready([p._value for p in model.parameters()])
+    blocks = max(-(-(n + decoded[-1] + 2 * chunk) // block_size)
+                 for n in prompt_lens)
+    eng = GenerationEngine(model, max_batch=len(prompt_lens),
+                           block_size=block_size,
+                           num_blocks=blocks * len(prompt_lens))
+    contract = model.serving_contract()
+    rng = np.random.default_rng([seed % 2 ** 63, 27])
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in prompt_lens]
+    say(f"mla_moe logits: {cfg.num_hidden_layers} layers, hidden "
+        f"{cfg.hidden_size}, experts {cfg.held} of {cfg.n_routed_experts}, "
+        f"prompts {list(prompt_lens)}, logits at the prefill and after "
+        f"{list(decoded)} decoded tokens")
+
+    def prefill_logits(i):
+        s = len(prompts[i])
+        s_pad = eng._prefill_bucket(s, 0)
+        ids = np.zeros((1, s_pad), np.int32)
+        ids[0, :s] = prompts[i]
+        return np.asarray(eng._prefill_program(s_pad, 0)(
+            [t._value for t in eng._state], ids, np.int32(s), None)[0],
+            np.float32)
+
+    def decode_logits():
+        """The next token's logits of every resident row, from the pools as
+        the engine holds them now (functional: the pools are not touched)."""
+        w = eng._max_blocks_per_seq
+        tables = jnp.asarray([list(s.blocks) + [s.blocks[-1]] * (w - len(s.blocks))
+                              for s in eng._slots], jnp.int32)
+        lens = jnp.asarray([s.seq_len + 1 for s in eng._slots], jnp.int32)
+        tok = jnp.asarray([[s.last_token] for s in eng._slots], jnp.int32)
+        with paddle.no_grad():
+            h, _, _ = contract.decode(tok, [list(p) for p in eng._pools],
+                                      tables, lens)
+            return np.asarray(contract.logits(h)._value[:, -1], np.float32)
+
+    def drive():
+        """Admit, decode to both boundaries; the program's logits per request
+        and place, and the tokens."""
+        firsts = [eng.add_request(f"r{i}", p, max_new_tokens=decoded[-1] + 2 * chunk)
+                  for i, p in enumerate(prompts)]
+        got = [[prefill_logits(i)] for i in range(len(prompts))]
+        for i, first in enumerate(firsts):
+            check(int(got[i][0].argmax()) == first,
+                  f"r{i}: the prefill program's argmax is the first token")
+        steps = 0
+        for d in decoded:
+            while steps < d // chunk:
+                eng.step()
+                steps += 1
+            lg = decode_logits()
+            for i in range(len(prompts)):
+                got[i].append(lg[i])
+        eng.step()       # the tokens those last logits predict
+        toks = [list(eng._results[f"r{i}"]) for i in range(len(prompts))]
+        for i in range(len(prompts)):
+            check(int(got[i][-1].argmax()) == toks[i][decoded[-1] + 1],
+                  f"r{i}: the decode step's argmax over the resident pool is "
+                  f"the token the engine emitted next")
+        while eng.has_work():
+            eng.step()
+        return got, toks
+
+    t0 = time.perf_counter()
+    got, toks = drive()
+    say(f"mla_moe logits: engine driven in {time.perf_counter() - t0:.1f} s")
+    weights, sizes = fam.reference_weights(model), fam.reference_sizes(cfg_file)
+    errors, ties, refs = [], [], []
+    for i, p in enumerate(prompts):
+        t0 = time.perf_counter()
+        ids = np.concatenate([p, np.asarray(toks[i][:decoded[-1] + 1], np.int32)])
+        at = [len(p) - 1] + [len(p) + d for d in decoded]
+        want, tie = ref.logits_and_near_ties(weights, sizes, ids, at,
+                                             ROUTER_TIE_TAU)
+        want, tie = np.asarray(want), np.asarray(tie)
+        refs.append((ids, at, want, tie))
+        for j, place in enumerate(["prefill"] + [f"decoded {d}" for d in decoded]):
+            err = _row_error(got[i][j], want[j])
+            errors.append(err)
+            ties.append(bool(tie[j]))
+            tol = MLA_MOE_TIE_TOL if tie[j] else MLA_MOE_LOGIT_TOL
+            check(err <= tol,
+                  f"r{i} (prompt {len(p)}) {place}: logits within {tol} sigma "
+                  f"of the reference ({err:.4f}"
+                  + (", a near tie of its own routing)" if tie[j] else ")"))
+        say(f"mla_moe logits: reference of r{i} in {time.perf_counter() - t0:.1f} s")
+    clean = [e for e, t in zip(errors, ties) if not t]
+    say(f"mla_moe logits: {len(errors)} rows compared, {sum(ties)} near ties; "
+        f"worst clean row {max(clean):.4f} sigma, worst of all "
+        f"{max(errors):.4f} sigma (limits {MLA_MOE_LOGIT_TOL} and "
+        f"{MLA_MOE_TIE_TOL})")
+    out = {"errors": errors, "ties": ties}
+    del eng
+    out.update(precision_probes(model, weights, sizes, refs[0][0][:len(prompts[0])],
+                                seed=seed, block_size=block_size,
+                                lens=[n + decoded[-1] for n in prompt_lens]))
+    _release()
+    return out
+
+
+def _bfloat16_route(m, router_w, *, top_k, scale, normalize=True):
+    """`models.mla_moe.route` with every type lowered: the control."""
+    import jax
+    import jax.numpy as jnp
+
+    bf = jnp.bfloat16
+    score = jax.nn.sigmoid(jnp.dot(m.astype(bf), router_w.astype(bf)))
+    top_s, top_i = jax.lax.top_k(score, top_k)
+    w = top_s / jnp.sum(top_s, -1, keepdims=True) if normalize else top_s
+    return top_i.astype(jnp.int32), (w * bf(scale)).astype(jnp.float32)
+
+
+def _bfloat16_softmax_attention(q, pool, tables, lens, *, rank, width):
+    """`models.mla_moe.absorbed_attention` with the scores rounded to
+    bfloat16 and the softmax computed in it: the control."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    keys = pa.paged_gather(pool, tables)[:, 0]
+    score = jnp.einsum("bnr,bsr->bns", q, keys,
+                       preferred_element_type=jnp.float32)
+    score = score.astype(jnp.bfloat16) / jnp.bfloat16(math.sqrt(width))
+    seen = jnp.arange(keys.shape[1])[None, :] < lens[:, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None, :], score, -1e30), axis=-1)
+    return jnp.einsum("bns,bsr->bnr", p.astype(keys.dtype), keys[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
+def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
+    """The router and the decode softmax, each against the reference ON THE
+    SAME INPUTS, and each once more with its types lowered to bfloat16 (the
+    controls, which `main` requires to fail at the published widths; a
+    rehearsal's few dozen tokens and eight experts have no near ties to
+    show): what a row of logits cannot tell.
+
+    Router: `families/mla_moe.routing_agreement` over the prompt `ids` — the
+    program's `route` on the reference's own router inputs.  Softmax: the
+    program's `absorbed_attention` over a paged pool of seeded rows (one row
+    a live token, `lens` live tokens a sequence, pages in a shuffled order)
+    against `reference_mla_moe.absorbed_attention`, queries scaled so that
+    the scores spread as a trained model's do."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.models import mla_moe
+    from perfbench import reference_mla_moe as ref
+    from perfbench.families import mla_moe as fam
+
+    cfg = model.config
+    out = {}
+    for name, router in (("route_agreement", None),
+                         ("route_agreement_bfloat16", _bfloat16_route)):
+        out[name], pairs = fam.routing_agreement(model, weights, sizes, ids,
+                                                 ref, route=router)
+    say(f"mla_moe probes: on the reference's router inputs the program's "
+        f"router chooses the reference's experts for "
+        f"{100 * out['route_agreement']:.2f}% of {pairs} (token, expert layer)"
+        f" pairs; a bfloat16 router for "
+        f"{100 * out['route_agreement_bfloat16']:.2f}% (limit "
+        f"{100 * ROUTE_AGREEMENT_MIN:.0f}%)")
+    check(out["route_agreement"] >= ROUTE_AGREEMENT_MIN,
+          "the program's router agrees with the reference on its own inputs")
+
+    rank, width = cfg.kv_lora_rank, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    heads, row = cfg.num_attention_heads, cfg.latent_width
+    per = -(-max(lens) // block_size)
+    draw = np.random.default_rng([seed % 2 ** 63, 28])
+    tables = jnp.asarray(draw.permutation(per * len(lens)).reshape(len(lens), per),
+                         jnp.int32)
+    k_pool, k_q = jax.random.split(jax.random.key(seed % 2 ** 31))
+    pool = jax.random.normal(k_pool, (per * len(lens), 1, block_size, row),
+                             jnp.float32).astype(jnp.bfloat16)
+    lens = jnp.asarray(lens, jnp.int32)
+    rows = np.asarray(pool[:, 0])[np.asarray(tables)].reshape(
+        len(lens), per * block_size, row)
+    for spread in (SOFTMAX_SCORE_SPREAD, 0.3):
+        # unit-variance rows: a score's deviation is |q| / sqrt(width)
+        q = (jax.random.normal(k_q, (len(lens), heads, row), jnp.float32)
+             * spread * math.sqrt(width / row)).astype(jnp.bfloat16)
+        want = np.asarray(ref.absorbed_attention(q, rows, lens, rank, width))
+        for name, fn in (("float32", mla_moe.absorbed_attention),
+                         ("bfloat16", _bfloat16_softmax_attention)):
+            got = np.asarray(jax.jit(
+                lambda q, pool, tables, lens, fn=fn: fn(
+                    q, pool, tables, lens, rank=rank, width=width))(
+                        q, pool, tables, lens))
+            out[f"softmax_rms_{name}_spread_{spread:g}"] = float(
+                np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean()))
+        say(f"mla_moe probes: decode attention over {list(map(int, lens))} live "
+            f"rows, scores spread {spread:g}: rms error "
+            f"{out[f'softmax_rms_float32_spread_{spread:g}']:.5f} of the "
+            f"output's rms; with the softmax in bfloat16 "
+            f"{out[f'softmax_rms_bfloat16_spread_{spread:g}']:.5f}"
+            + (f" (limit {SOFTMAX_RMS_TOL})" if spread == SOFTMAX_SCORE_SPREAD
+               else " (the seeded weights' spread: no limit)"))
+    s = f"{SOFTMAX_SCORE_SPREAD:g}"
+    check(out[f"softmax_rms_float32_spread_{s}"] <= SOFTMAX_RMS_TOL,
+          "the program's decode softmax agrees with a float32 softmax on the "
+          "same inputs")
+    return out
+
+
 # ---------------------------------------------------------- four chips ----
 
 def four_chip_phase(cfg, *, batch, seq, steps, seed, devices, lr=3e-4) -> dict:
@@ -461,6 +741,11 @@ def main(argv=None) -> int:
                     help="run ONLY the sharded train step on a dp=2 x mp=2 "
                          "mesh of four chips and the one-chip step it is "
                          "compared with")
+    ap.add_argument("--mla-moe-logits", action="store_true",
+                    help="run ONLY the logit comparison of the latent-"
+                         "attention / routed-expert configuration "
+                         "(perfbench/configs/openpangu-ultra-moe-718b.json) "
+                         "through GenerationEngine against its reference")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -488,7 +773,24 @@ def main(argv=None) -> int:
     seq = 2048
     t_start = time.perf_counter()
 
-    if args.four_chips:
+    if args.mla_moe_logits:
+        import os
+
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "perfbench", "configs",
+                               "openpangu-ultra-moe-718b.json")) as f:
+            cfg_file = json.load(f)
+        out = mla_moe_logits_phase(cfg_file, seed=args.seed, device=dev,
+                                   prompt_lens=(2048, 4096, 8192),
+                                   decoded=(8, 64), block_size=128)
+        check(out["route_agreement_bfloat16"] < ROUTE_AGREEMENT_MIN,
+              "a bfloat16 router does NOT agree with the reference on its "
+              "inputs: the comparison tells it from float32")
+        check(out[f"softmax_rms_bfloat16_spread_{SOFTMAX_SCORE_SPREAD:g}"]
+              > SOFTMAX_RMS_TOL,
+              "a bfloat16 softmax does NOT agree with a float32 softmax on "
+              "the same inputs: the comparison tells it from float32")
+    elif args.four_chips:
         batch = 2  # one sequence per data-parallel group
         depth, why = choose_depth("train", widths, limit, batch=batch, seq=seq,
                                   ceiling=1)

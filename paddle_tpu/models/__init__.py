@@ -6,6 +6,12 @@ from .llama import (  # noqa: F401
     llama_7b,
     llama_tiny,
 )
+from .mla_moe import (  # noqa: F401
+    MlaMoeConfig,
+    MlaMoeForCausalLM,
+    MlaMoeModel,
+    mla_moe_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForMaskedLM,

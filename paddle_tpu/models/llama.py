@@ -21,6 +21,7 @@ import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu._core.tensor import Tensor
+from paddle_tpu.models.contract import CacheSpec, PoolSpec, ServingContract
 from paddle_tpu.tensor._ops_common import apply
 
 __all__ = [
@@ -634,6 +635,60 @@ def _model_forward_cached(model: "LlamaModel", input_ids, caches, position_offse
     return model.norm(h), new_caches
 
 
+class LlamaServing(ServingContract):
+    """The model contract (models/contract.py) of the dense GQA decoder: a
+    K pool and a V pool a layer, `num_key_value_heads` rows of `head_dim`.
+    Every method is the function the engine called directly before the
+    contract existed, so streams and programs are unchanged."""
+
+    def __init__(self, lm: "LlamaForCausalLM"):
+        cfg = lm.config
+        self.lm = lm
+        head_dim = cfg.hidden_size // cfg.num_attention_heads
+        dtype = "bfloat16" if cfg.dtype == "bfloat16" else "float32"
+        self.spec = CacheSpec(cfg.num_hidden_layers, tuple(
+            PoolSpec(n, cfg.num_key_value_heads, head_dim, dtype)
+            for n in ("k", "v")))
+
+    @property
+    def max_positions(self) -> int:
+        return int(self.lm.model.rope_cos.shape[0])
+
+    @property
+    def num_query_heads(self) -> int:
+        return self.lm.config.num_attention_heads
+
+    def forward_cached(self, ids, caches, offset, n_real=None):
+        h, caches = _model_forward_cached(self.lm.model, ids, caches, offset)
+        return h, caches, {}
+
+    def decode(self, tokens, pools, tables, lens, active=None, **kv_only):
+        model = self.lm.model
+        h = model.embed_tokens(Tensor(tokens))
+        h, kps, vps = _decode_layers_paged(
+            model.layers, h, model.rope_cos._value, model.rope_sin._value,
+            pools[0], pools[1], tables, lens, **kv_only)
+        return model.norm(h), [kps, vps], {}
+
+    def logits(self, h):
+        return self.lm._logits(h)
+
+    def pool_carry(self, pools):
+        return list(_pool_carry(self.lm.model.layers, *pools))
+
+    def pool_unpack(self, pools):
+        return list(_pool_unpack(self.lm.model.layers, *pools))
+
+    def prefill_scope(self, cfg):
+        return prefill_chain_scope(cfg)
+
+    def shard(self, mesh, mp_axis):
+        shard_llama(self.lm, mesh, mp_axis=mp_axis)
+
+    def adapter_layers(self):
+        return self.lm.model.layers
+
+
 class LlamaForCausalLM(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -665,6 +720,10 @@ class LlamaForCausalLM(nn.Layer):
         if self.lm_head is not None:
             return self.lm_head(h)
         return paddle.matmul(h, self.model.embed_tokens.weight, transpose_y=True)
+
+    def serving_contract(self) -> LlamaServing:
+        """What `serving.GenerationEngine` asks of this model."""
+        return LlamaServing(self)
 
     @paddle.no_grad()
     def _speculative_decode(self, input_ids, max_new_tokens, draft_model, K):

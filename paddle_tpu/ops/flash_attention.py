@@ -125,8 +125,10 @@ def _require_vmem(kernel, seq_name, seq, row_bytes, tile_bytes):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
-    # q_ref: [bq, H]; k_ref/v_ref: [S, H]; o_ref: [bq, H]; lse_ref: [bq, 128]
-    bq, head_dim = q_ref.shape
+    # q_ref: [bq, H]; k_ref: [S, H]; v_ref: [S, Hv]; o_ref: [bq, Hv];
+    # lse_ref: [bq, 128].  Hv == H everywhere but latent attention's prefill
+    # (q/k 192 = nope 128 + rope 64, v 128: models/mla_moe.py)
+    bq, head_dim = q_ref.shape[0], v_ref.shape[1]
     seq_k = k_ref.shape[0]
     qi = pl.program_id(2)  # q-block index
     q = q_ref[:].astype(jnp.float32) * jnp.float32(scale)
@@ -176,16 +178,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
 
 
 def _fwd(q, k, v, scale, causal, block_q, block_k):
-    # q: [B, N, Sq, H]; k/v: [B, Nkv, Sk, H]
+    # q: [B, N, Sq, H]; k: [B, Nkv, Sk, H]; v: [B, Nkv, Sk, Hv]
     batch, num_heads, seq_q, head_dim = q.shape
-    num_kv_heads, seq_k = k.shape[1], k.shape[2]
+    num_kv_heads, seq_k, v_dim = k.shape[1], k.shape[2], v.shape[3]
     group = num_heads // num_kv_heads
     grid = (batch, num_heads, seq_q // block_q)
     interpret = _pl_utils.interpret()
     if not interpret:
         isz = q.dtype.itemsize
-        _require_vmem("forward", "seq_k", seq_k, 2 * head_dim * isz,
-                      2 * block_q * head_dim * isz + block_q * 128 * 4)
+        _require_vmem("forward", "seq_k", seq_k, (head_dim + v_dim) * isz,
+                      block_q * (head_dim + v_dim) * isz + block_q * 128 * 4)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=block_k),
@@ -193,14 +195,14 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((None, None, block_q, head_dim), imap(lambda b, n, i: (b, n, i, 0))),
             pl.BlockSpec((None, None, seq_k, head_dim), imap(lambda b, n, i: (b, n // group, 0, 0))),
-            pl.BlockSpec((None, None, seq_k, head_dim), imap(lambda b, n, i: (b, n // group, 0, 0))),
+            pl.BlockSpec((None, None, seq_k, v_dim), imap(lambda b, n, i: (b, n // group, 0, 0))),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, block_q, head_dim), imap(lambda b, n, i: (b, n, i, 0))),
+            pl.BlockSpec((None, None, block_q, v_dim), imap(lambda b, n, i: (b, n, i, 0))),
             pl.BlockSpec((None, None, block_q, 128), imap(lambda b, n, i: (b, n, i, 0))),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:3] + (v_dim,), q.dtype),
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, 128), jnp.float32),
         ],
         interpret=interpret,
@@ -398,6 +400,8 @@ def _ragged(seq_q, seq_k, block_q, block_k):
 
 def flash_attention(q, k, v, *, causal=False, scale=None):
     """Blockwise flash attention.  q/k/v: [B, S, N, H] (paddle layout).
+    v may have a width of its own ([B, S, N, Hv]; the output takes it):
+    that case is forward-only, the backward kernels keep one width.
 
     Lengths the kernels cannot tile (see _ragged): causal self-attention
     (Sq == Sk, e.g. a prompt of any length) is zero-padded to a multiple of
@@ -438,7 +442,10 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
             stacklevel=2,
         )
         return flash_attention_reference(q, k, v, causal=causal, scale=scale)
-    out = _flash_bnsh(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
+    if vt.shape[-1] != qt.shape[-1]:
+        out, _ = _fwd(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
+    else:
+        out = _flash_bnsh(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
     return jnp.swapaxes(out[:, :, :seq_q], 1, 2)
 
 

@@ -60,7 +60,11 @@ def _pallas_rows(kernel, x2d, params, out_dtype, rows_block=None):
     rows, hidden = x2d.shape
     br = rows_block or _rows_block(rows, hidden, x2d.dtype)
     if rows % br:
-        br = rows  # small/ragged: single block
+        # the largest multiple of 8 under the cap that tiles the rows
+        # (8192 rows of 7680: the cap is 136, the block 128); a single
+        # block only for small or ragged row counts that nothing tiles
+        br = next((b for b in range(br - br % 8, 7, -8) if rows % b == 0),
+                  rows)
     grid = (rows // br,)
     in_specs = [pl.BlockSpec((br, hidden), imap(lambda i: (i, 0)))]
     in_specs += [pl.BlockSpec((hidden,), imap(lambda i: (0,))) for _ in params]

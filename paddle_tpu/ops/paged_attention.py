@@ -29,6 +29,7 @@ import jax.numpy as jnp
 __all__ = [
     "QuantPool",
     "alloc_paged_cache",
+    "alloc_paged_pool",
     "paged_write",
     "paged_write_chunk",
     "paged_pour_blocks",
@@ -204,20 +205,25 @@ def rope_rotate_by_position(t, cos, sin, positions):
     return rope_rotate_chunk(t[:, None], cos, sin, positions[:, None])[:, 0]
 
 
-def alloc_paged_cache(num_blocks, num_kv_heads, block_size, head_dim, dtype=jnp.bfloat16):
-    """One K and one V pool: [num_blocks, Nkv, block_size, H].
+def alloc_paged_pool(num_blocks, heads, block_size, width, dtype=jnp.bfloat16):
+    """One pool [num_blocks, heads, block_size, width] of zeros: a token
+    occupies `heads` rows of `width` values (a K or a V pool: Nkv rows of
+    H; a latent-attention pool: one row of the latent plus the rope key).
 
-    dtype 'int8' (or jnp.int8) allocates QuantPool pairs instead — int8
+    dtype 'int8' (or jnp.int8) allocates a QuantPool instead — int8
     payload plus per-block-per-head float32 scales (FLAGS_kv_cache_dtype).
     """
-    shape = (num_blocks, num_kv_heads, block_size, head_dim)
+    shape = (num_blocks, heads, block_size, width)
     if jnp.dtype(dtype) == jnp.int8:
-        def _one():
-            return QuantPool(jnp.zeros(shape, jnp.int8),
-                             jnp.zeros((num_blocks, num_kv_heads), jnp.float32))
+        return QuantPool(jnp.zeros(shape, jnp.int8),
+                         jnp.zeros((num_blocks, heads), jnp.float32))
+    return jnp.zeros(shape, dtype)
 
-        return _one(), _one()
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+def alloc_paged_cache(num_blocks, num_kv_heads, block_size, head_dim, dtype=jnp.bfloat16):
+    """One K and one V pool (`alloc_paged_pool` twice)."""
+    return tuple(alloc_paged_pool(num_blocks, num_kv_heads, block_size,
+                                  head_dim, dtype) for _ in range(2))
 
 
 def paged_write(cache, new, block_tables, positions):
@@ -245,6 +251,16 @@ def paged_gather(cache, block_tables):
         pages = jnp.take(cache.data, block_tables, axis=0)  # [B,mb,Nkv,bs,H]
         scales = jnp.take(cache.scale, block_tables, axis=0)  # [B,mb,Nkv]
         pages = pages.astype(jnp.float32) * scales[..., None, None]
+    elif cache.shape[1] == 1:
+        # one row a token (a latent pool, or one K/V head): whole pages by a
+        # clipped take and a reshape that moves nothing, ONE pass over the
+        # pages.  The general form below also moves the heads axis and
+        # fills out-of-range pages: three more passes over the table width,
+        # half of a latent-attention token step (PERF.md section 6, PR 27).
+        # Block tables hold valid pages only, so clipping changes nothing.
+        pages = jnp.take(cache[:, 0], block_tables, axis=0, mode="clip")
+        b, mb, bs, h = pages.shape
+        return pages.reshape(b, 1, mb * bs, h)
     else:
         pages = jnp.take(cache, block_tables, axis=0)  # [B, mb, Nkv, bs, H]
     b, mb, nkv, bs, h = pages.shape

@@ -38,6 +38,7 @@ __all__ = [
     "export_chrome_tracing",
     "load_profiler_result",
     "SPAN_NAMES",
+    "SCOPE_NAMES",
 ]
 
 # Every span name the program emits, layer by layer (PERF.md section 3 says
@@ -53,6 +54,16 @@ SPAN_NAMES = (
     "serving.admit.first_token", "serving.admit.pour",
     "serving.step", "serving.step.schedule", "serving.step.dispatch",
     "serving.step.sync", "serving.step.retire",
+)
+
+# `jax.named_scope`s INSIDE the compiled programs (the prefill program, the
+# decode macro-step): they reach the device operations' names and metadata
+# in a trace, not the host's span buffer, so they are listed apart from
+# SPAN_NAMES (which a Profiler run must reproduce exactly).  The Pallas
+# kernels' names (`name=` on every pallas_call) are in docs/DECODE.md.
+SCOPE_NAMES = (
+    # models/mla_moe.py: latent attention's two paths, the routed experts
+    "mla.prefill", "mla.decode", "moe.route", "moe.experts", "moe.shared",
 )
 
 _active_profiler = None  # checked by the op funnel (cheap global)

@@ -199,7 +199,8 @@ def decode_line(stats: dict) -> str:
     programs' hit share (calls that found their program built) beside it.
     With the prefix cache or capacity counters active, a second line
     reports hits/misses/avoided-prefill-tokens/evictions and pool bytes
-    per resident request (the int8-KV capacity metric)."""
+    per resident request (the int8-KV capacity metric).  A model with routed
+    experts adds a line "Expert load"."""
     adm = stats.get("admissions", 0)
     if not stats.get("dispatches") and not adm:
         return ""
@@ -251,6 +252,21 @@ def decode_line(stats: dict) -> str:
                stats.get("prefix_evictions", 0),
                stats.get("pool_bytes_per_resident", 0.0),
                stats.get("resident_peak", 0))
+        )
+    steps = stats.get("moe_layer_steps", 0)
+    if steps:
+        # routed experts (docs/DECODE.md "Reading expert load"): counted on
+        # the device over the decode steps' expert layers, committed rows only
+        asg, held = stats.get("moe_assignments", 0), stats.get("moe_held_assignments", 0)
+        line += (
+            "\nExpert load: %d expert-layer steps, %d assignments, %d held "
+            "here (%.4f); a step touches %.2f held experts, its busiest "
+            "takes %.2f tokens; prefill %d of %d held"
+            % (steps, asg, held, held / asg if asg else 0.0,
+               stats.get("moe_experts_touched", 0) / steps,
+               stats.get("moe_peak_expert_assignments", 0) / steps,
+               stats.get("moe_prefill_held_assignments", 0),
+               stats.get("moe_prefill_assignments", 0))
         )
     if stats.get("mesh_shape"):
         # TP-sharded engine: per-device pool footprint vs the global total

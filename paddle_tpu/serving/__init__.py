@@ -19,6 +19,7 @@ pool pages; decode then advances all live slots together.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 import warnings
 import weakref
@@ -65,6 +66,13 @@ _DECODE_STATS = {
     # requests observed — bytes/resident is the int8-KV capacity metric
     "pool_bytes": 0,
     "resident_peak": 0,
+    # the same bytes by the cache specification's pool names (the model
+    # contract, docs/DECODE.md): a K/V engine fills k_ and v_, a latent-
+    # attention engine latent_pool_bytes (draft pools count in pool_bytes
+    # only)
+    "k_pool_bytes": 0,
+    "v_pool_bytes": 0,
+    "latent_pool_bytes": 0,
     # sharded-serving tier: the most recent engine's PER-DEVICE pool
     # bytes (each pool leaf's committed sharding divides its global
     # bytes — ops.paged_attention.pool_device_nbytes over pool_parts)
@@ -114,6 +122,23 @@ _DECODE_STATS = {
     "prefill_programs_built": 0,
     "prefill_pad_tokens": 0,
     "prefill_eager_fallbacks": 0,
+    # expert load (docs/DECODE.md "Reading expert load"): counted ON THE
+    # DEVICE by a model with routed experts (the contract's `aux`), summed
+    # over the macro-step's scan and read with its tokens; committed rows
+    # only; zero for a model without experts.  An expert-layer step is one
+    # expert layer on one decode token step.  assignments = tokens x top-k
+    # over those steps, held = the part that chose an expert this chip
+    # holds, peak = tokens on the busiest held expert (summed over the
+    # steps), experts_touched = held experts with at least one token
+    # (summed).  The two moe_prefill_ counters are a committed admission's
+    # prompt tokens x top-k x expert layers, and the held part.
+    "moe_assignments": 0,
+    "moe_held_assignments": 0,
+    "moe_peak_expert_assignments": 0,
+    "moe_experts_touched": 0,
+    "moe_layer_steps": 0,
+    "moe_prefill_assignments": 0,
+    "moe_prefill_held_assignments": 0,
 }
 
 _ADMIT_PHASES = ("match", "prefill", "first_token", "pour")
@@ -276,9 +301,10 @@ def _invalidate_decode_steps(_changed):
 
 
 def _cache_blocks(caches, start_tok, s0, bs):
-    """Naive prefill caches ([1, S, Nkv, H] K and V per layer, Tensors or
-    raw arrays) -> the pool's block layout for tokens [start_tok, s0): per
-    layer [n, Nkv, bs, H], the last block's tail zero-padded.  start_tok is
+    """Naive prefill caches (`caches[layer][p]`: [1, S, heads, width] per
+    pool of the cache specification, Tensors or raw arrays) -> the pools'
+    block layout for tokens [start_tok, s0): `blocks[p][layer]`, each
+    [n, heads, bs, width], the last block's tail zero-padded.  start_tok is
     block-aligned (it skips the prefix-matched region: the caches hold the
     FULL logical sequence).  The ONE shaper: eager admissions call it on
     the host, the prefill program inside its trace."""
@@ -287,22 +313,33 @@ def _cache_blocks(caches, start_tok, s0, bs):
 
     def shape(t):
         kv = jnp.moveaxis(getattr(t, "_value", t), 1, 2)
-        kv = kv[0, :, start_tok:s0]                          # [Nkv, S', H]
+        kv = kv[0, :, start_tok:s0]                      # [heads, S', width]
         if pad:
             kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0)))
-        nkv, _, head_dim = kv.shape
-        return kv.reshape(nkv, n, bs, head_dim).swapaxes(0, 1)
+        heads, _, width = kv.shape
+        return kv.reshape(heads, n, bs, width).swapaxes(0, 1)
 
-    return [shape(k) for k, _ in caches], [shape(v) for _, v in caches]
+    return [[shape(layer[p]) for layer in caches]
+            for p in range(len(caches[0]))]
 
 
-@jax.jit
+def _empty_caches(spec, batch=1):
+    """Length-0 naive caches of a cache specification, `caches[layer][p]`."""
+    import paddle_tpu as paddle
+
+    return [tuple(paddle.zeros([batch, 0, p.heads, p.width], dtype=p.dtype)
+                  for p in spec.pools) for _ in range(spec.n_layers)]
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _pour_new_blocks(pool, blocks, idx):
     """`paged_pour_blocks` of a request's pages `idx` [n_t]: `blocks`
     [n, Nkv, bs, H] cut or zero-extended to n_t blocks.  The caller's blocks
     are all-zero past the request's real tokens (a bucket's padding), so
     cutting drops nothing; extending zeroes the future decode pages.  One
-    compiled scatter per (pool, n, n_t), in place of six eager calls."""
+    compiled scatter per (pool, n, n_t), in place of six eager calls, and in
+    place: the pool is donated (the caller rebinds it at once, as it does
+    after a macro-step), so no second copy of a layer's pool waits for room."""
     from paddle_tpu.ops import paged_attention as pa
 
     n_t = idx.shape[0]
@@ -544,7 +581,7 @@ class GenerationEngine:
                  prefill_chunk_blocks=None):
         """mesh: optional ProcessMesh/jax Mesh with an `mp_axis` dimension —
         the engine then serves TENSOR-PARALLEL: weights get Megatron
-        placements (models.llama.shard_llama), the paged-KV pool is sharded
+        placements (the contract's `shard`), the paged-KV pool is sharded
         over the KV-head dim, and the ONE compiled decode program runs
         GSPMD-partitioned over the mesh (VERDICT r3 #6; reference capability:
         analysis_predictor multi-device serving).  The WHOLE feature set
@@ -606,8 +643,12 @@ class GenerationEngine:
         the per-block pour writes the same bytes (and the same
         per-block quant scales) the atomic pour batches.  Ignored by
         speculative engines (their draft pour rides atomic admission)."""
-        cfg = model.config
         self.model = model
+        # the model contract (models/contract.py): everything the engine
+        # asks of the model goes through it, and every pool it holds comes
+        # from the contract's cache specification
+        contract = self._contract = model.serving_contract()
+        spec = self._spec = contract.spec
         if prefill_chunk is not None and int(prefill_chunk) < 1:
             raise ValueError("prefill_chunk must be a positive token count")
         self.prefill_chunk = None if prefill_chunk is None else int(prefill_chunk)
@@ -619,32 +660,31 @@ class GenerationEngine:
         self.block_size = int(block_size)
         self.max_batch = int(max_batch)
         self.eos_token_id = eos_token_id
-        self._n_layers = cfg.num_hidden_layers
-        self._nkv = cfg.num_key_value_heads
-        self._head_dim = cfg.hidden_size // cfg.num_attention_heads
+        self._n_layers = spec.n_layers
 
         self._pool_sharding = self._d_pool_sharding = None
         self._mp_axis = mp_axis
         if mesh is not None:
             from paddle_tpu.distributed.auto_parallel import ProcessMesh
-            from paddle_tpu.models.llama import shard_llama
 
+            self._require_kv("a mesh (tensor-parallel serving)")
             if not isinstance(mesh, ProcessMesh):
                 mesh = ProcessMesh(mesh)
             if mp_axis not in mesh.dim_names:
                 raise ValueError(
                     f"mesh has no {mp_axis!r} axis: {mesh.dim_names}")
-            shard_llama(model, mesh, mp_axis=mp_axis)
+            contract.shard(mesh, mp_axis)
             # pool pages sharded over KV heads: each mp rank holds its
             # heads' pages; the paged-attention gather stays local
             self._pool_sharding = self._kv_pool_sharding(
-                mesh, mp_axis, self._nkv, "")
+                mesh, mp_axis, spec.pools[0].heads, "")
         self.mesh = mesh
 
         from paddle_tpu.ops import paged_attention as pa
 
-        # pool pages [num_blocks, Nkv, bs, H] per layer, plus one dedicated
-        # scratch page per slot (masked lanes write there, never the pool)
+        # per pool of the specification and per layer, pages
+        # [num_blocks, heads, bs, width], plus one dedicated scratch page
+        # per slot (masked lanes write there, never the pool)
         self._num_blocks = int(num_blocks)
         total = self._num_blocks + self.max_batch
         kv_dt = (kv_cache_dtype if kv_cache_dtype is not None
@@ -652,24 +692,22 @@ class GenerationEngine:
         if kv_dt not in ("bf16", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'bf16' or 'int8', got {kv_dt!r}")
+        if kv_dt == "int8":
+            self._require_kv("an int8 pool (kv_cache_dtype='int8')")
         self._kv_dtype = kv_dt  # resolved ONCE: pools are allocated now
-        dt = (jnp.int8 if kv_dt == "int8"
-              else jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
-        pools = [pa.alloc_paged_cache(total, self._nkv, self.block_size,
-                                      self._head_dim, dt)
-                 for _ in range(self._n_layers)]
         # leaf-wise placement: a QuantPool's int8 payload [blocks,Nkv,bs,H]
         # and its f32 scales [blocks,Nkv] both shard on the KV-head dim
         # (the same PartitionSpec(None, mp) covers both ranks — trailing
         # dims replicate), so int8 pools compose with the mesh engine
-        self._kpools = [self._place_pool(k, self._pool_sharding)
-                        for k, _ in pools]
-        self._vpools = [self._place_pool(v, self._pool_sharding)
-                        for _, v in pools]
+        self._pools = self._alloc_pools(spec, total, self._pool_sharding)
         self._free = list(range(self._num_blocks))
         self._ref = [0] * total  # per-block request refcounts (allocator)
         pc = (bool(prefix_cache) if prefix_cache is not None
               else bool(_flags.flag("FLAGS_prefix_cache")))
+        if pc:
+            self._require_kv("the prefix cache")
+        if self.prefill_chunk is not None:
+            self._require_kv("chunked prefill (prefill_chunk)")
         self._prefix = RadixPrefixCache(self.block_size) if pc else None
         self._pending: deque = deque()  # admission retries (pool pressure)
         self._parked: dict = {}   # rid -> parked record (preempted LOWs)
@@ -709,36 +747,31 @@ class GenerationEngine:
 
         # ---- speculative tier: draft model + its own paged pools --------
         self.draft_model = draft_model
+        self._prefill_chunk_blocks()   # refuses interleaved prefill by name
         self.num_speculative = int(num_speculative_tokens)
         self._draft_fn = self._verify_fn = None
         if draft_model is not None:
             if self.num_speculative < 1:
                 raise ValueError("num_speculative_tokens must be >= 1")
-            dc = draft_model.config
-            if dc.vocab_size != cfg.vocab_size:
+            self._require_kv("speculative decoding (draft_model)")
+            if draft_model.config.vocab_size != model.config.vocab_size:
                 raise ValueError("draft and target must share a vocabulary")
+            d_contract = self._d_contract = draft_model.serving_contract()
+            d_spec = self._d_spec = d_contract.spec
+            if not d_spec.kv_pair:
+                raise NotImplementedError(
+                    "GenerationEngine: a draft model must keep K/V pools; "
+                    f"this one keeps {[p.name for p in d_spec.pools]}")
             if mesh is not None and draft_model is not model:
                 # the draft serves the same mesh: Megatron placements on
                 # its weights, its pools sharded over ITS KV-head count
                 # (which may differ from the target's)
-                from paddle_tpu.models.llama import shard_llama
-
-                shard_llama(draft_model, mesh, mp_axis=mp_axis)
-            self._d_layers = dc.num_hidden_layers
-            self._d_nkv = dc.num_key_value_heads
-            self._d_hd = dc.hidden_size // dc.num_attention_heads
+                d_contract.shard(mesh, mp_axis)
             if mesh is not None:
                 self._d_pool_sharding = self._kv_pool_sharding(
-                    mesh, mp_axis, self._d_nkv, "draft ")
-            ddt = (jnp.int8 if kv_dt == "int8"
-                   else jnp.bfloat16 if dc.dtype == "bfloat16" else jnp.float32)
-            d_pools = [pa.alloc_paged_cache(total, self._d_nkv,
-                                            self.block_size, self._d_hd, ddt)
-                       for _ in range(self._d_layers)]
-            self._d_kpools = [self._place_pool(k, self._d_pool_sharding)
-                              for k, _ in d_pools]
-            self._d_vpools = [self._place_pool(v, self._d_pool_sharding)
-                              for _, v in d_pools]
+                    mesh, mp_axis, d_spec.pools[0].heads, "draft ")
+            self._d_pools = self._alloc_pools(d_spec, total,
+                                              self._d_pool_sharding)
             self._d_state = list(draft_model.state_dict().values())
             self._spec_stats = {"ticks": 0, "proposed": 0, "accepted": 0,
                                 "emitted": 0}
@@ -747,6 +780,8 @@ class GenerationEngine:
         self._pack = None
         if adapters is not None:
             from paddle_tpu.nn.lora import AdapterPack
+
+            self._require_kv("LoRA adapter slots (adapters)")
 
             # speculative + adapters composes with a BASE-MODEL draft:
             # the draft proposes adapter-free tokens and the target
@@ -779,11 +814,17 @@ class GenerationEngine:
             self._slot_clock = 0
             _LORA_STATS["slots_total"] = S - 1
             _LORA_STATS["slots_resident"] = 0
-        all_pools = (self._kpools + self._vpools
-                     + getattr(self, "_d_kpools", [])
-                     + getattr(self, "_d_vpools", []))
+        all_pools = [p for pools in (self._pools
+                                     + getattr(self, "_d_pools", []))
+                     for p in pools]
         _DECODE_STATS["pool_bytes"] = sum(pa.pool_nbytes(p)
                                           for p in all_pools)
+        for k in _DECODE_STATS:
+            if k.endswith("_pool_bytes"):
+                _DECODE_STATS[k] = 0
+        for ps, pools in zip(spec.pools, self._pools):
+            _DECODE_STATS[ps.name + "_pool_bytes"] = sum(
+                pa.pool_nbytes(p) for p in pools)
         # per-device footprint: each pool leaf's committed sharding
         # divides its bytes (== pool_bytes on single-device engines)
         _DECODE_STATS["pool_bytes_per_device"] = sum(
@@ -798,6 +839,28 @@ class GenerationEngine:
             from paddle_tpu.static.mesh_lint import lint_engine
 
             lint_engine(self, raise_on_error=True)
+
+    # ------------------------------------------- the cache specification
+    def _require_kv(self, feature):
+        """The engine's optional features were built for a K pool and a V
+        pool a layer; a model whose contract specifies other pools is
+        refused BY NAME here, never served by K/V code on its pool."""
+        if not self._spec.kv_pair:
+            raise NotImplementedError(
+                f"GenerationEngine: {feature} cannot hold this model's "
+                f"cache pools {[p.name for p in self._spec.pools]} yet; it "
+                "was built for a K/V pair (docs/DECODE.md, the model "
+                "contract)")
+
+    def _alloc_pools(self, spec, total, sharding):
+        """`pools[p][layer]` of a cache specification, zeroed and placed."""
+        from paddle_tpu.ops import paged_attention as pa
+
+        return [[self._place_pool(pa.alloc_paged_pool(
+                    total, ps.heads, self.block_size, ps.width,
+                    jnp.int8 if self._kv_dtype == "int8" else ps.dtype),
+                    sharding)
+                 for _ in range(spec.n_layers)] for ps in spec.pools]
 
     # ------------------------------------------------------ pool placement
     @staticmethod
@@ -1168,9 +1231,12 @@ class GenerationEngine:
         would desynchronize d_seq_len mid-prefill."""
         if self.draft_model is not None:
             return 0
-        if self.prefill_chunk_blocks is not None:
-            return self.prefill_chunk_blocks
-        return max(0, int(_flags.flag("FLAGS_prefill_chunk_blocks")))
+        n = (self.prefill_chunk_blocks
+             if self.prefill_chunk_blocks is not None
+             else max(0, int(_flags.flag("FLAGS_prefill_chunk_blocks"))))
+        if n:
+            self._require_kv("interleaved prefill (prefill_chunk_blocks)")
+        return n
 
     def _admit_pending(self):
         """Retry queued admissions — called at macro-step boundaries — in
@@ -1256,7 +1322,7 @@ class GenerationEngine:
         st["admit_seconds"] += time.perf_counter() - t0
         for name in _ADMIT_PHASES:
             st["admit_" + name + "_seconds"] += acc[name]
-        for name in _ADMIT_COUNTS:
+        for name in acc.keys() - set(_ADMIT_PHASES):
             st[name] += acc[name]
         queued_at = self._queued_at.pop(req["rid"], None)
         if queued_at is not None:
@@ -1268,7 +1334,6 @@ class GenerationEngine:
         """_try_admit's body, into the free `slot`; `acc` tallies this
         attempt's phases."""
         import paddle_tpu as paddle
-        from paddle_tpu.models.llama import _model_forward_cached
 
         # ---- adapter residency: the request's adapter must hold a pack
         # slot before prefill (adapted projections feed the K/V it pours).
@@ -1325,15 +1390,11 @@ class GenerationEngine:
             try:
                 if not compiled:
                     caches = self._prefix_or_empty(
-                        self._kpools, self._vpools, matched, m_len,
-                        self._n_layers, self._nkv, self._head_dim,
-                        model.config.dtype)
+                        self._spec, self._pools, matched, m_len)
                 elif m_len:
-                    prefix = [(k._value, v._value) for k, v in
+                    prefix = [tuple(t._value for t in layer) for layer in
                               self._gather_prefix(
-                                  self._kpools, self._vpools, matched,
-                                  m_len, self._nkv, self._head_dim,
-                                  model.config.dtype)]
+                                  self._spec, self._pools, matched, m_len)]
                 else:
                     prefix = None    # no 2N empty tensors for a program
             except BaseException:
@@ -1348,15 +1409,16 @@ class GenerationEngine:
                 from paddle_tpu.nn.lora import adapter_prefill_scope
 
                 prefill_ctx = adapter_prefill_scope(
-                    model.model.layers, self._pack, ad_slot)
+                    self._contract.adapter_layers(), self._pack, ad_slot)
             else:
                 prefill_ctx = contextlib.nullcontext()
             with prefill_ctx, paddle.no_grad():
                 with _admit_phase("prefill", acc):
                     if compiled:
-                        logits_last, k_new, v_new = self._prefill_compiled(
+                        logits_last, new_blocks, aux = self._prefill_compiled(
                             prompt, prefix, m_len, acc)
                     else:
+                        aux = {}
                         ops0 = _autograd.funnel_calls()
                         h, caches = self._prefill_suffix(prompt, caches,
                                                          m_len)
@@ -1367,32 +1429,33 @@ class GenerationEngine:
                 # host waits here for everything the prefill enqueued
                 with _admit_phase("first_token", acc):
                     if not compiled:
-                        logits_last = model._logits(
+                        logits_last = self._contract.logits(
                             h[:, -1:, :])._value[0, -1, :]
                     first = int(np.asarray(jnp.argmax(logits_last)))
+                    # what the device counted (the contract's aux) came
+                    # with the same program: no second wait
+                    acc.update({k: int(v) for k, v in
+                                jax.device_get(aux).items()})
 
-            # pour the suffix K/V into this request's exclusive pages
-            # (matched prefix pages are shared and immutable)
+            # pour the suffix's cache rows into this request's exclusive
+            # pages (matched prefix pages are shared and immutable)
             with _admit_phase("pour", acc):
                 if not compiled:
-                    k_new, v_new = _cache_blocks(caches, m_len, s0, bs)
-                self._pour(self._kpools, self._vpools, k_new, v_new,
-                           fresh, sharding=self._pool_sharding)
+                    new_blocks = _cache_blocks(caches, m_len, s0, bs)
+                self._pour(self._pools, new_blocks, fresh,
+                           sharding=self._pool_sharding)
             if self.draft_model is not None:
                 # draft prefill over the same suffix into the draft pools
                 # (cached pages were poured to BOTH pool sets at insert
                 # time, so a matched prefix covers the draft too)
                 with _admit_phase("prefill", acc), paddle.no_grad():
                     d_caches = self._prefix_or_empty(
-                        self._d_kpools, self._d_vpools, matched, m_len,
-                        self._d_layers, self._d_nkv, self._d_hd,
-                        self.draft_model.config.dtype)
-                    _, d_caches = _model_forward_cached(
-                        self.draft_model.model,
+                        self._d_spec, self._d_pools, matched, m_len)
+                    _, d_caches, _ = self._d_contract.forward_cached(
                         paddle.to_tensor(prompt[:, m_len:]), d_caches, m_len)
                 with _admit_phase("pour", acc):
-                    self._pour(self._d_kpools, self._d_vpools,
-                               *_cache_blocks(d_caches, m_len, s0, bs),
+                    self._pour(self._d_pools,
+                               _cache_blocks(d_caches, m_len, s0, bs),
                                fresh, sharding=self._d_pool_sharding)
                 slot.d_seq_len = s0
         except BaseException:
@@ -1455,25 +1518,22 @@ class GenerationEngine:
         """The target model's eager forward over prompt[:, m_len:] on top
         of `caches` (the matched prefix, or empties): (hidden, caches)."""
         import paddle_tpu as paddle
-        from paddle_tpu.models.llama import (_model_forward_cached,
-                                             prefill_chain_scope)
 
-        model, s0 = self.model.model, prompt.shape[1]
+        forward, s0 = self._contract.forward_cached, prompt.shape[1]
         if self.prefill_chunk is None or s0 - m_len <= self.prefill_chunk:
-            return _model_forward_cached(
-                model, paddle.to_tensor(prompt[:, m_len:]), caches, m_len)
+            return forward(paddle.to_tensor(prompt[:, m_len:]), caches,
+                           m_len)[:2]
         # chunked prefill: fixed-size chunks through the cached forward
         # (bottom-right-aligned cross-length attention) cap the peak
         # activation footprint for long prompts.  An accepted
         # prefill-chain config routes each DIVISIBLE chunk's attention
         # core through the fused K-tiled kernel (schedule search;
         # PrefillChainSpec)
-        with prefill_chain_scope(self._resolve_prefill_chain()):
+        with self._contract.prefill_scope(self._resolve_prefill_chain()):
             off = m_len
             while off < s0:
                 chunk = prompt[:, off:off + self.prefill_chunk]
-                h, caches = _model_forward_cached(
-                    model, paddle.to_tensor(chunk), caches, off)
+                h, caches, _ = forward(paddle.to_tensor(chunk), caches, off)
                 off += chunk.shape[1]
         return h, caches
 
@@ -1483,19 +1543,19 @@ class GenerationEngine:
         tokens: the next power of two, at least one pool block, clipped so
         that m_len + bucket stays inside the rope table.  One program per
         bucket serves every real length in it."""
-        rope_len = int(self.model.model.rope_cos.shape[0])
         return min(max(self.block_size, 1 << (s - 1).bit_length()),
-                   rope_len - m_len)
+                   self._contract.max_positions - m_len)
 
     def _prefill_program(self, s_pad, m_len):
         """The compiled model work of one atomic admission, built once per
         (padded suffix length, prefix length) and kept in `_prefill_fns`:
         (state_vals, ids[1, s_pad], n_real, prefix_caches) ->
-        (logits_last[vocab], k_blocks, v_blocks).  The forward over the
+        (logits_last[vocab], blocks, aux).  The contract's forward over the
         suffix on top of the gathered prefix (None when m_len == 0), the
         logits of position n_real - 1 (a traced scalar: one program serves
-        every real length of its bucket), and every layer's new K/V already
-        in the pool's block layout, [ceil(s_pad / bs), Nkv, bs, H] a layer.
+        every real length of its bucket), every layer's new cache rows
+        already in the pools' block layout (`blocks[p][layer]`,
+        [ceil(s_pad / bs), heads, bs, width]), and what the model counted.
         Right padding is invisible to the real positions under the causal
         bottom-right-aligned mask; positions at or past n_real are ZEROED
         before the blocks are shaped, so a partial block's tail (and every
@@ -1508,10 +1568,9 @@ class GenerationEngine:
             return fn
         from paddle_tpu._core.autograd import no_grad
         from paddle_tpu._core.tensor import Tensor
-        from paddle_tpu.models.llama import (_empty_caches,
-                                             _model_forward_cached)
 
-        model, state, bs = self.model, self._state, self.block_size
+        contract, state, bs = self._contract, self._state, self.block_size
+        spec = self._spec
 
         def prefill_program(state_vals, ids, n_real, prefix_caches):
             originals = [t._value for t in state]
@@ -1519,21 +1578,20 @@ class GenerationEngine:
                 for t, v in zip(state, state_vals):
                     t._bind(v)
                 with no_grad():
-                    caches = (_empty_caches(model.config, 1) if not m_len
-                              else [(Tensor(k), Tensor(v))
-                                    for k, v in prefix_caches])
-                    h, caches = _model_forward_cached(
-                        model.model, Tensor(ids), caches, m_len)
+                    caches = (_empty_caches(spec) if not m_len
+                              else [tuple(Tensor(t) for t in layer)
+                                    for layer in prefix_caches])
+                    h, caches, aux = contract.forward_cached(
+                        Tensor(ids), caches, m_len, n_real=n_real)
                     last = jax.lax.dynamic_slice_in_dim(
                         h._value, n_real - 1, 1, axis=1)
-                    logits_last = model._logits(Tensor(last))._value[0, -1]
+                    logits_last = contract.logits(Tensor(last))._value[0, -1]
                 real = (jnp.arange(m_len + s_pad)
                         < m_len + n_real)[None, :, None, None]
-                k_blocks, v_blocks = _cache_blocks(
-                    [(jnp.where(real, k._value, 0),
-                      jnp.where(real, v._value, 0)) for k, v in caches],
-                    m_len, m_len + s_pad, bs)
-                return logits_last, k_blocks, v_blocks
+                blocks = _cache_blocks(
+                    [tuple(jnp.where(real, t._value, 0) for t in layer)
+                     for layer in caches], m_len, m_len + s_pad, bs)
+                return logits_last, blocks, aux
             finally:
                 for t, v in zip(state, originals):
                     t._bind(v)
@@ -1545,8 +1603,8 @@ class GenerationEngine:
 
     def _prefill_compiled(self, prompt, prefix, m_len, acc):
         """Run the suffix prompt[:, m_len:] through its bucket's program:
-        (logits_last, k_blocks, v_blocks), the blocks of the padded bucket
-        (all-zero past the real tokens).  Nothing is read back here."""
+        (logits_last, blocks, aux), the blocks of the padded bucket (all-zero
+        past the real tokens).  Nothing is read back here."""
         suffix = prompt[:, m_len:]
         s = suffix.shape[1]
         s_pad = self._prefill_bucket(s, m_len)
@@ -1599,9 +1657,8 @@ class GenerationEngine:
             return False
         m_len = len(matched) * bs
         try:
-            caches = self._prefix_or_empty(
-                self._kpools, self._vpools, matched, m_len, self._n_layers,
-                self._nkv, self._head_dim, self.model.config.dtype)
+            caches = self._prefix_or_empty(self._spec, self._pools, matched,
+                                           m_len)
         except BaseException:
             self._back_out(fresh, matched)
             raise
@@ -1675,28 +1732,25 @@ class GenerationEngine:
         prefill_chunk=block_size chunks.  Returns True when the prompt
         completed (the slot activated)."""
         import paddle_tpu as paddle
-        from paddle_tpu.models.llama import (_model_forward_cached,
-                                             prefill_chain_scope)
 
         st = slot.prefill
         prompt = st.req["prompt"]
         s0 = prompt.shape[1]
         bs = self.block_size
-        model = self.model
+        contract = self._contract
         try:
             if self._pack is not None and slot.adapter_slot:
                 from paddle_tpu.nn.lora import adapter_prefill_scope
 
-                ctx = adapter_prefill_scope(model.model.layers, self._pack,
-                                            slot.adapter_slot)
+                ctx = adapter_prefill_scope(contract.adapter_layers(),
+                                            self._pack, slot.adapter_slot)
             else:
                 ctx = contextlib.nullcontext()
             pf_cfg = self._resolve_prefill_chain()
-            with ctx, prefill_chain_scope(pf_cfg), paddle.no_grad():
+            with ctx, contract.prefill_scope(pf_cfg), paddle.no_grad():
                 chunk = prompt[:, st.off:st.off + bs]
-                st.h, st.caches = _model_forward_cached(
-                    model.model, paddle.to_tensor(chunk), st.caches,
-                    st.off)
+                st.h, st.caches, _ = contract.forward_cached(
+                    paddle.to_tensor(chunk), st.caches, st.off)
                 st.off += chunk.shape[1]
             _DECODE_STATS["prefill_chunks"] += 1
             # pour freshly COMPLETED blocks as we go: per-block pour
@@ -1735,16 +1789,12 @@ class GenerationEngine:
         st = slot.prefill
         lo = j * bs
         b = slot.blocks[j]
-        for li, (k, v) in enumerate(st.caches):
-            kv = jnp.moveaxis(k._value, 1, 2)[0, :, lo:lo + bs]  # [Nkv,bs,H]
-            vv = jnp.moveaxis(v._value, 1, 2)[0, :, lo:lo + bs]
-            self._kpools[li] = pa.paged_pour_block(self._kpools[li], kv, b)
-            self._vpools[li] = pa.paged_pour_block(self._vpools[li], vv, b)
-            if self._pool_sharding is not None:
-                self._kpools[li] = self._place_pool(self._kpools[li],
-                                                    self._pool_sharding)
-                self._vpools[li] = self._place_pool(self._vpools[li],
-                                                    self._pool_sharding)
+        for li, layer in enumerate(st.caches):
+            for pools, t in zip(self._pools, layer):
+                rows = jnp.moveaxis(t._value, 1, 2)[0, :, lo:lo + bs]
+                pools[li] = self._place_pool(      # rows: [heads, bs, width]
+                    pa.paged_pour_block(pools[li], rows, b),
+                    self._pool_sharding)
 
     def _finish_prefill(self, slot):
         """Last chunk landed: pour the remainder (the partial tail block
@@ -1760,11 +1810,11 @@ class GenerationEngine:
         s0 = prompt.shape[1]
         bs = self.block_size
         with paddle.no_grad():
-            logits_last = self.model._logits(
+            logits_last = self._contract.logits(
                 st.h[:, -1:, :])._value[0, -1, :]
         first = int(np.asarray(jnp.argmax(logits_last)))
-        self._pour(self._kpools, self._vpools,
-                   *_cache_blocks(st.caches, st.poured * bs, s0, bs),
+        self._pour(self._pools,
+                   _cache_blocks(st.caches, st.poured * bs, s0, bs),
                    slot.blocks[st.poured:], sharding=self._pool_sharding)
         _DECODE_STATS["prefill_eager_fallbacks"] += 1
         slot.active = True
@@ -1835,6 +1885,11 @@ class GenerationEngine:
             return False
         if not _flags.flag("FLAGS_preempt_low_priority"):
             return False
+        if not self._spec.kv_pair:
+            # the parking lot (serving/snapshot.py) holds K/V pages only:
+            # on other pools a LOW resident is never preempted, the higher
+            # class waits for a slot as it does with the flag off
+            return False
         victims = [s for s in self._slots
                    if s.active and s.priority >= _PRIORITY["low"]
                    and s.adapter_slot == 0 and s.req is not None]
@@ -1894,59 +1949,46 @@ class GenerationEngine:
             sum(1 for s in self._slots if s.active))
         return True
 
-    def _prefix_or_empty(self, kpools, vpools, matched, m_len, n_layers,
-                         nkv, head_dim, dtype):
+    def _prefix_or_empty(self, spec, pools, matched, m_len):
         """Naive-cache seed for a suffix prefill: the matched prefix
-        gathered out of `kpools`/`vpools`, or length-0 empties.  One
-        builder for the main and draft pools so their prefix-gather
-        contracts cannot drift apart."""
-        import paddle_tpu as paddle
-
+        gathered out of `pools`, or length-0 empties, shaped by the cache
+        specification.  One builder for the main and draft pools so their
+        prefix-gather contracts cannot drift apart."""
         if m_len:
-            return self._gather_prefix(kpools, vpools, matched, m_len,
-                                       nkv, head_dim, dtype)
-        return [
-            (paddle.zeros([1, 0, nkv, head_dim], dtype=dtype),
-             paddle.zeros([1, 0, nkv, head_dim], dtype=dtype))
-            for _ in range(n_layers)
-        ]
+            return self._gather_prefix(spec, pools, matched, m_len)
+        return _empty_caches(spec)
 
-    def _gather_prefix(self, kpools, vpools, blocks, length, nkv, head_dim,
-                       dtype):
-        """Materialize a matched prefix's K/V as naive-cache Tensors
-        ([1, L, Nkv, H] per layer): the suffix prefill attends these
-        through the same cross-length path chunked prefill uses.
-        Quantized pools dequantize here — gather-side dequant, exactly as
-        the decode step does."""
+    def _gather_prefix(self, spec, pools, blocks, length):
+        """Materialize a matched prefix's cache rows as naive-cache
+        Tensors (`caches[layer][p]`: [1, L, heads, width]): the suffix
+        prefill attends these through the same cross-length path chunked
+        prefill uses.  Quantized pools dequantize here — gather-side
+        dequant, exactly as the decode step does."""
         from paddle_tpu._core.tensor import Tensor
         from paddle_tpu.ops import paged_attention as pa
 
         tables = jnp.asarray(np.asarray(blocks, np.int32)[None])
-        dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-        out = []
-        for kc, vc in zip(kpools, vpools):
-            kv = pa.paged_gather(kc, tables)[:, :, :length]  # [1,Nkv,L,H]
-            vv = pa.paged_gather(vc, tables)[:, :, :length]
-            out.append((Tensor(jnp.moveaxis(kv, 1, 2).astype(dt)),
-                        Tensor(jnp.moveaxis(vv, 1, 2).astype(dt))))
-        return out
+        return [tuple(
+            Tensor(jnp.moveaxis(                     # [1, heads, L, width]
+                pa.paged_gather(per_layer[li], tables)[:, :, :length], 1, 2
+            ).astype(ps.dtype))
+            for ps, per_layer in zip(spec.pools, pools))
+            for li in range(spec.n_layers)]
 
-    def _pour(self, kpools, vpools, k_blocks, v_blocks, pages,
-              sharding=None):
-        """Scatter per-layer blocks (`_cache_blocks` layout) into `pages`,
-        a request's exclusively owned pool pages in order: one
+    def _pour(self, pools, blocks, pages, sharding=None):
+        """Scatter blocks (`_cache_blocks` layout, `blocks[p][layer]`) into
+        `pages`, a request's exclusively owned pool pages in order: one
         `paged_pour_blocks` per pool.  Pages past the given blocks — the
         request's future decode pages — are poured with zeros, which on a
         quantized pool also resets a recycled page's stale scale."""
         idx = jnp.asarray(pages, jnp.int32)
-        for li in range(len(kpools)):
-            kpools[li] = _pour_new_blocks(kpools[li], k_blocks[li], idx)
-            vpools[li] = _pour_new_blocks(vpools[li], v_blocks[li], idx)
-            if sharding is not None:
-                # keep the pool committed to its head-sharded layout so the
-                # decode executable's input shardings stay stable
-                kpools[li] = self._place_pool(kpools[li], sharding)
-                vpools[li] = self._place_pool(vpools[li], sharding)
+        for per_layer, new in zip(pools, blocks):
+            for li in range(len(per_layer)):
+                # placed: the pool stays committed to its head-sharded
+                # layout, so the decode executable's input shardings stay
+                # stable
+                per_layer[li] = self._place_pool(
+                    _pour_new_blocks(per_layer[li], new[li], idx), sharding)
 
     def _finish(self, slot):
         _DECODE_STATS["completed_" + _PRI_NAMES[slot.priority]] += 1
@@ -1987,6 +2029,7 @@ class GenerationEngine:
         already-cached prefix simply adopts fewer (possibly zero) blocks
         and returns that count — shipping is an optimization; admission
         always works without it.  Geometry mismatches raise."""
+        self._require_kv("cluster page shipping (adopt_pages)")
         if self._prefix is None:
             raise RuntimeError(
                 "adopt_pages needs the prefix cache: shipped pages are "
@@ -2029,7 +2072,7 @@ class GenerationEngine:
         n = min(n_wire, len(toks) // bs)
         from paddle_tpu.ops import paged_attention as pa
 
-        want_leaves = {name for name, _a in pa.pool_parts(self._kpools[0])}
+        want_leaves = {name for name, _a in pa.pool_parts(self._pools[0][0])}
         for li in range(self._n_layers):
             for leaves in (k_blocks[li], v_blocks[li]):
                 if set(leaves) != want_leaves:
@@ -2043,7 +2086,8 @@ class GenerationEngine:
                         f"kv_cache_dtype mismatch between sender and "
                         "this engine?)")
                 got = tuple(np.asarray(leaves["payload"]).shape[1:])
-                want = (self._nkv, bs, self._head_dim)
+                want = (self._spec.pools[0].heads, bs,
+                        self._spec.pools[0].width)
                 if got != want:
                     raise ValueError(
                         f"shipped page geometry {got} != pool {want} "
@@ -2066,12 +2110,12 @@ class GenerationEngine:
                   for name, arr in k_blocks[li].items()}
             vb = {name: jnp.asarray(arr)[start:n]
                   for name, arr in v_blocks[li].items()}
-            self._kpools[li] = pa.pool_set_blocks(self._kpools[li], idx, kb)
-            self._vpools[li] = pa.pool_set_blocks(self._vpools[li], idx, vb)
+            self._pools[0][li] = pa.pool_set_blocks(self._pools[0][li], idx, kb)
+            self._pools[1][li] = pa.pool_set_blocks(self._pools[1][li], idx, vb)
             if self._pool_sharding is not None:
-                self._kpools[li] = self._place_pool(self._kpools[li],
+                self._pools[0][li] = self._place_pool(self._pools[0][li],
                                                     self._pool_sharding)
-                self._vpools[li] = self._place_pool(self._vpools[li],
+                self._pools[1][li] = self._place_pool(self._pools[1][li],
                                                     self._pool_sharding)
         self._prefix.insert(toks[: n * bs], matched + fresh, ns=ns)
         return len(fresh)
@@ -2086,6 +2130,7 @@ class GenerationEngine:
         only.  Returns the committed step tag."""
         from paddle_tpu.serving.snapshot import EngineSnapshot
 
+        self._require_kv("the engine snapshot (snapshot / drain)")
         store = self._snapshot_store
         if store is None or store.dir != str(dir):
             # one store per engine+dir: its manifest-validity cache makes
@@ -2245,12 +2290,15 @@ class GenerationEngine:
         if self._decode_chain_cfg is not _CHAIN_UNSET:
             return self._decode_chain_cfg
         cfg = None
-        if (_flags.flag("FLAGS_schedule_search")
+        # the fused chain is a K/V kernel: other specifications keep the
+        # model's own decode attention
+        if (self._spec.kv_pair and _flags.flag("FLAGS_schedule_search")
                 and _flags.flag("FLAGS_schedule_search_decode")):
             mesh = self.mesh
-            n_heads = self.model.config.num_attention_heads
+            n_heads = self._contract.num_query_heads
+            kv = self._spec.pools[0]
             mp = mesh.get_dim_size(self._mp_axis) if mesh is not None else 1
-            if mesh is not None and (n_heads % mp or self._nkv % mp):
+            if mesh is not None and (n_heads % mp or kv.heads % mp):
                 _SCHED_DECODE_STATS["decode_chains_mesh_skipped"] += 1
             else:
                 from paddle_tpu.ops import decode_chain as _dc
@@ -2259,16 +2307,13 @@ class GenerationEngine:
                 spec = _dc.DecodeChainSpec(
                     batch=self.max_batch,
                     num_heads=n_heads,
-                    num_kv_heads=self._nkv,
-                    head_dim=self._head_dim,
+                    num_kv_heads=kv.heads,
+                    head_dim=kv.width,
                     block_size=self.block_size,
                     max_blocks=self._max_blocks_per_seq,
                     num_blocks=self._num_blocks + self.max_batch,
                     kv=self._kv_dtype,
-                    dtype=jnp.dtype(
-                        jnp.bfloat16
-                        if self.model.config.dtype == "bfloat16"
-                        else jnp.float32),
+                    dtype=jnp.dtype(kv.dtype),
                     mesh=mesh,
                     mp_axis=self._mp_axis,
                 )
@@ -2305,7 +2350,7 @@ class GenerationEngine:
         canonical mid-prompt geometry — an S=prefill_chunk query chunk
         against a T=2·prefill_chunk cache span — and an accepted config
         makes every DIVISIBLE chunk's attention core run as one K-tiled
-        Pallas dispatch under models.llama.prefill_chain_scope; chunks
+        Pallas dispatch under the contract's prefill_scope; chunks
         the config doesn't tile keep the XLA path.  Single-device
         engines only: mesh engines keep GSPMD prefill (the pour is
         bandwidth-bound on the pool commit, not the attention core).
@@ -2326,12 +2371,9 @@ class GenerationEngine:
             spec = _dc.PrefillChainSpec(
                 seq=eff,
                 kv_len=2 * eff,
-                num_heads=self.model.config.num_attention_heads,
-                head_dim=self._head_dim,
-                dtype=jnp.dtype(
-                    jnp.bfloat16
-                    if self.model.config.dtype == "bfloat16"
-                    else jnp.float32),
+                num_heads=self._contract.num_query_heads,
+                head_dim=self._spec.pools[0].width,
+                dtype=jnp.dtype(self._spec.pools[0].dtype),
             )
             decision = _dc.ensure_decision(spec)
             if decision.accepted:
@@ -2365,11 +2407,8 @@ class GenerationEngine:
         one program; swaps change argument VALUES only, never shapes, so
         the executable is reused across them."""
         from paddle_tpu._core.autograd import no_grad
-        from paddle_tpu._core.tensor import Tensor
-        from paddle_tpu.models.llama import (_decode_layers_paged,
-                                             _pool_carry, _pool_unpack)
 
-        model = self.model
+        contract = self._contract
         state = self._state
         eos = self.eos_token_id
         has_pack = self._pack is not None
@@ -2379,14 +2418,14 @@ class GenerationEngine:
         # phase 2; docs/SCHEDULE_SEARCH.md)
         chain_cfg = self._resolve_decode_chain()
 
-        def decode_macro_step(state_vals, kpools, vpools, tokens, tables,
+        def decode_macro_step(state_vals, pools, tokens, tables,
                               scratch_tables, lens, max_lens, done0, temps,
                               keys, steps, *lora_args):
+            kv_only = {} if chain_cfg is None else {"chain_cfg": chain_cfg}
             if has_pack:
                 ad_slots, pack_ab, pack_scaling = lora_args
-                row_scale = jnp.take(pack_scaling, ad_slots)  # [B]
-            else:
-                ad_slots = pack_ab = row_scale = None
+                kv_only.update(adapters=pack_ab, slots=ad_slots,
+                               scaling=jnp.take(pack_scaling, ad_slots))
             originals = [t._value for t in state]
             try:
                 for t, v in zip(state, state_vals):
@@ -2394,15 +2433,14 @@ class GenerationEngine:
                 # carry form ONCE per dispatch: a LayerStack's pools scan
                 # as one stacked [N, ...] buffer each — the N-pool concat
                 # is paid per dispatch, never per decoded token
-                kpools, vpools = _pool_carry(model.model.layers,
-                                             kpools, vpools)
+                pools = contract.pool_carry(pools)
 
                 # the body is defined INSIDE the traced step: lax.scan
                 # caches body jaxprs by the body's identity, and a shared
                 # body would leak one trace's bound-weight tracers into
                 # the next trace
                 def one(carry, _):
-                    tok, kps, vps, lens_c, steps_c, done = carry
+                    tok, pools_c, lens_c, steps_c, done = carry
                     # finished/inactive lanes park on their scratch page
                     # with lens 1 — same geometry the host gives inactive
                     # slots, so their writes never touch the shared pool
@@ -2410,16 +2448,10 @@ class GenerationEngine:
                                            tables)
                     lens_eff = jnp.where(done, jnp.int32(1), lens_c)
                     with no_grad():
-                        h = model.model.embed_tokens(Tensor(tok))
-                        cos = model.model.rope_cos._value
-                        sin = model.model.rope_sin._value
-                        h, kps, vps = _decode_layers_paged(
-                            model.model.layers, h, cos, sin, kps, vps,
-                            tables_eff, lens_eff, adapters=pack_ab,
-                            slots=ad_slots, scaling=row_scale,
-                            chain_cfg=chain_cfg)
-                        h = model.model.norm(h)
-                        logits = model._logits(h)
+                        h, pools_c, aux = contract.decode(
+                            tok, pools_c, tables_eff, lens_eff,
+                            active=~done, **kv_only)
+                        logits = contract.logits(h)
                     lg = logits._value[:, -1, :]
                     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                     # per-slot temperature sampling inside the SAME
@@ -2439,22 +2471,25 @@ class GenerationEngine:
                     new_done = done | fin | (lens_c + 1 >= max_lens)
                     lens_n = jnp.where(done, lens_c, lens_c + 1)
                     steps_n = jnp.where(done, steps_c, steps_c + 1)
-                    return (nxt[:, None], kps, vps, lens_n, steps_n,
-                            new_done), nxt
+                    return (nxt[:, None], pools_c, lens_n, steps_n,
+                            new_done), (nxt, aux)
 
-                (tok, kpools, vpools, *_), toks = jax.lax.scan(
-                    one, (tokens, kpools, vpools, lens, steps, done0),
+                (tok, pools, *_), (toks, aux) = jax.lax.scan(
+                    one, (tokens, pools, lens, steps, done0),
                     None, length=chunk)
-                kpools, vpools = _pool_unpack(model.model.layers,
-                                              kpools, vpools)
-                return jnp.moveaxis(toks, 0, 1), kpools, vpools
+                # what the model counted on the device (the contract's
+                # aux; {} for most), summed over the chunk: it rides back
+                # with the tokens, read at the step's one sync
+                aux = {k: jnp.sum(v) for k, v in aux.items()}
+                return (jnp.moveaxis(toks, 0, 1),
+                        contract.pool_unpack(pools), aux)
             finally:
                 for t, v in zip(state, originals):
                     t._bind(v)
 
         # the function's name is the program's: `jit_decode_macro_step` on
         # the trace's XLA Modules line and in PjitFunction(...) host events
-        return jax.jit(decode_macro_step, donate_argnums=(1, 2))
+        return jax.jit(decode_macro_step, donate_argnums=(1,))
 
     def _step_avals(self):
         """ShapeDtypeStruct mirror of step()'s exact dispatch signature,
@@ -2474,8 +2509,7 @@ class GenerationEngine:
         B, W = self.max_batch, self._max_blocks_per_seq
         avals = (
             [arr_aval(t._value) for t in self._state],
-            jax.tree_util.tree_map(arr_aval, list(self._kpools)),
-            jax.tree_util.tree_map(arr_aval, list(self._vpools)),
+            jax.tree_util.tree_map(arr_aval, [list(p) for p in self._pools]),
             jax.ShapeDtypeStruct((B, 1), jnp.int32),     # tokens
             jax.ShapeDtypeStruct((B, W), jnp.int32),     # tables
             arr_aval(self._scratch_tables),
@@ -2550,17 +2584,15 @@ class GenerationEngine:
             # there), and the poured-back pool is DISCARDED — only the
             # compiled programs persist
             idx = jnp.asarray([self._scratch[0]], jnp.int32)
-            for pool in (self._kpools[0], self._vpools[0]):
+            for pool in (pools[0] for pools in self._pools):
                 leaves = pa.pool_get_blocks(pool, idx)
                 pa.pool_set_blocks(pool, idx, dict(leaves))
         return {"chunks": warmed, "seconds": time.perf_counter() - t0}
 
     def _build_draft_step(self):
         from paddle_tpu._core.autograd import no_grad
-        from paddle_tpu._core.tensor import Tensor
-        from paddle_tpu.models.llama import _decode_layers_paged
 
-        model = self.draft_model
+        contract = self._d_contract
         state = self._d_state
 
         def draft_step(state_vals, kpools, vpools, tokens, tables, lens):
@@ -2569,14 +2601,9 @@ class GenerationEngine:
                 for t, v in zip(state, state_vals):
                     t._bind(v)
                 with no_grad():
-                    h = model.model.embed_tokens(Tensor(tokens))
-                    cos = model.model.rope_cos._value
-                    sin = model.model.rope_sin._value
-                    h, new_k, new_v = _decode_layers_paged(
-                        model.model.layers, h, cos, sin, kpools, vpools,
-                        tables, lens)
-                    h = model.model.norm(h)
-                    logits = model._logits(h)
+                    h, (new_k, new_v), _ = contract.decode(
+                        tokens, [kpools, vpools], tables, lens)
+                    logits = contract.logits(h)
                 return (jnp.argmax(logits._value[:, -1, :], axis=-1)
                         .astype(jnp.int32), new_k, new_v)
             finally:
@@ -2587,10 +2614,8 @@ class GenerationEngine:
 
     def _build_verify(self):
         from paddle_tpu._core.autograd import no_grad
-        from paddle_tpu._core.tensor import Tensor
-        from paddle_tpu.models.llama import _decode_layers_paged
 
-        model = self.model
+        contract = self._contract
         state = self._state
         has_pack = self._pack is not None
 
@@ -2604,25 +2629,19 @@ class GenerationEngine:
             through each row's adapter even though the draft proposed
             with the base model, so acceptance only ever keeps tokens
             the adapted model would decode."""
+            kv_only = {"chunk": True}
             if has_pack:
                 ad_slots, pack_ab, pack_scaling = lora_args
-                row_scale = jnp.take(pack_scaling, ad_slots)  # [B]
-            else:
-                ad_slots = pack_ab = row_scale = None
+                kv_only.update(adapters=pack_ab, slots=ad_slots,
+                               scaling=jnp.take(pack_scaling, ad_slots))
             originals = [t._value for t in state]
             try:
                 for t, v in zip(state, state_vals):
                     t._bind(v)
                 with no_grad():
-                    h = model.model.embed_tokens(Tensor(tokens))
-                    cos = model.model.rope_cos._value
-                    sin = model.model.rope_sin._value
-                    h, new_k, new_v = _decode_layers_paged(
-                        model.model.layers, h, cos, sin, kpools, vpools,
-                        tables, lens, chunk=True, adapters=pack_ab,
-                        slots=ad_slots, scaling=row_scale)
-                    h = model.model.norm(h)
-                    logits = model._logits(h)
+                    h, (new_k, new_v), _ = contract.decode(
+                        tokens, [kpools, vpools], tables, lens, **kv_only)
+                    logits = contract.logits(h)
                 return (jnp.argmax(logits._value, axis=-1).astype(jnp.int32),
                         new_k, new_v)
             finally:
@@ -2670,9 +2689,9 @@ class GenerationEngine:
         for j in range(K + 1):
             lens_d = jnp.asarray(d0 + 1 + j)
             tok1, dk, dv = self._draft_fn(
-                d_state, list(self._d_kpools), list(self._d_vpools),
+                d_state, list(self._d_pools[0]), list(self._d_pools[1]),
                 tok, tables_j, lens_d)
-            self._d_kpools, self._d_vpools = list(dk), list(dv)
+            self._d_pools[0], self._d_pools[1] = list(dk), list(dv)
             if j < K:
                 prop_dev.append(tok1)
                 tok = tok1[:, None]  # stays on device: steps pipeline
@@ -2694,9 +2713,9 @@ class GenerationEngine:
             _LORA_STATS["gather_dispatches"] += 1
         preds, nk, nv = self._verify_fn(
             [t._value for t in self._state],
-            list(self._kpools), list(self._vpools),
+            list(self._pools[0]), list(self._pools[1]),
             jnp.asarray(chunk), tables_j, lens_v, *lora_args)
-        self._kpools, self._vpools = list(nk), list(nv)
+        self._pools[0], self._pools[1] = list(nk), list(nv)
         _DECODE_STATS["dispatches"] += 1
         t_sync = time.perf_counter()
         preds = np.asarray(preds)  # [B, K+1]
@@ -2848,20 +2867,21 @@ class GenerationEngine:
                 lora_args = (jnp.asarray(ad_slots), self._pack.ab,
                              self._pack.scaling)
                 _LORA_STATS["gather_dispatches"] += 1
-            nxt, new_k, new_v = step_fn(
+            nxt, new_pools, aux = step_fn(
                 [t._value for t in self._state],
-                list(self._kpools), list(self._vpools),
+                [list(p) for p in self._pools],
                 jnp.asarray(tokens), jnp.asarray(tables),
                 self._scratch_tables, jnp.asarray(lens),
                 jnp.asarray(max_lens), jnp.asarray(done0),
                 jnp.asarray(temps), jnp.asarray(keys), jnp.asarray(steps),
                 *lora_args,
             )
-        self._kpools = list(new_k)
-        self._vpools = list(new_v)
+        self._pools = [list(p) for p in new_pools]
         t_sync = time.perf_counter()
         with RecordEvent("serving.step.sync"):
             nxt = np.asarray(nxt)  # [B, D] — the one device sync per chunk
+            for k, v in jax.device_get(aux).items():   # same program
+                _DECODE_STATS[k] += int(v)
         _DECODE_STATS["dispatches"] += 1
         _DECODE_STATS["macro_steps"] += 1
         _DECODE_STATS["last_chunk"] = D

@@ -169,9 +169,9 @@ def park_request_state(eng, slot):
         return {name: np.asarray(a) for name, a in blocks.items()}
 
     pages_k = [host(pa.pool_get_blocks(p, slot.blocks))
-               for p in eng._kpools]
+               for p in eng._pools[0]]
     pages_v = [host(pa.pool_get_blocks(p, slot.blocks))
-               for p in eng._vpools]
+               for p in eng._pools[1]]
     return {
         "req": slot.req, "seq_len": slot.seq_len, "max_len": slot.max_len,
         "n_blocks": len(slot.blocks), "last_token": slot.last_token,
@@ -196,14 +196,14 @@ def unpark_request_state(eng, slot, rec):
         return False
     idx = jnp.asarray(blocks, jnp.int32)
     for li in range(eng._n_layers):
-        eng._kpools[li] = pa.pool_set_blocks(eng._kpools[li], idx,
+        eng._pools[0][li] = pa.pool_set_blocks(eng._pools[0][li], idx,
                                              rec["pages_k"][li])
-        eng._vpools[li] = pa.pool_set_blocks(eng._vpools[li], idx,
+        eng._pools[1][li] = pa.pool_set_blocks(eng._pools[1][li], idx,
                                              rec["pages_v"][li])
         if eng._pool_sharding is not None:
-            eng._kpools[li] = eng._place_pool(eng._kpools[li],
+            eng._pools[0][li] = eng._place_pool(eng._pools[0][li],
                                               eng._pool_sharding)
-            eng._vpools[li] = eng._place_pool(eng._vpools[li],
+            eng._pools[1][li] = eng._place_pool(eng._pools[1][li],
                                               eng._pool_sharding)
     slot.rid = rec["req"]["rid"]
     slot.active = True
@@ -401,14 +401,14 @@ class EngineSnapshot:
         from paddle_tpu.ops import paged_attention as pa
 
         pools = {}
-        for li, p in enumerate(engine._kpools):
+        for li, p in enumerate(engine._pools[0]):
             pools.update(pa.pool_state_dict(f"pool.k{li}", p))
-        for li, p in enumerate(engine._vpools):
+        for li, p in enumerate(engine._pools[1]):
             pools.update(pa.pool_state_dict(f"pool.v{li}", p))
         if engine.draft_model is not None:
-            for li, p in enumerate(engine._d_kpools):
+            for li, p in enumerate(engine._d_pools[0]):
                 pools.update(pa.pool_state_dict(f"pool.dk{li}", p))
-            for li, p in enumerate(engine._d_vpools):
+            for li, p in enumerate(engine._d_pools[1]):
                 pools.update(pa.pool_state_dict(f"pool.dv{li}", p))
         # device->host sync happens HERE (shard-wise for TP engines: each
         # pool leaf's unique shards + global offsets enter the metadata,
@@ -572,15 +572,15 @@ class EngineSnapshot:
             pool = pa.pool_from_state(template, fetch, prefix)
             return eng._place_pool(pool, sharding)
 
-        eng._kpools = [load(f"pool.k{li}", p, eng._pool_sharding)
-                       for li, p in enumerate(eng._kpools)]
-        eng._vpools = [load(f"pool.v{li}", p, eng._pool_sharding)
-                       for li, p in enumerate(eng._vpools)]
+        eng._pools[0] = [load(f"pool.k{li}", p, eng._pool_sharding)
+                         for li, p in enumerate(eng._pools[0])]
+        eng._pools[1] = [load(f"pool.v{li}", p, eng._pool_sharding)
+                         for li, p in enumerate(eng._pools[1])]
         if draft_model is not None:
-            eng._d_kpools = [load(f"pool.dk{li}", p, eng._d_pool_sharding)
-                             for li, p in enumerate(eng._d_kpools)]
-            eng._d_vpools = [load(f"pool.dv{li}", p, eng._d_pool_sharding)
-                             for li, p in enumerate(eng._d_vpools)]
+            eng._d_pools[0] = [load(f"pool.dk{li}", p, eng._d_pool_sharding)
+                               for li, p in enumerate(eng._d_pools[0])]
+            eng._d_pools[1] = [load(f"pool.dv{li}", p, eng._d_pool_sharding)
+                               for li, p in enumerate(eng._d_pools[1])]
 
         # ---- allocator + requests
         eng._free = list(extras["alloc"]["free"])

@@ -683,11 +683,12 @@ class MeshLinter:
         from paddle_tpu.ops.paged_attention import pool_parts
 
         d_sharding = getattr(engine, "_d_pool_sharding", None)
+        d_pools = getattr(engine, "_d_pools", None) or [[], []]
         pool_lists = [
-            ("k", engine._kpools, engine._pool_sharding),
-            ("v", engine._vpools, engine._pool_sharding),
-            ("draft_k", getattr(engine, "_d_kpools", None) or [], d_sharding),
-            ("draft_v", getattr(engine, "_d_vpools", None) or [], d_sharding),
+            ("k", engine._pools[0], engine._pool_sharding),
+            ("v", engine._pools[1], engine._pool_sharding),
+            ("draft_k", d_pools[0], d_sharding),
+            ("draft_v", d_pools[1], d_sharding),
         ]
         pool_named, scale_named = [], []
         for tag, pools, sharding in pool_lists:

@@ -99,3 +99,28 @@ def test_compile_cache_directory_rule(monkeypatch, tmp_path):
     assert compile_cache.default_dir() == want
     assert compile_cache.enable() == want
     assert ("jax_compilation_cache_dir", want) in calls
+
+
+def test_mla_moe_logits_phase_on_cpu_tiny(monkeypatch):
+    """Rehearsal 1 of `--mla-moe-logits`: the same drive at a tiny float32
+    size, where program and reference agree to rounding and route alike."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                    "perfbench"))
+    from test_perfbench_mla_moe import TINY
+
+    monkeypatch.setattr(chip_smoke, "MLA_MOE_LOGIT_TOL", 1e-4)
+    monkeypatch.setattr(chip_smoke, "MLA_MOE_TIE_TOL", 1e-4)
+    # 4 experts top-2 over 128 pairs: a bfloat16 router parts on a few only
+    monkeypatch.setattr(chip_smoke, "ROUTE_AGREEMENT_MIN", 1.0)
+    out = chip_smoke.mla_moe_logits_phase(
+        TINY, seed=3, device=jax.devices()[0], prompt_lens=(16, 32, 64),
+        decoded=(8, 16), block_size=8)
+    assert len(out["errors"]) == 9 and max(out["errors"]) < 1e-4
+    # the probes ran and the program passed; the softmax control reads
+    # worse, the router control has no near tie to trip on at this size
+    assert out["route_agreement"] == 1.0 >= out["route_agreement_bfloat16"]
+    assert (out["softmax_rms_float32_spread_4"] < chip_smoke.SOFTMAX_RMS_TOL
+            < out["softmax_rms_bfloat16_spread_4"])

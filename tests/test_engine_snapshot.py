@@ -168,10 +168,10 @@ def test_int8_pools_roundtrip_bit_exact(tmp_path):
     eng2 = restore_engine(m, str(tmp_path))
     assert eng2._kv_dtype == "int8"
     # payload and scales are bit-equal to the source engine's
-    np.testing.assert_array_equal(np.asarray(eng2._kpools[0].data),
-                                  np.asarray(eng._kpools[0].data))
-    np.testing.assert_array_equal(np.asarray(eng2._kpools[0].scale),
-                                  np.asarray(eng._kpools[0].scale))
+    np.testing.assert_array_equal(np.asarray(eng2._pools[0][0].data),
+                                  np.asarray(eng._pools[0][0].data))
+    np.testing.assert_array_equal(np.asarray(eng2._pools[0][0].scale),
+                                  np.asarray(eng._pools[0][0].scale))
     _drain(eng2)
     assert _results(eng2) == _results(ref)
 

@@ -74,8 +74,8 @@ def test_single_to_mesh_restore_bit_identical(tmp_path):
         eng2 = restore_engine(m2, str(tmp_path), mesh=mesh)
     finally:
         paddle.set_flags({"FLAGS_verify_sharding": False})
-    assert isinstance(eng2._kpools[0].sharding, NamedSharding)
-    assert "mp" in str(eng2._kpools[0].sharding.spec)
+    assert isinstance(eng2._pools[0][0].sharding, NamedSharding)
+    assert "mp" in str(eng2._pools[0][0].sharding.spec)
     qw = m2.model.layers[0].self_attn.q_proj.weight
     assert "mp" in str(qw._value.sharding.spec)
     _drain(eng2)
@@ -95,8 +95,8 @@ def test_mesh_to_single_restore_bit_identical(tmp_path):
         eng.add_request("late", P2, max_new_tokens=3)
 
     eng2 = restore_engine(_model(), str(tmp_path), step=step)
-    assert eng2._kpools[0].sharding is None or len(
-        eng2._kpools[0].sharding.device_set) == 1
+    assert eng2._pools[0][0].sharding is None or len(
+        eng2._pools[0][0].sharding.device_set) == 1
     _drain(eng2)
     assert {r: eng2.result(r) for r in ("g", "s")} == ref
 
@@ -115,6 +115,6 @@ def test_mesh_to_wider_mesh_int8_restore(tmp_path):
     mesh4 = ProcessMesh(np.arange(4), ["mp"])
     eng2 = restore_engine(_model(), str(tmp_path), mesh=mesh4)
     assert eng2._kv_dtype == "int8"
-    assert "mp" in str(eng2._kpools[0].data.sharding.spec)
+    assert "mp" in str(eng2._pools[0][0].data.sharding.spec)
     _drain(eng2)
     assert {r: eng2.result(r) for r in ("g", "s")} == ref

@@ -157,7 +157,7 @@ def _first_decode_logits(eng):
         sin = model.model.rope_sin._value
         h, _, _ = _decode_layers_paged(
             model.model.layers, h, cos, sin,
-            list(eng._kpools), list(eng._vpools), tables, lens)
+            list(eng._pools[0]), list(eng._pools[1]), tables, lens)
         h = model.model.norm(h)
         return np.asarray(model._logits(h)._value[0, -1, :], np.float32)
 
@@ -293,7 +293,7 @@ def test_int8_plus_mesh_constructs_sharded():
     mesh = ProcessMesh(np.arange(2).reshape(2), ["mp"])
     eng = GenerationEngine(m, num_blocks=8, kv_cache_dtype="int8",
                            mesh=mesh)
-    kp = eng._kpools[0]
+    kp = eng._pools[0][0]
     assert isinstance(kp, pa.QuantPool)
     assert "mp" in str(kp.data.sharding.spec)
     assert "mp" in str(kp.scale.sharding.spec)

@@ -433,9 +433,9 @@ def test_adopt_pages_int8_ship_deterministic_and_lossless():
     ab = eng._prefix.match(toks)[0]
     for li in range(2):  # (b): adopted pool blocks == the shipped leaves
         np.testing.assert_array_equal(
-            np.asarray(eng._kpools[li].data[ab]), k1[li]["payload"][0])
+            np.asarray(eng._pools[0][li].data[ab]), k1[li]["payload"][0])
         np.testing.assert_array_equal(
-            np.asarray(eng._kpools[li].scale[ab]), k1[li]["scale"][0])
+            np.asarray(eng._pools[0][li].scale[ab]), k1[li]["scale"][0])
     # and an int8 admission over adopted pages serves a complete stream
     eng.add_request("g", P_G1, max_new_tokens=4)
     while eng.has_work():

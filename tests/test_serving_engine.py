@@ -154,7 +154,7 @@ def test_mp_sharded_engine_matches_single_device():
     assert isinstance(qw._value.sharding, NamedSharding)
     assert "mp" in str(qw._value.sharding.spec)
     # pool pages sharded over the KV-head dim
-    assert "mp" in str(eng._kpools[0].sharding.spec)
+    assert "mp" in str(eng._pools[0][0].sharding.spec)
 
     eng.add_request("a", p1, max_new_tokens=8)
     eng.step()

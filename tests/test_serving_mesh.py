@@ -104,7 +104,7 @@ def test_plain_engine_mesh_matches_single_device(mp, kv_dtype):
                            num_blocks=16, kv_cache_dtype=kv_dtype,
                            mesh=_mesh(mp))
     # pools really committed to the KV-head sharding (scales too on int8)
-    for _part, arr in pa.pool_parts(eng._kpools[0]):
+    for _part, arr in pa.pool_parts(eng._pools[0][0]):
         assert "mp" in str(arr.sharding.spec)
     got = _run_workload(eng)
     assert got == ref

@@ -100,9 +100,9 @@ def test_spec_engine_mesh_matches_single_device(mp, kv_dtype):
             eng = build(_mesh(mp))
     else:
         eng = build(_mesh(mp))
-        dk = eng._d_kpools[0]
+        dk = eng._d_pools[0][0]
         assert "mp" in str(getattr(dk, "data", dk).sharding.spec)
-    kp = eng._kpools[0]
+    kp = eng._pools[0][0]
     assert "mp" in str(getattr(kp, "data", kp).sharding.spec)
     got = _run(eng)
     assert got == ref

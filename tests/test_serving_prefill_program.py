@@ -79,8 +79,8 @@ def test_admission_matches_the_eager_forward(model, n, bucket):
     blocks = eng._slots[0].blocks
     assert len(blocks) == -(-(n + 16) // BS)
     for li in range(model.config.num_hidden_layers):
-        for pool, ref in ((eng._kpools[li], ref_k[li]),
-                          (eng._vpools[li], ref_v[li])):
+        for pool, ref in ((eng._pools[0][li], ref_k[li]),
+                          (eng._pools[1][li], ref_v[li])):
             got = _pages(pool, blocks)
             np.testing.assert_allclose(got[:n], ref, rtol=1e-5, atol=1e-5)
             assert not got[n:].any()     # the block's tail and the decode pages
@@ -111,8 +111,8 @@ def test_prefix_hit_runs_the_program_of_its_prefix_length(model):
     slot = next(s for s in eng._slots if s.rid == "b")
     assert slot.blocks[:2] == next(s for s in eng._slots if s.rid == "a").blocks[:2]
     for li in range(model.config.num_hidden_layers):
-        got_k = _pages(eng._kpools[li], slot.blocks)
-        got_v = _pages(eng._vpools[li], slot.blocks)
+        got_k = _pages(eng._pools[0][li], slot.blocks)
+        got_v = _pages(eng._pools[1][li], slot.blocks)
         np.testing.assert_allclose(got_k[:43], ref_k[li], rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(got_v[:43], ref_v[li], rtol=1e-5, atol=1e-5)
         assert not got_k[43:].any() and not got_v[43:].any()
@@ -239,13 +239,13 @@ def test_int8_pool_scales_see_no_padding_and_recycled_pages_are_reset(model):
     eng = _engine(model, max_batch=1, num_blocks=4, kv_cache_dtype="int8")
     eng.add_request("old", _prompt(10, 40), max_new_tokens=20)
     _drain(eng)                               # all four pages written, then freed
-    assert all(float(jnp.abs(p.scale[:4]).min()) > 0 for p in eng._kpools)
+    assert all(float(jnp.abs(p.scale[:4]).min()) > 0 for p in eng._pools[0])
     prompt = _prompt(11, 21)
     eng.add_request("new", prompt, max_new_tokens=30)
     blocks = eng._slots[0].blocks
     assert len(blocks) == 4
     _first, ref_k, ref_v = _reference(model, prompt)
-    for pools, ref in ((eng._kpools, ref_k), (eng._vpools, ref_v)):
+    for pools, ref in ((eng._pools[0], ref_k), (eng._pools[1], ref_v)):
         for li, pool in enumerate(pools):
             got = pa.pool_get_blocks(pool, blocks)
             want = pa.paged_pour_blocks(

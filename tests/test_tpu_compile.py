@@ -124,6 +124,31 @@ def test_flash_attention_limit_is_the_compilers(one_chip, mosaic):
     jax.jit(jax.grad(loss, 1)).lower(x, w).compile()
 
 
+@pytest.mark.parametrize("seq", [2048, 8192])
+def test_flash_forward_compiles_with_a_value_width_of_its_own(one_chip, mosaic,
+                                                              seq):
+    """Latent attention's prefill (models/mla_moe.py) at the published
+    widths: 128 heads, q/k 192 wide (128 + 64 rope lanes), v 128 wide, one
+    prompt of the longest bucket the serving cell sends.  K and V of a head
+    stay whole in VMEM: 8,192 x (192 + 128) x 2 B, twice."""
+    qk, v = (1, seq, 128, 192), (1, seq, 128, 128)
+    compiled = _compile(_flash, qk, qk, v, sharding=one_chip)
+    assert f"bf16[1,128,{seq},128]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,hidden", [(8192, 7680), (32, 7680),
+                                         (8192, 1536), (8192, 512)])
+def test_fused_rms_norm_compiles_at_the_latent_models_widths(one_chip, mosaic,
+                                                             rows, hidden):
+    """8,192 rows of 7,680: the row cap is 136, which does not tile 8,192,
+    and one block of the whole array was 120 MB of VMEM; the block is now
+    the largest multiple of 8 under the cap that does (128)."""
+    from paddle_tpu.ops import fused_rms_norm
+
+    _compile(lambda x, w: fused_rms_norm(x, w), (rows, hidden), (hidden,),
+             sharding=one_chip)
+
+
 @pytest.mark.parametrize("case", ["fwd", "residual", "bwd"])
 def test_fused_rms_norm_compiles(one_chip, mosaic, case):
     from paddle_tpu.ops import fused_rms_norm
