@@ -7,6 +7,9 @@
     python chip_smoke.py --mla-moe-logits  # one chip: the latent-attention /
                                        # routed-expert model's LOGITS through
                                        # the engine against its plain reference
+    python chip_smoke.py --dense-softmax   # one chip: the dense decode attention
+                                       # over a bfloat16 paged pool against a
+                                       # float32 softmax on the same rows
 
 Drives the two entry points users of this framework call — the compiled train
 step (`paddle_tpu.jit.TrainStep`) and the serving engine
@@ -383,6 +386,14 @@ def serve_phase(cfg, *, prompt_lens, max_new_tokens, seed, device,
 
 # ------------------------------------- latent attention, routed experts ----
 
+def _rms_error(got, want):
+    """rms of the error over the rms of what was wanted."""
+    import numpy as np
+
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean()))
+
+
 def _row_error(got, want):
     import numpy as np
 
@@ -609,8 +620,8 @@ def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
                 lambda q, pool, tables, lens, fn=fn: fn(
                     q, pool, tables, lens, rank=rank, width=width))(
                         q, pool, tables, lens))
-            out[f"softmax_rms_{name}_spread_{spread:g}"] = float(
-                np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean()))
+            out[f"softmax_rms_{name}_spread_{spread:g}"] = _rms_error(got,
+                                                                      want)
         say(f"mla_moe probes: decode attention over {list(map(int, lens))} live "
             f"rows, scores spread {spread:g}: rms error "
             f"{out[f'softmax_rms_float32_spread_{spread:g}']:.5f} of the "
@@ -622,6 +633,98 @@ def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
     check(out[f"softmax_rms_float32_spread_{s}"] <= SOFTMAX_RMS_TOL,
           "the program's decode softmax agrees with a float32 softmax on the "
           "same inputs")
+    return out
+
+
+def _bfloat16_softmax_dense(q, kc, vc, tables, lens):
+    """`ops.paged_attention.paged_chunk_attention` with the scores rounded
+    to bfloat16 and the softmax computed in it, so that the probabilities
+    are bfloat16: the control."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    # bfloat16 VALUES in float32 containers: the products are those of
+    # bfloat16 operands on any backend (the CPU's has no bfloat16 dot of
+    # every shape), the sums float32
+    f32 = jnp.float32
+    keys = pa.paged_gather(kc, tables).astype(f32)
+    vals = pa.paged_gather(vc, tables).astype(f32)
+    b, t, n, h = q.shape
+    qg = q.astype(f32).reshape(b, t, keys.shape[1], -1, h)
+    score = jnp.einsum("btkgh,bksh->bkgts", qg, keys)
+    score = score.astype(jnp.bfloat16) / jnp.bfloat16(math.sqrt(h))
+    seen = jnp.arange(keys.shape[2])[None, :] < lens[:, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None, None, None, :], score, -1e30),
+                       axis=-1)
+    out = jnp.einsum("bkgts,bksh->btkgh", p.astype(f32), vals)
+    return out.reshape(b, t, n, h).astype(q.dtype)
+
+
+def dense_softmax_probe(*, seed, heads=16, kv_heads=8, head_dim=128,
+                        block_size=16, table_width=96, lens=(130, 300, 512)):
+    """The dense twin of the latent softmax probe: the decode attention a
+    dense model's macro-step runs (`paged_chunk_attention`, one token a row)
+    over bfloat16 K/V pools at internlm2-1.8b's heads, `lens` live positions
+    a row behind a table of `table_width` pages in a shuffled order (so the
+    width it reads is the ladder's, not the table's), against a softmax
+    computed in float64 on the host over the SAME bfloat16 queries and rows:
+    rms error over the output's rms, scores spread as a trained model's; and
+    once more with the softmax in bfloat16, the control."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    rows = len(lens)
+    draw = np.random.default_rng([seed % 2 ** 63, 28])
+    tables = jnp.asarray(draw.permutation(table_width * rows).reshape(
+        rows, table_width), jnp.int32)
+    k_k, k_v, k_q = jax.random.split(jax.random.key(seed % 2 ** 31), 3)
+    shape = (table_width * rows, kv_heads, block_size, head_dim)
+    kc = jax.random.normal(k_k, shape, jnp.float32).astype(jnp.bfloat16)
+    vc = jax.random.normal(k_v, shape, jnp.float32).astype(jnp.bfloat16)
+    # unit-variance keys: a score's deviation is |q| / sqrt(head_dim)
+    q = (jax.random.normal(k_q, (rows, 1, heads, head_dim), jnp.float32)
+         * SOFTMAX_SCORE_SPREAD).astype(jnp.bfloat16)
+    lens = jnp.asarray(lens, jnp.int32)
+
+    def held(cache):  # [rows, kv_heads, S, head_dim], float64, on the host
+        pages = np.asarray(cache.astype(jnp.float32), np.float64)[
+            np.asarray(tables)]
+        return np.moveaxis(pages, 2, 1).reshape(rows, kv_heads, -1, head_dim)
+
+    keys, vals = held(kc), held(vc)
+    qh = np.asarray(q.astype(jnp.float32), np.float64)[:, 0].reshape(
+        rows, kv_heads, -1, head_dim)
+    want = np.zeros((rows, kv_heads, heads // kv_heads, head_dim))
+    for i, n in enumerate(np.asarray(lens)):
+        score = np.einsum("kgh,ksh->kgs", qh[i], keys[i, :, :n]) / math.sqrt(
+            head_dim)
+        p = np.exp(score - score.max(-1, keepdims=True))
+        want[i] = np.einsum("kgs,ksh->kgh", p / p.sum(-1, keepdims=True),
+                            vals[i, :, :n])
+    want = want.reshape(rows, 1, heads, head_dim)
+    out = {}
+    for name, fn in (("float32", pa.paged_chunk_attention),
+                     ("bfloat16", _bfloat16_softmax_dense)):
+        out[f"dense_softmax_rms_{name}"] = _rms_error(
+            jax.jit(fn)(q, kc, vc, tables, lens).astype(jnp.float32), want)
+    read, live = pa.attn_positions(tables, block_size, lens)
+    out["positions_read"], out["positions_live"] = int(read), int(live)
+    say(f"dense probe: decode attention over {list(map(int, lens))} live "
+        f"positions of {table_width * block_size} ({heads} / {kv_heads} heads "
+        f"x {head_dim}, bfloat16 pools, {out['positions_read']} positions "
+        f"read for {out['positions_live']} live), scores spread "
+        f"{SOFTMAX_SCORE_SPREAD:g}: rms error "
+        f"{out['dense_softmax_rms_float32']:.5f} of the output's rms; with "
+        f"the softmax in bfloat16 {out['dense_softmax_rms_bfloat16']:.5f} "
+        f"(limit {SOFTMAX_RMS_TOL})")
+    check(out["dense_softmax_rms_float32"] <= SOFTMAX_RMS_TOL,
+          "the dense decode attention agrees with a float32 softmax on the "
+          "same rows")
     return out
 
 
@@ -746,6 +849,10 @@ def main(argv=None) -> int:
                          "attention / routed-expert configuration "
                          "(perfbench/configs/openpangu-ultra-moe-718b.json) "
                          "through GenerationEngine against its reference")
+    ap.add_argument("--dense-softmax", action="store_true",
+                    help="run ONLY the dense decode attention over a "
+                         "bfloat16 paged pool (internlm2-1.8b's heads) "
+                         "against a float32 softmax on the same rows")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -790,6 +897,11 @@ def main(argv=None) -> int:
               > SOFTMAX_RMS_TOL,
               "a bfloat16 softmax does NOT agree with a float32 softmax on "
               "the same inputs: the comparison tells it from float32")
+    elif args.dense_softmax:
+        out = dense_softmax_probe(seed=args.seed)
+        check(out["dense_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
+              "a bfloat16 softmax does NOT agree with a float32 softmax on "
+              "the same rows: the comparison tells it from float32")
     elif args.four_chips:
         batch = 2  # one sequence per data-parallel group
         depth, why = choose_depth("train", widths, limit, batch=batch, seq=seq,

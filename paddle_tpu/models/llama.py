@@ -668,7 +668,17 @@ class LlamaServing(ServingContract):
         h, kps, vps = _decode_layers_paged(
             model.layers, h, model.rope_cos._value, model.rope_sin._value,
             pools[0], pools[1], tables, lens, **kv_only)
-        return model.norm(h), [kps, vps], {}
+        # what this token step's attention read and what was live, once a
+        # step (every layer reads the same width); the fused decode chain
+        # reads the whole table
+        from paddle_tpu.ops import paged_attention as pa
+
+        k0 = pools[0][0] if isinstance(pools[0], (list, tuple)) else pools[0]
+        read, live = pa.attn_positions(
+            tables, pa.pool_block_size(k0), lens, active,
+            whole_table=kv_only.get("chain_cfg") is not None)
+        return model.norm(h), [kps, vps], {
+            "attn_positions_read": read, "attn_positions_live": live}
 
     def logits(self, h):
         return self.lm._logits(h)

@@ -49,9 +49,9 @@ with the per-device kernel geometry taken from
 ``NamedSharding.shard_shape`` (the same source the serving telemetry's
 ``pool_device_nbytes`` uses).  GQA head contiguity makes every candidate
 layout head-local: device d's query-head shard [d·n/mp, (d+1)·n/mp)
-attends exactly its own kv-head shard (``gathered_attention`` repeats kv
-heads in contiguous groups), so the fused chain runs ZERO in-kernel
-collectives and the mesh adds NO drift — parity re-gates bit-exactly
+attends exactly its own kv-head shard (``gathered_attention`` contracts
+query heads in contiguous groups per kv head), so the fused chain runs ZERO
+in-kernel collectives and the mesh adds NO drift — parity re-gates bit-exactly
 against the sharded XLA twin (synthetic args committed to the engine's
 NamedShardings, reference jitted under GSPMD), the PR-11 contract.  The
 roofline costs PER-DEVICE traffic plus ``collective_bytes`` — the psum an
@@ -253,7 +253,7 @@ class DecodeChainSpec:
     def collective_bytes(self, config) -> int:
         """ICI bytes of the psum the attention epilogue needs, per device.
         Every current layout is head-local — P(None, mp) keeps each query
-        head's whole GQA kv group on its own device (contiguous repeat in
+        head's whole GQA kv group on its own device (contiguous groups in
         gathered_attention), so the chain runs zero in-kernel collectives
         and this is 0; o_proj's row-parallel psum stays OUTSIDE the chain
         (GSPMD's epilogue, costed by the step program, not the kernel).

@@ -8,14 +8,25 @@ mapping its logical positions onto pool blocks, so cache memory is allocated
 per-16-token page instead of per-max-seq-len (vLLM-style paging).
 
 TPU-native design: the pool is ONE [num_blocks, Nkv, block_size, H] array per
-K and V; block writes are scatter-at-index updates and decode attention
-gathers each sequence's pages with jnp.take on the block table.  Both lower
-to XLA dynamic-scatter/gather which on TPU are HBM-bandwidth-bound copies —
-the same roofline the hand-written CUDA kernel targets — and the whole
-decode step (gather + QK^T + softmax + PV) fuses into one executable.
-Everything is shape-static: max_blocks_per_seq bounds the gather and a
-length mask handles raggedness, so the step jits once and is reused for the
-whole decode.
+K and V; block writes are scatter-at-index updates, and decode attention
+(`paged_chunk_attention`) reads each sequence's pages with ONE clipped
+jnp.take on its block table and contracts them as gathered,
+[B, pages, Nkv, block_size, H], in the pool's own type with float32
+accumulation: no moved axis, no float32 copy of K or V, and query heads
+contracted in their KV groups, never a repeated K/V.  The take still writes
+the gathered pages to HBM once and the contractions read them back (XLA
+does not fuse a gather into its consumer), so the step moves about three
+times the live K/V bytes; a kernel that reads each row's pages straight
+from the pool is the next step (ROADMAP S3).
+
+Everything is shape-static, so the step jits once: the block table bounds
+the gather and a length mask handles raggedness.  How much of the table is
+read is chosen ON THE DEVICE from max(seq_lens), among a short ladder of
+static widths (`page_ladder`: powers of two pages from 16 up, capped at the
+table's width), by a lax.switch whose branches differ only in
+`block_tables[:, :w]`; masked positions contribute exactly 0, so every
+branch that covers the longest row computes the same numbers.
+`attn_positions` says what a step read and what was live.
 """
 
 from __future__ import annotations
@@ -26,6 +37,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops import _pl_utils
+
 __all__ = [
     "QuantPool",
     "alloc_paged_cache",
@@ -35,10 +48,13 @@ __all__ = [
     "paged_pour_blocks",
     "paged_pour_block",
     "paged_gather",
+    "page_ladder",
+    "attn_positions",
     "gathered_attention",
     "paged_decode_attention",
     "paged_chunk_attention",
     "pool_num_kv_heads",
+    "pool_block_size",
     "pool_nbytes",
     "pool_device_nbytes",
     "pool_parts",
@@ -91,6 +107,11 @@ class QuantPool:
 def pool_num_kv_heads(cache):
     """Nkv of a paged pool, quantized or plain."""
     return (cache.data if isinstance(cache, QuantPool) else cache).shape[1]
+
+
+def pool_block_size(cache):
+    """Positions a page of a paged pool holds (per-layer or stacked)."""
+    return (cache.data if isinstance(cache, QuantPool) else cache).shape[-2]
 
 
 def pool_nbytes(cache):
@@ -370,31 +391,125 @@ def paged_pour_block(cache, kv, block_id):
     return paged_pour_blocks(cache, kv[None], [int(block_id)])
 
 
+def _as_pages(kv):
+    """An already-gathered [B, Nkv, S, H] view as ONE page of S positions,
+    [B, 1, Nkv, S, H]: the form `gathered_attention` contracts (a reshape
+    that moves nothing)."""
+    return kv[:, None] if kv.ndim == 4 else kv
+
+
 def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
-    """The sdpa core of the decode tier over ALREADY-GATHERED views:
-    q [B, T, N, H]; keys/vals [B, Nkv, S, H] (dequantized); seq_lens [B]
-    INCLUDING all T chunk tokens.  The ONE masked-softmax definition —
-    paged_chunk_attention feeds it the paged_gather views and the fused
-    decode-chain kernel (ops/decode_chain.py) feeds it VMEM-gathered
-    pages, so the two paths cannot drift numerically."""
+    """The sdpa core of the decode tier over ALREADY-GATHERED K/V:
+    q [B, T, N, H]; keys/vals the pages as taken from the pool,
+    [B, M, Nkv, bs, H] (position m * bs + s), or a [B, Nkv, S, H] view (one
+    page of S positions); seq_lens [B] INCLUDING all T chunk tokens.  The
+    ONE masked-softmax definition — paged_chunk_attention feeds it the
+    pages of the width it chose and the fused decode-chain kernel
+    (ops/decode_chain.py) feeds it VMEM-gathered views, so the two paths
+    cannot drift numerically.
+
+    K and V are contracted in the type they arrive in, accumulated in
+    float32 (for bfloat16 values, the products a float32 contraction of
+    the same values forms); scores, mask, softmax and probabilities are
+    float32, and PV multiplies float32 probabilities with V converted
+    inside the reduce.  The N query heads are contracted in their Nkv
+    groups of N // Nkv (MHA is a group of one): K/V are never repeated."""
     b, t, n, h = q.shape
-    nkv = keys.shape[1]
+    keys, vals = _as_pages(keys), _as_pages(vals)
+    _b, m, nkv, bs, _h = keys.shape
     if scale is None:
         scale = 1.0 / math.sqrt(h)
-    if n != nkv:
-        group = n // nkv
-        keys = jnp.repeat(keys, group, axis=1)
-        vals = jnp.repeat(vals, group, axis=1)
-    logits = jnp.einsum(
-        "btnh,bnsh->bnts", q.astype(jnp.float32), keys.astype(jnp.float32)
-    ) * jnp.float32(scale)
-    span = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 3)  # key pos
+    # both operands in the wider of the two types (the pool's, when q is
+    # the model's own type), float32 accumulation
+    dt = jnp.promote_types(q.dtype, keys.dtype)
+    qg = q.astype(dt).reshape(b, t, nkv, n // nkv, h)
+    # highest precision: a float32 operand (the probabilities; a float32 or
+    # dequantised pool) is never cut to bfloat16 passes of the matrix unit;
+    # bfloat16 operands are exact in one pass whatever it says
+    exact = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum("btkgh,bmksh->bkgtms", qg, keys.astype(dt),
+                        precision=exact, preferred_element_type=jnp.float32
+                        ) * jnp.float32(scale)
+    kpos = (jnp.arange(m, dtype=jnp.int32)[:, None] * bs
+            + jnp.arange(bs, dtype=jnp.int32)[None, :])            # [M, bs]
     qpos = (seq_lens[:, None] - t + jnp.arange(t, dtype=jnp.int32)[None, :])
-    allowed = span <= qpos[:, None, :, None]
-    logits = jnp.where(allowed, logits, jnp.float32(-1e30))
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bnts,bnsh->btnh", probs, vals.astype(jnp.float32))
-    return out.astype(q.dtype)
+    allowed = kpos[None, None] <= qpos[:, :, None, None]       # [B, T, M, bs]
+    logits = jnp.where(allowed[:, None, None], logits, jnp.float32(-1e30))
+    # one flat axis of positions: the fused chain's one-page view and the
+    # pages of any width then reduce alike (its parity gate is bit-exact)
+    flat = logits.reshape(logits.shape[:4] + (m * bs,))
+    probs = jax.nn.softmax(flat, axis=-1).reshape(logits.shape)
+    out = jnp.einsum("bkgtms,bmksh->btkgh", probs, vals,
+                     precision=exact, preferred_element_type=jnp.float32)
+    return out.reshape(b, t, n, h).astype(q.dtype)
+
+
+def page_ladder(table_width):
+    """The static widths, in pages, among which decode attention chooses
+    how much of a [B, table_width] block table to read: powers of two from
+    16 up, capped at the table's own width (96 -> 16, 32, 64, 96; a table
+    of 16 pages or fewer has the one width)."""
+    ladder, w = [], 16
+    while w < table_width:
+        ladder.append(w)
+        w *= 2
+    return (*ladder, table_width)
+
+
+def _ladder_index(ladder, block_size, seq_lens):
+    """Index of the narrowest width of `ladder` that covers the longest
+    row of `seq_lens` (traced)."""
+    longest = jnp.max(seq_lens)
+    reach = jnp.asarray([w * block_size for w in ladder[:-1]], jnp.int32)
+    return jnp.sum(longest > reach).astype(jnp.int32)
+
+
+def attn_positions(block_tables, block_size, seq_lens, active=None, *,
+                   whole_table=False):
+    """What one `paged_chunk_attention` call over these rows reads and what
+    of it is live, as two int32 scalars: (`active` rows x positions of the
+    ladder width it takes, sum of the active rows' lengths).  Their
+    quotient is the step's read amplification (1 would take a ragged
+    kernel that reads each row's own pages).  `whole_table`: a reader that
+    takes the table's full width whatever the lengths (the fused decode
+    chain)."""
+    ladder = page_ladder(block_tables.shape[1])
+    pages = jnp.asarray(ladder, jnp.int32)[
+        len(ladder) - 1 if whole_table
+        else _ladder_index(ladder, block_size, seq_lens)]
+    if active is None:
+        active = jnp.ones(seq_lens.shape, bool)
+    read = jnp.sum(active) * pages * block_size
+    live = jnp.sum(jnp.where(active, seq_lens, 0))
+    return read.astype(jnp.int32), live.astype(jnp.int32)
+
+
+def _as_written(cache):
+    """A plain pool, told to lie inside a conditional's branch as it lies
+    in the step around it.  On a TPU the slot writes (`paged_write_chunk`'s
+    scatter over block and slot) keep a pool slot-major within a page,
+    [block][slot][head][H]; a branch takes its operands in the default
+    order unless told, and XLA then copies the WHOLE pool into every
+    branch: per K, per V, per layer, per token step (the macro-step compiled
+    for a described v5e: 48 copies of 102 MB a token step; none with this).
+    A hint, not semantics: where the pool arrives in another order (a
+    program with no slot write in it) XLA copies it once, as it did."""
+    if isinstance(cache, QuantPool) or not _pl_utils.on_tpu():
+        return cache
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(cache, Layout(major_to_minor=(0, 2, 1, 3)))
+
+
+def _take_pages(cache, block_tables):
+    """Each row's pages as they lie in the pool: [B, w, Nkv, bs, H], ONE
+    pass (a clipped take: block tables hold valid pages only, so nothing
+    is filled; no moved axis).  A QuantPool dequantises here (float32)."""
+    if isinstance(cache, QuantPool):
+        pages = jnp.take(cache.data, block_tables, axis=0, mode="clip")
+        scales = jnp.take(cache.scale, block_tables, axis=0, mode="clip")
+        return pages.astype(jnp.float32) * scales[..., None, None]
+    return jnp.take(cache, block_tables, axis=0, mode="clip")
 
 
 def paged_chunk_attention(q, key_cache, value_cache, block_tables, seq_lens,
@@ -403,7 +518,24 @@ def paged_chunk_attention(q, key_cache, value_cache, block_tables, seq_lens,
     verify / chunked decode): q [B, T, N, H]; seq_lens [B] INCLUDING all
     T chunk tokens.  Chunk position j sits at global position
     seq_lens - T + j and attends keys <= that position (bottom-right
-    causal within the chunk).  Returns [B, T, N, H]."""
-    keys = paged_gather(key_cache, block_tables)  # [B, Nkv, S, H]
-    vals = paged_gather(value_cache, block_tables)
-    return gathered_attention(q, keys, vals, seq_lens, scale=scale)
+    causal within the chunk).  Returns [B, T, N, H].
+
+    Reads the first `w` pages of every row, `w` the narrowest width of
+    `page_ladder` that covers max(seq_lens), chosen on the device."""
+    ladder = page_ladder(block_tables.shape[1])
+    bs = pool_block_size(key_cache)
+
+    def at_width(w, pool=lambda cache: cache):
+        def attend(q, key_cache, value_cache, block_tables, seq_lens):
+            tables = block_tables[:, :w]
+            return gathered_attention(
+                q, _take_pages(pool(key_cache), tables),
+                _take_pages(pool(value_cache), tables), seq_lens,
+                scale=scale)
+        return attend
+
+    args = (q, key_cache, value_cache, block_tables, seq_lens)
+    if len(ladder) == 1:
+        return at_width(ladder[0])(*args)
+    return jax.lax.switch(_ladder_index(ladder, bs, seq_lens),
+                          [at_width(w, _as_written) for w in ladder], *args)

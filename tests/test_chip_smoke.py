@@ -124,3 +124,14 @@ def test_mla_moe_logits_phase_on_cpu_tiny(monkeypatch):
     assert out["route_agreement"] == 1.0 >= out["route_agreement_bfloat16"]
     assert (out["softmax_rms_float32_spread_4"] < chip_smoke.SOFTMAX_RMS_TOL
             < out["softmax_rms_bfloat16_spread_4"])
+
+
+def test_dense_softmax_probe_on_cpu():
+    """Rehearsal 1 of `--dense-softmax`, at the probe's own sizes (they are
+    small): the program's decode attention reads under the limit, the
+    bfloat16-softmax control over it, and the 512-position row takes the
+    32-page width of a 96-page table."""
+    out = chip_smoke.dense_softmax_probe(seed=2147484099)
+    assert (out["dense_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL
+            < out["dense_softmax_rms_bfloat16"])
+    assert (out["positions_read"], out["positions_live"]) == (3 * 512, 942)

@@ -233,3 +233,48 @@ def test_kernel_names_reach_the_hlo_instruction_names(one_chip, mosaic):
     # the trace reduction names an operation "<instruction> <opcode> -> ..."
     matched = {n for n in names if pat.search(n + " custom-call -> ")}
     assert matched == {n for n in names if "flash_" in n} and len(matched) == 3
+
+
+def test_decode_attention_branches_take_the_pool_as_it_is_written(one_chip,
+                                                                  mosaic):
+    """The write -> attend sequence of a decode layer inside a scan (the
+    macro-step's shape), at decode-sat's geometry: the ladder's branches
+    hold NO copy of a pool.  Without `paged_attention._as_written` each
+    branch copies both whole pools (102 MB each, per layer and token step):
+    the loop keeps a pool in the order its slot writes prefer, a branch
+    takes its operands in the default order unless told."""
+    import re
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, nkv, h, w, bs, nb = 32, 16, 8, 128, 96, 16, 3104
+
+    def steps(q, kc, vc, new, tables, lens):
+        def one(carry, _):
+            kc, vc, lens, acc = carry
+            kc = pa.paged_write(kc, new, tables, lens - 1)
+            vc = pa.paged_write(vc, new, tables, lens - 1)
+            o = pa.paged_decode_attention(q + acc.astype(q.dtype), kc, vc,
+                                          tables, lens)
+            return (kc, vc, lens + 1, acc + o.astype(jnp.float32)), None
+
+        carry, _ = jax.lax.scan(
+            one, (kc, vc, lens, jnp.zeros(q.shape, jnp.float32)), None,
+            length=2)
+        return carry
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((nb, nkv, bs, h))
+    text = jax.jit(steps, donate_argnums=(1, 2)).lower(
+        s((b, n, h)), pool, pool, s((b, nkv, h)), s((b, w), jnp.int32),
+        s((b,), jnp.int32)).compile().as_text()
+    branches = re.search(r"conditional\(.*branch_computations=\{([^}]*)\}",
+                         text).group(1).split(", ")
+    assert len(branches) == len(pa.page_ladder(w)) == 4
+    for name in branches:
+        body = text.split(f"\n{name} (", 1)[1].split("\n}\n", 1)[0]
+        assert f"[{nb},{nkv},{bs},{h}]" in body        # the pool is read here
+        assert not re.search(
+            rf"= bf16\[{nb},{nkv},{bs},{h}\]\S* copy\(", body), name
