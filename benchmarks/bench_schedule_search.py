@@ -1,6 +1,6 @@
 """Benchmark: cost-model-driven Pallas schedule search + measured-win gate.
 
-Exercises the full ROADMAP-item-2/item-4 loop on four searched subjects no
+Exercises the full search loop on three searched subjects no
 named pattern covers (the XLA fusion-miss classes of arXiv 2301.13062):
 
 - **matmul chain** — matmul→bias-add→relu→mean tail (matmul-rooted with a
@@ -15,22 +15,6 @@ named pattern covers (the XLA fusion-miss classes of arXiv 2301.13062):
   is exercised: the decision persists as a disabled entry in the
   per-device autotune cache and a cold reload must skip the subgraph
   without a single re-measurement.
-- **decode hot chain** (phase 2) — the serving macro-step's paged gather →
-  dequant → sdpa core → quant-write sequence (ops/decode_chain.py), bf16
-  AND int8 variants, searched through the same enumerate→prune→parity→
-  measure→gate loop; every candidate must pass the numerics parity gate
-  vs the unfused twin BEFORE it may be measured, and the disabled int8
-  verdict must serve a cold reload with zero re-measures.
-- **sharded decode chain** (schedule search over the mesh) — the same hot
-  chain searched by a REAL 2-device TP engine workload: the verdict
-  caches under the (device kind, mesh shape) key, an adoption builds the
-  chain inside shard_map over the engine's committed pool sharding
-  (decode_chains_mesh_fused counts it), and the token streams must stay
-  bit-identical to the search-off sharded twin whether the verdict is
-  adopt or an honest disable.
-- **fused prefill attention** — the K-tiled long-prompt-pour candidate
-  (ops/decode_chain.PrefillChainSpec) joins the same search with a
-  BIT-EXACT parity gate on every candidate.
 
 Timing: in full mode candidates are measured for real through
 cost_model.OpCostModel.measure (hard_sync device barrier — meaningful on
@@ -45,9 +29,7 @@ numerics always checked for real.
 Prints ONE JSON line shaped like bench.py: {"metric", "value", ...}.
 value = the best accepted schedule's measured win ratio over XLA (0.0 when
 the gate disabled everything — an honest loss is not a regression signal;
-tools/check_bench_regression.py skips zero values).  detail.decode_chain
-carries the per-variant decode verdicts the regression gate compares
-win-to-win, skipping disabled sides honestly.
+tools/check_bench_regression.py skips zero values).
 """
 
 from __future__ import annotations
@@ -60,14 +42,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# the mesh decode-chain case dispatches a REAL 2-device engine workload:
-# the host-platform device count must be pinned before jax initializes
-# (a no-op on TPU backends — the flag only shapes the CPU platform)
-_xla_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        _xla_flags + " --xla_force_host_platform_device_count=8")
 
 
 def main() -> int:
@@ -83,7 +57,6 @@ def main() -> int:
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F
     from paddle_tpu.ops import autotune as at
-    from paddle_tpu.ops import decode_chain as dc
     from paddle_tpu.static import schedule_search as ss
     from paddle_tpu.static.program import Program, program_guard
     from paddle_tpu.static.rewrite import ScheduleSearchPass
@@ -99,25 +72,16 @@ def main() -> int:
         M, K, N = 32, 16, 64
         B, S, H = 2, 8, 32
         MT, KT, NT = 32, 256, 64
-        DEC = dict(batch=2, num_heads=4, num_kv_heads=2, head_dim=8,
-                   block_size=4, max_blocks=2, num_blocks=8)
-        PS = 8  # prefill chunk length (kv span = 2*PS)
     elif jax.default_backend() == "tpu":
         M, K, N = 1024, 512, 512
         B, S, H = 8, 128, 512
         MT, KT, NT = 1024, 2048, 1024
-        DEC = dict(batch=8, num_heads=16, num_kv_heads=8, head_dim=128,
-                   block_size=16, max_blocks=16, num_blocks=136)
-        PS = 128
     else:
         # full mode off-chip: real timing of interpret-mode kernels — keep
         # shapes small enough that an honest all-disabled outcome is cheap
         M, K, N = 128, 64, 128
         B, S, H = 4, 32, 64
         MT, KT, NT = 64, 512, 128
-        DEC = dict(batch=2, num_heads=4, num_kv_heads=2, head_dim=16,
-                   block_size=8, max_blocks=4, num_blocks=16)
-        PS = 16
 
     def _feed(prog, name, shape):
         return prog.add_feed(
@@ -160,20 +124,12 @@ def main() -> int:
         """Deterministic roofline-shaped cost model: the matmul chains'
         schedules win (the large-K twin only through a genuinely K-tiled
         config; grid overhead mildly penalizes tiny blocks), the softmax
-        chain's and the int8 decode chain's schedules deliberately LOSE
-        to XLA, the bf16 decode chain (single-device AND its mesh-keyed
-        twin) and the prefill chain win."""
+        chain's schedules deliberately LOSE to XLA."""
         measured_labels.append(label)
         if config is None:
             return 1.0
         if label.startswith("schedule/reduce"):
             return 4.0  # the deliberately-bad schedule family
-        if label.startswith("schedule/decode_int8"):
-            return 4.0  # exercise the decode disable path
-        if label.startswith("schedule/decode_bf16"):
-            return 0.4
-        if label.startswith("schedule/prefill"):
-            return 0.4
         if f"k={KT}" in label:
             # the K-tiled twin: only a contraction split beats XLA here
             return 0.3 if config.get("block_k", KT) < KT else 4.0
@@ -214,112 +170,6 @@ def main() -> int:
             "cache_entry": cache_entry(kernel, key_sub),
         }
 
-    def run_decode_case(kv, budget=3):
-        """Search the decode hot chain at the bench geometry.  Numerics
-        ride the searcher's parity gate: a candidate that fails the
-        bit-exact (bf16) / drift-bounded (int8) check vs the unfused twin
-        is rejected before it may be measured."""
-        spec = dc.DecodeChainSpec(kv=kv, dtype=np.float32, **DEC)
-        decision = dc.ensure_decision(
-            spec, ss.ScheduleSearcher(budget=budget, iters=1, warmup=1))
-        entry = cache_entry(spec.kernel_name()) or {}
-        meta = entry.get("meta") or {}
-        return {
-            "status": decision.status,
-            "accepted": bool(decision.accepted),
-            "config": dict(decision.config) if decision.config else None,
-            "win": float(meta.get("win", 0.0) or 0.0)
-            if not entry.get("config", {}).get("disabled") else 0.0,
-            "disabled_persisted": bool(entry.get("config", {})
-                                       .get("disabled")),
-        }
-
-    def run_prefill_case(budget=3):
-        """Search the K-tiled fused prefill-attention candidate (the
-        long-prompt pour's attention core at the canonical chunk
-        geometry) through the same loop; every candidate's parity gate
-        is BIT-EXACT vs the jax.nn reference."""
-        spec = dc.PrefillChainSpec(seq=PS, kv_len=2 * PS,
-                                   num_heads=DEC["num_heads"],
-                                   head_dim=DEC["head_dim"],
-                                   dtype=np.float32)
-        decision = dc.ensure_decision(
-            spec, ss.ScheduleSearcher(budget=budget, iters=1, warmup=1))
-        entry = cache_entry(spec.kernel_name()) or {}
-        meta = entry.get("meta") or {}
-        return {
-            "status": decision.status,
-            "accepted": bool(decision.accepted),
-            "config": dict(decision.config) if decision.config else None,
-            "win": float(meta.get("win", 0.0) or 0.0)
-            if not entry.get("config", {}).get("disabled") else 0.0,
-        }
-
-    def run_mesh_decode_case():
-        """Schedule search over the mesh: a REAL 2-device TP engine
-        workload under FLAGS_schedule_search.  The sharded searcher's
-        verdict caches under the (device kind, mesh shape) key; an
-        adoption builds the chain inside shard_map over the engine's
-        committed pool sharding and MUST leave the token streams
-        bit-identical to the search-off sharded twin; an honest disable
-        keeps the unfused GSPMD path (streams compare identically
-        either way)."""
-        from paddle_tpu.distributed.auto_parallel import ProcessMesh
-        from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-        from paddle_tpu.serving import (GenerationEngine,
-                                        reset_schedule_decode_stats,
-                                        schedule_decode_stats)
-
-        if len(jax.devices()) < 2:
-            return {"skipped": "needs >= 2 devices"}
-
-        def build_model():
-            paddle.seed(41)
-            m = LlamaForCausalLM(llama_tiny(
-                vocab_size=128, hidden_size=32, intermediate_size=64,
-                num_hidden_layers=2, num_attention_heads=4,
-                num_key_value_heads=4, max_position_embeddings=64,
-                dtype="float32"))
-            m.eval()
-            return m
-
-        def workload(eng):
-            eng.add_request("g", [5, 9, 17, 33, 2], max_new_tokens=8)
-            eng.step()
-            eng.add_request("s", [7, 11, 3], max_new_tokens=6,
-                            temperature=3.0, seed=42)  # joins mid-flight
-            while eng.has_work():
-                eng.step()
-            return {"g": eng.result("g"), "s": eng.result("s")}
-
-        mesh = ProcessMesh(np.arange(2), ["mp"])
-        kw = dict(max_batch=2, block_size=8, num_blocks=16,
-                  kv_cache_dtype="bf16", mesh=mesh)
-        ref = workload(GenerationEngine(build_model(), **kw))
-        reset_schedule_decode_stats()
-        paddle.set_flags({"FLAGS_schedule_search": True})
-        try:
-            got = workload(GenerationEngine(build_model(), **kw))
-        finally:
-            paddle.set_flags({"FLAGS_schedule_search": False})
-        stats = schedule_decode_stats()
-        entry = cache_entry("schedule/decode_bf16",
-                            key_sub="mesh=mp2") or {}
-        meta = entry.get("meta") or {}
-        disabled = bool(entry.get("config", {}).get("disabled"))
-        return {
-            "mesh_fused": int(stats["decode_chains_mesh_fused"]),
-            "mesh_skipped": int(stats["decode_chains_mesh_skipped"]),
-            "streams_identical": bool(got == ref),
-            "win": 0.0 if disabled
-            else float(meta.get("win", 0.0) or 0.0),
-            # the verdict's cache key must carry the mesh shape — the
-            # single-device bf16 verdict above lives beside it, distinct
-            "cache_key_mesh": next(
-                (k for k in cache_entries("schedule/decode_bf16")
-                 if "mesh=mp2" in k), None),
-        }
-
     ctx = (ss.measure_override(smoke_measure) if smoke
            else contextlib.nullcontext())
     with ctx:
@@ -329,10 +179,6 @@ def main() -> int:
                                "schedule/matmul", key_sub=f"k={KT}|")
         softmax_case = run_case("softmax_chain", capture_softmax_chain,
                                 "schedule/reduce")
-        decode_bf16 = run_decode_case("bf16")
-        decode_int8 = run_decode_case("int8")
-        prefill_case = run_prefill_case()
-        mesh_case = run_mesh_decode_case()
 
         # never-refire: cold cache reload, a disabled subgraph must be
         # skipped without a single new measurement
@@ -344,14 +190,6 @@ def main() -> int:
             [out2._vid],
             searcher=ss.ScheduleSearcher(budget=3, iters=1, warmup=1)
         ).apply(prog2)
-        # ... and the decode verdicts serve a cold reload with zero
-        # re-measures too (accepted bf16 config AND disabled int8)
-        dc.ensure_decision(
-            dc.DecodeChainSpec(kv="bf16", dtype=np.float32, **DEC),
-            ss.ScheduleSearcher(budget=3, iters=1, warmup=1))
-        dc.ensure_decision(
-            dc.DecodeChainSpec(kv="int8", dtype=np.float32, **DEC),
-            ss.ScheduleSearcher(budget=3, iters=1, warmup=1))
         after = len(measured_labels) if smoke else \
             ss.schedule_search_stats()["measured"]
         never_refired = (after == before)
@@ -365,8 +203,6 @@ def main() -> int:
         if case["substituted"] and not entry.get("config", {}).get("disabled"):
             win = max(win, float((entry.get("meta") or {}).get("win", 0.0)
                                  or 0.0))
-    for case in (decode_bf16, decode_int8, prefill_case, mesh_case):
-        win = max(win, float(case.get("win", 0.0) or 0.0))
     disabled_entry = softmax_case["cache_entry"] or {}
     numerics_ok = (matmul_case["numerics_identical"]
                    and ktiled_case["numerics_identical"]
@@ -390,10 +226,6 @@ def main() -> int:
                     "matmul_chain": matmul_case,
                     "ktiled_matmul": ktiled_case,
                     "softmax_chain": softmax_case,
-                    "decode_chain": {"bf16": decode_bf16,
-                                     "int8": decode_int8,
-                                     "mesh": mesh_case,
-                                     "prefill": prefill_case},
                     "disabled_persisted": bool(disabled_entry.get(
                         "config", {}).get("disabled")),
                     "never_refired": bool(never_refired),
@@ -406,29 +238,16 @@ def main() -> int:
         ),
         flush=True,
     )
-    # stream parity on the sharded engine is a numerics claim, valid in
-    # smoke AND full mode (adopt or disable, the streams must match)
-    ok = (numerics_ok and never_refired
-          and bool(mesh_case.get("streams_identical", True)))
+    ok = numerics_ok and never_refired
     if smoke:
         # the deterministic cost model must produce exactly these decisions
         ktc = (ktiled_case["cache_entry"] or {}).get("config", {})
-        mesh_ok = ("skipped" in mesh_case) or (
-            mesh_case["mesh_fused"] > 0
-            and mesh_case["win"] > 1.0
-            and bool(mesh_case["cache_key_mesh"]))
         ok = ok and matmul_case["substituted"] == 1 and win > 1.0 \
             and softmax_case["substituted"] == 0 \
             and bool(disabled_entry.get("config", {}).get("disabled")) \
             and ktiled_case["substituted"] == 1 \
-            and 0 < ktc.get("block_k", 0) < KT \
-            and decode_bf16["accepted"] and decode_bf16["win"] > 1.0 \
-            and decode_int8["status"] in ("disabled", "cache_disabled") \
-            and decode_int8["disabled_persisted"] \
-            and prefill_case["accepted"] and prefill_case["win"] > 1.0 \
-            and mesh_ok
+            and 0 < ktc.get("block_k", 0) < KT
     return 0 if ok else 4
-
 
 
 if __name__ == "__main__":

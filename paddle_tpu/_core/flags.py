@@ -204,16 +204,6 @@ define_flag(
     "disabled for this device kind and never re-measured",
 )
 define_flag(
-    "FLAGS_schedule_search_decode",
-    True,
-    "With FLAGS_schedule_search on, also point the searcher at the serving "
-    "engine's decode hot chain (paged gather -> dequant -> sdpa core -> "
-    "quant-write; ops/decode_chain.py): the compiled macro-step consumes an "
-    "accepted per-device-kind schedule, TP-sharded engines skip with a "
-    "counted telemetry skip.  Off = Program-level search only "
-    "(docs/SCHEDULE_SEARCH.md phase 2)",
-)
-define_flag(
     "FLAGS_verify_sharding",
     False,
     "Mesh lint for the distributed tier (static/mesh_lint.py): statically "

@@ -42,7 +42,6 @@ serve those features and need no implementation elsewhere.
 
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass
 
 __all__ = ["PoolSpec", "CacheSpec", "ServingContract"]
@@ -96,8 +95,8 @@ class ServingContract:
         """tokens [B, T] int32 (T == 1 unless `chunk=True`); pools in carry
         form; tables [B, W]; lens [B] INCLUDING these tokens; active [B]
         bool or None.  Returns (hidden Tensor [B, T, h] after the final
-        norm, pools, aux).  `kv_only`: chunk, adapters, slots, scaling,
-        chain_cfg, passed only by features a K/V specification admits."""
+            norm, pools, aux).  `kv_only`: chunk, adapters, slots, scaling,
+        passed only by features a K/V specification admits."""
         raise NotImplementedError
 
     def logits(self, h):
@@ -110,10 +109,6 @@ class ServingContract:
     def pool_unpack(self, pools):
         return [list(p) for p in pools]
 
-    def prefill_scope(self, cfg):
-        """Scope of an accepted chunked-prefill attention schedule."""
-        return contextlib.nullcontext()
-
     def shard(self, mesh, mp_axis):
         raise NotImplementedError(
             f"{type(self).__name__} has no tensor-parallel placement")
@@ -121,7 +116,3 @@ class ServingContract:
     def adapter_layers(self):
         raise NotImplementedError(
             f"{type(self).__name__} has no LoRA target layers")
-
-    @property
-    def num_query_heads(self) -> int:
-        raise NotImplementedError

@@ -34,7 +34,6 @@ __all__ = [
     "LLAMA_TP_ROW_TARGETS",
     "pipeline_llama",
     "context_parallel_llama",
-    "prefill_chain_scope",
     "llama_tiny",
     "llama_7b",
 ]
@@ -117,36 +116,6 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_offset=0):
         )
 
     return apply("rotary_pos_emb", _rope, q, k, cos, sin)
-
-
-# Accepted prefill-attention schedule (schedule search; PrefillChainSpec)
-# for the chunked-prefill scope the engine is currently inside, or None.
-# A module global, not engine state: LlamaAttention.forward is the one
-# place that knows whether THIS call is the eligible prefill core.
-_PREFILL_CHAIN_CFG = None
-
-
-def prefill_chain_scope(cfg):
-    """Scope an accepted prefill-chain config over a chunked prefill
-    (serving._try_admit): inside the scope every eligible
-    LlamaAttention.forward prefill core — batch 1, multi-token chunk, no
-    explicit mask, no context parallelism, shapes the config tiles —
-    runs as ONE fused K-tiled Pallas dispatch (ops.decode_chain.
-    fused_prefill_attention) instead of the XLA einsum chain; everything
-    else keeps the XLA path.  cfg=None is a no-op scope."""
-    import contextlib
-
-    @contextlib.contextmanager
-    def _ctx():
-        global _PREFILL_CHAIN_CFG
-        prev = _PREFILL_CHAIN_CFG
-        _PREFILL_CHAIN_CFG = cfg
-        try:
-            yield
-        finally:
-            _PREFILL_CHAIN_CFG = prev
-
-    return _ctx()
 
 
 class LlamaAttention(nn.Layer):
@@ -234,34 +203,12 @@ class LlamaAttention(nn.Layer):
 
             out = sep_attention(q, k, v, causal=True, mode=self._sep_mode)
         else:
-            chain = _PREFILL_CHAIN_CFG
-            bq = int(chain.get("block_q", 0)) if chain else 0
-            kch = int(chain.get("kchunk", 1) or 1) if chain else 1
-            if (chain is not None and attn_mask is None and s > 1
-                    and b == 1 and bq >= 2 and s % bq == 0
-                    and int(k.shape[1]) % kch == 0):
-                # fused chunked-prefill attention core (prefill_chain_scope;
-                # the accepted schedule tiles this chunk exactly) — the
-                # config rides kwargs so the dispatch cache keys on it
-                from paddle_tpu.ops import decode_chain as _dc
-
-                def _fused_prefill(qv, kv_, vv, *, block_q, stage, kchunk):
-                    return _dc.fused_prefill_attention(
-                        qv, kv_, vv, block_q=block_q, stage=stage,
-                        kchunk=kchunk)
-
-                out = apply("fused_prefill_attention", _fused_prefill,
-                            q, k, v,
-                            block_q=int(chain["block_q"]),
-                            stage=chain.get("stage", "take"),
-                            kchunk=int(chain.get("kchunk", 1) or 1))
-            else:
-                # empty-cache prefill is causal; a cached single-token
-                # decode attends to everything it has
-                out = F.scaled_dot_product_attention(
-                    q, k, v, attn_mask=attn_mask,
-                    is_causal=(kv_cache is None) or s > 1
-                )
+            # empty-cache prefill is causal; a cached single-token
+            # decode attends to everything it has
+            out = F.scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask,
+                is_causal=(kv_cache is None) or s > 1
+            )
         out = out.reshape([b, s, self.num_heads * self.head_dim])
         out = self.o_proj(out)
         if new_cache is not None:
@@ -425,7 +372,7 @@ def _mlp_paged(mlp, x, ad, slots, scaling):
 
 
 def _decode_layer_paged(layer, h, cos, sin, kc, vc, tables, lens,
-                        ad=None, slots=None, scaling=None, chain_cfg=None):
+                        ad=None, slots=None, scaling=None):
     """One decoder layer on one new token against the paged KV pools.
 
     h: Tensor [B, 1, D]; kc/vc: [num_blocks, Nkv, bs, H] pools (raw arrays);
@@ -437,12 +384,6 @@ def _decode_layer_paged(layer, h, cos, sin, kc, vc, tables, lens,
     slots [B] picks each batch row's adapter slot and scaling [B] its
     alpha/rank, so mixed-adapter batches decode in this ONE program
     (slot 0 gathers zeros — the exact base-model identity; nn/lora.py).
-
-    chain_cfg: an ACCEPTED decode-chain schedule (ops/decode_chain.py;
-    docs/SCHEDULE_SEARCH.md phase 2) — the write→write→attend sequence
-    below runs as one fused Pallas dispatch instead of separate XLA ops.
-    Only the serving engine passes this, and only after the measured-win
-    gate and the stream parity gate said yes.
     """
     from paddle_tpu.ops import paged_attention as pa
 
@@ -460,15 +401,9 @@ def _decode_layer_paged(layer, h, cos, sin, kc, vc, tables, lens,
     pos = lens - 1
     qv = pa.rope_rotate_by_position(qv, cos, sin, pos)
     kv_ = pa.rope_rotate_by_position(kv_, cos, sin, pos)
-    if chain_cfg is not None:
-        from paddle_tpu.ops import decode_chain as _dc
-
-        o, kc, vc = _dc.fused_decode_step(kc, vc, qv, kv_, vv, tables,
-                                          lens, config=chain_cfg)
-    else:
-        kc = pa.paged_write(kc, kv_, tables, pos)
-        vc = pa.paged_write(vc, vv, tables, pos)
-        o = pa.paged_decode_attention(qv, kc, vc, tables, lens)
+    kc = pa.paged_write(kc, kv_, tables, pos)
+    vc = pa.paged_write(vc, vv, tables, pos)
+    o = pa.paged_decode_attention(qv, kc, vc, tables, lens)
     out = Tensor(_proj_lora(attn.o_proj, Tensor(o.reshape(b, 1, n * hd)),
                             ad, "self_attn.o_proj", slots, scaling))
     h = residual + out
@@ -516,7 +451,7 @@ def _decode_layer_paged_chunk(layer, h, cos, sin, kc, vc, tables, lens,
 
 def _decode_layers_paged(layers, h, cos, sin, kpools, vpools, tables, lens,
                          chunk=False, adapters=None, slots=None,
-                         scaling=None, chain_cfg=None):
+                         scaling=None):
     """Run every decoder layer's paged decode step over per-layer pools.
 
     ``layers`` is either a LayerList (unrolled view loop — the program
@@ -537,21 +472,10 @@ def _decode_layers_paged(layers, h, cos, sin, kpools, vpools, tables, lens,
     LEADING LAYER AXIS; on the LayerStack path the pack rides the decode
     scan as extra per-layer xs, on the view loop each layer indexes its
     slice.  slots [B] / scaling [B] are per-batch-row (nn/lora.py).
-
-    chain_cfg: accepted fused decode-chain schedule for the SINGLE-TOKEN
-    step (ops/decode_chain.py) — invalid with chunk=True, whose T-token
-    chain the searcher does not cover.
     """
     from paddle_tpu.ops import paged_attention as pa
 
     step = _decode_layer_paged_chunk if chunk else _decode_layer_paged
-    extra_kw = {}
-    if chain_cfg is not None:
-        if chunk:
-            raise ValueError(
-                "decode-chain fusion covers the single-token step only; "
-                "chunked/verify paths must not pass chain_cfg")
-        extra_kw = {"chain_cfg": chain_cfg}
     if isinstance(layers, nn.LayerStack):
         # per-layer form is a list/tuple; anything else (a raw stacked
         # array or a stacked QuantPool pytree) is the carry form
@@ -561,13 +485,13 @@ def _decode_layers_paged(layers, h, cos, sin, kpools, vpools, tables, lens,
         if adapters is None:
             h, k_state, v_state = layers.decode_scan(
                 lambda layer, hh, kc, vc: step(
-                    layer, hh, cos, sin, kc, vc, tables, lens, **extra_kw),
+                    layer, hh, cos, sin, kc, vc, tables, lens),
                 h, k_state, v_state)
         else:
             h, k_state, v_state = layers.decode_scan(
                 lambda layer, hh, kc, vc, ad: step(
                     layer, hh, cos, sin, kc, vc, tables, lens,
-                    ad=ad, slots=slots, scaling=scaling, **extra_kw),
+                    ad=ad, slots=slots, scaling=scaling),
                 h, k_state, v_state, extra=adapters)
         if stacked_in:
             return h, k_state, v_state
@@ -581,8 +505,7 @@ def _decode_layers_paged(layers, h, cos, sin, kpools, vpools, tables, lens,
         ad_l = (None if adapters is None else
                 jax.tree_util.tree_map(lambda a: a[li], adapters))
         h, kc, vc = step(layer, h, cos, sin, kpools[li], vpools[li],
-                         tables, lens, ad=ad_l, slots=slots, scaling=scaling,
-                         **extra_kw)
+                         tables, lens, ad=ad_l, slots=slots, scaling=scaling)
         new_k.append(kc)
         new_v.append(vc)
     return h, new_k, new_v
@@ -654,10 +577,6 @@ class LlamaServing(ServingContract):
     def max_positions(self) -> int:
         return int(self.lm.model.rope_cos.shape[0])
 
-    @property
-    def num_query_heads(self) -> int:
-        return self.lm.config.num_attention_heads
-
     def forward_cached(self, ids, caches, offset, n_real=None):
         h, caches = _model_forward_cached(self.lm.model, ids, caches, offset)
         return h, caches, {}
@@ -669,14 +588,12 @@ class LlamaServing(ServingContract):
             model.layers, h, model.rope_cos._value, model.rope_sin._value,
             pools[0], pools[1], tables, lens, **kv_only)
         # what this token step's attention read and what was live, once a
-        # step (every layer reads the same width); the fused decode chain
-        # reads the whole table
+        # step (every layer reads the same width)
         from paddle_tpu.ops import paged_attention as pa
 
         k0 = pools[0][0] if isinstance(pools[0], (list, tuple)) else pools[0]
         read, live = pa.attn_positions(
-            tables, pa.pool_block_size(k0), lens, active,
-            whole_table=kv_only.get("chain_cfg") is not None)
+            tables, pa.pool_block_size(k0), lens, active)
         return model.norm(h), [kps, vps], {
             "attn_positions_read": read, "attn_positions_live": live}
 
@@ -688,9 +605,6 @@ class LlamaServing(ServingContract):
 
     def pool_unpack(self, pools):
         return list(_pool_unpack(self.lm.model.layers, *pools))
-
-    def prefill_scope(self, cfg):
-        return prefill_chain_scope(cfg)
 
     def shard(self, mesh, mp_axis):
         shard_llama(self.lm, mesh, mp_axis=mp_axis)

@@ -391,22 +391,12 @@ def paged_pour_block(cache, kv, block_id):
     return paged_pour_blocks(cache, kv[None], [int(block_id)])
 
 
-def _as_pages(kv):
-    """An already-gathered [B, Nkv, S, H] view as ONE page of S positions,
-    [B, 1, Nkv, S, H]: the form `gathered_attention` contracts (a reshape
-    that moves nothing)."""
-    return kv[:, None] if kv.ndim == 4 else kv
-
-
 def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
     """The sdpa core of the decode tier over ALREADY-GATHERED K/V:
     q [B, T, N, H]; keys/vals the pages as taken from the pool,
-    [B, M, Nkv, bs, H] (position m * bs + s), or a [B, Nkv, S, H] view (one
-    page of S positions); seq_lens [B] INCLUDING all T chunk tokens.  The
-    ONE masked-softmax definition — paged_chunk_attention feeds it the
-    pages of the width it chose and the fused decode-chain kernel
-    (ops/decode_chain.py) feeds it VMEM-gathered views, so the two paths
-    cannot drift numerically.
+    [B, M, Nkv, bs, H] (position m * bs + s); seq_lens [B] INCLUDING all T
+    chunk tokens.  The ONE masked-softmax definition:
+    paged_chunk_attention feeds it the pages of the width it chose.
 
     K and V are contracted in the type they arrive in, accumulated in
     float32 (for bfloat16 values, the products a float32 contraction of
@@ -415,7 +405,6 @@ def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
     inside the reduce.  The N query heads are contracted in their Nkv
     groups of N // Nkv (MHA is a group of one): K/V are never repeated."""
     b, t, n, h = q.shape
-    keys, vals = _as_pages(keys), _as_pages(vals)
     _b, m, nkv, bs, _h = keys.shape
     if scale is None:
         scale = 1.0 / math.sqrt(h)
@@ -435,8 +424,7 @@ def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
     qpos = (seq_lens[:, None] - t + jnp.arange(t, dtype=jnp.int32)[None, :])
     allowed = kpos[None, None] <= qpos[:, :, None, None]       # [B, T, M, bs]
     logits = jnp.where(allowed[:, None, None], logits, jnp.float32(-1e30))
-    # one flat axis of positions: the fused chain's one-page view and the
-    # pages of any width then reduce alike (its parity gate is bit-exact)
+    # one flat axis of positions: pages of any width then reduce alike
     flat = logits.reshape(logits.shape[:4] + (m * bs,))
     probs = jax.nn.softmax(flat, axis=-1).reshape(logits.shape)
     out = jnp.einsum("bkgtms,bmksh->btkgh", probs, vals,
@@ -464,19 +452,15 @@ def _ladder_index(ladder, block_size, seq_lens):
     return jnp.sum(longest > reach).astype(jnp.int32)
 
 
-def attn_positions(block_tables, block_size, seq_lens, active=None, *,
-                   whole_table=False):
+def attn_positions(block_tables, block_size, seq_lens, active=None):
     """What one `paged_chunk_attention` call over these rows reads and what
     of it is live, as two int32 scalars: (`active` rows x positions of the
     ladder width it takes, sum of the active rows' lengths).  Their
     quotient is the step's read amplification (1 would take a ragged
-    kernel that reads each row's own pages).  `whole_table`: a reader that
-    takes the table's full width whatever the lengths (the fused decode
-    chain)."""
+    kernel that reads each row's own pages)."""
     ladder = page_ladder(block_tables.shape[1])
     pages = jnp.asarray(ladder, jnp.int32)[
-        len(ladder) - 1 if whole_table
-        else _ladder_index(ladder, block_size, seq_lens)]
+        _ladder_index(ladder, block_size, seq_lens)]
     if active is None:
         active = jnp.ones(seq_lens.shape, bool)
     read = jnp.sum(active) * pages * block_size

@@ -539,23 +539,17 @@ def schedule_search_stats(reset: bool = False) -> dict:
     """Pallas schedule-search counters (FLAGS_schedule_search; see
     static/schedule_search.py and docs/SCHEDULE_SEARCH.md): subgraphs
     discovered and searched, candidate tilings enumerated, candidates
-    pruned by the roofline model vs the VMEM budget vs the numerics
-    parity gate, candidates measured on device, subgraphs accepted
+    pruned by the roofline model vs the VMEM budget, candidates
+    measured on device, subgraphs accepted
     (schedule beat XLA by the win margin) vs disabled, and cache service
     (accepted configs / disabled skips reloaded from the per-device
     autotune cache).  Steady state shows cache hits with measured flat —
     climbing measured means shape churn is defeating the schedule cache.
     The schedule_search module owns those counters — one schema, no
-    drift; the phase-2 decode-chain counters
-    (decode_chains_found/accepted/disabled/mesh_skipped) are owned by the
-    SERVING module (discovery happens at the engine) and merged in
-    here."""
-    from paddle_tpu import serving as _serving
+    drift."""
     from paddle_tpu.static import schedule_search as _ss
 
-    out = _ss.schedule_search_stats(reset=reset)
-    out.update(_serving.schedule_decode_stats(reset=reset))
-    return out
+    return _ss.schedule_search_stats(reset=reset)
 
 
 def snapshot_stats(reset: bool = False) -> dict:

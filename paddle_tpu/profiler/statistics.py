@@ -378,55 +378,20 @@ def schedule_line(stats: dict) -> str:
     Profiler.summary(); empty when the search tier never ran this process.
     `disabled` nonzero is healthy honesty (the measured-win gate found XLA
     faster and said so); `measured` climbing in steady state means shape
-    churn is defeating the per-device schedule cache.  A second line
-    reports the serving decode-chain verdicts (phase 2) when any engine
-    consulted the searcher — mesh_fused counts TP-sharded engines whose
-    macro-step adopted the shard_map chain, mesh_skipped the sharded
-    engines with replicated pools that kept the unfused scan body by
-    design; a third line mirrors the chunked-prefill chain verdicts
-    (PrefillChainSpec) when any engine searched one."""
-    decode = any(stats.get(k) for k in (
-        "decode_chains_found", "decode_chains_accepted",
-        "decode_chains_disabled", "decode_chains_build_errors",
-        "decode_chains_mesh_skipped", "decode_chains_mesh_fused"))
-    prefill = any(stats.get(k) for k in (
-        "prefill_chains_found", "prefill_chains_accepted",
-        "prefill_chains_disabled", "prefill_chains_build_errors"))
+    churn is defeating the per-device schedule cache."""
     if not (stats.get("subgraphs_found") or stats.get("cache_hits")
-            or stats.get("disabled_hits") or decode or prefill):
+            or stats.get("disabled_hits")):
         return ""
-    line = (
+    return (
         "Schedule search: subgraphs=%d candidates=%d pruned_roofline=%d "
-        "pruned_vmem=%d pruned_parity=%d measured=%d accepted=%d "
+        "pruned_vmem=%d measured=%d accepted=%d "
         "disabled=%d build_errors=%d; cache hits=%d disabled_hits=%d"
         % (stats["subgraphs_found"], stats["candidates"],
            stats["pruned_roofline"], stats["pruned_vmem"],
-           stats.get("pruned_parity", 0),
            stats["measured"], stats["accepted"], stats["disabled"],
            stats.get("build_errors", 0),
            stats["cache_hits"], stats["disabled_hits"])
     )
-    if decode:
-        line += (
-            "\nDecode chains: found=%d accepted=%d disabled=%d "
-            "build_errors=%d mesh_fused=%d mesh_skipped=%d"
-            % (stats.get("decode_chains_found", 0),
-               stats.get("decode_chains_accepted", 0),
-               stats.get("decode_chains_disabled", 0),
-               stats.get("decode_chains_build_errors", 0),
-               stats.get("decode_chains_mesh_fused", 0),
-               stats.get("decode_chains_mesh_skipped", 0))
-        )
-    if prefill:
-        line += (
-            "\nPrefill chains: found=%d accepted=%d disabled=%d "
-            "build_errors=%d"
-            % (stats.get("prefill_chains_found", 0),
-               stats.get("prefill_chains_accepted", 0),
-               stats.get("prefill_chains_disabled", 0),
-               stats.get("prefill_chains_build_errors", 0))
-        )
-    return line
 
 
 def checkpoint_line(stats: dict) -> str:

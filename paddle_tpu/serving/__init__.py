@@ -36,7 +36,6 @@ from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["GenerationEngine", "RadixPrefixCache", "decode_stats",
            "reset_decode_stats", "lora_stats", "reset_lora_stats",
-           "schedule_decode_stats", "reset_schedule_decode_stats",
            "EngineSnapshot", "restore_engine", "snapshot_stats",
            "reset_snapshot_stats"]
 
@@ -240,59 +239,10 @@ def reset_lora_stats():
             _LORA_STATS[k] = 0
 
 
-# Decode-chain schedule-search counters (schedule search, phase 2 —
-# docs/SCHEDULE_SEARCH.md; profiler.schedule_search_stats merges these into
-# the search-tier schema).  The SERVING module owns them because the engine
-# is where decode-chain discovery/adoption happens: found = eligible
-# engines that consulted the searcher for their macro-step geometry;
-# accepted = engines whose compiled macro-step adopted a fused config;
-# disabled = engines that kept the unfused ops (measured loss, cache
-# verdict, a failed cache-config parity re-gate, or a mesh-lint
-# violation on the sharded kernel); build_errors = engines whose chain
-# kernel RAISED at build/compile/run (a Mosaic refusal on the chip — kept
-# apart from disabled, which is a verdict); mesh_fused = the accepted subset
-# whose engine is TP-sharded (the shard_map chain over the mesh);
-# mesh_skipped = TP-sharded engines whose pools ride REPLICATED (head
-# counts the mp axis doesn't divide) — no head-local layout to fuse
-# over, a counted skip, never a crash.  prefill_chains_* mirror the same
-# verdict schema for the chunked-prefill attention chain
-# (PrefillChainSpec; single-device engines with prefill_chunk set).
-_SCHED_DECODE_STATS = {
-    "decode_chains_found": 0,
-    "decode_chains_accepted": 0,
-    "decode_chains_disabled": 0,
-    "decode_chains_build_errors": 0,
-    "decode_chains_mesh_skipped": 0,
-    "decode_chains_mesh_fused": 0,
-    "prefill_chains_found": 0,
-    "prefill_chains_accepted": 0,
-    "prefill_chains_disabled": 0,
-    "prefill_chains_build_errors": 0,
-}
-
-
-def schedule_decode_stats(reset: bool = False) -> dict:
-    """Decode-chain counters for the schedule-search telemetry (see
-    _SCHED_DECODE_STATS above; docs/SCHEDULE_SEARCH.md phase 2)."""
-    out = dict(_SCHED_DECODE_STATS)
-    if reset:
-        reset_schedule_decode_stats()
-    return out
-
-
-def reset_schedule_decode_stats():
-    for k in _SCHED_DECODE_STATS:
-        _SCHED_DECODE_STATS[k] = 0
-
-
 # Live engines hold compiled decode executables; any flag change may alter
 # what those programs traced (FLAGS_decode_chunk, matmul precision, ...), so
 # set_flags drops them — the same contract as the eager dispatch cache.
 _ENGINES: "weakref.WeakSet[GenerationEngine]" = weakref.WeakSet()
-
-# sentinel: the engine's decode-chain verdict is resolved lazily at the
-# first _build_step and re-resolved after any flag change
-_CHAIN_UNSET = object()
 
 
 @_flags.on_change
@@ -301,10 +251,6 @@ def _invalidate_decode_steps(_changed):
         eng._step_fns.clear()
         eng._prefill_fns.clear()
         eng._draft_fn = eng._verify_fn = None
-        # flags govern whether (and which) fused decode-chain schedule the
-        # rebuilt steps may consume — re-resolve with the steps
-        eng._decode_chain_cfg = _CHAIN_UNSET
-        eng._prefill_chain_cfg = _CHAIN_UNSET
 
 
 def _cache_blocks(caches, start_tok, s0, bs):
@@ -729,8 +675,6 @@ class GenerationEngine:
         self._step_fns: dict = {}  # macro-step executables, keyed by D
         # admission prefill programs, keyed by (padded suffix, prefix length)
         self._prefill_fns: dict = {}
-        self._decode_chain_cfg = _CHAIN_UNSET  # lazy (_resolve_decode_chain)
-        self._prefill_chain_cfg = _CHAIN_UNSET  # lazy (_resolve_prefill_chain)
         # masked lanes' block tables (every page is the slot's scratch
         # page): constant, so committed to the device ONCE here — not
         # re-transferred on every dispatch
@@ -1387,8 +1331,7 @@ class GenerationEngine:
             # the atomic base-model prefill runs as ONE compiled program
             # per (suffix bucket, prefix length); what stays eager is
             # selected on what this attempt observes: a suffix longer than
-            # prefill_chunk (chunked prefill, and with it an accepted
-            # prefill-chain schedule), and an adapter request (its
+            # prefill_chunk (chunked prefill), and an adapter request (its
             # forward-post hooks close over the pack's arrays, which a
             # trace would freeze).  Interleaved prefill never comes here.
             compiled = not ad_slot and (
@@ -1532,16 +1475,12 @@ class GenerationEngine:
                            m_len)[:2]
         # chunked prefill: fixed-size chunks through the cached forward
         # (bottom-right-aligned cross-length attention) cap the peak
-        # activation footprint for long prompts.  An accepted
-        # prefill-chain config routes each DIVISIBLE chunk's attention
-        # core through the fused K-tiled kernel (schedule search;
-        # PrefillChainSpec)
-        with self._contract.prefill_scope(self._resolve_prefill_chain()):
-            off = m_len
-            while off < s0:
-                chunk = prompt[:, off:off + self.prefill_chunk]
-                h, caches, _ = forward(paddle.to_tensor(chunk), caches, off)
-                off += chunk.shape[1]
+        # activation footprint for long prompts
+        off = m_len
+        while off < s0:
+            chunk = prompt[:, off:off + self.prefill_chunk]
+            h, caches, _ = forward(paddle.to_tensor(chunk), caches, off)
+            off += chunk.shape[1]
         return h, caches
 
     # ------------------------------------------- the prefill program
@@ -1733,8 +1672,7 @@ class GenerationEngine:
         a mid-prefill admission can already hit them on the chunk
         boundary.  The span [off, off+bs) is a function of the prompt
         alone — never of scheduling — and each chunk keeps its own
-        full-chunk attention geometry (the PR-16 PrefillChainSpec
-        shape-identity rule), which is why the emitted stream is
+        full-chunk attention geometry, which is why the emitted stream is
         bit-identical to an atomic engine prefilling in
         prefill_chunk=block_size chunks.  Returns True when the prompt
         completed (the slot activated)."""
@@ -1753,8 +1691,7 @@ class GenerationEngine:
                                             self._pack, slot.adapter_slot)
             else:
                 ctx = contextlib.nullcontext()
-            pf_cfg = self._resolve_prefill_chain()
-            with ctx, contract.prefill_scope(pf_cfg), paddle.no_grad():
+            with ctx, paddle.no_grad():
                 chunk = prompt[:, st.off:st.off + bs]
                 st.h, st.caches, _ = contract.forward_cached(
                     paddle.to_tensor(chunk), st.caches, st.off)
@@ -2271,130 +2208,6 @@ class GenerationEngine:
             return self._decode_chunk
         return max(1, int(_flags.flag("FLAGS_decode_chunk")))
 
-    def _resolve_decode_chain(self):
-        """Consult the schedule searcher for this engine's decode hot
-        chain (paged gather → dequant → sdpa core → quant-write; schedule
-        search phase 2, docs/SCHEDULE_SEARCH.md) and cache the verdict:
-        an ACCEPTED config — served from the per-device-kind AutotuneCache
-        with zero re-measurement, or freshly searched (enumerate → prune
-        → parity → measure → measured-win gate) on a never-seen geometry
-        — makes the compiled macro-step run the chain as ONE fused Pallas
-        dispatch per layer per token; anything else keeps the unfused XLA
-        ops.  A flag change re-resolves alongside the invalidated step
-        executables.
-
-        TP-sharded engines search the MESH spec (schedule search over the
-        mesh, ROADMAP item 3): the spec carries the engine's mesh, so its
-        verdict caches under the (device kind, mesh shape) key, parity
-        gates against the sharded XLA twin, and the adopted kernel builds
-        inside shard_map over the committed pool layout.  Before adoption
-        the kernel's collectives are statically linted
-        (mesh_lint.lint_decode_chain) — a violation is a counted disable,
-        never a dispatch.  Engines whose pools ride replicated (head
-        counts the mp axis doesn't divide — the constructor's fallback)
-        keep the counted mesh skip: there is no head-local layout to fuse
-        over."""
-        if self._decode_chain_cfg is not _CHAIN_UNSET:
-            return self._decode_chain_cfg
-        cfg = None
-        # the fused chain is a K/V kernel: other specifications keep the
-        # model's own decode attention
-        if (self._spec.kv_pair and _flags.flag("FLAGS_schedule_search")
-                and _flags.flag("FLAGS_schedule_search_decode")):
-            mesh = self.mesh
-            n_heads = self._contract.num_query_heads
-            kv = self._spec.pools[0]
-            mp = mesh.get_dim_size(self._mp_axis) if mesh is not None else 1
-            if mesh is not None and (n_heads % mp or kv.heads % mp):
-                _SCHED_DECODE_STATS["decode_chains_mesh_skipped"] += 1
-            else:
-                from paddle_tpu.ops import decode_chain as _dc
-
-                _SCHED_DECODE_STATS["decode_chains_found"] += 1
-                spec = _dc.DecodeChainSpec(
-                    batch=self.max_batch,
-                    num_heads=n_heads,
-                    num_kv_heads=kv.heads,
-                    head_dim=kv.width,
-                    block_size=self.block_size,
-                    max_blocks=self._max_blocks_per_seq,
-                    num_blocks=self._num_blocks + self.max_batch,
-                    kv=self._kv_dtype,
-                    dtype=jnp.dtype(kv.dtype),
-                    mesh=mesh,
-                    mp_axis=self._mp_axis,
-                )
-                decision = _dc.ensure_decision(spec)
-                adopted = decision.accepted
-                if adopted and mesh is not None:
-                    from paddle_tpu.static.mesh_lint import lint_decode_chain
-
-                    if lint_decode_chain(spec, decision.config):
-                        adopted = False  # named violation → counted disable
-                if adopted:
-                    cfg = dict(decision.config)
-                    _SCHED_DECODE_STATS["decode_chains_accepted"] += 1
-                    if mesh is not None:
-                        # the live mesh handle rides NON-PERSISTED config
-                        # entries (fused_decode_step pops them): the cache
-                        # stores the pure schedule, the step builds the
-                        # shard_map chain
-                        cfg["_mesh"] = mesh
-                        cfg["_mp_axis"] = self._mp_axis
-                        _SCHED_DECODE_STATS["decode_chains_mesh_fused"] += 1
-                elif decision.status == "build_error":
-                    _SCHED_DECODE_STATS["decode_chains_build_errors"] += 1
-                    warnings.warn("decode chain kernel failed to build; "
-                                  f"serving unfused: {decision.error}")
-                else:
-                    _SCHED_DECODE_STATS["decode_chains_disabled"] += 1
-        self._decode_chain_cfg = cfg
-        return cfg
-
-    def _resolve_prefill_chain(self):
-        """The chunked-prefill twin of _resolve_decode_chain
-        (PrefillChainSpec): engines with a fixed prefill_chunk search the
-        canonical mid-prompt geometry — an S=prefill_chunk query chunk
-        against a T=2·prefill_chunk cache span — and an accepted config
-        makes every DIVISIBLE chunk's attention core run as one K-tiled
-        Pallas dispatch under the contract's prefill_scope; chunks
-        the config doesn't tile keep the XLA path.  Single-device
-        engines only: mesh engines keep GSPMD prefill (the pour is
-        bandwidth-bound on the pool commit, not the attention core).
-        INTERLEAVED engines (prefill_chunk_blocks > 0) search their
-        actual chunk geometry — one pool block — since every granted
-        chunk is exactly block_size tokens."""
-        if self._prefill_chain_cfg is not _CHAIN_UNSET:
-            return self._prefill_chain_cfg
-        cfg = None
-        eff = (self.block_size if self._prefill_chunk_blocks() > 0
-               else self.prefill_chunk)
-        if (eff is not None and self.mesh is None and eff >= 2
-                and _flags.flag("FLAGS_schedule_search")
-                and _flags.flag("FLAGS_schedule_search_decode")):
-            from paddle_tpu.ops import decode_chain as _dc
-
-            _SCHED_DECODE_STATS["prefill_chains_found"] += 1
-            spec = _dc.PrefillChainSpec(
-                seq=eff,
-                kv_len=2 * eff,
-                num_heads=self._contract.num_query_heads,
-                head_dim=self._spec.pools[0].width,
-                dtype=jnp.dtype(self._spec.pools[0].dtype),
-            )
-            decision = _dc.ensure_decision(spec)
-            if decision.accepted:
-                cfg = dict(decision.config)
-                _SCHED_DECODE_STATS["prefill_chains_accepted"] += 1
-            elif decision.status == "build_error":
-                _SCHED_DECODE_STATS["prefill_chains_build_errors"] += 1
-                warnings.warn("prefill chain kernel failed to build; "
-                              f"serving unfused: {decision.error}")
-            else:
-                _SCHED_DECODE_STATS["prefill_chains_disabled"] += 1
-        self._prefill_chain_cfg = cfg
-        return cfg
-
     def _build_step(self, chunk: int):
         """One macro-step executable: `chunk` decode tokens per dispatch.
 
@@ -2419,16 +2232,11 @@ class GenerationEngine:
         state = self._state
         eos = self.eos_token_id
         has_pack = self._pack is not None
-        # accepted decode-chain schedule (or None): resolved OUTSIDE the
-        # trace, so the compiled program bakes one fixed fused/unfused
-        # shape — adoption never changes mid-stream (schedule search
-        # phase 2; docs/SCHEDULE_SEARCH.md)
-        chain_cfg = self._resolve_decode_chain()
 
         def decode_macro_step(state_vals, pools, tokens, tables,
                               scratch_tables, lens, max_lens, done0, temps,
                               keys, steps, *lora_args):
-            kv_only = {} if chain_cfg is None else {"chain_cfg": chain_cfg}
+            kv_only = {}
             if has_pack:
                 ad_slots, pack_ab, pack_scaling = lora_args
                 kv_only.update(adapters=pack_ab, slots=ad_slots,

@@ -328,6 +328,10 @@ def _decode_serve(spec, eng, tracked, ring_in, out, killer):
                 ctx.hit_toks_reported = hits
                 tracked.discard(rid)
 
+    # a restored engine may hold claimed results that finished before its
+    # snapshot (the old router died unread): nothing will step for them,
+    # so deliver them now, behind the resume report that claimed them
+    emit_progress()
     while True:
         busy = eng.has_work()
         try:

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import collections
 import random
+import select
 import socket
 import struct
 import threading
@@ -313,6 +314,21 @@ class TcpRing:
 
     _SEND_CHUNK = 1 << 16
 
+    @staticmethod
+    def _peer_hung_up(conn):
+        """True when the kernel already holds the peer's FIN or RST for
+        `conn`.  A send into such a connection succeeds and the bytes are
+        lost, so the tx loop asks before every frame and does not wait
+        for the rx thread to get round to the same news (under load it
+        came late, and a frame pushed across a drop never arrived)."""
+        try:
+            readable, _, _ = select.select([conn], [], [], 0)
+            return bool(readable) and conn.recv(1, socket.MSG_PEEK) == b""
+        except socket.timeout:
+            return False  # the rx thread took the bytes first: alive
+        except (OSError, ValueError):
+            return True
+
     def _send_frame(self, conn, gen, frame):
         """Write one frame in bounded chunks.  The socket's 0.2s timeout
         bounds the TOTAL duration of ``sendall`` (not per-syscall), so a
@@ -326,6 +342,9 @@ class TcpRing:
         is BACKPRESSURE — retry on the same connection — while only a
         real socket error is a drop.  Returns True when the frame went
         out whole on this connection."""
+        if self._peer_hung_up(conn):
+            self._drop(gen)
+            return False
         view = memoryview(frame)
         off = 0
         while off < len(view):

@@ -67,7 +67,6 @@ __all__ = [
     "lint_program",
     "lint_train_step",
     "lint_engine",
-    "lint_decode_chain",
     "mesh_lint_stats",
     "reset_mesh_lint_stats",
 ]
@@ -121,13 +120,17 @@ class MeshLintError(RuntimeError):
 
 
 # Collective primitives whose participation must be congruent across the
-# mesh.  shard_map rewrites psum->psum2 and inserts pbroadcast as a
-# replication-rule marker — pbroadcast/axis_index are NOT collectives (no
-# cross-device rendezvous), so they are deliberately absent: flagging them
-# under a cond would false-positive every data-dependent branch.
+# mesh.  Under shard_map's varying-axes typing (check_vma, jax's default)
+# psum binds as psum_invariant and all_gather as all_gather_invariant /
+# all_gather_reduced; pvary is the typing's marker and axis_index reads a
+# coordinate — NOT collectives (no cross-device rendezvous), so they are
+# deliberately absent: flagging them under a cond would false-positive
+# every data-dependent branch.
 _COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pmean", "ppermute", "pshuffle",
-    "all_gather", "all_gather_invariant", "all_to_all", "reduce_scatter",
+    "psum", "psum_invariant", "unreduced_psum", "pmax", "pmin", "pmean",
+    "ppermute", "pshuffle", "pbroadcast", "all_gather",
+    "all_gather_invariant", "all_gather_reduced", "all_to_all",
+    "ragged_all_to_all", "reduce_scatter", "unreduced_reduce_scatter",
     "psum_scatter", "pgather",
 })
 
@@ -252,6 +255,23 @@ class MeshLinter:
                     "bad-groups",
                     f"collective axis_index_groups rejected at abstract "
                     f"trace: {e}", site)]
+            _COUNTERS["trace_skips"] += 1
+            return []
+        except TypeError as e:
+            if "varying manual axes" in str(e):
+                # shard_map's varying-axes typing refuses a cond / while
+                # whose paths disagree on which mesh axes the result
+                # varies over: one path reduces over an axis (a
+                # collective) that the other never runs.  No jaxpr exists
+                # to walk, so jax's refusal IS the finding, not a skip
+                _COUNTERS["collectives_checked"] += 1
+                return [MeshViolation(
+                    "conditional-collective",
+                    "the paths of a data-dependent conditional disagree "
+                    "on the mesh axes their results vary over — a "
+                    "collective is reachable only under the predicate "
+                    "(the distributed deadlock/SIGSEGV class); jax "
+                    f"refused the trace: {e}", site)]
             _COUNTERS["trace_skips"] += 1
             return []
         except Exception:
@@ -775,63 +795,3 @@ def lint_engine(engine, mesh=None, raise_on_error=False, **kwargs):
     violations, est = linter.lint_engine(engine)
     _finish(violations, "Mesh lint failed (GenerationEngine)", raise_on_error)
     return violations, est
-
-
-def _chain_avals(spec):
-    """Abstract engine-shaped args of a DecodeChainSpec's canonical
-    (kc, vc, q, kn, vn, tables, lens) signature — ShapeDtypeStructs only,
-    so the lint trace never allocates a pool."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops import paged_attention as pa
-
-    sds = jax.ShapeDtypeStruct
-    pool_dt = jnp.int8 if spec.kv == "int8" else jnp.dtype(spec.dtype)
-    pool_shape = (spec.num_blocks, spec.num_kv_heads, spec.block_size,
-                  spec.head_dim)
-    if spec.kv == "int8":
-        def quant():
-            return pa.QuantPool(
-                sds(pool_shape, pool_dt),
-                sds((spec.num_blocks, spec.num_kv_heads), jnp.float32))
-
-        kc, vc = quant(), quant()
-    else:
-        kc, vc = sds(pool_shape, pool_dt), sds(pool_shape, pool_dt)
-    dt = jnp.dtype(spec.dtype)
-    return (kc, vc,
-            sds((spec.batch, spec.num_heads, spec.head_dim), dt),
-            sds((spec.batch, spec.num_kv_heads, spec.head_dim), dt),
-            sds((spec.batch, spec.num_kv_heads, spec.head_dim), dt),
-            sds((spec.batch, spec.max_blocks), jnp.int32),
-            sds((spec.batch,), jnp.int32))
-
-
-def lint_decode_chain(spec, config, mesh=None, raise_on_error=False,
-                      **kwargs):
-    """Statically check a fused decode-chain kernel's collectives BEFORE
-    an engine adopts the config (docs/MESH_LINT.md kernel-collective
-    check): abstractly trace ``spec.build(config)`` over engine-shaped
-    avals and walk the jaxpr — shard_map mesh congruence, collective
-    axis/size checks, conditional collectives — without ever executing
-    the kernel.  A head-local sharded chain walks clean (zero in-kernel
-    collectives is the layout's contract); anything else is a named
-    violation the adopt path turns into a counted disable.  Same
-    authority rule as lint_engine: the spec's OWN mesh judges it — a
-    single-device spec lints mesh-less regardless of session state."""
-    if mesh is None:
-        mesh = getattr(spec, "mesh", None) or {}
-    linter = MeshLinter(mesh=mesh, **kwargs)
-    try:
-        fn = spec.build(config)
-    except Exception as e:
-        violations = [MeshViolation(
-            "unknown-axis",
-            f"decode-chain build rejected the config before trace: {e}",
-            spec.label())]
-        return _finish(violations, "Mesh lint failed (decode chain)",
-                       raise_on_error)
-    violations = linter.lint_callable(fn, *_chain_avals(spec),
-                                      site=spec.label())
-    return _finish(violations, "Mesh lint failed (decode chain)",
-                   raise_on_error)

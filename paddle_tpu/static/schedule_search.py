@@ -60,7 +60,6 @@ __all__ = [
     "build_kernel",
     "build_reference",
     "Decision",
-    "build_error_decision",
     "ScheduleSearcher",
     "measure_override",
     "schedule_search_stats",
@@ -79,8 +78,6 @@ _COUNTERS = {
     "pruned_roofline": 0,     # dropped by the analytic roofline ranking
     "pruned_vmem": 0,         # dropped by the VMEM working-set budget
     "measured": 0,            # candidates actually timed on device
-    "pruned_parity": 0,       # candidates whose numerics failed the spec's
-                              # parity gate vs the XLA twin (never measured)
     "accepted": 0,            # subgraphs whose best schedule beat XLA
     "disabled": 0,            # subgraphs recorded as losing
     "build_errors": 0,        # candidates (or cached winners) whose kernel
@@ -183,10 +180,7 @@ class SubgraphSpec:
 
         return f"{self.kernel_name()}|{_key_str(self.key())}"
 
-    # ---- searcher protocol (shared with ops.decode_chain.DecodeChainSpec:
-    # the ScheduleSearcher drives any spec through these six hooks) -------
-    check_parity = False  # Program subgraphs rely on differential_check
-
+    # ---- what the ScheduleSearcher asks of a spec ----------------------
     def enumerate_configs(self):
         return enumerate_candidates(self)
 
@@ -209,9 +203,6 @@ class SubgraphSpec:
         return tuple(
             jnp.asarray(rng.standard_normal(e.shape), e.dtype)
             for e in self.ext)
-
-    def parity_ok(self, fn, args, reference_out):  # noqa: ARG002
-        return True
 
     def config_label(self, config):
         lbl = (f"#{config['block_rows']}x{config['block_cols']}"
@@ -1060,10 +1051,6 @@ class ScheduleSearcher:
             label, fn, *args, iters=self.iters, warmup=self.warmup) * 1e3
 
     @staticmethod
-    def _synthetic_args(spec):
-        return spec.synthetic_args()
-
-    @staticmethod
     def _cached(spec):
         from paddle_tpu.ops import autotune as at
 
@@ -1081,14 +1068,9 @@ class ScheduleSearcher:
 
     # -------------------------------------------------------------- search
     def search(self, spec) -> Decision:
-        """Drive any spec implementing the searcher protocol — a Program
-        SubgraphSpec or an ops.decode_chain.DecodeChainSpec — through
-        enumerate → roofline → VMEM → (parity) → measure → gate →
-        persist.  Specs with ``check_parity`` have every candidate's
-        numerics compared against the XLA twin BEFORE it may be measured:
-        a candidate that fails parity can never be accepted, however fast
-        (Program specs instead rely on the differential replay under
-        FLAGS_verify_programs)."""
+        """Drive a SubgraphSpec through enumerate → roofline → VMEM →
+        measure → gate → persist.  A substituted kernel's numerics are
+        checked by the differential replay under FLAGS_verify_programs."""
         cached = self._cached(spec)
         if cached is not None:
             if cached.get("disabled"):
@@ -1120,7 +1102,6 @@ class ScheduleSearcher:
         fit.sort(key=lambda rc: rc[0])
 
         ref_fn = jax.jit(spec.reference())
-        ref_out = None
         best_cfg, best_ms = None, float("inf")
         failed = None  # last candidate that raised, as a Decision
         budget_left = max(1, self.budget)
@@ -1129,14 +1110,6 @@ class ScheduleSearcher:
                 break
             try:
                 fn = jax.jit(spec.build(cfg))
-                if spec.check_parity:
-                    if ref_out is None:
-                        ref_out = ref_fn(*args)
-                    if not spec.parity_ok(fn, args, ref_out):
-                        # wrong numerics beat nothing: rejected before any
-                        # timing, without burning a measure-budget slot
-                        _COUNTERS["pruned_parity"] += 1
-                        continue
                 ms = self._measure_ms(
                     spec.label() + spec.config_label(cfg), fn, args, cfg)
             except Exception as e:  # noqa: BLE001 — search must go on

@@ -29,3 +29,20 @@ from paddle_tpu._core import compile_cache  # noqa: E402
 
 compile_cache.enable()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def reference_source():
+    """Reads a file of the reference checkout, or skips the test:
+    /root/reference is mounted on some machines only."""
+    def read(path):
+        fp = f"/root/reference/python/paddle/{path}"
+        if not os.path.exists(fp):
+            pytest.skip(f"{fp} is not mounted on this machine")
+        with open(fp) as f:
+            return f.read()
+
+    return read

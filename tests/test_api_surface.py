@@ -70,8 +70,8 @@ def test_rewrite_pattern_op_types_resolve_in_registry():
         f"(renamed op?): {unresolved}")
 
 
-def test_reference_top_level_surface_complete():
-    src = open("/root/reference/python/paddle/__init__.py").read()
+def test_reference_top_level_surface_complete(reference_source):
+    src = reference_source("__init__.py")
     m = re.search(r"__all__ = \[(.*?)\]", src, re.S)
     ref_all = set(re.findall(r"'([^']+)'", m.group(1)))
     missing = sorted(n for n in ref_all if not hasattr(paddle, n))
@@ -220,8 +220,8 @@ def test_tolist_and_t_():
     assert x.shape == [3, 2]
 
 
-def test_tensor_method_surface_complete():
-    src = open("/root/reference/python/paddle/tensor/__init__.py").read()
+def test_tensor_method_surface_complete(reference_source):
+    src = reference_source("tensor/__init__.py")
     m = re.search(r"tensor_method_func = \[(.*?)\]", src, re.S)
     methods = set(re.findall(r"'([^']+)'", m.group(1)))
     t = paddle.ones([2, 2])
@@ -229,8 +229,8 @@ def test_tensor_method_surface_complete():
     assert not missing, f"Tensor missing {len(missing)} methods: {missing[:20]}"
 
 
-def test_distributed_surface_complete():
-    src = open("/root/reference/python/paddle/distributed/__init__.py").read()
+def test_distributed_surface_complete(reference_source):
+    src = reference_source("distributed/__init__.py")
     m = re.search(r"__all__ = \[(.*?)\]", src, re.S)
     ref = set(re.findall(r'"([^"]+)"', m.group(1))) | set(re.findall(r"'([^']+)'", m.group(1)))
     import paddle_tpu.distributed as dist

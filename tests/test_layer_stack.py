@@ -1,8 +1,9 @@
 """nn.LayerStack — scan-over-layers numerics equivalence + layout round-trip.
 
 The stack must be OBSERVATIONALLY identical to the unrolled loop: same
-outputs (bit-exact on CPU f32 — the scan body runs the same op sequence),
-same grads (to accumulation-order tolerance), and state_dict layouts must
+outputs (to a few ulp on CPU f32 — the scan body runs the same op sequence,
+but as another XLA program), same grads (to accumulation-order tolerance),
+and state_dict layouts must
 interconvert so checkpoints survive flipping fuse_layer_stack.
 """
 
@@ -48,8 +49,15 @@ def test_scan_matches_unrolled_forward_and_grads():
     h = x2
     for b in loop:
         h = b(h, s)
-    # same op sequence, same backend: bit-exact where the dtype allows
-    assert np.array_equal(np.asarray(out._value), np.asarray(h._value))
+    # same op sequence, same backend, but two XLA programs: the scan body
+    # is compiled as one computation and may fuse (and so round) where the
+    # unrolled loop's op-by-op executables do not.  4 ulp of float32 at the
+    # output's largest magnitude: every element is a sum of O(1) terms and
+    # carries their rounding, however small it is itself
+    want = np.asarray(h._value)
+    np.testing.assert_allclose(
+        np.asarray(out._value), want, rtol=0,
+        atol=4 * np.spacing(np.abs(want).max()))
 
     out.sum().backward()
     h.sum().backward()
@@ -285,7 +293,12 @@ def test_heterogeneous_blocks_rejected():
 def test_dropout_stack_rng_and_eval_mode():
     """Stochastic stacks draw fresh per-call randomness in train mode and
     are deterministic in eval — eval() must reach the hidden template (the
-    mode sync), and MHA's functional dropout must trip needs_rng."""
+    mode sync), and MHA's functional dropout must trip needs_rng.
+
+    The eval forwards are compared with the eager dispatch cache off, so
+    that both run the same executables: with it on, an op's fourth sighting
+    swaps its op-by-op run for one jitted program, which rounds differently
+    by an ulp (not dropout: the same happens at dropout=0.0)."""
     from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 
     paddle.seed(0)
@@ -297,7 +310,12 @@ def test_dropout_stack_rng_and_eval_mode():
     assert not np.array_equal(np.asarray(a._value), np.asarray(b._value)), (
         "train-mode dropout produced identical outputs across calls")
     m.eval()
-    c, d = m(x), m(x)
+    paddle.set_flags({"FLAGS_eager_op_jit": False})
+    try:
+        c, d = m(x), m(x)
+    finally:
+        paddle.set_flags({"FLAGS_eager_op_jit": True})
+    assert not m.gpt.h.__dict__["_template"].training
     assert np.array_equal(np.asarray(c._value), np.asarray(d._value)), (
         "eval() did not reach the scan body (dropout still active)")
 

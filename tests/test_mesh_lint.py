@@ -111,6 +111,21 @@ def test_conditional_collective_flagged_and_twins():
     bad = linter.lint_callable(conditional, _AVAL)
     assert "conditional-collective" in _codes(bad)
 
+    # the same branch typed so that shard_map's varying-axes check lets it
+    # trace (the psum binds as psum_invariant), and with the check off
+    # (a plain psum): the walker itself must see both
+    def typed_body(v):
+        return lax.cond(
+            v.sum() > 0,
+            lambda t: lax.pcast(lax.psum(t, "dp"), "dp", to="varying"),
+            lambda t: t, v)
+
+    for body, kw in ((typed_body, {}), (cond_body, {"check_vma": False})):
+        walked = shard_map(body, mesh=_dp8(), in_specs=P("dp"),
+                           out_specs=P("dp"), **kw)
+        assert "conditional-collective" in _codes(
+            linter.lint_callable(walked, _AVAL))
+
     # twin 1: the unconditional collective is clean
     flat = shard_map(lambda v: lax.psum(v, "dp"), mesh=_dp8(),
                      in_specs=P("dp"), out_specs=P())

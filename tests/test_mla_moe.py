@@ -338,6 +338,57 @@ def test_the_engine_imports_no_private_name_of_a_model_module():
     assert "isinstance(model" not in src and "model_type" not in src
 
 
+def test_the_contract_names_no_kernel_and_ops_know_no_higher_layer():
+    """The contract carries a model's mathematics, never a kernel or a
+    schedule option, and the kernel layer imports nothing from the
+    Program tier above it."""
+    import inspect
+
+    from paddle_tpu.models import llama
+    from paddle_tpu.models.contract import ServingContract
+
+    assert not hasattr(ServingContract, "prefill_scope")
+    kv_only = {"chunk", "adapters", "slots", "scaling"}
+    # what the engine passes `decode` beyond the positional contract ...
+    path = os.path.join(REPO, "paddle_tpu", "serving", "__init__.py")
+    passed = set()
+    for node in ast.walk(ast.parse(open(path).read())):
+        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Dict) \
+                and any(isinstance(t, ast.Name) and t.id == "kv_only"
+                        for t in node.targets):
+            passed |= {k.value for k in node.value.keys}
+        if isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr == "update" \
+                and isinstance(node.func.value, ast.Name) \
+                and node.func.value.id == "kv_only":
+            passed |= {k.arg for k in node.keywords}
+    assert passed == kv_only, passed
+    # ... is what the dense model's decode hands on, and no more
+    params = inspect.signature(llama._decode_layers_paged).parameters
+    assert {n for n, p in params.items()
+            if p.default is not inspect.Parameter.empty} == kv_only
+
+    ops = os.path.join(REPO, "paddle_tpu", "ops")
+    seen = []
+    for root, _dirs, files in os.walk(ops):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            fp = os.path.join(root, name)
+            for node in ast.walk(ast.parse(open(fp).read())):
+                if isinstance(node, ast.ImportFrom):
+                    mods = [node.module or ""] + [
+                        f"{node.module or ''}.{a.name}" for a in node.names]
+                elif isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                else:
+                    continue
+                seen += [(os.path.relpath(fp, REPO), mod) for mod in mods
+                         if "static" in mod.split(".")]
+    assert not seen, seen
+
+
 def test_the_dense_model_serves_through_the_same_contract():
     paddle.seed(0)
     m = LlamaForCausalLM(llama_tiny(dtype="float32"))

@@ -11,7 +11,7 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 
 
-def test_submodule_surfaces_complete():
+def test_submodule_surfaces_complete(reference_source):
     import importlib
     import re
 
@@ -28,7 +28,7 @@ def test_submodule_surfaces_complete():
         ("incubate.nn.functional", "incubate/nn/functional/__init__.py"),
     ]
     for name, path in pairs:
-        src = open(f"/root/reference/python/paddle/{path}").read()
+        src = reference_source(path)
         m = re.search(r"__all__ = \[(.*?)\]", src, re.S)
         if not m:
             continue

@@ -166,8 +166,6 @@ def test_attn_positions_counts_the_width_taken_and_the_live_lengths():
     assert (int(read), int(live)) == (3 * 64, 60)    # 33 needs 32 pages
     read, live = pa.attn_positions(tables, BS, lens)
     assert (int(read), int(live)) == (4 * 64, 61)
-    read, _ = pa.attn_positions(tables, BS, lens, active, whole_table=True)
-    assert int(read) == 3 * W * BS
     for longest, pages in ((31, 16), (32, 16), (33, 32), (64, 32), (65, 40),
                            (80, 40)):
         read, live = pa.attn_positions(

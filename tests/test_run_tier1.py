@@ -67,9 +67,6 @@ def test_build_plan_isolates_collective_modules():
     for mod in ("test_lora.py", "test_serving_lora.py",
                 "test_bench_lora.py"):
         assert mod in rest_files, mod
-    # the decode-chain schedule-search module is single-device (interpret
-    # Pallas + one-process engines): ordinary round-robin, no isolation
-    assert "test_decode_chain.py" in rest_files
     # the TP-sharded serving modules dispatch GSPMD decode programs over
     # the in-process multi-device communicator every test: DEDICATED
     # isolated workers, never round-robin (and never slow-marked).  The

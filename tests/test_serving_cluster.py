@@ -476,11 +476,57 @@ def test_adopt_pages_loud_on_bad_shapes_and_modes():
         eng8.adopt_pages(toks, k_layers, v_layers)
 
 
+def test_restored_replica_delivers_results_that_finished_before_its_snapshot():
+    """A replica that outlives its router finishes its residents and
+    snapshots them finished-but-undelivered.  Restored, it claims them in
+    its resume report, and must then hand them over although nothing is
+    left to step (it used to report progress only after a step: the
+    claimed streams never arrived and the cluster waited for ever)."""
+    import pickle
+
+    from paddle_tpu.serving.cluster_worker import (_claimed_rids,
+                                                   _decode_serve)
+
+    eng = GenerationEngine(make_model(), prefix_cache=True, **_EKW)
+    eng.add_request("g", P_G1, max_new_tokens=4)
+    while eng.has_work():
+        eng.step()
+    want = list(eng.result("g"))
+    tracked = _claimed_rids(eng)       # what a restored engine resurrects
+    assert tracked == {"g"} and not eng.has_work()
+
+    class Ring:
+        def pop(self, timeout_ms):
+            return pickle.dumps({"t": "stop"})
+
+    class Out:
+        def __init__(self):
+            self.pushed = []
+
+        def push(self, msg):
+            self.pushed.append(msg)
+
+    class Killer:
+        def hit(self, point):
+            pass
+
+    out = Out()
+    _decode_serve({"snapshot_dir": None}, eng, tracked, Ring(), out,
+                  Killer())
+    kinds = [(m["t"], m.get("rid")) for m in out.pushed]
+    assert kinds == [("tokens", "g"), ("done", "g"), ("bye", None)], kinds
+    assert out.pushed[0]["start"] == 0 and out.pushed[0]["toks"] == want
+    assert out.pushed[1]["n"] == len(want)
+
+
 # ------------------------------------------------------- adapter namespaces
-# cluster adapter specs: (name, rank, alpha, seed) — alpha 64 so the
-# tiny model's greedy argmax genuinely moves under the adapter (tenant
-# streams must be OBSERVABLY distinct, or isolation tests prove nothing)
-_ADAPTER_SPECS = [("tenant-a", 4, 64.0, 11), ("tenant-b", 4, 64.0, 12)]
+# cluster adapter specs: (name, rank, alpha, seed) — alpha 1024 so that
+# the tiny model's FIRST greedy token on P_G1 moves under each adapter,
+# to three different tokens for base / tenant-a / tenant-b (asserted once,
+# in test_adopt_pages_adapter_namespace_isolation_and_stale_epoch): tenant
+# streams must be OBSERVABLY distinct, or isolation tests prove nothing.
+# At alpha 64 tenant-a's stream coincided with the base model's
+_ADAPTER_SPECS = [("tenant-a", 4, 1024.0, 11), ("tenant-b", 4, 1024.0, 12)]
 
 
 def test_cluster_adapter_table_lockstep_with_engine_registration():
@@ -561,9 +607,10 @@ def test_adopt_pages_adapter_namespace_isolation_and_stale_epoch():
         while eng.has_work():
             eng.step()
         assert decode_stats()["prefix_hits"] == 0, (rid, adapter)
-    # and the tenants' streams are genuinely distinct computations
-    assert eng.result("qa") != eng.result("qc")
-    assert eng.result("qa") != eng.result("qb")
+    # and the tenants' streams are genuinely distinct computations, from
+    # the first greedy token on (what _ADAPTER_SPECS' alpha was chosen for)
+    firsts = {eng.result(rid)[0] for rid in ("qa", "qb", "qc")}
+    assert len(firsts) == 3, firsts
 
     # stale epoch: tenant-a re-registers (epoch bumps), so a shipment
     # pinned at the OLD epoch holds K/V this engine no longer serves —

@@ -238,81 +238,16 @@ def test_sharded_engine_lints_clean_and_reports_per_device():
     assert "pool_bytes/device=%d" % st["pool_bytes_per_device"] in out
 
 
-@pytest.fixture
-def _sched_scratch(tmp_path):
-    """Scratch autotune cache + clean decode counters for the schedule
-    search adopt-path tests (verdicts must not land in checked-in
-    seeds)."""
-    from paddle_tpu.ops import autotune as at
-    from paddle_tpu.serving import reset_schedule_decode_stats
-
-    prev = paddle.get_flags("FLAGS_autotune_cache_dir")
-    paddle.set_flags({"FLAGS_autotune_cache_dir": str(tmp_path)})
-    at._CACHES.clear()
-    reset_schedule_decode_stats()
-    yield tmp_path
-    paddle.set_flags(prev)
-    at._CACHES.clear()
-
-
-def _win(fn, args, *, label, config):
-    return 0.4 if config is not None else 1.0
-
-
-def test_sharded_engine_adopts_fused_decode_chain(_sched_scratch):
-    """Schedule search OVER the mesh (docs/SCHEDULE_SEARCH.md): a
-    TP-sharded engine whose head counts the mp axis divides searches the
-    MESH spec — verdict keyed by (device kind, mesh shape), parity gated
-    against the sharded XLA twin, kernel collectives statically linted —
-    and an adoption runs the in-scan chain as one shard_map'd Pallas
-    dispatch with streams BIT-IDENTICAL to the search-off sharded
-    engine."""
-    from paddle_tpu.serving import schedule_decode_stats
-    from paddle_tpu.static import schedule_search as ss
-
-    ref = _run_workload(GenerationEngine(
-        _model(), max_batch=2, block_size=8, num_blocks=16, mesh=_mesh(2)))
-    paddle.set_flags({"FLAGS_schedule_search": True})
-    try:
-        with ss.measure_override(_win):
-            eng = GenerationEngine(_model(), max_batch=2, block_size=8,
-                                   num_blocks=16, mesh=_mesh(2))
-            got = _run_workload(eng)
-    finally:
-        paddle.set_flags({"FLAGS_schedule_search": False})
-    assert got == ref
-    stats = schedule_decode_stats()
-    assert stats["decode_chains_mesh_fused"] >= 1
-    assert stats["decode_chains_found"] >= 1
-    assert stats["decode_chains_accepted"] >= 1
-    assert stats["decode_chains_mesh_skipped"] == 0
-    assert profiler.schedule_search_stats()["decode_chains_mesh_fused"] >= 1
-
-
-def test_sharded_engine_skips_decode_chain_replicated_pools(_sched_scratch):
-    """The counted mesh skip SURVIVES for engines whose pools ride
-    replicated (head counts the mp axis doesn't divide — the
-    constructor's fallback): there is no head-local layout to fuse over,
-    so the searcher is never consulted and the streams stay the unfused
-    sharded path."""
-    from paddle_tpu.serving import schedule_decode_stats
-    from paddle_tpu.static import schedule_search as ss
-
+def test_sharded_engine_replicated_pools_match_single_device():
+    """A TP engine whose head counts the mp axis does not divide (4 query
+    heads, 1 KV head, mp = 2) keeps its pools replicated (the
+    constructor's fallback) and decodes the single-device engine's
+    streams."""
     kw = dict(num_attention_heads=4, num_key_value_heads=1)
     ref = _run_workload(GenerationEngine(
-        _model(**kw), max_batch=2, block_size=8, num_blocks=16,
-        mesh=_mesh(2)))
-    paddle.set_flags({"FLAGS_schedule_search": True})
-    try:
-        with ss.measure_override(_win):
-            got = _run_workload(GenerationEngine(
-                _model(**kw), max_batch=2, block_size=8, num_blocks=16,
-                mesh=_mesh(2)))
-    finally:
-        paddle.set_flags({"FLAGS_schedule_search": False})
-    assert got == ref
-    stats = schedule_decode_stats()
-    assert stats["decode_chains_mesh_skipped"] >= 1
-    assert stats["decode_chains_found"] == 0  # never consulted a searcher
-    assert stats["decode_chains_mesh_fused"] == 0
-    assert profiler.schedule_search_stats()["decode_chains_mesh_skipped"] >= 1
+        _model(**kw), max_batch=2, block_size=8, num_blocks=16))
+    eng = GenerationEngine(_model(**kw), max_batch=2, block_size=8,
+                           num_blocks=16, mesh=_mesh(2))
+    for _part, arr in pa.pool_parts(eng._pools[0][0]):
+        assert "mp" not in str(arr.sharding.spec)
+    assert _run_workload(eng) == ref

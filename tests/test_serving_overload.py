@@ -3,9 +3,8 @@ with decode (FLAGS_prefill_chunk_blocks), priority/SLO-class admission, and
 preemptible LOW-priority requests (FLAGS_preempt_low_priority).
 
 The bit-exactness backbone: a prefill chunk is one pool block, every chunk
-keeps its own full-chunk geometry (the PrefillChainSpec shape-identity
-rule), and the per-block pour computes the same per-block-per-head scales
-the batched atomic pour computes — so the chunk boundary is pure data
+keeps its own full-chunk geometry, and the per-block pour computes the
+same per-block-per-head scales the batched atomic pour computes — so the chunk boundary is pure data
 movement and chunked streams are token-for-token identical to atomic
 admission.  Preempted requests park their pool pages host-side verbatim
 (pool_get_blocks/pool_set_blocks) and resume bit-identically because the

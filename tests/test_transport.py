@@ -185,6 +185,33 @@ def test_dial_before_listen_attach_retries():
         b.destroy()
 
 
+def test_frame_pushed_before_the_drop_is_noticed_is_not_lost():
+    """The peer's FIN is in the kernel but the rx thread has not acted on
+    it yet (made late here; under load it is late by itself): a frame
+    pushed now must wait for the replacement connection, not vanish into
+    the dead one."""
+    a, b = _pair()
+    try:
+        a.push(b"before", timeout_ms=5000)
+        assert b.pop(timeout_ms=5000) == b"before"
+        drop = b._drop
+
+        def late_drop(gen):
+            time.sleep(0.5)
+            drop(gen)
+
+        b._drop = late_drop
+        with a._cv:
+            conn = a._conn
+        conn.shutdown(socket.SHUT_RDWR)
+        conn.close()
+        b.push(b"uphill", timeout_ms=5000)
+        assert a.pop(timeout_ms=20000) == b"uphill"
+    finally:
+        a.destroy()
+        b.destroy()
+
+
 def test_dial_without_listener_fails_at_deadline():
     probe = socket.socket()
     probe.bind(("127.0.0.1", 0))

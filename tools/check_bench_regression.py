@@ -58,17 +58,6 @@ simulator, fleet/meta_parallel/schedules.py) gate each schedule's bubble
 LOWER-is-better at the regular --threshold — the numbers are
 deterministic host math, so any growth means a schedule table got worse
 — and skip silently on pre-schedule payloads.
-
-Schedule-search payloads carrying the decode-chain section
-(bench_schedule_search.py detail.decode_chain: per-variant
-win-or-disabled verdicts — kv dtypes "bf16"/"int8", plus "mesh" for the
-2-device sharded-engine verdict keyed by (device kind, mesh shape) and
-"prefill" for the K-tiled fused prefill-attention candidate) gate each
-variant's measured win like the headline metric; a DISABLED side (win 0
-— an honest measured loss, e.g. CPU interpret mode) skips that variant
-rather than fabricating a signal, and is never recorded as value=0 by
-the bench in the first place.  The loop is generic over variant names,
-so sides missing a variant (pre-mesh rounds) skip it silently.
 """
 
 from __future__ import annotations
@@ -200,18 +189,6 @@ def load_pipeline(path):
         return None
     sch = pl.get("schedules")
     return sch if isinstance(sch, dict) else None
-
-
-def load_decode_chain(path):
-    """The decode-chain section of a schedule-search bench payload
-    (bench_schedule_search.py detail.decode_chain: {"bf16": {"win": ...,
-    "disabled_persisted": ...}, "int8": {...}}), or None when the payload
-    has no such section — pre-phase-2 rounds skip the gate."""
-    data, _err = _payload_dict(path)
-    if not isinstance(data, dict):
-        return None
-    dec = (data.get("detail") or {}).get("decode_chain")
-    return dec if isinstance(dec, dict) else None
 
 
 def main(argv=None):
@@ -401,29 +378,6 @@ def main(argv=None):
                 stat = "REGRESSION" if rel > args.threshold else "ok"
                 print(f"bench gate [pipeline {name}]: bubble {o:.4f} -> "
                       f"{n:.4f} ({rel:+.2%}) {stat}")
-            if stat == "REGRESSION":
-                rc = 1
-
-    # decode-chain gate (schedule search phase 2): per-variant measured
-    # wins, higher-is-better like the headline.  A disabled side (win 0)
-    # is an honest measured loss, not a regression — skip that variant.
-    old_dc, new_dc = load_decode_chain(args.old), load_decode_chain(args.new)
-    if old_dc and new_dc:
-        for kv in sorted(set(old_dc) & set(new_dc)):
-            try:
-                ow = float((old_dc[kv] or {}).get("win", 0.0) or 0.0)
-                nw = float((new_dc[kv] or {}).get("win", 0.0) or 0.0)
-            except (TypeError, ValueError):
-                continue
-            if ow <= 0.0 or nw <= 0.0:
-                print(f"bench gate [decode_chain {kv}]: SKIP — "
-                      f"{ow:.2f} -> {nw:.2f} (disabled side: an honest "
-                      "loss is never a regression)")
-                continue
-            rel = (nw - ow) / ow
-            stat = "REGRESSION" if rel < -args.threshold else "ok"
-            print(f"bench gate [decode_chain {kv}]: {ow:.2f} -> {nw:.2f} "
-                  f"({rel:+.2%}) {stat}")
             if stat == "REGRESSION":
                 rc = 1
     return rc

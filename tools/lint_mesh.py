@@ -50,9 +50,8 @@ def _battery() -> int:
     import paddle_tpu.distributed as dist
     from paddle_tpu.distributed import ProcessMesh
     from paddle_tpu.distributed.shard_map_compat import shard_map
-    from paddle_tpu.static.mesh_lint import (MeshLinter, lint_decode_chain,
-                                             lint_engine, lint_program,
-                                             lint_train_step,
+    from paddle_tpu.static.mesh_lint import (MeshLinter, lint_engine,
+                                             lint_program, lint_train_step,
                                              mesh_lint_stats)
     from paddle_tpu.static.passes import apply_pass
 
@@ -111,19 +110,6 @@ def _battery() -> int:
     print(f"     per-device estimate: "
           f"{ {k: int(v) for k, v in est.items()} }")
 
-    # 4. the fused decode-chain kernel a TP-sharded engine adopts
-    # (schedule search over the mesh): statically linted before dispatch
-    # — the head-local shard_map chain must walk with ZERO collectives
-    from paddle_tpu.ops.decode_chain import DecodeChainSpec
-
-    chain_spec = DecodeChainSpec(batch=2, num_heads=4, num_kv_heads=2,
-                                 head_dim=8, block_size=4, max_blocks=2,
-                                 num_blocks=8, kv="int8", mesh=mp2)
-    failures += _report(
-        "mesh-decode-chain-kernel",
-        lint_decode_chain(chain_spec,
-                          {"layout": "batch", "gather": "take"}))
-
     # ------------------------------------------------- seeded violations
     aval = jax.ShapeDtypeStruct((8, 4), jnp.float32)
     linter = MeshLinter(mesh=dp8)
@@ -165,15 +151,6 @@ def _battery() -> int:
     failures += _report("bad-ppermute-participation",
                         linter.lint_callable(bad_perm, aval),
                         expect_codes={"bad-permutation"})
-
-    # decode-chain kernel on a foreign session mesh: the mp-sharded
-    # chain judged against a dp-only session — the mesh-congruence class
-    # the adopt path's pre-dispatch lint turns into a counted disable
-    failures += _report(
-        "decode-chain-foreign-mesh",
-        lint_decode_chain(chain_spec, {"layout": "batch", "gather": "take"},
-                          mesh=dp8),
-        expect_codes={"unknown-axis"})
 
     # use-after-donation: fetch the PRE-update buffer of a donated,
     # in-place-written state var

@@ -67,11 +67,7 @@ ISOLATED_DEFAULT = (
     "test_serving_mesh.py",
     "test_serving_mesh_spec.py",
     "test_engine_snapshot_mesh.py",
-    # Sharded decode-chain fusion: shard_map'd interpret-mode Pallas
-    # bodies inside jitted decode scans on 2/4/8-device meshes, plus
-    # run_isolated_test subprocess workers of its own — and the bench
-    # smoke test, whose subprocess drives the same 2-device engine.
-    "test_decode_chain_mesh.py",
+    # The schedule-search bench smoke test drives a subprocess of its own.
     "test_bench_schedule_search.py",
     # The serving-cluster modules fork real engine/router processes and
     # SIGKILL them mid-protocol (heartbeat fail-over, drain migration,
