@@ -10,6 +10,9 @@
     python chip_smoke.py --dense-softmax   # one chip: the dense decode attention
                                        # over a bfloat16 paged pool against a
                                        # float32 softmax on the same rows
+    python chip_smoke.py --flash-softmax   # one chip: the flash kernels, forward
+                                       # and backward, on bfloat16 inputs
+                                       # against a float64 softmax
 
 Drives the two entry points users of this framework call — the compiled train
 step (`paddle_tpu.jit.TrainStep`) and the serving engine
@@ -728,6 +731,149 @@ def dense_softmax_probe(*, seed, heads=16, kv_heads=8, head_dim=128,
     return out
 
 
+# -------------------------------------------------- the flash kernels ----
+
+# (name, positions, heads, kv heads, q/k width, v width, backward too): the
+# two shapes the benchmark's cells hand `ops.flash_attention`: the latent
+# model's longest prefill bucket (models/mla_moe.py) and the train cell's step
+FLASH_PROBE_SHAPES = (
+    ("latent prefill", 8192, 128, 128, 192, 128, False),
+    ("train step", 4096, 32, 8, 128, 128, True),
+)
+
+
+def _float64_attention(q, k, v, w=None):
+    """Causal softmax(q k^T / sqrt(width)) v in float64 on the host for the
+    query heads `q` [g, S, d] of ONE key/value head `k` [S, d], `v`
+    [S, d_v]; with the output's cotangent `w` [g, S, d_v] also dq, dk, dv."""
+    import numpy as np
+
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    seen = np.tril(np.ones((q.shape[1], k.shape[0]), bool))
+    out, dq = np.zeros(q.shape[:2] + v.shape[1:]), np.zeros(q.shape)
+    dk, dv = np.zeros(k.shape), np.zeros(v.shape)
+    for g in range(q.shape[0]):
+        s = np.where(seen, q[g] @ k.T * scale, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[g] = p @ v
+        if w is not None:
+            dp = w[g] @ v.T
+            ds = p * (dp - (p * dp).sum(-1, keepdims=True)) * scale
+            dq[g], dk, dv = ds @ k, dk + ds.T @ q[g], dv + p.T @ w[g]
+    return (out, dq, dk, dv) if w is not None else (out,)
+
+
+def _bfloat16_scores_attention(q, k, v):
+    """The control: causal attention ([B, S, N, d], any head grouping) with
+    the SCORES rounded to bfloat16 and the softmax computed in it."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    b, s, n, d = q.shape
+    qg = q.astype(f32).reshape(b, s, k.shape[2], -1, d)
+    score = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(f32))
+    score = score.astype(jnp.bfloat16) / jnp.bfloat16(math.sqrt(d))
+    p = jax.nn.softmax(jnp.where(jnp.tril(jnp.ones((s, s), bool)), score,
+                                 -1e30), axis=-1)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(f32), v.astype(f32))
+    return out.reshape(b, s, n, v.shape[-1]).astype(q.dtype)
+
+
+def flash_softmax_probe(*, seed, shapes=FLASH_PROBE_SHAPES, sampled=2):
+    """The prefill twin of the two decode probes: `ops.flash_attention` on
+    bfloat16 inputs (the matrix unit gets bfloat16 operands, `p` and `ds`
+    rounded once; every sum, maximum and exponential float32), causal, at
+    `shapes`, against a float64 softmax on the host over the SAME bfloat16
+    inputs for `sampled` key/value heads and every query head of their
+    groups: rms error over the result's rms for the output and, where the
+    shape is trained on, dq, dk and dv; queries spread as a trained
+    model's scores.  Then the control on the same heads: the scores
+    rounded to bfloat16."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu import profiler
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    def loss(fn, w):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+
+    def host(x):  # [1, S, n, d] -> [n, S, d] float64
+        return np.moveaxis(np.asarray(x.astype(jnp.float32), np.float64)[0],
+                           1, 0)
+
+    traced = profiler.compile_stats()
+    out = {}
+    for at, (name, seq, heads, kv_heads, width, v_width, grads) in enumerate(
+            shapes):
+        group = heads // kv_heads
+        keys = jax.random.split(jax.random.key((seed + at) % 2 ** 31), 4)
+        dims = ((heads, width), (kv_heads, width), (kv_heads, v_width),
+                (heads, v_width))
+        q, k, v, w = (jax.random.normal(r, (1, seq, n, d), jnp.float32
+                                        ).astype(jnp.bfloat16)
+                      for r, (n, d) in zip(keys, dims))
+        q = (q.astype(jnp.float32) * SOFTMAX_SCORE_SPREAD).astype(jnp.bfloat16)
+        held = np.random.default_rng([seed % 2 ** 63, 30, at]).choice(
+            kv_heads, size=min(sampled, kv_heads), replace=False)
+        asked = np.concatenate([np.arange(h * group, (h + 1) * group)
+                                for h in held])
+        heads_of = (asked, asked, held, held)  # of out and dq; of dk and dv
+
+        def results(fn, q, k, v, w, sampled_already):
+            got = [jax.jit(fn)(q, k, v)]
+            if grads:
+                got += jax.jit(jax.grad(loss(fn, w.astype(jnp.float32)),
+                                        (0, 1, 2)))(q, k, v)
+            return [host(x if sampled_already else x[:, :, h])
+                    for x, h in zip(got, heads_of)]
+
+        names = ("out", "dq", "dk", "dv")[:4 if grads else 1]
+        picked = (q[:, :, asked], k[:, :, held], v[:, :, held], w[:, :, asked])
+        qs, ks, vs, ws = (host(x) for x in picked)
+        want = {n: [] for n in names}
+        for i in range(len(held)):
+            rows = slice(i * group, (i + 1) * group)
+            for n, x in zip(names, _float64_attention(
+                    qs[rows], ks[i], vs[i], ws[rows] if grads else None)):
+                want[n].append(x if x.ndim == 3 else x[None])
+        want = [np.concatenate(want[n]) for n in names]
+        tag = name.replace(" ", "_")
+        sides = (("flash", results(
+                     lambda q, k, v: flash_attention(q, k, v, causal=True),
+                     q, k, v, w, False)),
+                 ("bfloat16_scores", results(_bfloat16_scores_attention,
+                                             *picked, True)))
+        for side, got in sides:
+            for n, g, t in zip(names, got, want):
+                out[f"{side}_rms_{n}.{tag}"] = _rms_error(g, t)
+        say(f"flash probe, {name}: causal, {seq} positions, {heads} / "
+            f"{kv_heads} heads x {width} (v {v_width}), bfloat16, {len(held)} "
+            f"key/value heads against float64, queries spread "
+            f"{SOFTMAX_SCORE_SPREAD:g}: rms error over the result's rms "
+            + ", ".join(f"{n} {out[f'flash_rms_{n}.{tag}']:.5f}" for n in names)
+            + "; with the scores in bfloat16 "
+            + ", ".join(f"{n} {out[f'bfloat16_scores_rms_{n}.{tag}']:.5f}"
+                        for n in names) + f" (limit {SOFTMAX_RMS_TOL})")
+        check(all(out[f"flash_rms_{n}.{tag}"] <= SOFTMAX_RMS_TOL
+                  for n in names),
+              f"the flash kernels agree with a float64 softmax on the same "
+              f"bfloat16 inputs ({name})")
+    now = profiler.compile_stats()
+    for key in ("flash_bf16_operand_traces", "flash_f32_operand_traces"):
+        out[key] = now[key] - traced[key]
+    say(f"flash probe: traced with bfloat16 operands "
+        f"{out['flash_bf16_operand_traces']} times, with float32 operands "
+        f"{out['flash_f32_operand_traces']} times")
+    check(out["flash_bf16_operand_traces"] > 0
+          == out["flash_f32_operand_traces"],
+          "bfloat16 inputs reach the matrix unit as bfloat16 operands")
+    return out
+
+
 # ---------------------------------------------------------- four chips ----
 
 def four_chip_phase(cfg, *, batch, seq, steps, seed, devices, lr=3e-4) -> dict:
@@ -853,6 +999,11 @@ def main(argv=None) -> int:
                     help="run ONLY the dense decode attention over a "
                          "bfloat16 paged pool (internlm2-1.8b's heads) "
                          "against a float32 softmax on the same rows")
+    ap.add_argument("--flash-softmax", action="store_true",
+                    help="run ONLY the flash kernels on bfloat16 inputs (the "
+                         "latent prefill's forward at 8,192 positions, the "
+                         "train step's forward and backward at 4,096) "
+                         "against a float64 softmax on the same inputs")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -902,6 +1053,13 @@ def main(argv=None) -> int:
         check(out["dense_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
               "a bfloat16 softmax does NOT agree with a float32 softmax on "
               "the same rows: the comparison tells it from float32")
+    elif args.flash_softmax:
+        out = flash_softmax_probe(seed=args.seed)
+        check(all(v > SOFTMAX_RMS_TOL for k, v in out.items()
+                  if k.startswith("bfloat16_scores_rms_out.")),
+              "scores rounded to bfloat16 do NOT agree with a float64 "
+              "softmax on the same inputs: the comparison tells them from "
+              "float32")
     elif args.four_chips:
         batch = 2  # one sequence per data-parallel group
         depth, why = choose_depth("train", widths, limit, batch=batch, seq=seq,

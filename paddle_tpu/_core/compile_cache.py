@@ -32,7 +32,7 @@ import threading
 from . import flags
 
 __all__ = ["configure", "enable", "default_dir", "compile_stats",
-           "reset_compile_stats"]
+           "reset_compile_stats", "count"]
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -49,6 +49,10 @@ _stats = {
     "persistent_cache_hits": 0,
     "persistent_cache_misses": 0,
     "compile_seconds_saved": 0.0,
+    # counted by the code being traced (`count`): which operand type the
+    # flash kernels' matrix products were traced with (ops/flash_attention)
+    "flash_bf16_operand_traces": 0,
+    "flash_f32_operand_traces": 0,
 }
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -74,6 +78,11 @@ def _on_duration(event: str, duration: float, **kw):
         _stats["compile_seconds"] += duration
     elif event == _SAVED_EVENT:
         _stats["compile_seconds_saved"] += duration
+
+
+def count(name: str):
+    """One more of a trace-time event that the traced code counts itself."""
+    _stats[name] += 1
 
 
 def _install_listeners():

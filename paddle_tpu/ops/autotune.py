@@ -40,6 +40,7 @@ __all__ = [
     "tune_swiglu",
     "device_kind_slug",
     "flash_vmem_bytes",
+    "lane_padded",
     "validate_tile",
     "validate_flash_tile",
 ]
@@ -334,16 +335,23 @@ def tune_kernel(kernel, key, build, candidates, args, *, iters=3, inner=None,
 # per-kernel candidate spaces + drivers
 
 
-def flash_vmem_bytes(block_q, block_k, seq_k, head_dim):
-    """fp32 working-set estimate for one fwd grid step (double-buffered
-    pipeline): whole-K/V residency + q/o blocks + the scores tile."""
-    per = (
-        2 * seq_k * head_dim        # k + v (full sequence per (b, n))
-        + 2 * block_q * head_dim    # q + o
-        + block_q * block_k         # scores/probs tile
-        + block_q * 128             # lse lane padding
-    )
-    return per * 4 * 2
+def lane_padded(width):
+    """A row of `width` values as VMEM holds it: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+def flash_vmem_bytes(block_q, block_k, seq_k, head_dim, itemsize=4,
+                     v_dim=None):
+    """Working-set estimate for one fwd grid step, in the blocks' own type
+    (`itemsize` bytes a value, rows lane-padded, as
+    `flash_attention._require_vmem` counts them): whole-K/V residency + q/o
+    blocks + the float32 lse block, double-buffered by the pipeline, + the
+    float32 scores and probabilities tiles."""
+    row = lane_padded(head_dim) + lane_padded(
+        head_dim if v_dim is None else v_dim)
+    blocks = ((seq_k + block_q) * row * itemsize  # k, v; q, o
+              + block_q * 128 * 4)                # lse
+    return 2 * blocks + 2 * block_q * block_k * 4
 
 
 def validate_tile(vmem_bytes, budget=None):
@@ -360,9 +368,14 @@ def validate_tile(vmem_bytes, budget=None):
     return None
 
 
-def validate_flash_tile(block_q, block_k, seq_q, seq_k, head_dim):
+def validate_flash_tile(block_q, block_k, seq_q, seq_k, head_dim, *,
+                        dtype=None, v_dim=None):
     """None when valid; else a human-readable reason (kernels warn with it
-    rather than silently falling back — VERDICT r3 #10)."""
+    rather than silently falling back — VERDICT r3 #10).  `dtype` is the
+    blocks' (float32 where none is given), `v_dim` V's width where it has
+    one of its own."""
+    import numpy as np
+
     if block_q < 8 or block_q % 8:
         return f"block_q={block_q} must be a positive multiple of 8"
     if block_k < 8 or block_k % 8:
@@ -371,25 +384,51 @@ def validate_flash_tile(block_q, block_k, seq_q, seq_k, head_dim):
         return f"block_q={block_q} does not divide seq_q={seq_q}"
     if seq_k % block_k:
         return f"block_k={block_k} does not divide seq_k={seq_k}"
-    reason = validate_tile(flash_vmem_bytes(block_q, block_k, seq_k, head_dim))
+    itemsize = 4 if dtype is None else np.dtype(dtype).itemsize
+    reason = validate_tile(flash_vmem_bytes(block_q, block_k, seq_k, head_dim,
+                                            itemsize, v_dim))
     if reason:
         return f"tile ({block_q},{block_k}): {reason}"
     return None
 
 
-def flash_candidates(seq_q, seq_k, head_dim):
-    sizes = (64, 128, 256, 512)
+def flash_candidates(seq_q, seq_k, head_dim, *, dtype=None, v_dim=None):
+    # a block under 128 rows leaves the 128 x 128 matrix unit part empty:
+    # offered only to sequences shorter than that
+    sizes = [b for b in (64, 128, 256, 512, 1024)
+             if b >= min(128, seq_q, seq_k)]
     out = []
     for bq in sizes:
         for bk in sizes:
-            if validate_flash_tile(bq, bk, seq_q, seq_k, head_dim) is None:
+            if validate_flash_tile(bq, bk, seq_q, seq_k, head_dim,
+                                   dtype=dtype, v_dim=v_dim) is None:
                 out.append({"block_q": bq, "block_k": bk})
     return out
 
 
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def flash_key(seq_q, seq_k, head_dim, dtype, causal, v_dim=None):
+    """The table key of a flash kernel's shape signature; `v_dim` is part
+    of it only where V has a width of its own (latent attention's
+    prefill)."""
+    import numpy as np
+
+    key = {"seq_q": seq_q, "seq_k": seq_k, "head_dim": head_dim,
+           "dtype": np.dtype(dtype).name, "causal": bool(causal)}
+    if v_dim not in (None, head_dim):
+        key["v_dim"] = v_dim
+    return key
+
+
 def tune_flash(batch=1, num_heads=8, seq=2048, head_dim=128, dtype="bfloat16",
-               causal=True, **kw):
-    """Tune flash-attention fwd tiles for one shape signature."""
+               causal=True, v_dim=None, kernel="flash_fwd", **kw):
+    """Tune one flash-attention kernel's tile for one shape signature: the
+    forward (`v_dim` where V has a width of its own) or either backward
+    kernel, each under its own table key (`FLASH_KERNELS`).  What the
+    Mosaic compiler refuses (a tile whose blocks overflow VMEM) is
+    skipped by `tune_kernel`."""
     import jax
     import jax.numpy as jnp
 
@@ -400,22 +439,31 @@ def tune_flash(batch=1, num_heads=8, seq=2048, head_dim=128, dtype="bfloat16",
     fa = importlib.import_module("paddle_tpu.ops.flash_attention")
 
     jd = jnp.dtype(dtype)
-    key = {"seq_q": seq, "seq_k": seq, "head_dim": head_dim,
-           "dtype": jd.name, "causal": bool(causal)}
-    rng = jax.random.PRNGKey(0)
-    qkv = [
-        jax.random.normal(k, (batch, num_heads, seq, head_dim), jd)
-        for k in jax.random.split(rng, 3)
-    ]
+    key = flash_key(seq, seq, head_dim, jd, causal, v_dim)
+    scale = 1.0 / head_dim ** 0.5
+    widths = (head_dim, head_dim, v_dim or head_dim, v_dim or head_dim)
+    q, k, v, do = (
+        jax.random.normal(r, (batch, num_heads, seq, w), jd)
+        for r, w in zip(jax.random.split(jax.random.PRNGKey(0), 4), widths))
+    if kernel == "flash_fwd":
+        args = (q, k, v)
 
-    def build(cfg):
-        f = jax.jit(lambda q, k, v: fa._flash_bnsh(
-            q, k, v, 1.0 / head_dim ** 0.5, causal,
-            cfg["block_q"], cfg["block_k"]))
-        return f
+        def build(cfg):
+            return jax.jit(lambda q, k, v: fa._fwd(
+                q, k, v, scale, causal, cfg["block_q"], cfg["block_k"])[0])
+    else:
+        out, lse = fa._fwd(q, k, v, scale, causal, *fa._block_sizes(
+            seq, seq, head_dim, jd, causal))
+        args = (q, k, v, do, *fa._row_stats(out, lse, do))
+        run = {"flash_bwd_dq": fa._bwd_dq, "flash_bwd_dkv": fa._bwd_dkv}[kernel]
 
-    return tune_kernel("flash_fwd", key, build,
-                       flash_candidates(seq, seq, head_dim), qkv, **kw)
+        def build(cfg):
+            return jax.jit(lambda *a: run(
+                *a, scale, causal, cfg["block_q"], cfg["block_k"]))
+
+    return tune_kernel(kernel, key, build,
+                       flash_candidates(seq, seq, head_dim, dtype=jd,
+                                        v_dim=v_dim), args, **kw)
 
 
 def norm_candidates(rows, hidden):
@@ -529,13 +577,18 @@ def tune_matmul_epilogue(m=4096, k=4096, n=4096, dtype="bfloat16", **kw):
 # CLI: bounded-time sweep over the standard shape set
 
 
-# Flagship-first ordering: bench.py's hidden-2048/S=1024 LLaMA uses
-# flash(seq=1024, hd=128), norm rows=B*1024 x 2048, swiglu rows x 5632 —
-# a short on-chip budget tunes exactly those before the generic shapes.
+# The benchmark's cells first (PERF.md section 4): a short on-chip budget
+# tunes exactly the shapes they run before the generic ones — the train
+# cell's three flash kernels at 1 x 4096 x 32 heads x 128, the latent
+# model's prefill buckets (128 heads, q/k 192, v 128, forward only).
 _STANDARD_SHAPES = {
     "flash": [
-        dict(seq=1024, head_dim=128), dict(seq=2048, head_dim=128),
-        dict(seq=4096, head_dim=128), dict(seq=2048, head_dim=64),
+        *(dict(seq=4096, head_dim=128, num_heads=32, kernel=k)
+          for k in FLASH_KERNELS),
+        *(dict(seq=s, head_dim=192, v_dim=128, num_heads=128)
+          for s in (8192, 4096, 2048)),
+        *(dict(seq=2048, head_dim=128, num_heads=32, kernel=k)
+          for k in FLASH_KERNELS),
     ],
     "norm": [
         dict(rows=4096, hidden=2048), dict(rows=8192, hidden=2048),

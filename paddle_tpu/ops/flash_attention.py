@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu._core import compile_cache
 from paddle_tpu.ops import _pl_utils
+from paddle_tpu.ops import autotune as _at
 from paddle_tpu.ops._pl_utils import imap
 from jax.experimental.pallas import tpu as pltpu
 
@@ -36,30 +38,43 @@ def _mask_val():
     return jnp.float32(DEFAULT_MASK_VALUE)
 
 
-def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False):
-    """Tile selection, in precedence order (reference
-    phi/kernels/autotune/cache.h consults its config cache the same way):
+def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False, *,
+                 v_dim=None, kernel="flash_fwd", default=None):
+    """(block_q, block_k) for one of the three kernels, in precedence order
+    (reference phi/kernels/autotune/cache.h consults its config cache the
+    same way):
 
-    1. explicit FLAGS_flash_block_q/_k override — invalid values WARN
-       loudly and fall through (VERDICT r3 #10: no silent fallbacks);
-    2. the per-device-kind autotune cache (ops/autotune.py) for this
-       (seq, head_dim, dtype, causal) signature;
-    3. the 128x128 default (measured best on v5e at the flagship shapes).
+    1. explicit FLAGS_flash_block_q/_k override, for every kernel — invalid
+       values WARN loudly and fall through (VERDICT r3 #10: no silent
+       fallbacks);
+    2. the per-device-kind autotune table (ops/autotune.py,
+       ops/tuned/<device>.json) under `kernel` for this (seq, head_dim,
+       dtype, causal) signature.  Its v5e entries were measured on the chip
+       in PR 30, on the kernels whose products take bfloat16 operands, at
+       the shapes the benchmark's cells run; each backward kernel has
+       entries of its own (`flash_bwd_dq`, `flash_bwd_dkv`);
+    3. `default`: the forward's tile, for a backward kernel the table does
+       not know; else 128 x 128, the untuned shapes' tile: safe for every
+       type and length the kernels take, and 3 to 4.7 times slower than the
+       tuned tile at every shape PR 30 measured (PERF.md section 6).
     """
     import warnings
 
     from paddle_tpu._core import flags as _flags
-    from paddle_tpu.ops import autotune as _at
 
     def _fallback(seq):
         return min(128, seq)
+
+    def invalid(bq, bk):
+        return _at.validate_flash_tile(bq, bk, seq_q, seq_k, head_dim,
+                                       dtype=dtype, v_dim=v_dim)
 
     # 1. explicit flags
     fq, fk = int(_flags.flag("FLAGS_flash_block_q")), int(_flags.flag("FLAGS_flash_block_k"))
     if fq > 0 or fk > 0:
         bq = min(fq, seq_q) if fq > 0 else _fallback(seq_q)
         bk = min(fk, seq_k) if fk > 0 else _fallback(seq_k)
-        reason = _at.validate_flash_tile(bq, bk, seq_q, seq_k, head_dim)
+        reason = invalid(bq, bk)
         if reason is None:
             return bq, bk
         warnings.warn(
@@ -70,30 +85,31 @@ def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False):
         )
 
     # 2. autotune cache
-    key = {"seq_q": seq_q, "seq_k": seq_k, "head_dim": head_dim,
-           "dtype": jnp.dtype(dtype).name if dtype is not None else "bfloat16",
-           "causal": bool(causal)}
-    tuned = _at.lookup("flash_fwd", key)
+    key = _at.flash_key(seq_q, seq_k, head_dim,
+                        dtype if dtype is not None else "bfloat16", causal,
+                        v_dim)
+    tuned = _at.lookup(kernel, key)
     if tuned:
         bq, bk = int(tuned["block_q"]), int(tuned["block_k"])
-        reason = _at.validate_flash_tile(bq, bk, seq_q, seq_k, head_dim)
+        reason = invalid(bq, bk)
         if reason is None:
             return bq, bk
         warnings.warn(
-            f"flash_attention: cached tile ({bq},{bk}) for {key} is invalid "
-            f"on this device: {reason}; using the 128x128 default "
+            f"flash_attention: cached tile ({bq},{bk}) for {kernel} {key} is "
+            f"invalid on this device: {reason}; using the 128x128 default "
             "(re-run `python -m paddle_tpu.ops.autotune`)",
             stacklevel=3,
         )
 
     # 3. default
-    return _fallback(seq_q), _fallback(seq_k)
+    return default or (_fallback(seq_q), _fallback(seq_k))
 
 
 # Mosaic's own stack scratch on top of the pipeline's blocks (observed
 # <= 16 KiB when compiling for a v5e; the dkv kernel at exactly 16 MiB of
 # blocks was refused at "16.01M").
 _MOSAIC_SCRATCH = 32 << 10
+_LANE_F32 = 128 * 4  # one lse / delta row, lane-padded
 
 
 def _require_vmem(kernel, seq_name, seq, row_bytes, tile_bytes):
@@ -104,19 +120,65 @@ def _require_vmem(kernel, seq_name, seq, row_bytes, tile_bytes):
     scoped-VMEM allocation dump.  Name the limit instead.  No switch to
     the O(S^2) reference: a caller that needs longer sequences shards
     them (context_parallel_llama) until a streaming-K/V kernel exists."""
-    from paddle_tpu.ops.autotune import _VMEM_BUDGET
-
+    budget = _at._VMEM_BUDGET
     need = 2 * (seq * row_bytes + tile_bytes) + _MOSAIC_SCRATCH
-    if need <= _VMEM_BUDGET:
+    if need <= budget:
         return
-    longest = ((_VMEM_BUDGET - _MOSAIC_SCRATCH) // 2 - tile_bytes) // row_bytes
+    longest = ((budget - _MOSAIC_SCRATCH) // 2 - tile_bytes) // row_bytes
     raise ValueError(
         f"flash_attention ({kernel} kernel): {seq_name}={seq} needs "
         f"{need / (1 << 20):.2f} MiB of VMEM for its whole-sequence blocks "
         f"(double-buffered) but Mosaic's scoped-VMEM limit is "
-        f"{_VMEM_BUDGET >> 20} MiB; the longest {seq_name} this kernel "
+        f"{budget >> 20} MiB; the longest {seq_name} this kernel "
         f"compiles for at these widths is {longest // 128 * 128}. "
         "Shard the sequence (context parallelism) or shorten it.")
+
+
+# ---------------------------------------------------------------------------
+# What the matrix unit is fed
+# ---------------------------------------------------------------------------
+
+
+def _operand_dtype(*blocks):
+    """The type of every matrix product's operands, from the blocks' own.
+    bfloat16 blocks go to the matrix unit as they are stored, one pass with
+    float32 accumulation, whatever matmul precision the caller has set; the
+    float32 factors made inside a kernel (the probabilities `p`, the score
+    gradients `ds`) are rounded to it once before their product, as the
+    published modelling code of the served configurations does
+    (`softmax(..., dtype=float32).to(query.dtype)`).  Anything else
+    (float32, float16, a mix) is multiplied in float32 at the caller's
+    precision, as ever: about six passes at "highest"; at jax's default a
+    v5e gives a float32 product one pass too (PR 30 measured the two
+    operand types equally fast at every tile: PERF.md section 6), so on
+    the chip this states the products' precision more than it changes it.
+    Maxima, row sums (from the float32 `p`), lse, delta, exponentials and
+    every accumulator are float32 either way."""
+    if all(b.dtype == jnp.bfloat16 for b in blocks):
+        return jnp.bfloat16
+    return jnp.float32
+
+
+# contracted axes of (a, b): a b^T, a b, a^T b
+_NT, _NN, _TN = (1, 1), (1, 0), (0, 0)
+
+
+def _dot(a, b, axes):
+    # two bfloat16 values multiply exactly in float32: one pass IS full
+    # precision, and Mosaic refuses bfloat16 operands at any other setting
+    # ("Bad lhs type" under jax_default_matmul_precision=highest).  float32
+    # operands keep the caller's precision, as ever.
+    precision = jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
+    return jax.lax.dot_general(a, b, (((axes[0],), (axes[1],)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, row0, col0):
+    """Hide s[r, c] where key col0 + c lies after query row0 + r."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows >= cols, s, _mask_val())
 
 
 # ---------------------------------------------------------------------------
@@ -131,39 +193,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
     bq, head_dim = q_ref.shape[0], v_ref.shape[1]
     seq_k = k_ref.shape[0]
     qi = pl.program_id(2)  # q-block index
-    q = q_ref[:].astype(jnp.float32) * jnp.float32(scale)
+    mxu = _operand_dtype(q_ref, k_ref, v_ref)
+    scale = jnp.float32(scale)
+    q = q_ref[:].astype(mxu)
+    if mxu == jnp.float32:
+        q = q * scale  # float32 operands: scaled before the product, as ever
 
     num_kv = seq_k // block_k
     # bottom-right causal alignment for Sq != Sk (the kv-cache/decode
     # convention; matches flash_attention_reference's tril(k=Sk-Sq))
-    q_off = seq_k - pl.num_programs(2) * bq
+    row0 = seq_k - pl.num_programs(2) * bq + qi * bq  # first (aligned) q row
     if causal:
         # only kv blocks whose start <= last (aligned) q row
-        num_kv_dyn = (jnp.int32((qi + 1) * bq + q_off + block_k - 1)
-                      // jnp.int32(block_k))
-        num_kv_dyn = jnp.minimum(num_kv_dyn, num_kv)
+        num_kv_dyn = jnp.minimum(
+            jnp.int32(row0 + bq + block_k - 1) // jnp.int32(block_k), num_kv)
     else:
         num_kv_dyn = jnp.int32(num_kv)
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(mxu)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(mxu)
+        s = _dot(q, k, _NT)  # [bq, bk]
+        if mxu != jnp.float32:
+            s = s * scale  # the raw blocks were multiplied: scale the sums
         if causal:
-            q_pos = q_off + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _mask_val())
+            s = _causal_mask(s, row0, j * block_k)
         m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)  # [bq, bk]
         alpha = jnp.exp(m_prev - m_new)  # [bq, 1]
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc = acc * alpha + _dot(p.astype(mxu), v, _NN)
         return acc, m_new, l_new
 
     acc0 = jnp.zeros((bq, head_dim), jnp.float32)
@@ -185,9 +246,9 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     grid = (batch, num_heads, seq_q // block_q)
     interpret = _pl_utils.interpret()
     if not interpret:
-        isz = q.dtype.itemsize
-        _require_vmem("forward", "seq_k", seq_k, (head_dim + v_dim) * isz,
-                      block_q * (head_dim + v_dim) * isz + block_q * 128 * 4)
+        row = (_at.lane_padded(head_dim) + _at.lane_padded(v_dim)) * q.dtype.itemsize
+        _require_vmem("forward", "seq_k", seq_k, row,
+                      block_q * row + block_q * _LANE_F32)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=block_k),
@@ -220,33 +281,31 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, s
     bq, head_dim = q_ref.shape
     seq_k = k_ref.shape[0]
     qi = pl.program_id(2)
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
+    mxu = _operand_dtype(q_ref, k_ref, v_ref, do_ref)
+    q = q_ref[:].astype(mxu)
+    do = do_ref[:].astype(mxu)
     lse = lse_ref[:, :1]  # [bq, 1]
     delta = delta_ref[:, :1]  # [bq, 1]
     scale = jnp.float32(scale)
 
     num_kv = seq_k // block_k
-    q_off = seq_k - pl.num_programs(2) * bq  # bottom-right alignment
+    row0 = seq_k - pl.num_programs(2) * bq + qi * bq  # bottom-right alignment
     if causal:
         num_kv_dyn = jnp.minimum(
-            jnp.int32((qi + 1) * bq + q_off + block_k - 1) // jnp.int32(block_k),
-            num_kv)
+            jnp.int32(row0 + bq + block_k - 1) // jnp.int32(block_k), num_kv)
     else:
         num_kv_dyn = jnp.int32(num_kv)
 
     def body(j, dq):
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(mxu)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(mxu)
+        s = _dot(q, k, _NT) * scale
         if causal:
-            q_pos = q_off + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _mask_val())
+            s = _causal_mask(s, row0, j * block_k)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return dq + _dot(ds.astype(mxu), k, _NN)
 
     dq = jax.lax.fori_loop(jnp.int32(0), num_kv_dyn, body, jnp.zeros((bq, head_dim), jnp.float32))
     dq_ref[:] = dq.astype(dq_ref.dtype)
@@ -256,8 +315,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     bk, head_dim = k_ref.shape
     seq_q = q_ref.shape[0]
     ki = pl.program_id(2)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
+    mxu = _operand_dtype(q_ref, k_ref, v_ref, do_ref)
+    k = k_ref[:].astype(mxu)
+    v = v_ref[:].astype(mxu)
     scale = jnp.float32(scale)
 
     num_q = seq_q // block_q
@@ -272,20 +332,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
+        q = q_ref[pl.ds(i * block_q, block_q), :].astype(mxu)
+        do = do_ref[pl.ds(i * block_q, block_q), :].astype(mxu)
         lse = lse_ref[pl.ds(i * block_q, block_q), :1]
         delta = delta_ref[pl.ds(i * block_q, block_q), :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, _NT) * scale
         if causal:
-            q_pos = q_off + i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _mask_val())
+            s = _causal_mask(s, q_off + i * block_q, ki * bk)
         p = jnp.exp(s - lse)  # [bq_blk, bk]
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        dv = dv + _dot(p.astype(mxu), do, _TN)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        dk = dk + _dot(ds.astype(mxu), q, _TN)
         return dk, dv
 
     dk0 = jnp.zeros((bk, head_dim), jnp.float32)
@@ -295,30 +353,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
+def _bwd_dq(q, k, v, do, lse_b, delta_b, scale, causal, block_q, block_k):
+    # k, v: one head per query head (the caller repeats a KV group)
     batch, num_heads, seq_q, head_dim = q.shape
-    num_kv_heads, seq_k = k.shape[1], k.shape[2]
-    group = num_heads // num_kv_heads
-    if group > 1:
-        k_rep = jnp.repeat(k, group, axis=1)
-        v_rep = jnp.repeat(v, group, axis=1)
-    else:
-        k_rep, v_rep = k, v
-
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)  # [B,N,Sq]
-    lse_b = jnp.broadcast_to(lse[..., None], (*lse.shape, 128)).astype(jnp.float32)
-    delta_b = jnp.broadcast_to(delta[..., None], (*delta.shape, 128)).astype(jnp.float32)
+    seq_k = k.shape[2]
     interpret = _pl_utils.interpret()
     if not interpret:
-        isz = q.dtype.itemsize
-        lane_f32 = 128 * 4  # one lse / delta row, lane-padded
-        _require_vmem("backward dq", "seq_k", seq_k, 2 * head_dim * isz,
-                      3 * block_q * head_dim * isz + 2 * block_q * lane_f32)
-        _require_vmem("backward dk/dv", "seq_q", seq_q,
-                      2 * head_dim * isz + 2 * lane_f32,
-                      4 * block_k * head_dim * isz)
-
-    dq = pl.pallas_call(
+        row = _at.lane_padded(head_dim) * q.dtype.itemsize
+        _require_vmem("backward dq", "seq_k", seq_k, 2 * row,
+                      3 * block_q * row + 2 * block_q * _LANE_F32)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, block_k=block_k),
         grid=(batch, num_heads, seq_q // block_q),
         in_specs=[
@@ -333,9 +377,18 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k_rep, v_rep, do, lse_b, delta_b)
+    )(q, k, v, do, lse_b, delta_b)
 
-    dk_rep, dv_rep = pl.pallas_call(
+
+def _bwd_dkv(q, k, v, do, lse_b, delta_b, scale, causal, block_q, block_k):
+    batch, num_heads, seq_q, head_dim = q.shape
+    seq_k = k.shape[2]
+    interpret = _pl_utils.interpret()
+    if not interpret:
+        row = _at.lane_padded(head_dim) * q.dtype.itemsize
+        _require_vmem("backward dk/dv", "seq_q", seq_q,
+                      2 * row + 2 * _LANE_F32, 4 * block_k * row)
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q),
         grid=(batch, num_heads, seq_k // block_k),
         in_specs=[
@@ -351,12 +404,45 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
             pl.BlockSpec((None, None, block_k, head_dim), imap(lambda b, n, j: (b, n, j, 0))),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k_rep.shape, k.dtype),
-            jax.ShapeDtypeStruct(v_rep.shape, v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k_rep, v_rep, do, lse_b, delta_b)
+    )(q, k, v, do, lse_b, delta_b)
+
+
+def _row_stats(out, lse, do):
+    """lse and delta = sum(out * dO) per query row [B, N, Sq], float32 and
+    broadcast over 128 lanes: the form both backward kernels read."""
+    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    return tuple(jnp.broadcast_to(x[..., None], (*x.shape, 128)).astype(jnp.float32)
+                 for x in (lse, delta))
+
+
+def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
+    """dq, dk, dv.  `block_q` / `block_k` are the forward's tile: each
+    backward kernel runs the tile the table holds for IT at this shape
+    (its best differs from the forward's), and the forward's where the
+    table holds none."""
+    batch, num_heads, seq_q, head_dim = q.shape
+    num_kv_heads, seq_k = k.shape[1], k.shape[2]
+    group = num_heads // num_kv_heads
+    if group > 1:
+        k_rep = jnp.repeat(k, group, axis=1)
+        v_rep = jnp.repeat(v, group, axis=1)
+    else:
+        k_rep, v_rep = k, v
+
+    lse_b, delta_b = _row_stats(out, lse, do)
+
+    def tile(kernel):
+        return _block_sizes(seq_q, seq_k, head_dim, q.dtype, causal,
+                            kernel=kernel, default=(block_q, block_k))
+
+    args = (q, k_rep, v_rep, do, lse_b, delta_b, scale, causal)
+    dq = _bwd_dq(*args, *tile("flash_bwd_dq"))
+    dk_rep, dv_rep = _bwd_dkv(*args, *tile("flash_bwd_dkv"))
 
     if group > 1:
         dk = dk_rep.reshape(batch, num_kv_heads, group, seq_k, head_dim).sum(axis=2).astype(k.dtype)
@@ -420,7 +506,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
 
     def blocks(sq, sk):
         return _block_sizes(sq, sk, head_dim=qt.shape[-1], dtype=qt.dtype,
-                            causal=causal)
+                            causal=causal, v_dim=vt.shape[-1])
 
     block_q, block_k = blocks(seq_q, seq_k)
     pad = 0
@@ -442,6 +528,10 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
             stacklevel=2,
         )
         return flash_attention_reference(q, k, v, causal=causal, scale=scale)
+    # the counter that says which products this trace asked for
+    compile_cache.count("flash_bf16_operand_traces"
+                        if _operand_dtype(qt, kt, vt) == jnp.bfloat16
+                        else "flash_f32_operand_traces")
     if vt.shape[-1] != qt.shape[-1]:
         out, _ = _fwd(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
     else:

@@ -153,15 +153,41 @@ def test_tuner_end_to_end_with_fake_timer(tmp_cache):
                      slug="testdev") == cfg
 
 
+@pytest.mark.parametrize("kernel", ["flash_bwd_dq", "flash_bwd_dkv"])
+def test_tune_flash_runs_each_backward_kernel_under_its_own_key(tmp_cache,
+                                                                kernel):
+    """The two backward kernels are tuned apart from the forward (their best
+    tiles differ: PERF.md section 6, PR 30): the driver builds and RUNS the
+    kernel it was asked for and records the winner under that kernel's
+    name, where `_block_sizes(kernel=...)` finds it."""
+    from paddle_tpu.ops.flash_attention import _block_sizes
+
+    cfg, ms = _tune_retry(lambda: at.tune_flash(
+        batch=1, num_heads=1, seq=256, head_dim=128, dtype="float32",
+        kernel=kernel, slug=at.device_kind_slug(), iters=1, inner=4))
+    assert ms > 1e-4
+    tile = (cfg["block_q"], cfg["block_k"])
+    assert _block_sizes(256, 256, 128, np.float32, True, kernel=kernel) == tile
+    assert at.lookup("flash_fwd", at.flash_key(256, 256, 128, "float32", True)) is None
+
+
 def test_seeded_v5e_cache_is_well_formed():
+    """Every flash entry, of each of the three kernels, is a valid tile for
+    its shape in the blocks' own type, and a measurement (`ms` > 0): the
+    `"ms": 0.0` seeds of an earlier era are gone."""
     path = os.path.join(os.path.dirname(at.__file__), "tuned", "tpu_v5_lite.json")
     data = json.load(open(path))
-    for key, entry in data["flash_fwd"].items():
-        cfg = entry["config"]
-        dims = dict(kv.split("=") for kv in key.split("|"))
-        assert at.validate_flash_tile(
-            cfg["block_q"], cfg["block_k"],
-            int(dims["seq_q"]), int(dims["seq_k"]), int(dims["head_dim"])) is None
+    assert set(data) <= set(at.FLASH_KERNELS) and "flash_fwd" in data
+    for kernel in data:
+        for key, entry in data[kernel].items():
+            cfg = entry["config"]
+            dims = dict(kv.split("=") for kv in key.split("|"))
+            assert at.validate_flash_tile(
+                cfg["block_q"], cfg["block_k"],
+                int(dims["seq_q"]), int(dims["seq_k"]), int(dims["head_dim"]),
+                dtype=dims["dtype"],
+                v_dim=int(dims["v_dim"]) if "v_dim" in dims else None) is None
+            assert entry["ms"] > 0 and "measured" in entry["meta"]
 
 
 def test_v5p_readiness_geometry_and_peaks(tmp_cache):

@@ -135,3 +135,31 @@ def test_dense_softmax_probe_on_cpu():
     assert (out["dense_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL
             < out["dense_softmax_rms_bfloat16"])
     assert (out["positions_read"], out["positions_live"]) == (3 * 512, 942)
+
+
+@pytest.fixture(scope="module")
+def flash_probe():
+    """Rehearsal 1 of `--flash-softmax` at a twelfth of its lengths: the
+    probe's two shapes keep their widths and head grouping (q/k 192 with v
+    128, forward only; 128 with two query heads a key/value head, forward
+    and backward)."""
+    return chip_smoke.flash_softmax_probe(
+        seed=2147484123, sampled=2,
+        shapes=(("latent prefill", 512, 4, 4, 192, 128, False),
+                ("train step", 512, 4, 2, 128, 128, True)))
+
+
+@pytest.mark.parametrize("result", [
+    "out.latent_prefill", "out.train_step", "dq.train_step", "dk.train_step",
+    "dv.train_step"])
+def test_flash_softmax_probe_on_cpu(flash_probe, result):
+    """The bfloat16 kernels (bfloat16 operands, `p` and `ds` rounded once)
+    read under the limit against a float64 softmax on the same inputs, and
+    the control that rounds the SCORES to bfloat16 reads over it."""
+    assert (flash_probe[f"flash_rms_{result}"] < chip_smoke.SOFTMAX_RMS_TOL
+            < flash_probe[f"bfloat16_scores_rms_{result}"])
+
+
+def test_flash_softmax_probe_reads_the_trace_counters(flash_probe):
+    assert flash_probe["flash_bf16_operand_traces"] == 3  # fwd, fwd, grad
+    assert flash_probe["flash_f32_operand_traces"] == 0

@@ -249,6 +249,87 @@ def test_kernel_jaxpr_no_64bit(name, fn, args):
         assert bad not in jaxpr, f"{name}: {bad} value in kernel trace breaks Mosaic lowering"
 
 
+# ---------------------------------------------------------------------------
+# The operand type of the flash kernels' matrix products follows the
+# inputs' (PR 30): bfloat16 blocks reach the matrix unit as stored, anything
+# else is multiplied in float32 as ever.
+# ---------------------------------------------------------------------------
+
+def _kernel_eqns(jaxpr, kernel=None):
+    """(kernel name, equation) for every equation inside a pallas_call of
+    `jaxpr`, loops and branches included."""
+    for eqn in jaxpr.eqns:
+        inside = eqn.params["name"] if eqn.primitive.name == "pallas_call" else kernel
+        if inside is not None and inside is kernel:
+            yield kernel, eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_eqns(sub, inside)
+
+
+def _flash_jaxpr(dtype, grad, v_width=64):
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    q = jax.ShapeDtypeStruct((1, 256, 4, 64), dtype)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 64), dtype)
+    v = jax.ShapeDtypeStruct((1, 256, 2, v_width), dtype)
+    fn = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)  # noqa: E731
+    if grad:
+        fn = jax.grad(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum(), (0, 1, 2))
+    return jax.make_jaxpr(fn)(q, k, v).jaxpr
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("dtype,operands", [
+    (jnp.float32, jnp.float32), (jnp.float16, jnp.float32),
+    (jnp.bfloat16, jnp.bfloat16)], ids=["float32", "float16", "bfloat16"])
+def test_flash_dot_operands_follow_the_inputs(dtype, operands, grad):
+    """Every product of every kernel takes operands of ONE type with float32
+    sums: the inputs' for bfloat16, float32 for the rest; and a bfloat16
+    kernel widens no block it read (no convert to float32 of a bfloat16
+    value: q, k, v and dO are the only bfloat16 values it has besides the
+    rounded `p` and `ds`), while a float32 kernel holds no bfloat16 at all."""
+    dots = {}
+    for kernel, eqn in _kernel_eqns(_flash_jaxpr(dtype, grad)):
+        if eqn.primitive.name == "dot_general":
+            assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype(operands)}, (kernel, eqn)
+            assert eqn.params["preferred_element_type"] == jnp.float32
+            dots[kernel] = dots.get(kernel, 0) + 1
+        if eqn.primitive.name == "convert_element_type":
+            src, dst = eqn.invars[0].aval.dtype, eqn.params["new_dtype"]
+            if dtype == jnp.bfloat16:
+                assert not (src == jnp.bfloat16 and dst == jnp.float32), (kernel, eqn)
+            else:
+                assert jnp.bfloat16 not in (src, dst), (kernel, eqn)
+    want = {"flash_fwd": 2}
+    if grad:
+        want.update(flash_bwd_dq=3, flash_bwd_dkv=4)
+    assert dots == want  # all nine products were seen
+
+
+def test_flash_counts_its_traces_by_operand_type():
+    """`profiler.compile_stats()` says which products a program was traced
+    with (`flash_bf16_operand_traces`, `flash_f32_operand_traces`), counts
+    one per `flash_attention` call that reached the kernels (V with a width
+    of its own included), and resets with its family."""
+    from paddle_tpu import profiler
+
+    profiler.compile_stats(reset=True)
+    _flash_jaxpr(jnp.bfloat16, grad=True)
+    _flash_jaxpr(jnp.bfloat16, grad=False, v_width=128)
+    _flash_jaxpr(jnp.float32, grad=False)
+    stats = profiler.compile_stats(reset=True)
+    assert (stats["flash_bf16_operand_traces"],
+            stats["flash_f32_operand_traces"]) == (2, 1)
+    stats = profiler.compile_stats()
+    assert (stats["flash_bf16_operand_traces"],
+            stats["flash_f32_operand_traces"]) == (0, 0)
+
+
 def test_flash_block_size_flags():
     """FLAGS_flash_block_q/_k apply only when a positive multiple of 8 that
     divides the sequence; anything else keeps the 128 default, and ragged

@@ -21,6 +21,7 @@ from paddle_tpu.ops import _pl_utils
 # llama_7b widths (models.llama.llama_7b)
 HIDDEN, HEADS, HEAD_DIM, FFN = 4096, 32, 128, 11008
 ROWS = 4096  # tokens per step: batch 2 x seq 2048
+V5E_SLUG = "tpu_v5_lite"  # autotune.device_kind_slug() of a "TPU v5 lite"
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +52,13 @@ def mosaic(monkeypatch):
     described chip can be written there but never read back here."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    from paddle_tpu.ops import autotune
+
     monkeypatch.setattr(_pl_utils, "on_tpu", lambda: True)
+    # ... and to the tile table of the chip being described, which the
+    # kernels would consult there (`ops/tuned/tpu_v5_lite.json`)
+    monkeypatch.setattr(autotune, "device_kind_slug",
+                        lambda device=None: V5E_SLUG)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -88,6 +95,19 @@ def test_flash_attention_compiles(one_chip, mosaic, batch, seq, direction):
     _compile(fn, shape, shape, shape, sharding=one_chip)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_whatever_precision_the_caller_set(
+        one_chip, mosaic, direction):
+    """Mosaic refuses bfloat16 operands at "highest" ("Bad lhs type"), the
+    setting `serving/cluster_worker.py` and the references run under, and a
+    product of two bfloat16 values is exact in float32 at one pass: the
+    kernels ask for that themselves."""
+    fn = _flash if direction == "fwd" else jax.grad(_flash_loss, (0, 1, 2))
+    shape = (1, 1024, HEADS, HEAD_DIM)
+    with jax.default_matmul_precision("highest"):
+        _compile(fn, shape, shape, shape, sharding=one_chip)
+
+
 @pytest.mark.parametrize("seq", [5, 37, 200])
 def test_flash_attention_compiles_for_any_prompt_length(one_chip, mosaic, seq):
     """The serving engine prefills prompts as they come.  A 37-row block is
@@ -110,16 +130,19 @@ def test_flash_attention_names_its_length_limit(one_chip, mosaic, direction,
         _compile(fn, shape, shape, shape, sharding=one_chip)
 
 
-def test_flash_attention_limit_is_the_compilers(one_chip, mosaic):
+@pytest.mark.parametrize("seq", [5248, 4096])
+def test_flash_attention_limit_is_the_compilers(one_chip, mosaic, seq):
     """The stated limit is tight: the longest length the guard admits
     compiles in a program where XLA cannot relieve the kernel (it moves
-    operands of a bare kernel into VMEM itself, which hides the limit)."""
+    operands of a bare kernel into VMEM itself, which hides the limit);
+    so do, at the train cell's 4,096, the tiles the table holds for it
+    (the dk/dv kernel's 1,024 x 256 beside 12 MiB of whole-sequence blocks)."""
     def loss(x, w):
         b, s, d = x.shape
         q, k, v = ((x @ w).reshape(b, s, HEADS, HEAD_DIM) for _ in range(3))
         return _flash_loss(q, k, v)
 
-    x = jax.ShapeDtypeStruct((1, 5248, HIDDEN), jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, seq, HIDDEN), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((HIDDEN, HIDDEN), jnp.bfloat16, sharding=one_chip)
     jax.jit(jax.grad(loss, 1)).lower(x, w).compile()
 
@@ -134,6 +157,95 @@ def test_flash_forward_compiles_with_a_value_width_of_its_own(one_chip, mosaic,
     qk, v = (1, seq, 128, 192), (1, seq, 128, 128)
     compiled = _compile(_flash, qk, qk, v, sharding=one_chip)
     assert f"bf16[1,128,{seq},128]" in compiled.as_text()
+
+
+def _tuned_flash_entries():
+    """(kernel, key fields, tile) of every flash entry of the v5e's table."""
+    import json
+    import os
+
+    from paddle_tpu.ops import autotune
+
+    path = os.path.join(os.path.dirname(autotune.__file__), "tuned",
+                        V5E_SLUG + ".json")
+    with open(path) as f:
+        table = json.load(f)
+    return [(kernel, key, (e["config"]["block_q"], e["config"]["block_k"]),
+             e["ms"])
+            for kernel in autotune.FLASH_KERNELS
+            for key, e in sorted(table.get(kernel, {}).items())]
+
+
+@pytest.mark.parametrize("kernel,key,tile,ms", _tuned_flash_entries(),
+                         ids=lambda v: v if isinstance(v, str) else "")
+def test_every_tuned_flash_tile_is_one_mosaic_accepts(one_chip, mosaic, kernel,
+                                                      key, tile, ms):
+    """A tile recorded in the table was measured on the chip, on THAT
+    kernel: it has its measured time, the kernels find it under the key
+    they build (`_block_sizes`), `validate_flash_tile` admits it in the
+    blocks' own type, and Mosaic compiles the kernel with it (32 heads: a
+    kernel holds one head at a time)."""
+    import importlib
+
+    from paddle_tpu.ops import autotune
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    dims = dict(kv.split("=") for kv in key.split("|"))
+    seq, width = int(dims["seq_q"]), int(dims["head_dim"])
+    v_width, dtype = int(dims.get("v_dim", width)), jnp.dtype(dims["dtype"])
+    causal = dims["causal"] == "True"
+    assert int(dims["seq_k"]) == seq
+    assert ms > 0, "not a measurement"
+    assert fa._block_sizes(seq, seq, width, dtype, causal, v_dim=v_width,
+                           kernel=kernel) == tile
+    assert autotune.validate_flash_tile(*tile, seq, seq, width, dtype=dtype,
+                                        v_dim=v_width) is None
+    q = k = (1, 32, seq, width)
+    v = (1, 32, seq, v_width)
+    if kernel == "flash_fwd":
+        _compile(lambda q, k, v: fa._fwd(q, k, v, 0.1, causal, *tile)[0],
+                 q, k, v, sharding=one_chip, dtype=dtype)
+        return
+    run = {"flash_bwd_dq": fa._bwd_dq, "flash_bwd_dkv": fa._bwd_dkv}[kernel]
+    lanes = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.float32,
+                                 sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+            for s in (q, k, v, v)]
+    compiled = jax.jit(lambda *a: run(*a, 0.1, causal, *tile)).lower(
+        *args, lanes, lanes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_cells_shapes_are_in_the_table():
+    """The shapes the benchmark's cells hand the kernels each have their
+    entry: the train step's three kernels at 4,096 x 128, the latent
+    prefill's three buckets (q/k 192, v 128; forward only)."""
+    have = {(kernel, key) for kernel, key, _, _ in _tuned_flash_entries()}
+    train = "causal=True|dtype=bfloat16|head_dim=128|seq_k=4096|seq_q=4096"
+    assert {(k, train) for k in ("flash_fwd", "flash_bwd_dq",
+                                 "flash_bwd_dkv")} <= have
+    for seq in (2048, 4096, 8192):
+        assert ("flash_fwd", "causal=True|dtype=bfloat16|head_dim=192|"
+                f"seq_k={seq}|seq_q={seq}|v_dim=128") in have
+
+
+def test_flash_tile_validation_counts_the_blocks_own_type():
+    """The latent prefill's longest bucket: K and V of a head are 8,192 x
+    (192 -> 256 lanes + 128) x 2 B, twice = 12 MiB in bfloat16, which fits
+    the 16 MiB with a 128-row tile; reckoned at 4 bytes a value (as the
+    validator did before PR 30, for every type) no tile fits and the
+    table's entry could never be read."""
+    from paddle_tpu.ops import autotune
+
+    at_8k = dict(seq_q=8192, seq_k=8192, head_dim=192, v_dim=128)
+    assert autotune.validate_flash_tile(128, 128, **at_8k,
+                                        dtype=jnp.bfloat16) is None
+    assert "VMEM" in autotune.validate_flash_tile(128, 128, **at_8k,
+                                                  dtype=jnp.float32)
+    assert "VMEM" in autotune.validate_flash_tile(128, 128, **at_8k)
+    assert autotune.flash_candidates(8192, 8192, 192, dtype=jnp.bfloat16,
+                                     v_dim=128)
+    assert autotune.flash_candidates(8192, 8192, 192, v_dim=128) == []
 
 
 @pytest.mark.parametrize("rows,hidden", [(8192, 7680), (32, 7680),
