@@ -12,6 +12,12 @@ from .mla_moe import (  # noqa: F401
     MlaMoeModel,
     mla_moe_tiny,
 )
+from .window_moe import (  # noqa: F401
+    WindowMoeConfig,
+    WindowMoeForCausalLM,
+    WindowMoeModel,
+    window_moe_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForMaskedLM,

@@ -5,11 +5,17 @@ compiled programs (the admission's prefill program, the decode macro-step);
 the model owns the mathematics.  Between them stands a `ServingContract`,
 which a served model returns from `serving_contract()`:
 
-- `spec` — the CACHE SPECIFICATION (`CacheSpec`): per layer, which pools
-  the model keeps and what one token occupies in each (`PoolSpec`: `heads`
-  rows of `width` values, and the type).  The engine allocates, pours,
-  gathers and carries `[num_blocks, heads, block_size, width]` pools from
-  it, all layers alike, and never asks what the values mean.
+- `spec` — the CACHE SPECIFICATION (`CacheSpec`): the model's layers in
+  CACHE CLASSES (`CacheClass`).  A class names its layers, the pools each
+  of them keeps and what one token occupies in each (`PoolSpec`: `heads`
+  rows of `width` values, and the type), and a LIFETIME: paged (every
+  position is kept, in the blocks the allocator hands a request, found
+  through the request's block table) or window(W) (only the last W
+  positions are kept, in a ring of blocks the SLOT owns from the engine's
+  construction on, whatever a request's length).  The engine allocates,
+  pours, gathers and carries `[blocks, heads, block_size, width]` pools
+  from it and never asks what the values mean.  Most models have one
+  paged class of all their layers: `CacheSpec(n_layers, pools)`.
 - `forward_cached` — the prompt's forward pass over naive caches (per layer
   one `[B, S, heads, width]` tensor per pool, the prefix first): the hidden
   state after the final norm and the grown caches.  The prefill program
@@ -21,8 +27,11 @@ which a served model returns from `serving_contract()`:
 - `pool_carry` / `pool_unpack` — per-layer pool lists to and from the form
   the model's `decode` wants to be scanned over (stacked for a LayerStack).
 
-Two layouts of `pools` appear: the engine holds `pools[p][layer]` (one list
-per `PoolSpec`, in `spec.pools` order); caches are `caches[layer][p]`.
+Two layouts of `pools` appear: the engine holds `pools[p][i]` (one list per
+`PoolSpec`, in `spec.pools` order: class after class, each class's pools
+in order; `i` counts the class's layers in `CacheClass.layers` order);
+caches are `caches[layer][p]`, `layer` the model's own index and `p` over
+that layer's class's pools.
 
 `forward_cached` and `decode` also return `aux`, a dict of int32 scalars
 the DEVICE counted for this call (an expert layer's assignments, ...), `{}`
@@ -36,15 +45,17 @@ the model which rows are committed work; masked rows are not counted.
 Engine features built for K/V pools (int8 pool, prefix cache, chunked and
 interleaved prefill, LoRA slots, speculation, a mesh, snapshot / park,
 page shipping) ask `spec.kv_pair` and refuse any other specification by
-name (docs/DECODE.md "The model contract"); the optional methods below
-serve those features and need no implementation elsewhere.
+name — a latent pool, and any specification with a window class, whose
+ring none of them may treat as pages (docs/DECODE.md "The model
+contract", "Cache classes and the window ring"); the optional methods
+below serve those features and need no implementation elsewhere.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["PoolSpec", "CacheSpec", "ServingContract"]
+__all__ = ["PoolSpec", "CacheClass", "CacheSpec", "ServingContract"]
 
 
 @dataclass(frozen=True)
@@ -59,15 +70,79 @@ class PoolSpec:
 
 
 @dataclass(frozen=True)
+class CacheClass:
+    """The layers of a model that keep the same `pools` for the same
+    LIFETIME.  `layers`: their indices in the model, ascending.  `window`
+    None is the PAGED lifetime: every position of a request is kept, in
+    blocks the engine's allocator hands the request for its prompt and
+    output, and position t lives in block `table[t // block_size]` of the
+    request's block table.  `window` W keeps only the last W positions: the
+    class's pools hold a RING of `ring_blocks` blocks for each engine slot,
+    assigned when the engine is built and never allocated by request
+    length; position t lives in ring block `(t // block_size) % ring_blocks`
+    and overwrites position t - ring_blocks * block_size.  Pool names are
+    unique over a specification's classes (`<name>_pool_bytes`)."""
+    layers: tuple
+    pools: tuple
+    window: int | None = None
+
+    def ring_blocks(self, block_size: int) -> int:
+        """Blocks of a slot's ring: a query at position t reads t - W + 1
+        .. t, which lie in at most ceil((W - 1) / block_size) + 1 blocks
+        wherever t falls in its own (each decode token step writes ONE
+        position, then reads)."""
+        return -(-(self.window - 1) // block_size) + 1
+
+
+@dataclass(frozen=True)
 class CacheSpec:
-    """`n_layers` layers, each with the same `pools`."""
+    """A model's cache: `classes`, each a `CacheClass`.  `CacheSpec(n_layers,
+    pools)` is the common case, ONE paged class of all `n_layers` layers
+    with the same `pools`; `CacheSpec.of(classes)` takes several.  `pools`
+    is then every class's pools in order, `n_layers` the model's depth."""
     n_layers: int
     pools: tuple
+    classes: tuple = ()
+
+    def __post_init__(self):
+        if not self.classes:
+            object.__setattr__(self, "classes", (CacheClass(
+                tuple(range(self.n_layers)), tuple(self.pools)),))
+        names = [p.name for p in self.pools]
+        if len(set(names)) != len(names):
+            raise ValueError(f"pool names must be unique: {names}")
+
+    @classmethod
+    def of(cls, classes):
+        classes = tuple(classes)
+        layers = sorted(i for c in classes for i in c.layers)
+        if layers != list(range(len(layers))):
+            raise ValueError(f"classes must cover layers 0..n once: {layers}")
+        return cls(len(layers), tuple(p for c in classes for p in c.pools),
+                   classes)
+
+    @property
+    def windowed(self) -> bool:
+        """Some class keeps only a window (its pools are rings, not pages)."""
+        return any(c.window is not None for c in self.classes)
+
+    @property
+    def per_class_tables(self) -> bool:
+        """`decode` takes a TUPLE of tables, one a class, and not the one
+        block table: several classes, or a window class (whose table is the
+        slots' rings, never the requests' pages), even a single one."""
+        return len(self.classes) > 1 or self.windowed
+
+    def class_of(self, layer: int) -> CacheClass:
+        return next(c for c in self.classes if layer in c.layers)
 
     @property
     def kv_pair(self) -> bool:
-        """A K pool and a V pool of one shape: what the engine's optional
-        features (and ops/paged_attention's attention) were built for."""
+        """ONE paged class with a K pool and a V pool of one shape: what the
+        engine's optional features (and ops/paged_attention's attention)
+        were built for."""
+        if len(self.classes) != 1 or self.windowed:
+            return False
         if [p.name for p in self.pools] != ["k", "v"]:
             return False
         k, v = self.pools
@@ -93,10 +168,20 @@ class ServingContract:
 
     def decode(self, tokens, pools, tables, lens, active=None, **kv_only):
         """tokens [B, T] int32 (T == 1 unless `chunk=True`); pools in carry
-        form; tables [B, W]; lens [B] INCLUDING these tokens; active [B]
-        bool or None.  Returns (hidden Tensor [B, T, h] after the final
-            norm, pools, aux).  `kv_only`: chunk, adapters, slots, scaling,
-        passed only by features a K/V specification admits."""
+        form; lens [B] INCLUDING these tokens; active [B] bool or None.
+        `tables`: where a row's positions live.  For a specification of
+        ONE PAGED class it is that class's table, [B, W] int32: row b's
+        position t is in block `tables[b, t // block_size]` (an inactive
+        row's entries all name its slot's scratch page).  For any other
+        specification (`spec.per_class_tables`: several classes, or a
+        window class, even alone) it is a tuple with one table a class, in
+        `spec.classes` order: a paged class's as above, a window class's
+        [B, ring_blocks], the ring of each row's SLOT (position t in block
+        `table[b, (t // block_size) % ring_blocks]`; the same for active
+        and inactive rows, since a ring is never shared).  Returns (hidden
+        Tensor [B, T, h] after the final norm, pools, aux).  `kv_only`:
+        chunk, adapters, slots, scaling, passed only by features a K/V
+        specification admits."""
         raise NotImplementedError
 
     def logits(self, h):

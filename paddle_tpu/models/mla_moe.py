@@ -28,12 +28,13 @@ form against the paged latent pool: q~_i = q_nope_i W_kvb^K_i [r], score =
 q~_i.c + q_rope_i.k_rope, output (sum_s p c) W_kvb^V_i — all N query heads
 read one shared row, whose first r lanes are also the value.
 
-The routed-expert layer (`routed_experts`) is DROPLESS and is told which
-contiguous range of experts it holds (`held = (first, count)`): it routes
-over all `n_routed_experts`, normalises over the k chosen wherever they
-live, computes only its own experts' part (plus the shared expert) and
-passes that partial result on — one chip's share of an expert-parallel
-layer, without the exchange.  Nothing stands in for the absent chips.
+The routed-expert layer (`routed_experts`, models/experts.py, shared with
+models/window_moe.py) is DROPLESS and is told which contiguous range of
+experts it holds (`held = (first, count)`): it routes over all
+`n_routed_experts`, normalises over the k chosen wherever they live,
+computes only its own experts' part (plus the shared expert) and passes
+that partial result on — one chip's share of an expert-parallel layer,
+without the exchange.  Nothing stands in for the absent chips.
 
 Rope pairs adjacent lanes (2i, 2i+1), as ops/paged_attention.rope_rotate_chunk
 does for every model here; no rope scaling.  The angles of the positions a
@@ -57,20 +58,19 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu._core.tensor import Tensor
 from paddle_tpu.models.contract import CacheSpec, PoolSpec, ServingContract
+# the expert layer is shared with models/window_moe.py (one implementation);
+# `route`, `routed_experts` and `EXPERT_TILE` stay importable from here
+from paddle_tpu.models.experts import (EXPERT_TILE, RoutedExperts,  # noqa: F401
+                                       SwiGLU, add_counts, route,
+                                       routed_experts)
 from paddle_tpu.ops import paged_attention as pa
 
 __all__ = ["MlaMoeConfig", "MlaMoeForCausalLM", "MlaMoeModel", "route",
            "routed_experts", "absorbed_attention", "mla_moe_tiny"]
-
-# rows of one expert processed per pass of the expert loop (prefill); a
-# decode step's pass is its whole batch
-EXPERT_TILE = 256
-
 
 @dataclass
 class MlaMoeConfig:
@@ -109,146 +109,7 @@ class MlaMoeConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
 
-# ------------------------------------------------------------ routed experts
-
-def route(m, router_w, *, top_k, scale, normalize=True):
-    """The router: m [T, h], router_w [h, E] -> (chosen [T, k] int32, w [T, k]
-    float32).  s = sigmoid(m W_r) over ALL E experts, in float32 with the
-    product at highest precision whatever the types handed in (on a TPU a
-    float32 product is bfloat16 passes by default, and a score's eighth and
-    ninth expert lie within that rounding of each other for one token in
-    six); the k best; w_e = scale * s_e / (sum of the k chosen scores)."""
-    with jax.named_scope("moe.route"):
-        logits = jnp.dot(m.astype(jnp.float32), router_w.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
-        w = top_s
-        if normalize:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return top_i.astype(jnp.int32), w * jnp.float32(scale)
-
-
-def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
-                   normalize=True, active=None, tile=EXPERT_TILE):
-    """The held experts' part of a routed-expert layer, dropless.
-
-    m [T, h]; router_w [h, E] (E: ALL experts); gate_up and down: `count`
-    matrices each, [h, 2f] and [f, h], the weights of experts first ..
-    first + count - 1 (one array an expert: an expert nobody chose is then
-    an operand nobody reads); active [T] bool or None (rows that are not committed work route
-    nowhere and are not counted).  Returns (out [T, h] float32, counts):
-    out = sum over each row's chosen experts THAT ARE HELD of w_e E_e(m),
-    w normalised over all top_k chosen; counts = the int32 scalars
-    assignments, held, peak (rows on the busiest held expert), touched
-    (held experts with a row) and layer_steps (1 if any row is live).
-
-    Static shapes throughout, so it runs inside the macro-step's scan and
-    the prefill program: the (row, choice) pairs are sorted by held expert
-    (one stable argsort; pairs of absent experts sort behind), and each
-    held expert runs ceil(rows / tile) passes of `tile` rows of its
-    contiguous range — a while loop whose trip count is data, so an expert
-    nobody chose reads no weight, and no row is ever dropped whatever the
-    router does."""
-    first, count = held
-    t = m.shape[0]
-    top_i, w = route(m, router_w, top_k=top_k, scale=scale,
-                     normalize=normalize)
-    with jax.named_scope("moe.route"):
-        local = top_i - first
-        mine = (local >= 0) & (local < count)
-        live = jnp.ones((t,), bool) if active is None else active
-        mine = mine & live[:, None]
-        key = jnp.where(mine, local, count).reshape(-1)         # [T*k]
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        rows_of = order // top_k          # the row of each sorted pair
-        w_of = w.reshape(-1)[order]
-        per = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
-                      dtype=jnp.int32)                          # [count]
-        start = jnp.cumsum(per) - per
-    tile = min(tile, t)
-    n_pairs = t * top_k
-
-    def expert_pass(e, out):
-        w_gu, w_d = gate_up[e], down[e]
-        f = w_d.shape[0]
-
-        def one_tile(i, out):
-            at = start[e] + i * tile + jnp.arange(tile, dtype=jnp.int32)
-            ok = at < start[e] + per[e]
-            at = jnp.minimum(at, n_pairs - 1)
-            rows = rows_of[at]
-            x = m[rows]                                         # [tile, h]
-            gu = jnp.dot(x, w_gu, preferred_element_type=jnp.float32)
-            act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(m.dtype)
-            y = jnp.dot(act, w_d, preferred_element_type=jnp.float32)
-            y = y * jnp.where(ok, w_of[at], 0.0)[:, None]
-            return out.at[rows].add(y)
-
-        return jax.lax.fori_loop(0, -(-per[e] // tile), one_tile, out)
-
-    out = jnp.zeros((t, m.shape[1]), jnp.float32)
-    with jax.named_scope("moe.experts"):
-        for e in range(count):
-            out = expert_pass(e, out)
-    counts = {"assignments": jnp.sum(live, dtype=jnp.int32) * top_k,
-              "held": jnp.sum(per), "peak": jnp.max(per),
-              "touched": jnp.sum(per > 0, dtype=jnp.int32),
-              "layer_steps": jnp.any(live).astype(jnp.int32)}
-    return out, counts
-
-
 # ------------------------------------------------------------------ layers
-
-class SwiGLU(nn.Layer):
-    """silu(x W_g) * (x W_u) -> W_d, gate and up fused into one matmul: the
-    dense layers' FFN and the shared expert (the Pallas swiglu kernel on a
-    TPU, as models/llama.LlamaMLP)."""
-
-    def __init__(self, hidden, width):
-        super().__init__()
-        self.gate_up_proj = nn.Linear(hidden, 2 * width, bias_attr=False)
-        self.down_proj = nn.Linear(width, hidden, bias_attr=False)
-
-    def forward(self, x):
-        gate, up = paddle.split(self.gate_up_proj(x), 2, axis=-1)
-        from paddle_tpu import ops as _ops
-
-        if _ops.use_pallas():
-            import paddle_tpu.incubate.nn.functional as _FF
-
-            return self.down_proj(_FF.swiglu(gate, up))
-        return self.down_proj(F.silu(gate) * up)
-
-
-class RoutedExperts(nn.Layer):
-    """The expert layer's FFN: router over all experts, the held experts'
-    stacked weights, the shared expert.  `forward` returns (f, counts)."""
-
-    def __init__(self, config: MlaMoeConfig):
-        super().__init__()
-        h, f = config.hidden_size, config.moe_intermediate_size
-        self.config = config
-        count = config.held[1]
-        self.gate = nn.Linear(h, config.n_routed_experts, bias_attr=False)
-        # experts[i] is routed expert held[0] + i; only its weights are used
-        self.experts = nn.LayerList([SwiGLU(h, f) for _ in range(count)])
-        self.shared_experts = SwiGLU(h, f * config.n_shared_experts)
-
-    def forward(self, m, active=None):
-        cfg = self.config
-        shape = m.shape
-        flat = m._value.reshape(-1, shape[-1])
-        routed, counts = routed_experts(
-            flat, self.gate.weight._value,
-            [e.gate_up_proj.weight._value for e in self.experts],
-            [e.down_proj.weight._value for e in self.experts], held=cfg.held,
-            top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
-            normalize=cfg.norm_topk_prob, active=active)
-        with jax.named_scope("moe.shared"):
-            shared = self.shared_experts(m)
-        f = routed.reshape(shape) + shared._value.astype(jnp.float32)
-        return Tensor(f.astype(m._value.dtype)), counts
-
 
 class LatentAttention(nn.Layer):
     def __init__(self, config: MlaMoeConfig):
@@ -399,8 +260,14 @@ class MlaMoeDecoderLayer(nn.Layer):
         h, eps = config.hidden_size, config.rms_norm_eps
         self.dense = dense
         self.self_attn = LatentAttention(config)
+        f = config.moe_intermediate_size
         self.mlp = (SwiGLU(h, config.intermediate_size) if dense
-                    else RoutedExperts(config))
+                    else RoutedExperts(
+                        h, f, routed=config.n_routed_experts, held=config.held,
+                        top_k=config.num_experts_per_tok,
+                        scale=config.routed_scaling_factor,
+                        normalize=config.norm_topk_prob, scoring="sigmoid",
+                        shared_width=f * config.n_shared_experts))
         self.input_layernorm = nn.RMSNorm(h, eps)
         self.pre_mlp_layernorm = nn.RMSNorm(h, eps)
         if config.sandwich_norm:
@@ -460,19 +327,11 @@ class MlaMoeModel(nn.Layer):
             out, row = layer.self_attn.prefill(layer.input_layernorm(h))
             h, counts = layer.finish(h, out, active)
             rows.append(row)
-            totals = _add_counts(totals, counts)
+            totals = add_counts(totals, counts)
         return self.norm(h), rows, totals
 
     def forward(self, input_ids):
         return self.forward_prefill(input_ids)[0]
-
-
-def _add_counts(totals, counts):
-    if counts is None:
-        return totals
-    if totals is None:
-        return counts
-    return {k: totals[k] + v for k, v in counts.items()}
 
 
 class MlaMoeForCausalLM(nn.Layer):
@@ -537,7 +396,7 @@ class MlaMoeServing(ServingContract):
                                                pool, tables, lens)
             h, counts = layer.finish(h, out, active)
             new.append(pool)
-            totals = _add_counts(totals, counts)
+            totals = add_counts(totals, counts)
         aux = {} if totals is None else {
             "moe_assignments": totals["assignments"],
             "moe_held_assignments": totals["held"],

@@ -409,24 +409,30 @@ def flash_candidates(seq_q, seq_k, head_dim, *, dtype=None, v_dim=None):
 FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 
-def flash_key(seq_q, seq_k, head_dim, dtype, causal, v_dim=None):
+def flash_key(seq_q, seq_k, head_dim, dtype, causal, v_dim=None, window=None):
     """The table key of a flash kernel's shape signature; `v_dim` is part
     of it only where V has a width of its own (latent attention's
-    prefill)."""
+    prefill), `window` only where the forward takes one (a sliding
+    layer's prefill)."""
     import numpy as np
 
     key = {"seq_q": seq_q, "seq_k": seq_k, "head_dim": head_dim,
            "dtype": np.dtype(dtype).name, "causal": bool(causal)}
     if v_dim not in (None, head_dim):
         key["v_dim"] = v_dim
+    if window is not None:
+        key["window"] = int(window)
     return key
 
 
 def tune_flash(batch=1, num_heads=8, seq=2048, head_dim=128, dtype="bfloat16",
-               causal=True, v_dim=None, kernel="flash_fwd", **kw):
+               causal=True, v_dim=None, window=None, num_kv_heads=None,
+               kernel="flash_fwd", **kw):
     """Tune one flash-attention kernel's tile for one shape signature: the
-    forward (`v_dim` where V has a width of its own) or either backward
-    kernel, each under its own table key (`FLASH_KERNELS`).  What the
+    forward (`v_dim` where V has a width of its own, `window` for a
+    sliding layer's prefill, `num_kv_heads` for grouped K/V heads) or
+    either backward kernel, each under its own table key
+    (`FLASH_KERNELS`).  What the
     Mosaic compiler refuses (a tile whose blocks overflow VMEM) is
     skipped by `tune_kernel`."""
     import jax
@@ -439,18 +445,24 @@ def tune_flash(batch=1, num_heads=8, seq=2048, head_dim=128, dtype="bfloat16",
     fa = importlib.import_module("paddle_tpu.ops.flash_attention")
 
     jd = jnp.dtype(dtype)
-    key = flash_key(seq, seq, head_dim, jd, causal, v_dim)
+    key = flash_key(seq, seq, head_dim, jd, causal, v_dim, window)
+    if window is not None and kernel != "flash_fwd":
+        raise ValueError("the backward kernels take no window")
     scale = 1.0 / head_dim ** 0.5
     widths = (head_dim, head_dim, v_dim or head_dim, v_dim or head_dim)
+    heads = (num_heads, num_kv_heads or num_heads, num_kv_heads or num_heads,
+             num_heads)
     q, k, v, do = (
-        jax.random.normal(r, (batch, num_heads, seq, w), jd)
-        for r, w in zip(jax.random.split(jax.random.PRNGKey(0), 4), widths))
+        jax.random.normal(r, (batch, n, seq, w), jd)
+        for r, n, w in zip(jax.random.split(jax.random.PRNGKey(0), 4), heads,
+                           widths))
     if kernel == "flash_fwd":
         args = (q, k, v)
 
         def build(cfg):
             return jax.jit(lambda q, k, v: fa._fwd(
-                q, k, v, scale, causal, cfg["block_q"], cfg["block_k"])[0])
+                q, k, v, scale, causal, cfg["block_q"], cfg["block_k"],
+                window)[0])
     else:
         out, lse = fa._fwd(q, k, v, scale, causal, *fa._block_sizes(
             seq, seq, head_dim, jd, causal))
@@ -580,7 +592,8 @@ def tune_matmul_epilogue(m=4096, k=4096, n=4096, dtype="bfloat16", **kw):
 # The benchmark's cells first (PERF.md section 4): a short on-chip budget
 # tunes exactly the shapes they run before the generic ones — the train
 # cell's three flash kernels at 1 x 4096 x 32 heads x 128, the latent
-# model's prefill buckets (128 heads, q/k 192, v 128, forward only).
+# model's prefill buckets (128 heads, q/k 192, v 128, forward only), the
+# window / full model's (48 and 72 heads over 8, forward only).
 _STANDARD_SHAPES = {
     "flash": [
         *(dict(seq=4096, head_dim=128, num_heads=32, kernel=k)
@@ -589,6 +602,15 @@ _STANDARD_SHAPES = {
           for s in (8192, 4096, 2048)),
         *(dict(seq=2048, head_dim=128, num_heads=32, kernel=k)
           for k in FLASH_KERNELS),
+        # models/window_moe.py's prefill buckets (laguna-s-2.1): 48 query
+        # heads on the full layers, 72 under a window of 512 on the sliding
+        # ones, 8 K/V heads, forward only.  The full layers' 2,048 and
+        # 4,096 share their table key with the 32-head shapes above (the
+        # key has no head count: the grid is parallel over heads)
+        *(dict(seq=s, head_dim=128, num_heads=48, num_kv_heads=8)
+          for s in (8192, 4096, 2048)),
+        *(dict(seq=s, head_dim=128, num_heads=72, num_kv_heads=8, window=512)
+          for s in (8192, 4096, 2048)),
     ],
     "norm": [
         dict(rows=4096, hidden=2048), dict(rows=8192, hidden=2048),

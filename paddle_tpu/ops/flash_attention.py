@@ -39,7 +39,7 @@ def _mask_val():
 
 
 def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False, *,
-                 v_dim=None, kernel="flash_fwd", default=None):
+                 v_dim=None, window=None, kernel="flash_fwd", default=None):
     """(block_q, block_k) for one of the three kernels, in precedence order
     (reference phi/kernels/autotune/cache.h consults its config cache the
     same way):
@@ -49,10 +49,12 @@ def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False, *,
        fallbacks);
     2. the per-device-kind autotune table (ops/autotune.py,
        ops/tuned/<device>.json) under `kernel` for this (seq, head_dim,
-       dtype, causal) signature.  Its v5e entries were measured on the chip
-       in PR 30, on the kernels whose products take bfloat16 operands, at
-       the shapes the benchmark's cells run; each backward kernel has
-       entries of its own (`flash_bwd_dq`, `flash_bwd_dkv`);
+       dtype, causal) signature (and `window`, where the forward takes
+       one: a windowed row meets few key blocks, so its best tile differs).
+       Its v5e entries were measured on the chip (PRs 30 and 31), on the
+       kernels whose products take bfloat16 operands, at the shapes the
+       benchmark's cells run; each backward kernel has entries of its own
+       (`flash_bwd_dq`, `flash_bwd_dkv`);
     3. `default`: the forward's tile, for a backward kernel the table does
        not know; else 128 x 128, the untuned shapes' tile: safe for every
        type and length the kernels take, and 3 to 4.7 times slower than the
@@ -87,7 +89,7 @@ def _block_sizes(seq_q, seq_k, head_dim=128, dtype=None, causal=False, *,
     # 2. autotune cache
     key = _at.flash_key(seq_q, seq_k, head_dim,
                         dtype if dtype is not None else "bfloat16", causal,
-                        v_dim)
+                        v_dim, window)
     tuned = _at.lookup(kernel, key)
     if tuned:
         bq, bk = int(tuned["block_q"]), int(tuned["block_k"])
@@ -112,25 +114,33 @@ _MOSAIC_SCRATCH = 32 << 10
 _LANE_F32 = 128 * 4  # one lse / delta row, lane-padded
 
 
-def _require_vmem(kernel, seq_name, seq, row_bytes, tile_bytes):
+def _require_vmem(kernel, seq_name, seq, row_bytes, block, block_row_bytes):
     """Mosaic branch only: these kernels keep one head's WHOLE sequence
     resident in VMEM (K and V in the forward and dq kernels; Q, dO, lse
     and delta in the dk/dv kernel) and Pallas double-buffers every block,
     so past a length the chip's compiler refuses the kernel with a
-    scoped-VMEM allocation dump.  Name the limit instead.  No switch to
+    scoped-VMEM allocation dump.  Name the limit instead.  A `window`
+    does not lift it: the windowed forward visits only the key blocks its
+    window reaches, but K and V are still resident whole.  No switch to
     the O(S^2) reference: a caller that needs longer sequences shards
     them (context_parallel_llama) until a streaming-K/V kernel exists."""
     budget = _at._VMEM_BUDGET
-    need = 2 * (seq * row_bytes + tile_bytes) + _MOSAIC_SCRATCH
+    need = 2 * (seq * row_bytes + block * block_row_bytes) + _MOSAIC_SCRATCH
     if need <= budget:
         return
-    longest = ((budget - _MOSAIC_SCRATCH) // 2 - tile_bytes) // row_bytes
+    # the longest at the untuned 128-row tile: a property of the kernel and
+    # the widths, not of the tile this call happened to bring (a backward
+    # kernel with no entry of its own inherits the forward's, which may be
+    # 512 or 1,024 rows)
+    longest = (((budget - _MOSAIC_SCRATCH) // 2
+                - min(block, 128) * block_row_bytes) // row_bytes)
     raise ValueError(
         f"flash_attention ({kernel} kernel): {seq_name}={seq} needs "
         f"{need / (1 << 20):.2f} MiB of VMEM for its whole-sequence blocks "
         f"(double-buffered) but Mosaic's scoped-VMEM limit is "
         f"{budget >> 20} MiB; the longest {seq_name} this kernel "
-        f"compiles for at these widths is {longest // 128 * 128}. "
+        f"compiles for at these widths is {longest // 128 * 128} "
+        "(with or without a window: K and V stay resident whole). "
         "Shard the sequence (context parallelism) or shorten it.")
 
 
@@ -174,11 +184,16 @@ def _dot(a, b, axes):
                                preferred_element_type=jnp.float32)
 
 
-def _causal_mask(s, row0, col0):
-    """Hide s[r, c] where key col0 + c lies after query row0 + r."""
+def _causal_mask(s, row0, col0, window=None):
+    """Hide s[r, c] where key col0 + c lies after query row0 + r or, with
+    a `window`, at or before row0 + r - window (a query sees itself and
+    the window - 1 keys before it)."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, _mask_val())
+    seen = rows >= cols
+    if window is not None:
+        seen = seen & (cols > rows - jnp.int32(window))
+    return jnp.where(seen, s, _mask_val())
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +201,8 @@ def _causal_mask(s, row0, col0):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k,
+                window=None):
     # q_ref: [bq, H]; k_ref: [S, H]; v_ref: [S, Hv]; o_ref: [bq, Hv];
     # lse_ref: [bq, 128].  Hv == H everywhere but latent attention's prefill
     # (q/k 192 = nope 128 + rope 64, v 128: models/mla_moe.py)
@@ -209,6 +225,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
             jnp.int32(row0 + bq + block_k - 1) // jnp.int32(block_k), num_kv)
     else:
         num_kv_dyn = jnp.int32(num_kv)
+    # with a window, start at the first kv block the block's FIRST row still
+    # reaches (keys > row0 - window): the blocks before it are never read
+    first_kv = jnp.int32(0) if window is None else (
+        jnp.maximum(jnp.int32(row0 - window + 1), 0) // jnp.int32(block_k))
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
@@ -218,7 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
         if mxu != jnp.float32:
             s = s * scale  # the raw blocks were multiplied: scale the sums
         if causal:
-            s = _causal_mask(s, row0, j * block_k)
+            s = _causal_mask(s, row0, j * block_k, window)
         m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)  # [bq, bk]
@@ -230,7 +250,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
     acc0 = jnp.zeros((bq, head_dim), jnp.float32)
     m0 = jnp.full((bq, 1), DEFAULT_MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(jnp.int32(0), num_kv_dyn, body, (acc0, m0, l0))
+    acc, m, l = jax.lax.fori_loop(first_kv, num_kv_dyn, body, (acc0, m0, l0))
 
     l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
     o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
@@ -238,7 +258,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
     lse_ref[:] = jnp.broadcast_to(lse, lse_ref.shape)
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k):
+def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     # q: [B, N, Sq, H]; k: [B, Nkv, Sk, H]; v: [B, Nkv, Sk, Hv]
     batch, num_heads, seq_q, head_dim = q.shape
     num_kv_heads, seq_k, v_dim = k.shape[1], k.shape[2], v.shape[3]
@@ -247,11 +267,12 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     interpret = _pl_utils.interpret()
     if not interpret:
         row = (_at.lane_padded(head_dim) + _at.lane_padded(v_dim)) * q.dtype.itemsize
-        _require_vmem("forward", "seq_k", seq_k, row,
-                      block_q * row + block_q * _LANE_F32)
+        _require_vmem("forward", "seq_k", seq_k, row, block_q,
+                      row + _LANE_F32)
 
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=block_k),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          block_k=block_k, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, head_dim), imap(lambda b, n, i: (b, n, i, 0))),
@@ -360,8 +381,8 @@ def _bwd_dq(q, k, v, do, lse_b, delta_b, scale, causal, block_q, block_k):
     interpret = _pl_utils.interpret()
     if not interpret:
         row = _at.lane_padded(head_dim) * q.dtype.itemsize
-        _require_vmem("backward dq", "seq_k", seq_k, 2 * row,
-                      3 * block_q * row + 2 * block_q * _LANE_F32)
+        _require_vmem("backward dq", "seq_k", seq_k, 2 * row, block_q,
+                      3 * row + 2 * _LANE_F32)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, block_k=block_k),
         grid=(batch, num_heads, seq_q // block_q),
@@ -387,7 +408,7 @@ def _bwd_dkv(q, k, v, do, lse_b, delta_b, scale, causal, block_q, block_k):
     if not interpret:
         row = _at.lane_padded(head_dim) * q.dtype.itemsize
         _require_vmem("backward dk/dv", "seq_q", seq_q,
-                      2 * row + 2 * _LANE_F32, 4 * block_k * row)
+                      2 * row + 2 * _LANE_F32, block_k, 4 * row)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q),
         grid=(batch, num_heads, seq_k // block_k),
@@ -457,18 +478,24 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bnsh(q, k, v, scale, causal, block_q, block_k):
-    out, _ = _fwd(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bnsh(q, k, v, scale, causal, block_q, block_k, window=None):
+    out, _ = _fwd(q, k, v, scale, causal, block_q, block_k, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k):
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, window):
+    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, res, do):
+def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, do):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention: the backward kernels take no window (window="
+            f"{window}): only serving's prefill runs windowed layers today; "
+            "train them through flash_attention_reference(window=) or add "
+            "the window to _bwd_dq_kernel / _bwd_dkv_kernel (ROADMAP R4)")
     q, k, v, out, lse = res
     return _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k)
 
@@ -484,10 +511,18 @@ def _ragged(seq_q, seq_k, block_q, block_k):
                 or block_q % 8 or block_k % 8)
 
 
-def flash_attention(q, k, v, *, causal=False, scale=None):
+def flash_attention(q, k, v, *, causal=False, scale=None, window=None):
     """Blockwise flash attention.  q/k/v: [B, S, N, H] (paddle layout).
     v may have a width of its own ([B, S, N, Hv]; the output takes it):
     that case is forward-only, the backward kernels keep one width.
+
+    `window` W (causal only): a query sees itself and the W - 1 keys
+    before it.  The forward's loop over key blocks starts at the first
+    block the window reaches, so a sliding layer's prefill at 8,192 with
+    W = 512 does about an eighth of the products; K and V are still
+    resident in VMEM whole, so a window does NOT lift the whole-sequence
+    limit (`_require_vmem`).  Forward only: differentiating a windowed
+    call raises NotImplementedError by name.
 
     Lengths the kernels cannot tile (see _ragged): causal self-attention
     (Sq == Sk, e.g. a prompt of any length) is zero-padded to a multiple of
@@ -499,6 +534,12 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: window= needs causal=True")
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"flash_attention: window={window} must be >= 1")
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -506,7 +547,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
 
     def blocks(sq, sk):
         return _block_sizes(sq, sk, head_dim=qt.shape[-1], dtype=qt.dtype,
-                            causal=causal, v_dim=vt.shape[-1])
+                            causal=causal, v_dim=vt.shape[-1], window=window)
 
     block_q, block_k = blocks(seq_q, seq_k)
     pad = 0
@@ -527,19 +568,23 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
             "multiple of 128 for the Pallas kernel.",
             stacklevel=2,
         )
-        return flash_attention_reference(q, k, v, causal=causal, scale=scale)
+        return flash_attention_reference(q, k, v, causal=causal, scale=scale,
+                                         window=window)
     # the counter that says which products this trace asked for
     compile_cache.count("flash_bf16_operand_traces"
                         if _operand_dtype(qt, kt, vt) == jnp.bfloat16
                         else "flash_f32_operand_traces")
     if vt.shape[-1] != qt.shape[-1]:
-        out, _ = _fwd(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
+        out, _ = _fwd(qt, kt, vt, float(scale), bool(causal), block_q, block_k,
+                      window)
     else:
-        out = _flash_bnsh(qt, kt, vt, float(scale), bool(causal), block_q, block_k)
+        out = _flash_bnsh(qt, kt, vt, float(scale), bool(causal), block_q,
+                          block_k, window)
     return jnp.swapaxes(out[:, :, :seq_q], 1, 2)
 
 
-def flash_attention_reference(q, k, v, *, causal=False, scale=None):
+def flash_attention_reference(q, k, v, *, causal=False, scale=None,
+                              window=None):
     """Pure-jnp oracle with identical semantics ([B, S, N, H] layout)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -554,6 +599,9 @@ def flash_attention_reference(q, k, v, *, causal=False, scale=None):
     if causal:
         qlen, klen = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), bool), k=klen - qlen)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((qlen, klen), bool),
+                                    k=klen - qlen - int(window))
         logits = jnp.where(mask, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bnqk,bnkh->bnqh", probs, vt)

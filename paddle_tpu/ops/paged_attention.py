@@ -27,6 +27,13 @@ table's width), by a lax.switch whose branches differ only in
 `block_tables[:, :w]`; masked positions contribute exactly 0, so every
 branch that covers the longest row computes the same numbers.
 `attn_positions` says what a step read and what was live.
+
+A WINDOW class of a model's cache (models/contract.py: only the last W
+positions of a row are kept) lives in a per-slot RING of pool blocks and is
+read by `paged_window_attention`: the ring's pages through the slot's ring
+table, masked by ABSOLUTE position (`len - W <= j < len`), no ladder (the
+ring's width is fixed); `ring_write_chunk` writes position t into ring block
+`(t // block_size) % ring_blocks`.
 """
 
 from __future__ import annotations
@@ -53,6 +60,9 @@ __all__ = [
     "gathered_attention",
     "paged_decode_attention",
     "paged_chunk_attention",
+    "paged_window_attention",
+    "ring_write_chunk",
+    "window_positions",
     "pool_num_kv_heads",
     "pool_block_size",
     "pool_nbytes",
@@ -391,12 +401,18 @@ def paged_pour_block(cache, kv, block_id):
     return paged_pour_blocks(cache, kv[None], [int(block_id)])
 
 
-def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
+def gathered_attention(q, keys, vals, seq_lens, *, scale=None, window=None):
     """The sdpa core of the decode tier over ALREADY-GATHERED K/V:
     q [B, T, N, H]; keys/vals the pages as taken from the pool,
     [B, M, Nkv, bs, H] (position m * bs + s); seq_lens [B] INCLUDING all T
     chunk tokens.  The ONE masked-softmax definition:
-    paged_chunk_attention feeds it the pages of the width it chose.
+    paged_chunk_attention feeds it the pages of the width it chose,
+    paged_window_attention a slot's ring.
+
+    `window` W: the M pages are a RING of C = M * bs slots, slot c holding
+    the LATEST position <= len - 1 that is congruent to c (mod C), and a
+    query at position i sees i - W < j <= i; softmax sums in slot order,
+    which is no order of positions, and needs none.
 
     K and V are contracted in the type they arrive in, accumulated in
     float32 (for bfloat16 values, the products a float32 contraction of
@@ -420,9 +436,17 @@ def gathered_attention(q, keys, vals, seq_lens, *, scale=None):
                         precision=exact, preferred_element_type=jnp.float32
                         ) * jnp.float32(scale)
     kpos = (jnp.arange(m, dtype=jnp.int32)[:, None] * bs
-            + jnp.arange(bs, dtype=jnp.int32)[None, :])            # [M, bs]
+            + jnp.arange(bs, dtype=jnp.int32)[None, :])[None]   # [1, M, bs]
     qpos = (seq_lens[:, None] - t + jnp.arange(t, dtype=jnp.int32)[None, :])
-    allowed = kpos[None, None] <= qpos[:, :, None, None]       # [B, T, M, bs]
+    if window is not None:
+        # ring slot -> the absolute position it holds (negative: never
+        # written for this row)
+        last = (seq_lens - 1)[:, None, None]
+        kpos = last - jnp.mod(last - kpos, m * bs)              # [B, M, bs]
+    allowed = kpos[:, None] <= qpos[:, :, None, None]          # [B, T, M, bs]
+    if window is not None:
+        allowed = (allowed & (kpos[:, None] >= 0)
+                   & (kpos[:, None] > qpos[:, :, None, None] - window))
     logits = jnp.where(allowed[:, None, None], logits, jnp.float32(-1e30))
     # one flat axis of positions: pages of any width then reduce alike
     flat = logits.reshape(logits.shape[:4] + (m * bs,))
@@ -466,6 +490,42 @@ def attn_positions(block_tables, block_size, seq_lens, active=None):
     read = jnp.sum(active) * pages * block_size
     live = jnp.sum(jnp.where(active, seq_lens, 0))
     return read.astype(jnp.int32), live.astype(jnp.int32)
+
+
+def window_positions(ring_tables, block_size, seq_lens, window, active=None):
+    """`attn_positions` for one `paged_window_attention` call: (`active`
+    rows x the ring's positions, which it always reads whole; the sum over
+    the active rows of min(len, window), what is live in a window)."""
+    if active is None:
+        active = jnp.ones(seq_lens.shape, bool)
+    read = jnp.sum(active) * (ring_tables.shape[1] * block_size)
+    live = jnp.sum(jnp.where(active, jnp.minimum(seq_lens, window), 0))
+    return read.astype(jnp.int32), live.astype(jnp.int32)
+
+
+def ring_write_chunk(cache, new, ring_tables, positions):
+    """`paged_write_chunk` into a window class's ring: cache [blocks, Nkv,
+    bs, H]; new [B, T, Nkv, H]; ring_tables [B, R], each row's slot's ring;
+    positions [B, T] ABSOLUTE.  Position t goes to ring block
+    `(t // bs) % R`, slot `t % bs`, over whatever lived there (position
+    t - R * bs)."""
+    span = ring_tables.shape[1] * pool_block_size(cache)
+    return paged_write_chunk(cache, new, ring_tables, positions % span)
+
+
+def paged_window_attention(q, key_cache, value_cache, ring_tables, seq_lens,
+                           window, *, scale=None):
+    """Decode attention over a window class's ring: q [B, T, N, H];
+    ring_tables [B, R]; seq_lens [B] INCLUDING the T chunk tokens (already
+    written by `ring_write_chunk`; the ring holds the last R * bs
+    positions, which must cover window + T - 1).  A query at position i
+    attends i - window < j <= i.  Reads the R pages of every row whatever
+    its length: the width is fixed, so there is no ladder and no
+    conditional.  Returns [B, T, N, H]."""
+    return gathered_attention(
+        q, _take_pages(key_cache, ring_tables),
+        _take_pages(value_cache, ring_tables), seq_lens, scale=scale,
+        window=int(window))
 
 
 def _as_written(cache):

@@ -62,8 +62,15 @@ SPAN_NAMES = (
 # SPAN_NAMES (which a Profiler run must reproduce exactly).  The Pallas
 # kernels' names (`name=` on every pallas_call) are in docs/DECODE.md.
 SCOPE_NAMES = (
-    # models/mla_moe.py: latent attention's two paths, the routed experts
+    # models/mla_moe.py: latent attention's two paths; models/experts.py:
+    # the routed experts
     "mla.prefill", "mla.decode", "moe.route", "moe.experts", "moe.shared",
+    # models/window_moe.py: attention by cache class and path (full layers
+    # read pages, sliding layers a window: the flash kernel's window in the
+    # prefill program, the slot's ring in the macro-step), the per-head gate;
+    # its expert layer is models/experts.py's, under the moe.* scopes above
+    "attn.full.prefill", "attn.window.prefill", "attn.full.decode",
+    "attn.window.decode", "attn.gate",
 )
 
 _active_profiler = None  # checked by the op funnel (cheap global)

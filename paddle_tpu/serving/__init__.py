@@ -72,6 +72,12 @@ _DECODE_STATS = {
     "k_pool_bytes": 0,
     "v_pool_bytes": 0,
     "latent_pool_bytes": 0,
+    # a WINDOW class's pools (models/contract.py CacheClass: rings of
+    # window_ring_blocks blocks a slot, whatever the requests' lengths) are
+    # named apart by the model: wk_ / wv_ beside the paged k_ / v_
+    "wk_pool_bytes": 0,
+    "wv_pool_bytes": 0,
+    "window_ring_blocks": 0,
     # sharded-serving tier: the most recent engine's PER-DEVICE pool
     # bytes (each pool leaf's committed sharding divides its global
     # bytes — ops.paged_attention.pool_device_nbytes over pool_parts)
@@ -145,6 +151,12 @@ _DECODE_STATS = {
     # rows' lengths.  read / live is the step's read amplification.
     "attn_positions_read": 0,
     "attn_positions_live": 0,
+    # the same by cache class, for a model with a window class beside its
+    # paged one (read and live above are then the sum over both, once a
+    # class): the ring's positions are read whole, min(len, W) are live
+    "attn_full_positions_read": 0,
+    "attn_window_positions_read": 0,
+    "attn_window_positions_live": 0,
 }
 
 _ADMIT_PHASES = ("match", "prefill", "first_token", "pour")
@@ -250,17 +262,22 @@ def _invalidate_decode_steps(_changed):
     for eng in list(_ENGINES):
         eng._step_fns.clear()
         eng._prefill_fns.clear()
-        eng._draft_fn = eng._verify_fn = None
+        eng._draft_fn = eng._verify_fn = eng._logit_rows_fn = None
 
 
-def _cache_blocks(caches, start_tok, s0, bs):
+def _cache_blocks(spec, caches, start_tok, s0, bs, end=None):
     """Naive prefill caches (`caches[layer][p]`: [1, S, heads, width] per
-    pool of the cache specification, Tensors or raw arrays) -> the pools'
-    block layout for tokens [start_tok, s0): `blocks[p][layer]`, each
-    [n, heads, bs, width], the last block's tail zero-padded.  start_tok is
-    block-aligned (it skips the prefix-matched region: the caches hold the
-    FULL logical sequence).  The ONE shaper: eager admissions call it on
-    the host, the prefill program inside its trace."""
+    pool of the layer's cache class, Tensors or raw arrays) -> the pools'
+    block layout, `blocks[p][i]` in the engine's order (`spec.pools`; `i`
+    over the class's layers).  A PAGED class gets the tokens [start_tok,
+    s0) as [n, heads, bs, width], the last block's tail zero-padded;
+    start_tok is block-aligned (it skips the prefix-matched region: the
+    caches hold the FULL logical sequence).  A WINDOW class gets its ring,
+    [ring_blocks, heads, bs, width]: ring block r holds the latest block j
+    <= (end - 1) // bs of the sequence with j % ring_blocks == r (zeros
+    where there is none), `end` the count of real positions (s0 where not
+    given; traced inside the prefill program).  The ONE shaper: eager
+    admissions call it on the host, the prefill program inside its trace."""
     n = -(-(s0 - start_tok) // bs)
     pad = start_tok + n * bs - s0
 
@@ -272,8 +289,26 @@ def _cache_blocks(caches, start_tok, s0, bs):
         heads, _, width = kv.shape
         return kv.reshape(heads, n, bs, width).swapaxes(0, 1)
 
-    return [[shape(layer[p]) for layer in caches]
-            for p in range(len(caches[0]))]
+    def ring(t, blocks_in_ring):
+        if start_tok:
+            raise NotImplementedError("a window class's ring is poured from "
+                                      "a whole prompt, never behind a prefix")
+        blocks = shape(t)
+        last = (jnp.asarray(s0 if end is None else end, jnp.int32) - 1) // bs
+        r = jnp.arange(blocks_in_ring, dtype=jnp.int32)
+        j = last - jnp.mod(last - r, blocks_in_ring)
+        taken = jnp.take(blocks, jnp.clip(j, 0, n - 1), axis=0)
+        return jnp.where((j >= 0)[:, None, None, None], taken, 0)
+
+    out = []
+    for cls in spec.classes:
+        for p in range(len(cls.pools)):
+            if cls.window is None:
+                out.append([shape(caches[li][p]) for li in cls.layers])
+            else:
+                out.append([ring(caches[li][p], cls.ring_blocks(bs))
+                            for li in cls.layers])
+    return out
 
 
 def _empty_caches(spec, batch=1):
@@ -281,7 +316,8 @@ def _empty_caches(spec, batch=1):
     import paddle_tpu as paddle
 
     return [tuple(paddle.zeros([batch, 0, p.heads, p.width], dtype=p.dtype)
-                  for p in spec.pools) for _ in range(spec.n_layers)]
+                  for p in spec.class_of(li).pools)
+            for li in range(spec.n_layers)]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -653,6 +689,18 @@ class GenerationEngine:
         # (the same PartitionSpec(None, mp) covers both ranks — trailing
         # dims replicate), so int8 pools compose with the mesh engine
         self._pools = self._alloc_pools(spec, total, self._pool_sharding)
+        # a window class's RINGS: slot i owns blocks i * ring .. (i + 1) *
+        # ring - 1 of that class's pools from here on, whatever it serves;
+        # one constant [max_batch, ring] table a class (None for a paged
+        # class, whose table is the requests'): on the host for the pour's
+        # page indices, on the device once for the macro-step
+        self._ring_pages = [
+            None if c.window is None else np.arange(
+                self.max_batch * c.ring_blocks(self.block_size),
+                dtype=np.int32).reshape(self.max_batch, -1)
+            for c in spec.classes]
+        self._ring_tables = [None if t is None else jnp.asarray(t)
+                             for t in self._ring_pages]
         self._free = list(range(self._num_blocks))
         self._ref = [0] * total  # per-block request refcounts (allocator)
         pc = (bool(prefix_cache) if prefix_cache is not None
@@ -673,6 +721,7 @@ class GenerationEngine:
             raise ValueError("decode_chunk must be >= 1")
         self._decode_chunk = None if decode_chunk is None else int(decode_chunk)
         self._step_fns: dict = {}  # macro-step executables, keyed by D
+        self._logit_rows_fn = None  # next_token_logits' one program
         # admission prefill programs, keyed by (padded suffix, prefix length)
         self._prefill_fns: dict = {}
         # masked lanes' block tables (every page is the slot's scratch
@@ -776,6 +825,8 @@ class GenerationEngine:
         for ps, pools in zip(spec.pools, self._pools):
             _DECODE_STATS[ps.name + "_pool_bytes"] = sum(
                 pa.pool_nbytes(p) for p in pools)
+        _DECODE_STATS["window_ring_blocks"] = sum(
+            t.shape[1] for t in self._ring_tables if t is not None)
         # per-device footprint: each pool leaf's committed sharding
         # divides its bytes (== pool_bytes on single-device engines)
         _DECODE_STATS["pool_bytes_per_device"] = sum(
@@ -797,21 +848,30 @@ class GenerationEngine:
         pool a layer; a model whose contract specifies other pools is
         refused BY NAME here, never served by K/V code on its pool."""
         if not self._spec.kv_pair:
+            windows = [c.window for c in self._spec.classes
+                       if c.window is not None]
             raise NotImplementedError(
                 f"GenerationEngine: {feature} cannot hold this model's "
-                f"cache pools {[p.name for p in self._spec.pools]} yet; it "
-                "was built for a K/V pair (docs/DECODE.md, the model "
+                f"cache pools {[p.name for p in self._spec.pools]} yet"
+                + (f" (a window class: rings of the last {windows[0]} "
+                   "positions a slot, which are not pages)" if windows else "")
+                + "; it was built for a K/V pair (docs/DECODE.md, the model "
                 "contract)")
 
     def _alloc_pools(self, spec, total, sharding):
-        """`pools[p][layer]` of a cache specification, zeroed and placed."""
+        """`pools[p][i]` of a cache specification, zeroed and placed: a
+        paged class's pools `total` blocks each (the allocator's, plus a
+        scratch page a slot), a window class's max_batch rings."""
         from paddle_tpu.ops import paged_attention as pa
 
         return [[self._place_pool(pa.alloc_paged_pool(
-                    total, ps.heads, self.block_size, ps.width,
+                    total if cls.window is None
+                    else self.max_batch * cls.ring_blocks(self.block_size),
+                    ps.heads, self.block_size, ps.width,
                     jnp.int8 if self._kv_dtype == "int8" else ps.dtype),
                     sharding)
-                 for _ in range(spec.n_layers)] for ps in spec.pools]
+                 for _ in cls.layers]
+                for cls in spec.classes for ps in cls.pools]
 
     # ------------------------------------------------------ pool placement
     @staticmethod
@@ -874,6 +934,60 @@ class GenerationEngine:
 
     def result(self, rid):
         return self._results.get(rid)
+
+    def next_token_logits(self):
+        """{rid: float32 [V]}: the NEXT token's logits of every active
+        row — the contract's decode step, as the macro-step scans it, over
+        the pools the engine holds now (what the prefill program poured
+        and the macro-steps wrote; every cache class's, a window class's
+        rings through the slots' ring tables).  One compiled call that
+        hands back the logits alone: pools, slots and streams are
+        untouched, and a greedy row's argmax is the token the next step
+        emits.  For comparisons that need more than a token (perfbench's
+        logit rows, chip_smoke.py); serving a request never calls it."""
+        from paddle_tpu._core.autograd import no_grad
+
+        if self._pack is not None or self.draft_model is not None:
+            raise NotImplementedError(
+                "next_token_logits: the plain macro-step's decode only (no "
+                "adapter pack, no draft model)")
+        contract, state = self._contract, self._state
+        per_class = self._spec.per_class_tables
+
+        def logit_rows(state_vals, pools, tokens, tables, lens, active,
+                       ring_tables):
+            originals = [t._value for t in state]
+            try:
+                for t, v in zip(state, state_vals):
+                    t._bind(v)
+                if per_class:
+                    tables = tuple(tables if t is None else t
+                                   for t in ring_tables)
+                with no_grad():
+                    h, _, _ = contract.decode(tokens, contract.pool_carry(pools),
+                                              tables, lens, active=active)
+                    return contract.logits(h)._value[:, -1].astype(jnp.float32)
+            finally:
+                for t, v in zip(state, originals):
+                    t._bind(v)
+
+        if self._logit_rows_fn is None:
+            self._logit_rows_fn = jax.jit(logit_rows)
+        B, W = self.max_batch, self._max_blocks_per_seq
+        tokens = np.zeros((B, 1), np.int32)
+        tables = np.tile(np.asarray(self._scratch, np.int32)[:, None], (1, W))
+        lens = np.ones((B,), np.int32)
+        for i, s in enumerate(self._slots):
+            if s.active:
+                tokens[i, 0] = s.last_token
+                tables[i] = list(s.blocks) + [s.blocks[-1]] * (W - len(s.blocks))
+                lens[i] = s.seq_len + 1
+        rows = np.asarray(self._logit_rows_fn(
+            [t._value for t in self._state], [list(p) for p in self._pools],
+            jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(lens),
+            jnp.asarray([s.active for s in self._slots]),
+            list(self._ring_tables)))
+        return {s.rid: rows[i] for i, s in enumerate(self._slots) if s.active}
 
     # ---------------------------------------------------- adapter registry
     def _require_pack(self):
@@ -1391,9 +1505,11 @@ class GenerationEngine:
             # pages (matched prefix pages are shared and immutable)
             with _admit_phase("pour", acc):
                 if not compiled:
-                    new_blocks = _cache_blocks(caches, m_len, s0, bs)
+                    new_blocks = _cache_blocks(self._spec, caches, m_len, s0,
+                                               bs)
                 self._pour(self._pools, new_blocks, fresh,
-                           sharding=self._pool_sharding)
+                           sharding=self._pool_sharding,
+                           rings=self._slot_rings(self._slots.index(slot)))
             if self.draft_model is not None:
                 # draft prefill over the same suffix into the draft pools
                 # (cached pages were poured to BOTH pool sets at insert
@@ -1405,7 +1521,8 @@ class GenerationEngine:
                         paddle.to_tensor(prompt[:, m_len:]), d_caches, m_len)
                 with _admit_phase("pour", acc):
                     self._pour(self._d_pools,
-                               _cache_blocks(d_caches, m_len, s0, bs),
+                               _cache_blocks(self._d_spec, d_caches, m_len,
+                                             s0, bs),
                                fresh, sharding=self._d_pool_sharding)
                 slot.d_seq_len = s0
         except BaseException:
@@ -1500,8 +1617,10 @@ class GenerationEngine:
         suffix on top of the gathered prefix (None when m_len == 0), the
         logits of position n_real - 1 (a traced scalar: one program serves
         every real length of its bucket), every layer's new cache rows
-        already in the pools' block layout (`blocks[p][layer]`,
-        [ceil(s_pad / bs), heads, bs, width]), and what the model counted.
+        already in the pools' block layout (`blocks[p][i]`,
+        [ceil(s_pad / bs), heads, bs, width]; a window class's as its ring,
+        [ring_blocks, heads, bs, width], the prompt's last window only),
+        and what the model counted.
         Right padding is invisible to the real positions under the causal
         bottom-right-aligned mask; positions at or past n_real are ZEROED
         before the blocks are shaped, so a partial block's tail (and every
@@ -1535,8 +1654,9 @@ class GenerationEngine:
                 real = (jnp.arange(m_len + s_pad)
                         < m_len + n_real)[None, :, None, None]
                 blocks = _cache_blocks(
-                    [tuple(jnp.where(real, t._value, 0) for t in layer)
-                     for layer in caches], m_len, m_len + s_pad, bs)
+                    spec, [tuple(jnp.where(real, t._value, 0) for t in layer)
+                           for layer in caches], m_len, m_len + s_pad, bs,
+                    end=m_len + n_real)
                 return logits_last, blocks, aux
             finally:
                 for t, v in zip(state, originals):
@@ -1758,7 +1878,8 @@ class GenerationEngine:
                 st.h[:, -1:, :])._value[0, -1, :]
         first = int(np.asarray(jnp.argmax(logits_last)))
         self._pour(self._pools,
-                   _cache_blocks(st.caches, st.poured * bs, s0, bs),
+                   _cache_blocks(self._spec, st.caches, st.poured * bs, s0,
+                                 bs),
                    slot.blocks[st.poured:], sharding=self._pool_sharding)
         _DECODE_STATS["prefill_eager_fallbacks"] += 1
         slot.active = True
@@ -1919,14 +2040,29 @@ class GenerationEngine:
             for ps, per_layer in zip(spec.pools, pools))
             for li in range(spec.n_layers)]
 
-    def _pour(self, pools, blocks, pages, sharding=None):
-        """Scatter blocks (`_cache_blocks` layout, `blocks[p][layer]`) into
+    def _slot_rings(self, slot):
+        """Per pool of the specification (`self._pools` order): the ring
+        pages of slot number `slot` in a window class's pools, None for a
+        paged class's."""
+        return [None if t is None else t[slot]
+                for c, t in zip(self._spec.classes, self._ring_pages)
+                for _p in c.pools]
+
+    def _pour(self, pools, blocks, pages, sharding=None, rings=None):
+        """Scatter blocks (`_cache_blocks` layout, `blocks[p][i]`) into
         `pages`, a request's exclusively owned pool pages in order: one
         `paged_pour_blocks` per pool.  Pages past the given blocks — the
         request's future decode pages — are poured with zeros, which on a
-        quantized pool also resets a recycled page's stale scale."""
-        idx = jnp.asarray(pages, jnp.int32)
-        for per_layer, new in zip(pools, blocks):
+        quantized pool also resets a recycled page's stale scale.  A
+        window class's blocks are its whole ring and go to `rings[p]`, the
+        slot's ring pages (`_slot_rings`), never to `pages`: a ring is not
+        allocated by request."""
+        # on the device ONCE, then shared by every pour (a numpy index would
+        # be transferred again by each of the pools x layers calls)
+        pages = jnp.asarray(pages, jnp.int32)
+        rings = [None if r is None else jnp.asarray(r) for r in rings or ()]
+        for q, (per_layer, new) in enumerate(zip(pools, blocks)):
+            idx = pages if not rings or rings[q] is None else rings[q]
             for li in range(len(per_layer)):
                 # placed: the pool stays committed to its head-sharded
                 # layout, so the decode executable's input shardings stay
@@ -2233,9 +2369,17 @@ class GenerationEngine:
         eos = self.eos_token_id
         has_pack = self._pack is not None
 
+        per_class = self._spec.per_class_tables
+
         def decode_macro_step(state_vals, pools, tokens, tables,
                               scratch_tables, lens, max_lens, done0, temps,
-                              keys, steps, *lora_args):
+                              keys, steps, *more):
+            # a specification of several cache classes, or of a window
+            # class, brings one table a class after the block table's own
+            # arguments (None in a paged class's place): the model's
+            # `tables` is then a tuple, and a ring is never read as pages
+            class_tables, lora_args = ((more[:1], more[1:]) if per_class
+                                       else ((), more))
             kv_only = {}
             if has_pack:
                 ad_slots, pack_ab, pack_scaling = lora_args
@@ -2261,6 +2405,14 @@ class GenerationEngine:
                     # slots, so their writes never touch the shared pool
                     tables_eff = jnp.where(done[:, None], scratch_tables,
                                            tables)
+                    if class_tables:
+                        # a window class's table is the slots' own rings
+                        # for every row: a finished or empty lane writes
+                        # position 0 of a ring nobody reads until its
+                        # slot's next admission pours it anew
+                        tables_eff = tuple(
+                            tables_eff if t is None else t
+                            for t in class_tables[0])
                     lens_eff = jnp.where(done, jnp.int32(1), lens_c)
                     with no_grad():
                         h, pools_c, aux = contract.decode(
@@ -2335,6 +2487,9 @@ class GenerationEngine:
             jax.ShapeDtypeStruct((B, 2), jnp.uint32),    # keys
             jax.ShapeDtypeStruct((B,), jnp.uint32),      # steps
         )
+        if self._spec.per_class_tables:
+            avals += ([None if t is None else arr_aval(t)
+                       for t in self._ring_tables],)
         if self._pack is not None:
             avals += (jax.ShapeDtypeStruct((B,), jnp.int32),
                       jax.tree_util.tree_map(arr_aval, self._pack.ab),
@@ -2682,6 +2837,8 @@ class GenerationEngine:
                 lora_args = (jnp.asarray(ad_slots), self._pack.ab,
                              self._pack.scaling)
                 _LORA_STATS["gather_dispatches"] += 1
+            class_args = ((list(self._ring_tables),)
+                          if self._spec.per_class_tables else ())
             nxt, new_pools, aux = step_fn(
                 [t._value for t in self._state],
                 [list(p) for p in self._pools],
@@ -2689,7 +2846,7 @@ class GenerationEngine:
                 self._scratch_tables, jnp.asarray(lens),
                 jnp.asarray(max_lens), jnp.asarray(done0),
                 jnp.asarray(temps), jnp.asarray(keys), jnp.asarray(steps),
-                *lora_args,
+                *class_args, *lora_args,
             )
         self._pools = [list(p) for p in new_pools]
         t_sync = time.perf_counter()

@@ -126,6 +126,50 @@ def test_mla_moe_logits_phase_on_cpu_tiny(monkeypatch):
             < out["softmax_rms_bfloat16_spread_4"])
 
 
+def test_window_moe_logits_phase_on_cpu_tiny(monkeypatch):
+    """Rehearsal 1 of `--window-moe-logits`: the same drive at a tiny float32
+    size (window 8, rings of 3 x 4 positions that wrap before the first
+    decode boundary), where program and reference agree to rounding, and the
+    references with the window ignored or the gate left out do not."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                    "perfbench"))
+    from test_perfbench_window_moe import TINY
+
+    monkeypatch.setattr(chip_smoke, "WINDOW_MOE_LOGIT_TOL", 1e-3)
+    monkeypatch.setattr(chip_smoke, "WINDOW_MOE_TIE_TOL", 1e-3)
+    monkeypatch.setattr(chip_smoke, "WINDOW_ROUTE_AGREEMENT_MIN", 1.0)
+    out = chip_smoke.window_moe_logits_phase(
+        TINY, seed=3, device=jax.devices()[0], prompt_lens=(16, 32, 61),
+        decoded=(8, 16), block_size=4)
+    assert len(out["errors"]) == 9 and max(out["errors"]) < 1e-3
+    # every control reads beyond the limit the program's own rows stay under
+    assert min(out["ignore_window"]) > 1e-3 and min(out["no_gate"]) > 1e-3
+    assert out["route_agreement"] == 1.0 >= out["route_agreement_bfloat16"]
+    # a window of 8 has too few probabilities for bfloat16 to show beyond
+    # the limit (the probe's own sizes do: the test below)
+    assert (out["window_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL
+            and out["window_softmax_rms_float32"]
+            < out["window_softmax_rms_bfloat16"])
+    # rows of 7, 8, 32, 48, 77: the ring (12) read whole, min(len, 8) live
+    assert (out["positions_read"], out["positions_live"]) == (5 * 12, 7 + 4 * 8)
+
+
+def test_window_softmax_probe_on_cpu():
+    """Rehearsal 1 of the window read's probe at its own widths (72 / 8 heads
+    x 128, window 512, rings of 5 x 128), rows below, at and past the window
+    (the longest has wrapped its ring three times): the program's read under
+    the limit, the bfloat16-softmax control over it."""
+    out = chip_smoke.window_softmax_probe(seed=2147484099,
+                                          lens=(300, 512, 2100))
+    assert (out["window_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL
+            < out["window_softmax_rms_bfloat16"])
+    assert (out["positions_read"], out["positions_live"]) == (3 * 640,
+                                                              300 + 512 + 512)
+
+
 def test_dense_softmax_probe_on_cpu():
     """Rehearsal 1 of `--dense-softmax`, at the probe's own sizes (they are
     small): the program's decode attention reads under the limit, the

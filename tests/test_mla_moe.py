@@ -155,7 +155,7 @@ def test_expert_counters_reach_decode_stats_and_decode_line(model):
 
     assert "Expert load: 18 expert-layer steps, 72 assignments" in decode_line(st)
     assert {"mla.prefill", "mla.decode", "moe.route", "moe.experts",
-            "moe.shared"} == set(profiler.SCOPE_NAMES)
+            "moe.shared"} <= set(profiler.SCOPE_NAMES)
 
 
 def test_scopes_reach_the_programs(model):

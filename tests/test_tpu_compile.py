@@ -184,7 +184,7 @@ def test_every_tuned_flash_tile_is_one_mosaic_accepts(one_chip, mosaic, kernel,
     kernel: it has its measured time, the kernels find it under the key
     they build (`_block_sizes`), `validate_flash_tile` admits it in the
     blocks' own type, and Mosaic compiles the kernel with it (32 heads: a
-    kernel holds one head at a time)."""
+    kernel holds one head at a time; a `window` entry with the window)."""
     import importlib
 
     from paddle_tpu.ops import autotune
@@ -194,18 +194,21 @@ def test_every_tuned_flash_tile_is_one_mosaic_accepts(one_chip, mosaic, kernel,
     seq, width = int(dims["seq_q"]), int(dims["head_dim"])
     v_width, dtype = int(dims.get("v_dim", width)), jnp.dtype(dims["dtype"])
     causal = dims["causal"] == "True"
+    window = int(dims["window"]) if "window" in dims else None
     assert int(dims["seq_k"]) == seq
     assert ms > 0, "not a measurement"
     assert fa._block_sizes(seq, seq, width, dtype, causal, v_dim=v_width,
-                           kernel=kernel) == tile
+                           window=window, kernel=kernel) == tile
     assert autotune.validate_flash_tile(*tile, seq, seq, width, dtype=dtype,
                                         v_dim=v_width) is None
     q = k = (1, 32, seq, width)
     v = (1, 32, seq, v_width)
     if kernel == "flash_fwd":
-        _compile(lambda q, k, v: fa._fwd(q, k, v, 0.1, causal, *tile)[0],
+        _compile(lambda q, k, v: fa._fwd(q, k, v, 0.1, causal, *tile,
+                                         window)[0],
                  q, k, v, sharding=one_chip, dtype=dtype)
         return
+    assert window is None, "the backward kernels take no window"
     run = {"flash_bwd_dq": fa._bwd_dq, "flash_bwd_dkv": fa._bwd_dkv}[kernel]
     lanes = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.float32,
                                  sharding=one_chip)
@@ -227,6 +230,78 @@ def test_the_cells_shapes_are_in_the_table():
     for seq in (2048, 4096, 8192):
         assert ("flash_fwd", "causal=True|dtype=bfloat16|head_dim=192|"
                 f"seq_k={seq}|seq_q={seq}|v_dim=128") in have
+    # the window / full model's prefill buckets (models/window_moe.py): 48
+    # heads on full layers (the key has no head count: 2,048 and 4,096 are
+    # the entries above), 72 under a window of 512 on sliding ones
+    for seq in (2048, 4096, 8192):
+        plain = f"causal=True|dtype=bfloat16|head_dim=128|seq_k={seq}|seq_q={seq}"
+        assert ("flash_fwd", plain) in have
+        assert ("flash_fwd", plain + "|window=512") in have
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)])
+@pytest.mark.parametrize("seq", [2048, 8192])
+def test_flash_forward_compiles_at_the_window_models_grouped_heads(
+        one_chip, mosaic, seq, heads, window):
+    """models/window_moe.py's prefill at laguna-s-2.1's widths: 48 query
+    heads on a full layer and 72 under a window of 512 on a sliding one, over
+    8 K/V heads of 128 (groups of 6 and 9 through the forward's index map,
+    no repeated K/V), one prompt of the shortest and the longest bucket, at
+    the tile the table holds for the shape."""
+    from paddle_tpu.ops import autotune, flash_attention
+
+    q, kv = (1, seq, heads, 128), (1, seq, 8, 128)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=window),
+        q, kv, kv, sharding=one_chip)
+    assert f"bf16[1,{heads},{seq},128]" in compiled.as_text()
+    # 72-head shapes validate like any other: the tile is a head's
+    for tile in ((128, 128), (512, 512), (256, 1024)):
+        assert autotune.validate_flash_tile(*tile, seq, seq, 128,
+                                            dtype=jnp.bfloat16) is None
+    assert "divide" in autotune.validate_flash_tile(512, 3000, seq, seq, 128,
+                                                    dtype=jnp.bfloat16)
+
+
+def test_the_window_read_holds_no_conditional_and_no_pool_copy(one_chip,
+                                                               mosaic):
+    """A sliding layer's write -> read inside a scan (the macro-step's
+    shape) at the laguna cell's geometry: 32 rings of 5 x 128 positions,
+    72 / 8 heads.  The ring's width is fixed, so there is no ladder: the
+    program holds no conditional, and copies a pool at its edge at most."""
+    import re
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, nkv, h, ring, bs = 32, 72, 8, 128, 5, 128
+
+    def steps(q, kc, vc, new, tables, lens):
+        def one(carry, _):
+            kc, vc, lens, acc = carry
+            pos = (lens - 1)[:, None]
+            kc = pa.ring_write_chunk(kc, new, tables, pos)
+            vc = pa.ring_write_chunk(vc, new, tables, pos)
+            o = pa.paged_window_attention(q + acc.astype(q.dtype), kc, vc,
+                                          tables, lens, 512)
+            return (kc, vc, lens + 1, acc + o.astype(jnp.float32)), None
+
+        carry, _ = jax.lax.scan(
+            one, (kc, vc, lens, jnp.zeros(q.shape, jnp.float32)), None,
+            length=2)
+        return carry
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((b * ring, nkv, bs, h))
+    text = jax.jit(steps, donate_argnums=(1, 2)).lower(
+        s((b, 1, n, h)), pool, pool, s((b, 1, nkv, h)),
+        s((b, ring), jnp.int32), s((b,), jnp.int32)).compile().as_text()
+    assert "conditional(" not in text
+    copies = re.findall(rf"= bf16\[{b * ring},{nkv},{bs},{h}\]\S* copy\(", text)
+    # at most the program's edge: each pool once in and once out (the order
+    # the slot writes prefer), never one a token step and read
+    assert len(copies) <= 4, len(copies)
 
 
 def test_flash_tile_validation_counts_the_blocks_own_type():
