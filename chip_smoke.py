@@ -14,8 +14,11 @@
                                        # that must fail (window ignored, gate
                                        # left out, bfloat16 router / softmax)
     python chip_smoke.py --dense-softmax   # one chip: the dense decode attention
-                                       # over a bfloat16 paged pool against a
-                                       # float32 softmax on the same rows
+                                       # (the path it selects: the Pallas
+                                       # kernel on a chip) over bfloat16 paged
+                                       # pools at the two cells' geometries
+                                       # against a float32 softmax on the
+                                       # same rows
     python chip_smoke.py --flash-softmax   # one chip: the flash kernels, forward
                                        # and backward, on bfloat16 inputs
                                        # against a float64 softmax
@@ -875,16 +878,28 @@ def _bfloat16_softmax_dense(q, kc, vc, tables, lens):
     return out.reshape(b, t, n, h).astype(q.dtype)
 
 
-def dense_softmax_probe(*, seed, heads=16, kv_heads=8, head_dim=128,
-                        block_size=16, table_width=96, lens=(130, 300, 512)):
+# (name, query heads, K/V heads, head width, block size, table width, live
+# positions a row): the two geometries the serving cells hand the dense
+# decode attention (PERF.md section 4)
+DENSE_PROBE_SHAPES = (
+    ("internlm2-1.8b", 16, 8, 128, 16, 96, (130, 300, 512)),
+    ("laguna-s-2.1 full layer", 48, 8, 128, 128, 67, (2200, 4900, 8576)),
+)
+
+
+def dense_softmax_probe(*, seed, name="internlm2-1.8b", heads=16, kv_heads=8,
+                        head_dim=128, block_size=16, table_width=96,
+                        lens=(130, 300, 512)):
     """The dense twin of the latent softmax probe: the decode attention a
-    dense model's macro-step runs (`paged_chunk_attention`, one token a row)
-    over bfloat16 K/V pools at internlm2-1.8b's heads, `lens` live positions
-    a row behind a table of `table_width` pages in a shuffled order (so the
-    width it reads is the ladder's, not the table's), against a softmax
-    computed in float64 on the host over the SAME bfloat16 queries and rows:
-    rms error over the output's rms, scores spread as a trained model's; and
-    once more with the softmax in bfloat16, the control."""
+    dense model's macro-step runs (`paged_chunk_attention`, one token a row:
+    the path it SELECTS here, the Pallas kernel over each row's own pages
+    or the XLA form over the ladder's width; the probe says which) over
+    bfloat16 K/V pools, `lens` live positions a row behind a table of
+    `table_width` pages in a shuffled order, against a softmax computed in
+    float64 on the host over the SAME bfloat16 queries and rows: rms error
+    over the output's rms, scores spread as a trained model's; beside it
+    the XLA form on the same rows; and once more with the softmax in
+    bfloat16, the control."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -920,24 +935,32 @@ def dense_softmax_probe(*, seed, heads=16, kv_heads=8, head_dim=128,
         want[i] = np.einsum("kgs,ksh->kgh", p / p.sum(-1, keepdims=True),
                             vals[i, :, :n])
     want = want.reshape(rows, 1, heads, head_dim)
-    out = {}
-    for name, fn in (("float32", pa.paged_chunk_attention),
+    out = {"path": "kernel" if pa.reads_own_pages(kc) else "xla"}
+    for form, fn in (("float32", pa.paged_chunk_attention),
+                     ("xla", lambda *a: pa._paged_chunk_xla(*a, None)),
                      ("bfloat16", _bfloat16_softmax_dense)):
-        out[f"dense_softmax_rms_{name}"] = _rms_error(
+        out[f"dense_softmax_rms_{form}"] = _rms_error(
             jax.jit(fn)(q, kc, vc, tables, lens).astype(jnp.float32), want)
-    read, live = pa.attn_positions(tables, block_size, lens)
+    read, live = pa.attn_positions(tables, block_size, lens, pool=kc)
     out["positions_read"], out["positions_live"] = int(read), int(live)
-    say(f"dense probe: decode attention over {list(map(int, lens))} live "
-        f"positions of {table_width * block_size} ({heads} / {kv_heads} heads "
-        f"x {head_dim}, bfloat16 pools, {out['positions_read']} positions "
-        f"read for {out['positions_live']} live), scores spread "
+    say(f"dense probe, {name}: decode attention through the "
+        f"{'Pallas kernel' if out['path'] == 'kernel' else 'XLA form'} over "
+        f"{list(map(int, lens))} live positions of "
+        f"{table_width * block_size} ({heads} / {kv_heads} heads x "
+        f"{head_dim}, blocks of {block_size}, bfloat16 pools, "
+        f"{out['positions_read']} positions read for "
+        f"{out['positions_live']} live), scores spread "
         f"{SOFTMAX_SCORE_SPREAD:g}: rms error "
-        f"{out['dense_softmax_rms_float32']:.5f} of the output's rms; with "
-        f"the softmax in bfloat16 {out['dense_softmax_rms_bfloat16']:.5f} "
-        f"(limit {SOFTMAX_RMS_TOL})")
+        f"{out['dense_softmax_rms_float32']:.5f} of the output's rms (the "
+        f"XLA form on the same rows {out['dense_softmax_rms_xla']:.5f}); "
+        f"with the softmax in bfloat16 "
+        f"{out['dense_softmax_rms_bfloat16']:.5f} (limit {SOFTMAX_RMS_TOL})")
     check(out["dense_softmax_rms_float32"] <= SOFTMAX_RMS_TOL,
           "the dense decode attention agrees with a float32 softmax on the "
           "same rows")
+    check(out["dense_softmax_rms_float32"]
+          <= 1.05 * out["dense_softmax_rms_xla"] + 1e-6,
+          "the selected path reads no worse than the XLA form beside it")
     return out
 
 
@@ -1211,8 +1234,9 @@ def main(argv=None) -> int:
                          "GenerationEngine, logits against the float32 "
                          "reference, with the controls that must fail")
     ap.add_argument("--dense-softmax", action="store_true",
-                    help="run ONLY the dense decode attention over a "
-                         "bfloat16 paged pool (internlm2-1.8b's heads) "
+                    help="run ONLY the dense decode attention over "
+                         "bfloat16 paged pools (internlm2-1.8b's and "
+                         "laguna-s-2.1's geometries, the selected path) "
                          "against a float32 softmax on the same rows")
     ap.add_argument("--flash-softmax", action="store_true",
                     help="run ONLY the flash kernels on bfloat16 inputs (the "
@@ -1284,10 +1308,15 @@ def main(argv=None) -> int:
               "a bfloat16 softmax does NOT agree with a float32 softmax on "
               "the same rows: the comparison tells it from float32")
     elif args.dense_softmax:
-        out = dense_softmax_probe(seed=args.seed)
-        check(out["dense_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
-              "a bfloat16 softmax does NOT agree with a float32 softmax on "
-              "the same rows: the comparison tells it from float32")
+        for (name, heads, kv_heads, head_dim, block_size, table_width,
+             lens) in DENSE_PROBE_SHAPES:
+            out = dense_softmax_probe(
+                seed=args.seed, name=name, heads=heads, kv_heads=kv_heads,
+                head_dim=head_dim, block_size=block_size,
+                table_width=table_width, lens=lens)
+            check(out["dense_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
+                  "a bfloat16 softmax does NOT agree with a float32 softmax "
+                  "on the same rows: the comparison tells it from float32")
     elif args.flash_softmax:
         out = flash_softmax_probe(seed=args.seed)
         check(all(v > SOFTMAX_RMS_TOL for k, v in out.items()

@@ -53,6 +53,10 @@ _stats = {
     # flash kernels' matrix products were traced with (ops/flash_attention)
     "flash_bf16_operand_traces": 0,
     "flash_f32_operand_traces": 0,
+    # ... and which path decode attention over a paged pool was traced
+    # through: the Pallas kernel or the XLA form (ops/paged_attention)
+    "paged_kernel_traces": 0,
+    "paged_xla_traces": 0,
 }
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"

@@ -588,12 +588,12 @@ class LlamaServing(ServingContract):
             model.layers, h, model.rope_cos._value, model.rope_sin._value,
             pools[0], pools[1], tables, lens, **kv_only)
         # what this token step's attention read and what was live, once a
-        # step (every layer reads the same width)
+        # step (every layer reads the same pages)
         from paddle_tpu.ops import paged_attention as pa
 
         k0 = pools[0][0] if isinstance(pools[0], (list, tuple)) else pools[0]
         read, live = pa.attn_positions(
-            tables, pa.pool_block_size(k0), lens, active)
+            tables, pa.pool_block_size(k0), lens, active, pool=k0)
         return model.norm(h), [kps, vps], {
             "attn_positions_read": read, "attn_positions_live": live}
 

@@ -460,9 +460,10 @@ class WindowMoeServing(ServingContract):
         # step and class (every layer of a class reads the same)
         bs = pa.pool_block_size(pools[0][0])
         f_read = f_live = w_read = w_live = jnp.int32(0)
-        for cls, table in zip(self.spec.classes, tables):
+        for c, (cls, table) in enumerate(zip(self.spec.classes, tables)):
             if cls.window is None:
-                f_read, f_live = pa.attn_positions(table, bs, lens, active)
+                f_read, f_live = pa.attn_positions(table, bs, lens, active,
+                                                   pool=pools[2 * c][0])
             else:
                 w_read, w_live = pa.window_positions(table, bs, lens,
                                                      cls.window, active)

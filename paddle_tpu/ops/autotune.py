@@ -38,6 +38,7 @@ __all__ = [
     "tune_flash",
     "tune_fused_norm",
     "tune_swiglu",
+    "tune_paged",
     "device_kind_slug",
     "flash_vmem_bytes",
     "lane_padded",
@@ -585,6 +586,79 @@ def tune_matmul_epilogue(m=4096, k=4096, n=4096, dtype="bfloat16", **kw):
                        matmul_epilogue_candidates(m, k, n), (x, w, b), **kw)
 
 
+def paged_candidates(block_size, num_kv_heads, head_dim, table_width,
+                     itemsize=2):
+    """Pages a step of the paged decode kernel: powers of two whose two
+    double-buffered K and V buffers fit a quarter of VMEM."""
+    out = []
+    for pages in (1, 2, 4, 8, 16, 32, 64):
+        buffers = 4 * num_kv_heads * pages * block_size * lane_padded(
+            head_dim) * itemsize
+        if pages <= table_width and buffers <= _VMEM_BUDGET // 4:
+            out.append({"pages_per_step": pages})
+    return out
+
+
+def tune_paged(batch=32, num_heads=16, num_kv_heads=8, head_dim=128,
+               block_size=16, table_width=96, lens=(130, 512),
+               dtype="bfloat16", calls=16, **kw):
+    """Tune the paged decode kernel's pages a step for one page geometry
+    (`ops.paged_attention.paged_key`): `batch` rows whose lengths are
+    spread evenly over `lens`, their pages scattered over the pool.  One
+    timed dispatch is `calls` dependent calls (a call is shorter than a
+    dispatch); `ms` is one call's.  Prints the XLA form's ms on the same
+    rows beside the candidates': the selection rule is read off that."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    jd = jnp.dtype(dtype)
+    key = pa.paged_key(block_size, num_kv_heads, head_dim, jd)
+    rng = np.random.default_rng(0)
+    nb = batch * table_width + batch
+    tables = jnp.asarray(rng.permutation(nb)[:batch * table_width].reshape(
+        batch, table_width).astype(np.int32))
+    seq = jnp.asarray(rng.permutation(np.linspace(
+        lens[0], lens[1], batch).astype(np.int32)))
+    r = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(r[0], (batch, num_heads, head_dim), jd)
+    kc, vc = (jax.random.normal(x, (nb, num_kv_heads, block_size, head_dim),
+                                jd) for x in r[1:])
+    scale = 1.0 / head_dim ** 0.5
+
+    def repeated(attend):
+        def run(q, kc, vc, tables, seq):
+            def one(acc, _):
+                o = attend((q + acc).astype(q.dtype), kc, vc, tables, seq)
+                return o.astype(jnp.float32) * 1e-3, None
+            return jax.lax.scan(one, jnp.zeros(q.shape, jnp.float32), None,
+                                length=calls)[0]
+        return jax.jit(run)
+
+    def build(cfg):
+        return repeated(lambda *a: pa._paged_decode_pallas(
+            *a, scale, pages=cfg["pages_per_step"]))
+
+    args = (q, kc, vc, tables, seq)
+    timing = {k: kw[k] for k in ("iters", "inner", "timer") if k in kw}
+    if kw.get("verbose"):
+        xla = repeated(lambda q, *a: pa._paged_chunk_xla(
+            q[:, None], *a, scale)[:, 0])
+        print(f"  paged_decode XLA form: "
+              f"{_time_fn(xla, args, **timing) / calls:.4f} ms")
+    save = kw.pop("save", True)
+    cfg, ms = tune_kernel(
+        "paged_decode", key, build,
+        paged_candidates(block_size, num_kv_heads, head_dim, table_width,
+                         jd.itemsize), args, save=False, **kw)
+    # one call's time in the table, not the dispatch's
+    record("paged_decode", key, cfg, ms / calls, slug=kw.get("slug"),
+           save=save)
+    return cfg, ms / calls
+
+
 # ---------------------------------------------------------------------------
 # CLI: bounded-time sweep over the standard shape set
 
@@ -625,6 +699,14 @@ _STANDARD_SHAPES = {
         dict(m=4096, k=2048, n=8192), dict(m=4096, k=4096, n=4096),
         dict(m=8192, k=2048, n=2048),
     ],
+    # the two serving cells that read K/V pages (PERF.md section 4):
+    # laguna-s-2.1's full layers and internlm2-1.8b
+    "paged": [
+        dict(num_heads=48, num_kv_heads=8, block_size=128, table_width=67,
+             lens=(2200, 8600)),
+        dict(num_heads=16, num_kv_heads=8, block_size=16, table_width=96,
+             lens=(130, 512)),
+    ],
 }
 
 
@@ -633,7 +715,8 @@ def main(argv=None):
 
     p = argparse.ArgumentParser(description="Pallas kernel tile autotuner")
     p.add_argument("--kernel", default="all",
-                   choices=["all", "flash", "norm", "swiglu", "matmul"])
+                   choices=["all", "flash", "norm", "swiglu", "matmul",
+                            "paged"])
     p.add_argument("--budget-seconds", type=float, default=300.0,
                    help="total wall budget; stops between candidates")
     p.add_argument("--dtype", default="bfloat16")
@@ -647,7 +730,8 @@ def main(argv=None):
     slug = device_kind_slug()
     print(f"tuning for device kind: {slug}")
     runners = {"flash": tune_flash, "norm": tune_fused_norm,
-               "swiglu": tune_swiglu, "matmul": tune_matmul_epilogue}
+               "swiglu": tune_swiglu, "matmul": tune_matmul_epilogue,
+               "paged": tune_paged}
     todo = [args.kernel] if args.kernel != "all" else list(runners)
     for name in todo:
         for shape in _STANDARD_SHAPES[name]:

@@ -8,25 +8,49 @@ mapping its logical positions onto pool blocks, so cache memory is allocated
 per-16-token page instead of per-max-seq-len (vLLM-style paging).
 
 TPU-native design: the pool is ONE [num_blocks, Nkv, block_size, H] array per
-K and V; block writes are scatter-at-index updates, and decode attention
-(`paged_chunk_attention`) reads each sequence's pages with ONE clipped
-jnp.take on its block table and contracts them as gathered,
-[B, pages, Nkv, block_size, H], in the pool's own type with float32
-accumulation: no moved axis, no float32 copy of K or V, and query heads
-contracted in their KV groups, never a repeated K/V.  The take still writes
-the gathered pages to HBM once and the contractions read them back (XLA
-does not fuse a gather into its consumer), so the step moves about three
-times the live K/V bytes; a kernel that reads each row's pages straight
-from the pool is the next step (ROADMAP S3).
+K and V (a page with all its K/V heads is contiguous); block writes are
+scatter-at-index updates, and decode attention (`paged_chunk_attention`, its
+T = 1 face `paged_decode_attention`) has two forms with ONE arithmetic
+(`gathered_attention`'s: K and V enter the products in the pool's type with
+float32 sums; scores, mask, maximum, exponent and sum float32; PV with
+float32 probabilities at the exact product; query heads contracted in their
+K/V groups, never a repeated K/V):
 
-Everything is shape-static, so the step jits once: the block table bounds
-the gather and a length mask handles raggedness.  How much of the table is
-read is chosen ON THE DEVICE from max(seq_lens), among a short ladder of
-static widths (`page_ladder`: powers of two pages from 16 up, capped at the
-table's width), by a lax.switch whose branches differ only in
-`block_tables[:, :w]`; masked positions contribute exactly 0, so every
-branch that covers the longest row computes the same numbers.
-`attn_positions` says what a step read and what was live.
+- **the Pallas kernel** (`paged_decode`, `_paged_decode_kernel`): one token
+  a row.  Block tables and lengths are scalar-prefetched, the pools stay in
+  HBM, and a row reads ceil(len / block_size) pages, ITS OWN, each by one
+  DMA of Nkv x block_size x H into one of two VMEM buffers (the next
+  step's pages, or the next row's first, arrive under this step's
+  products); a running maximum, sum and [Nkv, G, H] float32 accumulator
+  fold the pages in.  Nothing beyond a row's length is read, no gathered
+  copy and no score array reach HBM.  How many pages a step takes is a
+  tile: `ops/tuned/<device>.json`, `python -m paddle_tpu.ops.autotune
+  --kernel paged`.
+- **the XLA form** (`_paged_chunk_xla`): ONE clipped jnp.take of the first
+  `w` pages of EVERY row, contracted as gathered, [B, w, Nkv, bs, H]; `w` is
+  chosen ON THE DEVICE from max(seq_lens) among a short ladder of static
+  widths (`page_ladder`: powers of two pages from 16 up, capped at the
+  table's width) by a lax.switch whose branches differ only in
+  `block_tables[:, :w]`; masked positions contribute exactly 0.  The take
+  writes the gathered pages to HBM and the contractions read them back, so
+  it moves about three times the table width it takes.
+
+`reads_own_pages(pool, T)` chooses, by what can be seen: `ops.use_pallas()`
+(a TPU, no mesh, FLAGS_use_pallas), a plain bfloat16 / float32 pool, T = 1,
+heads of whole 128-lane rows, pages of whole sublane tiles -> the kernel;
+everything else (T > 1: speculative verify and chunked decode; an int8
+pool; a TP mesh; the CPU) -> the XLA form.  Measured on one v5e at both
+serving cells' geometries (the tuned table's `meta`): 0.96 against 5.32 ms
+a call at 128-token pages (32 rows of 2.2k-8.6k), 0.085 against 0.253 ms at
+16-token pages (32 rows of 130-512).  The same predicate chooses the ORDER
+OF THE SLOT WRITES (`paged_write_chunk`): a Mosaic call takes its operands
+in the default order, and XLA's scatter over (block, slot) insists on a
+slot-major pool, so in front of the kernel it copied both whole pools per
+layer and token step; a pool the kernel reads is written as single rows of
+the pool seen as [blocks x heads x slots, H] (`_write_rows`), which keeps
+the default order through the step's loop and at its edge.
+`attn_positions` asks the same predicate and says what a step read and what
+was live.  Everything is shape-static, so the step jits once.
 
 A WINDOW class of a model's cache (models/contract.py: only the last W
 positions of a row are kept) lives in a per-slot RING of pool blocks and is
@@ -57,6 +81,7 @@ __all__ = [
     "paged_gather",
     "page_ladder",
     "attn_positions",
+    "reads_own_pages",
     "gathered_attention",
     "paged_decode_attention",
     "paged_chunk_attention",
@@ -77,6 +102,7 @@ __all__ = [
 ]
 
 _QMAX = 127.0  # symmetric int8 range; -128 is never produced
+_GROUP_TILE = 8  # query heads of a K/V head are padded to whole sublane tiles
 _EPS = 1e-12
 
 
@@ -333,12 +359,40 @@ def paged_write_chunk(cache, new, block_tables, positions):
     whole chunk in one shot."""
     if isinstance(cache, QuantPool):
         return _quant_write_chunk(cache, new, block_tables, positions)
+    if reads_own_pages(cache, new.shape[1]):
+        return _write_rows(cache, new, block_tables, positions)
+    return _write_slots(cache, new, block_tables, positions)
+
+
+def _write_slots(cache, new, block_tables, positions):
+    """The slot writes as ONE scatter over block and slot.  On a TPU that
+    scatter keeps the pool slot-major within a page through a step's loop
+    (`_as_written`): the order of the XLA form's reads."""
     bs = cache.shape[2]
     block_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B,T]
     slot = positions % bs
     # advanced indexing on dims 0 and 2 with [B, T] index arrays puts the
     # broadcast [B, T] in front: value shape [B, T, Nkv, H] == new
     return cache.at[block_idx, :, slot, :].set(new)
+
+
+def _write_rows(cache, new, block_tables, positions):
+    """The same writes as a scatter of single rows of H into the pool seen
+    as [num_blocks * Nkv * bs, H] (a view, nothing moves): one scattered
+    axis, the major one, so the pool keeps the DEFAULT order through a
+    step's loop, head-major within a page, which is the order a Mosaic
+    kernel takes its operands in.  With `_write_slots` in front of the
+    kernel XLA copies both whole pools per layer and token step (the
+    write -> attend scan compiled for a described v5e; tests/
+    test_tpu_compile.py holds that no such copy is left)."""
+    nb, nkv, bs, h = cache.shape
+    block_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B,T]
+    rows = ((block_idx[..., None] * nkv
+             + jnp.arange(nkv, dtype=jnp.int32)) * bs
+            + (positions % bs)[..., None])                       # [B,T,Nkv]
+    flat = cache.reshape(nb * nkv * bs, h).at[rows.reshape(-1)].set(
+        new.reshape(-1, h).astype(cache.dtype))
+    return flat.reshape(cache.shape)
 
 
 def _quant_write_chunk(pool, new, block_tables, positions):
@@ -476,18 +530,27 @@ def _ladder_index(ladder, block_size, seq_lens):
     return jnp.sum(longest > reach).astype(jnp.int32)
 
 
-def attn_positions(block_tables, block_size, seq_lens, active=None):
-    """What one `paged_chunk_attention` call over these rows reads and what
-    of it is live, as two int32 scalars: (`active` rows x positions of the
-    ladder width it takes, sum of the active rows' lengths).  Their
-    quotient is the step's read amplification (1 would take a ragged
-    kernel that reads each row's own pages)."""
-    ladder = page_ladder(block_tables.shape[1])
-    pages = jnp.asarray(ladder, jnp.int32)[
-        _ladder_index(ladder, block_size, seq_lens)]
+def attn_positions(block_tables, block_size, seq_lens, active=None, *,
+                   pool=None):
+    """What one `paged_chunk_attention` call (T = 1) over these rows reads
+    and what of it is live, as two int32 scalars: (positions read, sum of
+    the `active` rows' lengths).  Read is what the path selected for
+    `pool` reads (`reads_own_pages`, the predicate `paged_chunk_attention`
+    itself asks): through the kernel each active row's own pages,
+    ceil(len / block_size) of them; in the XLA form (and with no pool
+    given) active rows x the ladder width over the longest row.  Their
+    quotient is the step's read amplification (1 but for the last page's
+    rounding through the kernel)."""
     if active is None:
         active = jnp.ones(seq_lens.shape, bool)
-    read = jnp.sum(active) * pages * block_size
+    if pool is not None and reads_own_pages(pool):
+        own = jnp.clip(-(-seq_lens // block_size), 1, block_tables.shape[1])
+        read = jnp.sum(jnp.where(active, own, 0)) * block_size
+    else:
+        ladder = page_ladder(block_tables.shape[1])
+        pages = jnp.asarray(ladder, jnp.int32)[
+            _ladder_index(ladder, block_size, seq_lens)]
+        read = jnp.sum(active) * pages * block_size
     live = jnp.sum(jnp.where(active, seq_lens, 0))
     return read.astype(jnp.int32), live.astype(jnp.int32)
 
@@ -556,16 +619,216 @@ def _take_pages(cache, block_tables):
     return jnp.take(cache, block_tables, axis=0, mode="clip")
 
 
-def paged_chunk_attention(q, key_cache, value_cache, block_tables, seq_lens,
-                          *, scale=None):
-    """Multi-token decode attention over the paged cache (speculative
-    verify / chunked decode): q [B, T, N, H]; seq_lens [B] INCLUDING all
-    T chunk tokens.  Chunk position j sits at global position
-    seq_lens - T + j and attends keys <= that position (bottom-right
-    causal within the chunk).  Returns [B, T, N, H].
+# ---------------------------------------------------------------------------
+# The Pallas kernel: each row's OWN live pages, straight from the pool
 
-    Reads the first `w` pages of every row, `w` the narrowest width of
-    `page_ladder` that covers max(seq_lens), chosen on the device."""
+
+def _pages_per_step(block_size, num_kv_heads, head_dim, dtype):
+    """How many pages one step of the kernel's loop fetches and contracts
+    together: the measured winner for this page geometry on this device
+    kind (`ops/tuned/<device>.json`, kernel `paged_decode`, written by
+    `python -m paddle_tpu.ops.autotune --kernel paged`), else as many as
+    make 256 positions (within 1.1 times the winner at both measured
+    geometries), fewer where the four buffers would pass 4 MB of VMEM."""
+    from paddle_tpu.ops import autotune
+
+    cfg = autotune.lookup("paged_decode", paged_key(
+        block_size, num_kv_heads, head_dim, dtype))
+    if cfg and int(cfg.get("pages_per_step", 0)) >= 1:
+        return int(cfg["pages_per_step"])
+    page = num_kv_heads * block_size * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(256 // block_size, (1 << 20) // page))
+
+
+def paged_key(block_size, num_kv_heads, head_dim, dtype):
+    """The tuned table's key of a page geometry."""
+    return {"block_size": int(block_size), "num_kv_heads": int(num_kv_heads),
+            "head_dim": int(head_dim), "dtype": jnp.dtype(dtype).name}
+
+
+def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, slot_ref, *, scale, pages,
+                         exact):
+    """One grid step = one row.  A step of the inner loop = `pages` pages
+    of the row, fetched page by page from the pools in HBM into one of two
+    VMEM buffers [Nkv, pages * bs, H] (the DMA of the next step, which may
+    be the next ROW's first, runs under this step's products), contracted
+    for all K/V heads at once, folded into a running softmax."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    nb, nkv, bs, h = k_hbm.shape
+    width = tables_ref.shape[0] // lens_ref.shape[0]
+    span = pages * bs
+    mask_value = jnp.float32(-1e30)
+
+    def pages_of(row):
+        # a row reads its own pages, at least one, never past its table
+        return jnp.clip((lens_ref[row] + bs - 1) // bs, 1, width)
+
+    def copies(row, step, slot, do):
+        """`do` (start or wait) the two copies of each live page of step
+        `step` of row `row` into buffer `slot`: a loop of the row's own
+        count, so nothing beyond it is fetched."""
+        first = step * pages
+
+        def one(i, _):
+            idx = jnp.clip(tables_ref[row * width + first + i], 0, nb - 1)
+            at = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            do(pltpu.make_async_copy(
+                k_hbm.at[idx], k_buf.at[slot, :, at, :], sems.at[0, slot]))
+            do(pltpu.make_async_copy(
+                v_hbm.at[idx], v_buf.at[slot, :, at, :], sems.at[1, slot]))
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(pages_of(row) - first, pages), one,
+                          0)
+
+    @pl.when(b == 0)
+    def _():
+        if pages > 1:
+            # a step's last pages may lie past the row: what the buffer
+            # holds there is masked, and must be finite
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        copies(b, 0, 0, lambda c: c.start())
+
+    length = lens_ref[b]
+    steps = (pages_of(b) + pages - 1) // pages
+    slot0 = slot_ref[0]
+    q = q_ref[...]                                   # [Nkv, G, H]
+    g = q.shape[1]
+    precision = jax.lax.Precision.HIGHEST if exact else None
+
+    def step(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + c) % 2
+        last = c + 1 == steps
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < rows))
+        def _():
+            copies(jnp.where(last, b + 1, b), jnp.where(last, 0, c + 1),
+                   1 - slot, lambda cp: cp.start())
+
+        copies(b, c, slot, lambda cp: cp.wait())
+        k = k_buf[slot].astype(q.dtype)              # [Nkv, span, H]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), precision=precision,
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        kpos = c * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(kpos < length, s, mask_value)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        # float32 probabilities, never cut to bfloat16: the exact product
+        pv = jax.lax.dot_general(
+            p, v_buf[slot].astype(jnp.float32),
+            (((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, alpha * acc + pv
+
+    init = (jnp.full((nkv, g, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((nkv, g, 1), jnp.float32),
+            jnp.zeros((nkv, g, h), jnp.float32))
+    _m, l, acc = jax.lax.fori_loop(0, steps, step, init)
+    slot_ref[0] = (slot0 + steps) % 2
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+def _paged_decode_pallas(q, key_cache, value_cache, block_tables, seq_lens,
+                         scale, pages=None):
+    """T = 1 decode attention through the kernel: q [B, N, H]; plain pools
+    [num_blocks, Nkv, bs, H]; returns [B, N, H] in q's type.  `pages` a
+    step from the tuned table unless given."""
+    _nb, nkv, bs, h = key_cache.shape
+    if pages is None:
+        pages = _pages_per_step(bs, nkv, h, key_cache.dtype)
+    pages = max(1, min(int(pages), block_tables.shape[1]))
+    return _paged_decode_call(q, key_cache, value_cache, block_tables,
+                              seq_lens, scale=float(scale), pages=pages,
+                              interpret=_pl_utils.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+def _paged_decode_call(q, key_cache, value_cache, block_tables, seq_lens, *,
+                       scale, pages, interpret):
+    """The kernel's call, a jitted function of its own: the layers of a
+    model that call it with the same shapes share ONE trace and ONE
+    lowering of the kernel (unrolled, 24 layers of a dense model spent 12 s
+    of every process's set-up lowering 24 copies: no cache holds a
+    lowering).  What is looked up (the tile, the interpreter) is looked up
+    by the caller and is part of this function's key."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n, h = q.shape
+    nb, nkv, bs, _h = key_cache.shape
+    g = n // nkv
+    # both operands of QK^T in the wider of the two types, as
+    # `gathered_attention`; the group padded to whole sublane tiles
+    dt = jnp.promote_types(q.dtype, key_cache.dtype)
+    gp = -(-g // _GROUP_TILE) * _GROUP_TILE
+    qg = q.astype(dt).reshape(b, nkv, g, h)
+    if gp != g:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    buf = (2, nkv, pages * bs, h)
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale, pages=pages,
+                          exact=dt != jnp.bfloat16),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, nkv, gp, h),
+                             lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, nkv, gp, h),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(buf, key_cache.dtype),
+                pltpu.VMEM(buf, value_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, gp, h), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode",
+    )(seq_lens.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
+      qg, key_cache, value_cache)
+    return out[:, :, :g].reshape(b, n, h)
+
+
+def reads_own_pages(cache, t=1):
+    """Whether decode attention of `t` tokens a row over `cache` goes
+    through the Pallas kernel (each row's own live pages, straight from
+    the pool) and not the XLA form (the ladder width of every row, taken
+    and contracted).  Decided by what can be seen here, the same answer
+    for the write that precedes the read (`paged_write_chunk`), the read
+    (`paged_chunk_attention`) and the counter (`attn_positions`):
+    `ops.use_pallas()` (a TPU, no mesh, FLAGS_use_pallas), a plain
+    bfloat16 or float32 pool, one token a row, heads of whole 128-lane
+    rows and pages of whole sublane tiles."""
+    from paddle_tpu import ops
+
+    if isinstance(cache, QuantPool) or t != 1 or not ops.use_pallas():
+        return False
+    bs, h = cache.shape[-2:]
+    return (cache.dtype in (jnp.bfloat16, jnp.float32) and h % 128 == 0
+            and bs % (32 // cache.dtype.itemsize) == 0)
+
+
+def _paged_chunk_xla(q, key_cache, value_cache, block_tables, seq_lens, scale):
+    """The XLA form: the first `w` pages of every row, `w` the narrowest
+    width of `page_ladder` that covers max(seq_lens), chosen on the
+    device, taken (`_take_pages`) and contracted (`gathered_attention`)."""
     ladder = page_ladder(block_tables.shape[1])
     bs = pool_block_size(key_cache)
 
@@ -583,3 +846,30 @@ def paged_chunk_attention(q, key_cache, value_cache, block_tables, seq_lens,
         return at_width(ladder[0])(*args)
     return jax.lax.switch(_ladder_index(ladder, bs, seq_lens),
                           [at_width(w, _as_written) for w in ladder], *args)
+
+
+def paged_chunk_attention(q, key_cache, value_cache, block_tables, seq_lens,
+                          *, scale=None):
+    """Multi-token decode attention over the paged cache (speculative
+    verify / chunked decode): q [B, T, N, H]; seq_lens [B] INCLUDING all
+    T chunk tokens.  Chunk position j sits at global position
+    seq_lens - T + j and attends keys <= that position (bottom-right
+    causal within the chunk).  Returns [B, T, N, H].
+
+    T = 1 over a pool that `reads_own_pages` goes through the Pallas
+    kernel (`paged_decode`): each row's own pages and nothing beyond.
+    Everything else (T > 1, an int8 pool, a mesh, the CPU,
+    FLAGS_use_pallas=false) takes the XLA form, `_paged_chunk_xla`."""
+    from paddle_tpu._core import compile_cache
+
+    kernel = reads_own_pages(key_cache, q.shape[1])
+    # the counters that say which path this trace took
+    compile_cache.count("paged_kernel_traces" if kernel
+                        else "paged_xla_traces")
+    if kernel:
+        if scale is None:
+            scale = 1.0 / math.sqrt(q.shape[-1])
+        return _paged_decode_pallas(q[:, 0], key_cache, value_cache,
+                                    block_tables, seq_lens, scale)[:, None]
+    return _paged_chunk_xla(q, key_cache, value_cache, block_tables,
+                            seq_lens, scale)

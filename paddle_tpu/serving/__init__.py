@@ -146,9 +146,10 @@ _DECODE_STATS = {
     "moe_prefill_held_assignments": 0,
     # decode attention's reach (docs/DECODE.md "The decode attention"):
     # counted on the device by a model with K/V pools, the same road, once
-    # a token step: active rows x the positions of the table the step's
-    # attention read (the ladder width it chose), and the sum of those
-    # rows' lengths.  read / live is the step's read amplification.
+    # a token step: the positions the step's attention read (through the
+    # Pallas kernel the active rows' own pages, in the XLA form active rows
+    # x the ladder width it chose), and the sum of those rows' lengths.
+    # read / live is the step's read amplification.
     "attn_positions_read": 0,
     "attn_positions_live": 0,
     # the same by cache class, for a model with a window class beside its

@@ -177,8 +177,9 @@ def test_seeded_v5e_cache_is_well_formed():
     `"ms": 0.0` seeds of an earlier era are gone."""
     path = os.path.join(os.path.dirname(at.__file__), "tuned", "tpu_v5_lite.json")
     data = json.load(open(path))
-    assert set(data) <= set(at.FLASH_KERNELS) and "flash_fwd" in data
-    for kernel in data:
+    assert set(data) <= {*at.FLASH_KERNELS, "paged_decode"}
+    assert "flash_fwd" in data
+    for kernel in set(data) & set(at.FLASH_KERNELS):
         for key, entry in data[kernel].items():
             cfg = entry["config"]
             dims = dict(kv.split("=") for kv in key.split("|"))
@@ -188,6 +189,50 @@ def test_seeded_v5e_cache_is_well_formed():
                 dtype=dims["dtype"],
                 v_dim=int(dims["v_dim"]) if "v_dim" in dims else None) is None
             assert entry["ms"] > 0 and "measured" in entry["meta"]
+
+
+def test_seeded_v5e_paged_entries_are_candidates_and_measurements():
+    """Every `paged_decode` entry is a pages-a-step the tuner would offer
+    for its page geometry, with its measured time."""
+    path = os.path.join(os.path.dirname(at.__file__), "tuned", "tpu_v5_lite.json")
+    entries = json.load(open(path))["paged_decode"]
+    assert len(entries) >= 2
+    for key, entry in entries.items():
+        dims = dict(kv.split("=") for kv in key.split("|"))
+        offered = at.paged_candidates(
+            int(dims["block_size"]), int(dims["num_kv_heads"]),
+            int(dims["head_dim"]), table_width=64,
+            itemsize=np.dtype(dims["dtype"]).itemsize)
+        assert entry["config"] in offered
+        assert entry["ms"] > 0 and "measured" in entry["meta"]
+
+
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_tune_paged_records_one_calls_time_under_the_kernels_key(tmp_cache,
+                                                                 block_size):
+    """`tune_paged` drives the real kernel builder over its candidates (a
+    fake timer here: the second candidate is the fastest) and records the
+    winner under the key `_pages_per_step` builds, as ONE call's time of the
+    `calls` a dispatch holds; an untuned geometry keeps the default of 256
+    positions a step."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    seen = []
+
+    def timer(fn, args):
+        seen.append(1)
+        return {1: 8.0, 2: 2.0}.get(len(seen), 4.0)
+
+    assert pa._pages_per_step(block_size, 2, 128, "float32") == 256 // block_size
+    cfg, ms = at.tune_paged(
+        batch=2, num_heads=4, num_kv_heads=2, block_size=block_size,
+        table_width=4, lens=(5, 4 * block_size), dtype="float32", calls=4,
+        timer=timer, slug=at.device_kind_slug())
+    assert (cfg, ms) == ({"pages_per_step": 2}, 0.5) and len(seen) == 3
+    assert pa._pages_per_step(block_size, 2, 128, "float32") == 2
+    raw = json.load(open(os.path.join(
+        tmp_cache, at.device_kind_slug() + ".json")))["paged_decode"]
+    assert [e["ms"] for e in raw.values()] == [0.5]
 
 
 def test_v5p_readiness_geometry_and_peaks(tmp_cache):

@@ -170,15 +170,34 @@ def test_window_softmax_probe_on_cpu():
                                                               300 + 512 + 512)
 
 
-def test_dense_softmax_probe_on_cpu():
+@pytest.mark.parametrize("shape", chip_smoke.DENSE_PROBE_SHAPES,
+                         ids=lambda shape: shape[0].split()[0])
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_dense_softmax_probe_on_cpu(shape, path):
     """Rehearsal 1 of `--dense-softmax`, at the probe's own sizes (they are
-    small): the program's decode attention reads under the limit, the
-    bfloat16-softmax control over it, and the 512-position row takes the
-    32-page width of a 96-page table."""
-    out = chip_smoke.dense_softmax_probe(seed=2147484099)
+    small), through each path: the XLA form the CPU selects (the ladder's
+    width: 32 of 96 pages, 67 of 67) and the kernel interpreted (each row's
+    own pages).  The reading is under the limit, no worse than the XLA form
+    beside it, and the bfloat16-softmax control over it."""
+    import paddle_tpu as paddle
+
+    name, heads, kv_heads, head_dim, block_size, table_width, lens = shape
+    paddle.set_flags({"FLAGS_use_pallas":
+                      "true" if path == "kernel" else "auto"})
+    try:
+        out = chip_smoke.dense_softmax_probe(
+            seed=2147484099, name=name, heads=heads, kv_heads=kv_heads,
+            head_dim=head_dim, block_size=block_size, table_width=table_width,
+            lens=lens)
+    finally:
+        paddle.set_flags({"FLAGS_use_pallas": "auto"})
+    assert out["path"] == path
     assert (out["dense_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL
             < out["dense_softmax_rms_bfloat16"])
-    assert (out["positions_read"], out["positions_live"]) == (3 * 512, 942)
+    ladder = {16: 32, 128: 67}[block_size]
+    own = sum(-(-n // block_size) for n in lens)
+    assert (out["positions_read"], out["positions_live"]) == (
+        (own if path == "kernel" else 3 * ladder) * block_size, sum(lens))
 
 
 @pytest.fixture(scope="module")

@@ -4,7 +4,13 @@ pages are contracted as gathered, in the pool's type, with grouped heads.
 
 Against a dense float32 softmax on the values the pool holds; each ladder
 branch against the full width; what the bfloat16 path may not materialise;
-and the two counters a tiny engine hands to `decode_stats()`."""
+and the two counters a tiny engine hands to `decode_stats()`.
+
+Then the Pallas kernel (`paged_decode`, interpret mode here): one token a
+row over each row's OWN pages, against the same dense softmax and against
+`gathered_attention` on the same pools, with every page a row does not
+own holding NaN; which path is selected, what `attn_positions` counts for
+each, and the two trace counters."""
 
 import math
 
@@ -219,3 +225,220 @@ def test_a_tiny_engine_reports_what_its_lengths_say():
     assert (st["attn_positions_read"], st["attn_positions_live"]) == (
         want_read, want_live)
     assert 1.0 < st["attn_positions_read"] / st["attn_positions_live"]
+
+
+# --------------------------------------------------------------------------
+# the kernel: each row's own pages (interpret mode)
+
+KH = 128                 # the kernel takes heads of whole 128-lane rows
+KW = {16: 5, 128: 3}     # pages a row of the table, by block size
+
+
+@pytest.fixture
+def pallas_on():
+    """`ops.use_pallas()` true off a TPU: the kernel, interpreted."""
+    import paddle_tpu as paddle
+
+    paddle.set_flags({"FLAGS_use_pallas": "true"})
+    yield
+    paddle.set_flags({"FLAGS_use_pallas": "auto"})
+
+
+def _ragged_lens(bs, w):
+    """One batch: 1; one under, at and over a page boundary; the full
+    table; and an inactive row, parked at length 1 as the engine parks it."""
+    return np.asarray([1, bs - 1, bs, bs + 1, w * bs, 1], np.int32)
+
+
+def _own_page_pools(dtype, bs, nkv, lens, seed):
+    """K and V pools in which a row's live pages (ceil(len / bs) of its
+    table row, the table shuffled over the pool) hold seeded values and
+    EVERY OTHER page of the pool NaN: (kc, vc, tables, K, V), K / V the
+    float32 values of each row's whole table width, [B, W * bs, nkv, KH]
+    (NaN beyond the live pages)."""
+    rng = np.random.default_rng(seed)
+    b, w = len(lens), KW[bs]
+    nb = b * w + 2
+    tables = rng.permutation(nb)[:b * w].astype(np.int32).reshape(b, w)
+    pools, views = [], []
+    for _ in range(2):
+        pool = np.full((nb, nkv, bs, KH), np.nan, np.float32)
+        for r, n in enumerate(lens):
+            own = tables[r, :-(-int(n) // bs)]
+            pool[own] = rng.standard_normal((len(own), nkv, bs, KH))
+        pool = jnp.asarray(pool, dtype)
+        pools.append(pool)
+        view = np.asarray(pool, np.float32)[tables]        # [B, W, nkv, bs, KH]
+        views.append(np.moveaxis(view, 2, 3).reshape(b, w * bs, nkv, KH))
+    return pools[0], pools[1], jnp.asarray(tables), views[0], views[1]
+
+
+@pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bs", [16, 128])
+@pytest.mark.parametrize("group", [1, 2, 6])
+def test_kernel_reads_own_pages_and_matches_the_dense_softmax(group, bs, pool,
+                                                              pallas_on):
+    """Ragged rows of one batch through `paged_decode_attention` with the
+    kernel selected: against the dense softmax of the live positions and
+    against `gathered_attention` over the same values.  A page the kernel
+    read beyond a row's own would put NaN into that row."""
+    from paddle_tpu import profiler
+
+    nkv = 2
+    dtype = jnp.float32 if pool == "float32" else jnp.bfloat16
+    lens = _ragged_lens(bs, KW[bs])
+    kc, vc, tables, k, v = _own_page_pools(dtype, bs, nkv, lens,
+                                           seed=group * 100 + bs)
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((len(lens), group * nkv, KH)) * 2.0,
+                    dtype)
+    before = profiler.compile_stats()
+    got = pa.paged_decode_attention(q, kc, vc, tables, jnp.asarray(lens))
+    after = profiler.compile_stats()
+    assert (after["paged_kernel_traces"] - before["paged_kernel_traces"],
+            after["paged_xla_traces"] - before["paged_xla_traces"]) == (1, 0)
+    assert got.dtype == dtype and not np.isnan(np.asarray(got, np.float32)).any()
+    tol = dict(rtol=2e-2, atol=2e-2) if pool == "bfloat16" else dict(
+        rtol=2e-5, atol=2e-5)
+    want = _dense_reference(np.asarray(q, np.float32)[:, None], k, v, lens)
+    np.testing.assert_allclose(np.asarray(got, np.float64), want[:, 0], **tol)
+    # the ONE definition, on the same values with the foreign pages zeroed
+    # (0 x NaN is NaN there): the same products, another order of sums
+    clean = [jnp.nan_to_num(pa._take_pages(c, tables)) for c in (kc, vc)]
+    same = pa.gathered_attention(q[:, None], *clean, jnp.asarray(lens))[:, 0]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float64), np.asarray(same, np.float64),
+        **(dict(rtol=1e-2, atol=1e-2) if pool == "bfloat16" else dict(
+            rtol=2e-6, atol=2e-6)))
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3, 8])
+@pytest.mark.parametrize("bs", [16, 128])
+def test_kernel_gives_the_same_numbers_at_any_pages_a_step(bs, pages):
+    """Pages a step is a tile, not a result: a step whose last pages lie
+    beyond the row fetches only the row's own (the rest of the buffer is
+    masked, and finite)."""
+    lens = _ragged_lens(bs, KW[bs])
+    kc, vc, tables, k, v = _own_page_pools(jnp.float32, bs, 2, lens, seed=bs)
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((len(lens), 4, KH)) * 2.0,
+                    jnp.float32)
+    got = pa._paged_decode_pallas(q, kc, vc, tables, jnp.asarray(lens),
+                                  1.0 / math.sqrt(KH), pages=pages)
+    want = _dense_reference(np.asarray(q)[:, None], k, v, lens)
+    np.testing.assert_allclose(np.asarray(got, np.float64), want[:, 0],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["chunk", "int8", "narrow_heads", "flag_off"])
+def test_everything_else_keeps_the_xla_form(case, pallas_on):
+    """T > 1, an int8 pool, heads that are no whole 128-lane rows, and
+    FLAGS_use_pallas=false: `reads_own_pages` says no, the trace counter
+    says XLA, and `attn_positions` counts the ladder."""
+    import paddle_tpu as paddle
+    from paddle_tpu import profiler
+
+    h, t, dtype = KH, 1, jnp.bfloat16
+    if case == "chunk":
+        t = 2
+    elif case == "int8":
+        dtype = jnp.int8
+    elif case == "narrow_heads":
+        h = 64
+    elif case == "flag_off":
+        paddle.set_flags({"FLAGS_use_pallas": "false"})
+    kc, vc = pa.alloc_paged_cache(8, 2, 16, h, dtype)
+    tables = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+    lens = jnp.asarray([5, 40], jnp.int32)
+    assert not pa.reads_own_pages(kc, t)
+    before = profiler.compile_stats()
+    out = pa.paged_chunk_attention(jnp.ones((2, t, 4, h), jnp.bfloat16), kc,
+                                   vc, tables, lens)
+    after = profiler.compile_stats()
+    assert out.shape == (2, t, 4, h)
+    assert (after["paged_kernel_traces"] - before["paged_kernel_traces"],
+            after["paged_xla_traces"] - before["paged_xla_traces"]) == (0, 1)
+    if t == 1:
+        read, live = pa.attn_positions(tables, 16, lens, pool=kc)
+        assert (int(read), int(live)) == (2 * 4 * 16, 45)
+
+
+@pytest.mark.parametrize("selected", [True, False])
+def test_attn_positions_counts_own_pages_with_the_kernel_and_the_ladder_without(
+        selected, pallas_on):
+    import paddle_tpu as paddle
+
+    if not selected:
+        paddle.set_flags({"FLAGS_use_pallas": "false"})
+    kc, _vc = pa.alloc_paged_cache(4, 2, BS * 8, KH, jnp.bfloat16)
+    bs = pa.pool_block_size(kc)                       # 16
+    tables = jnp.zeros((4, W), jnp.int32)
+    lens = jnp.asarray([1, 33, 16, 7 * 16 + 1], jnp.int32)
+    active = jnp.asarray([False, True, True, True])
+    assert pa.reads_own_pages(kc) == selected
+    read, live = pa.attn_positions(tables, bs, lens, active, pool=kc)
+    own = (3 + 1 + 8) * bs                           # ceil(len / 16) pages
+    ladder = 3 * 16 * bs                             # 113 positions: 16 pages
+    assert (int(read), int(live)) == (own if selected else ladder, 162)
+    # no pool named: the XLA form's count, as before
+    read, _ = pa.attn_positions(tables, bs, lens, active)
+    assert int(read) == ladder
+
+
+@pytest.mark.parametrize("order", ["rows", "slots"])
+@pytest.mark.parametrize("t", [1, 3])
+def test_the_two_orders_of_the_slot_writes_write_the_same_pool(t, order):
+    """`_write_rows` (the order a pool read by the kernel keeps) and
+    `_write_slots` are one scatter seen two ways."""
+    rng = np.random.default_rng(t)
+    cache = jnp.asarray(rng.standard_normal((12, 2, 4, 8)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(12).reshape(3, 4).astype(np.int32))
+    pos = jnp.asarray([[0], [5], [13]], jnp.int32) + jnp.arange(t)[None, :]
+    new = jnp.asarray(rng.standard_normal((3, t, 2, 8)), jnp.float32)
+    write = pa._write_rows if order == "rows" else pa._write_slots
+    got = np.asarray(write(cache, new, tables, pos))
+    want = np.array(cache)
+    for b in range(3):
+        for j in range(t):
+            p = int(pos[b, j])
+            want[int(tables[b, p // 4]), :, p % 4] = np.asarray(new[b, j])
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_tiny_engine_through_the_kernel_emits_the_xla_forms_tokens(pallas_on):
+    """The macro-step with the kernel selected (rows writes, own pages):
+    the streams of the XLA form, token for token, and `attn_positions_read`
+    counting each active row's own pages."""
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+
+    block, prompts, new = 8, {"a": 5, "b": 29}, {"a": 6, "b": 12}
+
+    def serve(flag):
+        paddle.set_flags({"FLAGS_use_pallas": flag})
+        paddle.seed(0)
+        model = LlamaForCausalLM(llama_tiny(
+            dtype="float32", num_attention_heads=2, num_key_value_heads=1))
+        model.eval()
+        eng = serving.GenerationEngine(model, max_batch=3, block_size=block,
+                                       num_blocks=3 * 20)
+        rng = np.random.default_rng(0)
+        serving.reset_decode_stats()
+        for rid, n in prompts.items():
+            eng.add_request(rid, rng.integers(0, 1000, n).tolist(),
+                            max_new_tokens=new[rid])
+        out = {}
+        while eng.has_work():
+            for rid, toks in eng.step().items():
+                out.setdefault(rid, []).extend(toks)
+        return out, serving.decode_stats()
+
+    want, xla = serve("false")
+    got, st = serve("true")
+    assert got == want and st["tokens"] == xla["tokens"] == 16
+    # the j-th later token is one token step over a row of prompt + j
+    own = sum(-(-(prompts[r] + j) // block) * block
+              for r in prompts for j in range(1, new[r]))
+    assert st["attn_positions_read"] == own < xla["attn_positions_read"]
+    assert st["attn_positions_live"] == xla["attn_positions_live"]

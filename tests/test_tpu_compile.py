@@ -422,27 +422,25 @@ def test_kernel_names_reach_the_hlo_instruction_names(one_chip, mosaic):
     assert matched == {n for n in names if "flash_" in n} and len(matched) == 3
 
 
-def test_decode_attention_branches_take_the_pool_as_it_is_written(one_chip,
-                                                                  mosaic):
+# the two serving cells that read K/V pages: (rows, query heads, K/V heads,
+# head width, table width, block size, pool blocks)
+PAGED_CELLS = {"laguna-s-2.1": (32, 48, 8, 128, 67, 128, 2176),
+               "internlm2-1.8b": (32, 16, 8, 128, 96, 16, 3104)}
+
+
+def _write_attend_scan(write, attend, geometry, one_chip):
     """The write -> attend sequence of a decode layer inside a scan (the
-    macro-step's shape), at decode-sat's geometry: the ladder's branches
-    hold NO copy of a pool.  Without `paged_attention._as_written` each
-    branch copies both whole pools (102 MB each, per layer and token step):
-    the loop keeps a pool in the order its slot writes prefer, a branch
-    takes its operands in the default order unless told."""
-    import re
-
-    from paddle_tpu.ops import paged_attention as pa
-
-    b, n, nkv, h, w, bs, nb = 32, 16, 8, 128, 96, 16, 3104
+    macro-step's shape), compiled; its text."""
+    b, n, nkv, h, w, bs, nb = geometry
 
     def steps(q, kc, vc, new, tables, lens):
         def one(carry, _):
             kc, vc, lens, acc = carry
-            kc = pa.paged_write(kc, new, tables, lens - 1)
-            vc = pa.paged_write(vc, new, tables, lens - 1)
-            o = pa.paged_decode_attention(q + acc.astype(q.dtype), kc, vc,
-                                          tables, lens)
+            pos = (lens - 1)[:, None]
+            kc = write(kc, new[:, None], tables, pos)
+            vc = write(vc, new[:, None], tables, pos)
+            o = attend((q + acc.astype(q.dtype))[:, None], kc, vc, tables,
+                       lens)[:, 0]
             return (kc, vc, lens + 1, acc + o.astype(jnp.float32)), None
 
         carry, _ = jax.lax.scan(
@@ -454,14 +452,165 @@ def test_decode_attention_branches_take_the_pool_as_it_is_written(one_chip,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     pool = s((nb, nkv, bs, h))
-    text = jax.jit(steps, donate_argnums=(1, 2)).lower(
+    return jax.jit(steps, donate_argnums=(1, 2)).lower(
         s((b, n, h)), pool, pool, s((b, nkv, h)), s((b, w), jnp.int32),
         s((b,), jnp.int32)).compile().as_text()
+
+
+def _pool_copies(text, nb, nkv, bs, h):
+    import re
+
+    return re.findall(rf"= bf16\[{nb},{nkv},{bs},{h}\]\S* copy\(", text)
+
+
+def test_decode_attention_branches_take_the_pool_as_it_is_written(one_chip,
+                                                                  mosaic):
+    """The XLA form (`_write_slots` -> `_paged_chunk_xla`: what a mesh, an
+    int8 pool's neighbours and T > 1 run) at decode-sat's geometry: the
+    ladder's branches hold NO copy of a pool.  Without
+    `paged_attention._as_written` each branch copies both whole pools (102
+    MB each, per layer and token step): the loop keeps a pool in the order
+    its slot writes prefer, a branch takes its operands in the default
+    order unless told."""
+    import re
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, nkv, h, w, bs, nb = PAGED_CELLS["internlm2-1.8b"]
+    text = _write_attend_scan(
+        pa._write_slots,
+        lambda q, kc, vc, tables, lens: pa._paged_chunk_xla(
+            q, kc, vc, tables, lens, None),
+        PAGED_CELLS["internlm2-1.8b"], one_chip)
     branches = re.search(r"conditional\(.*branch_computations=\{([^}]*)\}",
                          text).group(1).split(", ")
     assert len(branches) == len(pa.page_ladder(w)) == 4
     for name in branches:
         body = text.split(f"\n{name} (", 1)[1].split("\n}\n", 1)[0]
         assert f"[{nb},{nkv},{bs},{h}]" in body        # the pool is read here
-        assert not re.search(
-            rf"= bf16\[{nb},{nkv},{bs},{h}\]\S* copy\(", body), name
+        assert not _pool_copies(body, nb, nkv, bs, h), name
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED_CELLS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_paged_decode_kernel_compiles_at_the_cells_geometries(
+        one_chip, mosaic, cell, dtype):
+    """The kernel through Mosaic as `paged_decode_attention` selects it:
+    scalar-prefetched tables, page DMAs predicated on the row's own page
+    count, groups of 6 and 2 query heads padded to the sublane tile, PV at
+    the exact product; under the name the trace will show."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, nkv, h, w, bs, nb = PAGED_CELLS[cell]
+    dt = jnp.dtype(dtype)
+
+    def s(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((nb, nkv, bs, h))
+    assert pa.reads_own_pages(pool)
+    text = jax.jit(pa.paged_decode_attention).lower(
+        s((b, n, h)), pool, pool, s((b, w), jnp.int32),
+        s((b,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "%paged_decode" in text
+    assert "conditional(" not in text
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED_CELLS))
+def test_the_kernels_write_attend_scan_copies_no_pool(one_chip, mosaic, cell):
+    """The twin of the test above for the kernel: a Mosaic call takes its
+    operands in the default order, and behind `_write_slots` XLA copied
+    both whole pools in front of it, per layer and token step (570 MB each
+    at laguna's geometry).  `paged_write_chunk` writes a pool the kernel
+    reads as rows (`_write_rows`), the loop keeps the default order, and no
+    operation of a pool's shape is copied: not in the loop, not at the
+    program's edge."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, nkv, h, w, bs, nb = PAGED_CELLS[cell]
+    text = _write_attend_scan(pa.paged_write_chunk, pa.paged_chunk_attention,
+                              PAGED_CELLS[cell], one_chip)
+    assert "%paged_decode" in text and "conditional(" not in text
+    assert not _pool_copies(text, nb, nkv, bs, h)
+    # ... which is the write's doing: the slot scatter in front of the same
+    # kernel brings the copies back
+    text = _write_attend_scan(pa._write_slots, pa.paged_chunk_attention,
+                              PAGED_CELLS[cell], one_chip)
+    assert len(_pool_copies(text, nb, nkv, bs, h)) >= 2
+
+
+def test_the_window_models_macro_step_copies_no_pool(one_chip, mosaic):
+    """`jit_decode_macro_step` of a window / full attention engine with
+    laguna-s-2.1's cache geometry (48 / 72 query heads over 8 K/V heads of
+    128, blocks of 128, a 67-page table, rings of 5; the other widths and
+    the row count small): the paged layers go through `paged_decode`, and
+    no pool, paged or ring, is copied anywhere in the program (the parent's
+    held 20 such copies, two a pool at the loop's edge)."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.window_moe import (WindowMoeForCausalLM,
+                                              window_moe_tiny)
+
+    paddle.seed(0)
+    rows = 8
+    model = WindowMoeForCausalLM(window_moe_tiny(
+        hidden_size=256, num_attention_heads_per_layer=(48, 72, 72, 72, 48),
+        num_key_value_heads=8, head_dim=128, sliding_window=512,
+        max_position_embeddings=16384, dtype="bfloat16",
+        held_experts=(2, 6)))
+    model.eval()
+    eng = serving.GenerationEngine(model, max_batch=rows, block_size=128,
+                                   num_blocks=rows * 67)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._step_avals())
+    text = eng._build_step(8).lower(*avals).compile().as_text()
+    assert len(re.findall(r"%paged_decode\S* = ", text)) >= 2
+    assert not re.findall(r"= bf16\[\d+,8,128,128\]\S* copy\(", text)
+
+
+def _tuned_paged_entries():
+    import json
+    import os
+
+    from paddle_tpu.ops import autotune
+
+    path = os.path.join(os.path.dirname(autotune.__file__), "tuned",
+                        V5E_SLUG + ".json")
+    with open(path) as f:
+        table = json.load(f)
+    return [(key, e["config"]["pages_per_step"], e["ms"])
+            for key, e in sorted(table.get("paged_decode", {}).items())]
+
+
+@pytest.mark.parametrize("key,pages,ms", _tuned_paged_entries(),
+                         ids=lambda v: v if isinstance(v, str) else "")
+def test_every_tuned_pages_a_step_is_one_the_kernel_finds_and_mosaic_accepts(
+        one_chip, mosaic, key, pages, ms):
+    """An entry of `paged_decode` was measured on the chip: it has its
+    time, the kernel finds it under the key it builds, and Mosaic compiles
+    the kernel with it; both cells' geometries have theirs."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    dims = dict(kv.split("=") for kv in key.split("|"))
+    bs, nkv, h = (int(dims[k]) for k in ("block_size", "num_kv_heads",
+                                         "head_dim"))
+    dt = jnp.dtype(dims["dtype"])
+    assert ms > 0, "not a measurement"
+    assert pa._pages_per_step(bs, nkv, h, dt) == pages
+    cells = {(g[5], g[2], g[3]) for g in PAGED_CELLS.values()}
+    assert (bs, nkv, h) in cells
+    assert {(int(d["block_size"]), int(d["num_kv_heads"]), int(d["head_dim"]))
+            for d in (dict(kv.split("=") for kv in k.split("|"))
+                      for k, _, _ in _tuned_paged_entries())} == cells
+
+    def s(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((1024, nkv, bs, h))
+    text = jax.jit(lambda *a: pa._paged_decode_pallas(*a, 0.1)).lower(
+        s((32, 2 * nkv, h)), pool, pool, s((32, 2 * pages), jnp.int32),
+        s((32,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
