@@ -13,6 +13,15 @@
                                        # plain reference, and the controls
                                        # that must fail (window ignored, gate
                                        # left out, bfloat16 router / softmax)
+    python chip_smoke.py --cca-moe-logits  # one chip: the convolved-latent
+                                       # attention / top-1 expert model's
+                                       # LOGITS through the engine (pages and
+                                       # state a slot) against its plain
+                                       # reference, the controls (conv, shift,
+                                       # carry, skip left out) and the probes
+                                       # on the same inputs (router type, the
+                                       # conv tail across a block boundary,
+                                       # decode attention over its pools)
     python chip_smoke.py --dense-softmax   # one chip: the dense decode attention
                                        # (the path it selects: the Pallas
                                        # kernel on a chip) over bfloat16 paged
@@ -414,7 +423,7 @@ def _row_error(got, want):
 
 
 def _engine_logits(fam, ref, cfg_file, *, seed, prompt_lens, decoded,
-                   block_size, label, tol, tie_tol) -> dict:
+                   block_size, label, tol, tie_tol, tau=None) -> dict:
     """A configuration of the family `fam` (a perfbench family module; `ref`
     its reference) served by GenerationEngine: one prompt of each length, and
     LOGITS, not tokens, against the plain float32 reference's full forward
@@ -422,7 +431,8 @@ def _engine_logits(fam, ref, cfg_file, *, seed, prompt_lens, decoded,
     decode step through the resident pools after `decoded[0]` and
     `decoded[1]` decoded tokens, teacher-forced on the engine's own tokens.
     Rows are held to `tol` sigma of the reference (`tie_tol` for a near tie
-    of the row's own routing) by `rows_within`, which the caller runs once
+    of the row's own routing, within `tau`: ROUTER_TIE_TAU where none is
+    given) by `rows_within`, which the caller runs once
     every reading has been said.  Returns the model, the reference's weights
     and sizes, per request (ids, places, reference logits, near ties) and the
     program's logits, the errors, and `rows_within`.
@@ -511,8 +521,8 @@ def _engine_logits(fam, ref, cfg_file, *, seed, prompt_lens, decoded,
         t0 = time.perf_counter()
         ids = np.concatenate([p, np.asarray(toks[i][:decoded[-1] + 1], np.int32)])
         at = [len(p) - 1] + [len(p) + d for d in decoded]
-        want, tie = ref.logits_and_near_ties(weights, sizes, ids, at,
-                                             ROUTER_TIE_TAU)
+        want, tie = ref.logits_and_near_ties(
+            weights, sizes, ids, at, ROUTER_TIE_TAU if tau is None else tau)
         want, tie = np.asarray(want), np.asarray(tie)
         refs.append((ids, at, want, tie))
         for j, place in enumerate(["prefill"] + [f"decoded {d}" for d in decoded]):
@@ -850,6 +860,185 @@ def window_moe_logits_phase(cfg_file, *, seed, device, prompt_lens, decoded,
     run["rows_within"]()     # after every reading has been said
     _release()
     return out
+
+
+# ------------------------------- attention in a convolved latent (ZAYA1) ----
+
+# The --cca-moe-logits comparison (PERF.md section 6, PR 34, has the readings
+# these were set from).  A row's error is max |program - reference| over the
+# vocabulary in the reference row's standard deviations, as above.  The router
+# is top-1: a NEAR TIE is a token whose first and second selection scores
+# (p + beta, probabilities) lie within CCA_ROUTER_TIE_TAU of each other in
+# some layer.  Readings (my chip runs, PR 34, seed 2147484013): clean rows
+# 0.0405-0.0581, one near tie that flipped 0.5067 (four that did not
+# 0.043-0.055); the rows of the 8,192-token request against the reference with
+# the depthwise convolution left out 0.3259-0.5595, the value shift 0.2195-
+# 0.5176, the carry 1.4267-1.8632, the skip choice's yield 0.0876-0.3705; a
+# bfloat16 router agrees with the reference on 99.54% of 24,576 pairs where
+# the program's agrees on 100.00%.
+CCA_MOE_LOGIT_TOL = 0.15
+CCA_MOE_TIE_TOL = 1.5
+CCA_ROUTER_TIE_TAU = 0.02
+CCA_ROUTE_AGREEMENT_MIN = 0.998
+# the convolutions' tail across a block boundary: rms error of the decode
+# form's q, k and v (state carried from the position before) against the
+# float32 reference over the whole sequence, relative to their rms (0.0028-
+# 0.0030 read; 0.87-0.94 with the tail dropped)
+CCA_CONV_TAIL_TOL = 0.03
+CCA_CONTROLS = ("no_conv0", "no_shift", "no_carry", "skip_zero")
+
+
+def _bfloat16_route_mlp(m, r_prev, w, *, eps):
+    """`models.cca_moe.route_mlp` with every type lowered: the control."""
+    import jax
+    import jax.numpy as jnp
+
+    bf = jnp.bfloat16
+    t = lambda a: a.astype(bf)  # noqa: E731
+    r = jnp.dot(t(m), t(w["down_w"])) + t(w["down_b"]) + t(w["gamma"]) * t(r_prev)
+    rf = r.astype(jnp.float32)
+    n = (rf * jax.lax.rsqrt(jnp.mean(rf * rf, -1, keepdims=True) + eps)).astype(bf)
+    a = jax.nn.gelu(jnp.dot(n * t(w["norm_g"]), t(w["w1"])) + t(w["b1"]),
+                    approximate=False)
+    a = jax.nn.gelu(jnp.dot(a, t(w["w2"])) + t(w["b2"]), approximate=False)
+    p = jax.nn.softmax(jnp.dot(a, t(w["w3"])), axis=-1)
+    chosen = jnp.argmax(p + t(w["beta"]), axis=-1).astype(jnp.int32)
+    weight = jnp.take_along_axis(p, chosen[:, None], axis=1)
+    return chosen[:, None], weight.astype(jnp.float32), r.astype(jnp.float32)
+
+
+def cca_conv_tail_probe(model, weights, sizes, *, seed, block_size) -> dict:
+    """The convolutions' tail across a block boundary, on the SAME inputs:
+    layer 0's attention sublayer is handed a seeded normed input of
+    block_size + 2 positions; its PREFILL form runs the first block_size - 1
+    and leaves the state a slot keeps, its DECODE form then takes positions
+    block_size - 1, block_size and block_size + 1 one at a time, each from
+    the state the one before left (the second and third lie in the next page
+    of the K/V pools; what the convolutions and the value shift need of the
+    token before comes from the state, never from a page).  Its q, k and v
+    at those three positions against the float32 reference's over the whole
+    sequence; and once more with the state zeroed at the boundary (the tail
+    dropped), the control."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu._core.tensor import Tensor
+    from perfbench import reference_cca_moe as ref
+
+    attn = model.model.layers[0].self_attn
+    cfg = model.config
+    s = block_size + 2
+    draw = np.random.default_rng([seed % 2 ** 63, 29])
+    dt = attn.qk_proj.weight._value.dtype
+    n = jnp.asarray(draw.standard_normal((1, s, cfg.hidden_size)), dt)
+    w = weights["layers"][0]
+    want = ref._latent(
+        n[0].astype(jnp.float32),
+        {k: w[k] for k in ("w_qk", "w_v", "conv0_w", "conv0_b", "conv1_w",
+                           "conv1_b", "tau")},
+        heads=sizes["heads"], kv_heads=sizes["kv_heads"], d=sizes["head_dim"],
+        theta=sizes["theta"], rotary=sizes["rotary"], dt=jnp.float32,
+        no_conv0=False, no_conv1=False, no_shift=False)
+    p = block_size - 1
+
+    @jax.jit
+    def carried(n, drop):
+        _o, _k, _v, state = attn.prefill(Tensor(n[:, :p]))
+        state = jax.tree_util.tree_map(
+            lambda x: jnp.where(drop, jnp.zeros_like(x), x), state)
+        z, c, v2 = state
+        z, c = z[:, :, 0], c[:, :, 0]      # the one "head" of C channels
+        out = []
+        for t in range(p, s):
+            q, k, v, (z, c, v2) = attn.project(
+                Tensor(n[:, t:t + 1]), (z, c, v2),
+                jnp.full((1, 1), t, jnp.int32))
+            out.append((q[0, 0], k[0, 0], v[0, 0]))
+        return out
+
+    out = {}
+    for name, drop in (("conv_tail_rms", False), ("conv_tail_rms_dropped", True)):
+        got = carried(n, drop)
+        out[name] = max(
+            _rms_error(g.astype(jnp.float32), x[t])
+            for t, step in zip(range(p, s), got) for g, x in zip(step, want))
+    say(f"cca probes: the convolutions' tail across the block boundary at "
+        f"{block_size}: decode-form q, k, v at positions {p}..{s - 1} stand "
+        f"{out['conv_tail_rms']:.5f} of their rms from the float32 reference "
+        f"over the whole sequence; with the state zeroed at the boundary "
+        f"{out['conv_tail_rms_dropped']:.5f} (limit {CCA_CONV_TAIL_TOL})")
+    check(out["conv_tail_rms"] <= CCA_CONV_TAIL_TOL,
+          "the decode form carries the convolutions' tail across a block "
+          "boundary as the whole-sequence reference has it")
+    return out
+
+
+def cca_moe_logits_phase(cfg_file, *, seed, device, prompt_lens, decoded,
+                         block_size, control_prompt=-1) -> dict:
+    """The configuration `cfg_file` (a perfbench configuration of the
+    `cca_moe` family) through `_engine_logits`: the prefill program and the
+    decode step through the pages AND the state a slot after `decoded` tokens
+    against the float32 reference.  Then the CONTROLS, which `main` requires
+    to fail: the program's rows of request `control_prompt` against the
+    reference with the depthwise convolution, the value shift, the carry
+    across depth or the skip choice's yield left out; and the probes on the
+    same inputs that no end-to-end row can give: the program's router and a
+    bfloat16 one on the reference's router inputs, the convolutions' tail
+    across a block boundary (`cca_conv_tail_probe`), the decode attention
+    over paged pools of this model's geometry (`dense_softmax_probe`)."""
+    import numpy as np
+
+    from perfbench import reference_cca_moe as ref
+    from perfbench.families import cca_moe as fam
+
+    run = _engine_logits(fam, ref, cfg_file, seed=seed,
+                         prompt_lens=prompt_lens, decoded=decoded,
+                         block_size=block_size, label="cca_moe logits",
+                         tol=CCA_MOE_LOGIT_TOL, tie_tol=CCA_MOE_TIE_TOL,
+                         tau=CCA_ROUTER_TIE_TAU)
+    out = {"errors": run["errors"], "ties": run["ties"]}
+    model, weights, sizes = run["model"], run["weights"], run["sizes"]
+    ids, at, want, _tie = run["refs"][control_prompt]
+    got = run["got"][control_prompt]
+    for control in CCA_CONTROLS:
+        t0 = time.perf_counter()
+        wrong = np.asarray(ref.logits_at(weights, {**sizes, control: True},
+                                         ids, at))
+        out[control] = [_row_error(g, w) for g, w in zip(got, wrong)]
+        say(f"cca_moe logits: against the reference with {control} (prompt "
+            f"{len(ids) - decoded[-1] - 1}) the program's rows stand "
+            + ", ".join(f"{e:.4f}" for e in out[control])
+            + f" sigma off (limit {CCA_MOE_LOGIT_TOL}; the two references "
+            "stand " + ", ".join(f"{_row_error(w, r):.4f}"
+                                 for w, r in zip(wrong, want))
+            + f" apart); {time.perf_counter() - t0:.1f} s")
+    prompt = run["refs"][0][0][:len(run["prompts"][0])]
+    for name, router in (("route_agreement", None),
+                         ("route_agreement_bfloat16", _bfloat16_route_mlp)):
+        out[name], pairs = fam.routing_agreement(model, weights, sizes, prompt,
+                                                 ref, route=router)
+    say(f"cca probes: on the reference's router inputs the program's MLP "
+        f"router makes the reference's choice for "
+        f"{100 * out['route_agreement']:.2f}% of {pairs} (token, layer) "
+        f"pairs; a bfloat16 router for "
+        f"{100 * out['route_agreement_bfloat16']:.2f}% (limit "
+        f"{100 * CCA_ROUTE_AGREEMENT_MIN:.1f}%)")
+    check(out["route_agreement"] >= CCA_ROUTE_AGREEMENT_MIN,
+          "the program's router agrees with the reference on its own inputs")
+    out.update(cca_conv_tail_probe(model, weights, sizes, seed=seed,
+                                   block_size=block_size))
+    cfg = model.config
+    out.update(dense_softmax_probe(
+        seed=seed, name=f"{cfg_file.get('name', 'cca_moe')} K/V pools",
+        heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
+        head_dim=cfg.head_dim, block_size=block_size,
+        table_width=-(-(max(prompt_lens) + decoded[-1] + 16) // block_size),
+        lens=tuple(n + decoded[-1] for n in prompt_lens)))
+    run["rows_within"]()     # after every reading has been said
+    _release()
+    return out
+
 
 
 def _bfloat16_softmax_dense(q, kc, vc, tables, lens):
@@ -1233,6 +1422,12 @@ def main(argv=None) -> int:
                          "(perfbench/configs/laguna-s-2.1.json) through "
                          "GenerationEngine, logits against the float32 "
                          "reference, with the controls that must fail")
+    ap.add_argument("--cca-moe-logits", action="store_true",
+                    help="one chip: perfbench/configs/zaya1-8b.json through "
+                         "GenerationEngine, logits (prefill, decode through "
+                         "pages and state a slot) against the float32 "
+                         "reference; the controls and the probes on the same "
+                         "inputs")
     ap.add_argument("--dense-softmax", action="store_true",
                     help="run ONLY the dense decode attention over "
                          "bfloat16 paged pools (internlm2-1.8b's and "
@@ -1305,6 +1500,29 @@ def main(argv=None) -> int:
               "a bfloat16 router does NOT agree with the reference on its "
               "inputs: the comparison tells it from float32")
         check(out["window_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
+              "a bfloat16 softmax does NOT agree with a float32 softmax on "
+              "the same rows: the comparison tells it from float32")
+    elif args.cca_moe_logits:
+        import os
+
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "perfbench", "configs",
+                               "zaya1-8b.json")) as f:
+            cfg_file = json.load(f)
+        out = cca_moe_logits_phase(cfg_file, seed=args.seed, device=dev,
+                                   prompt_lens=(2048, 4096, 8192),
+                                   decoded=(8, 64), block_size=128)
+        for control in CCA_CONTROLS:
+            check(max(out[control]) > CCA_MOE_LOGIT_TOL,
+                  f"the reference with {control} does NOT agree with the "
+                  "program: the comparison tells the mechanism left out")
+        check(out["route_agreement_bfloat16"] < CCA_ROUTE_AGREEMENT_MIN,
+              "a bfloat16 router does NOT agree with the reference on its "
+              "inputs: the comparison tells it from float32")
+        check(out["conv_tail_rms_dropped"] > CCA_CONV_TAIL_TOL,
+              "the convolutions with their tail dropped at a block boundary "
+              "do NOT agree with the reference")
+        check(out["dense_softmax_rms_bfloat16"] > SOFTMAX_RMS_TOL,
               "a bfloat16 softmax does NOT agree with a float32 softmax on "
               "the same rows: the comparison tells it from float32")
     elif args.dense_softmax:

@@ -18,6 +18,12 @@ from .window_moe import (  # noqa: F401
     WindowMoeModel,
     window_moe_tiny,
 )
+from .cca_moe import (  # noqa: F401
+    CcaMoeConfig,
+    CcaMoeForCausalLM,
+    CcaMoeModel,
+    cca_moe_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForMaskedLM,

@@ -1,10 +1,13 @@
 """The routed-expert layer every served model with experts shares: the router
-(`route`), the held experts' part of the layer (`routed_experts`), and the
+(`route`), the held experts' part of the layer for experts already chosen
+(`expert_loop`; `routed_experts` is `route` and then the loop), and the
 layers that hold their weights (`SwiGLU`, `RoutedExperts`).  models/mla_moe.py
 (sigmoid scoring, openPangu-Ultra-MoE's family) and models/window_moe.py
 (softmax scoring, Laguna's) both build their expert layers from here; what
 differs between them (scoring, top-k, the scale, the shared expert's width)
-is an argument taken from the model's own config.
+is an argument taken from the model's own config.  models/cca_moe.py's
+router is not one matrix (an MLP over a state carried across depth, top-1
+with a skip choice): it chooses for itself and calls `expert_loop`.
 
 The layer is DROPLESS and is told which contiguous range of experts it holds
 (`held = (first, count)`): it routes over ALL `routed` experts, normalises
@@ -25,8 +28,8 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu.nn import initializer as I
 from paddle_tpu._core.tensor import Tensor
 
-__all__ = ["EXPERT_TILE", "SCORINGS", "route", "routed_experts", "SwiGLU",
-           "RoutedExperts", "add_counts"]
+__all__ = ["EXPERT_TILE", "SCORINGS", "route", "routed_experts",
+           "expert_loop", "SwiGLU", "RoutedExperts", "add_counts"]
 
 # rows of one expert processed per pass of the expert loop (prefill); a
 # decode step's pass is its whole batch
@@ -58,21 +61,39 @@ def route(m, router_w, *, top_k, scale, normalize=True, scoring="sigmoid"):
 def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
                    normalize=True, scoring="sigmoid", active=None,
                    tile=EXPERT_TILE):
-    """The held experts' part of a routed-expert layer, dropless.
+    """The held experts' part of a routed-expert layer whose router is one
+    matrix: `route` over router_w [h, E] (E: ALL experts), then
+    `expert_loop` over what it chose (w normalised over all top_k chosen,
+    wherever they live)."""
+    top_i, w = route(m, router_w, top_k=top_k, scale=scale,
+                     normalize=normalize, scoring=scoring)
+    return expert_loop(m, top_i, w, gate_up, down, held=held, active=active,
+                       tile=tile)
 
-    m [T, h]; router_w [h, E] (E: ALL experts); gate_up and down: the
-    weights of experts first .. first + count - 1, [h, 2f] and [f, h] each,
-    either a LIST of `count` arrays (one array an expert: the loop over
-    experts is then unrolled, an expert's passes a code path of their own)
-    or ONE array with a leading axis of `count` (the loop over experts is
-    then one loop whose body indexes the stack: a program 1/count the size);
-    in both an expert nobody chose runs no pass and reads no weight.
-    active [T] bool or None (rows that are not committed work route nowhere
-    and are not counted).  Returns (out [T, h] float32, counts):
-    out = sum over each row's chosen experts THAT ARE HELD of w_e E_e(m),
-    w normalised over all top_k chosen; counts = the int32 scalars
-    assignments, held, peak (rows on the busiest held expert), touched
-    (held experts with a row) and layer_steps (1 if any row is live).
+
+def expert_loop(m, chosen, w, gate_up, down, *, held, active=None,
+                tile=EXPERT_TILE, routed=None, base=None):
+    """The held experts' part of a routed-expert layer, dropless, for experts
+    ALREADY CHOSEN: m [T, h]; chosen [T, k] int32 and w [T, k] float32, each
+    row's experts among ALL of them and their weights, from whatever router
+    the caller has.
+
+    gate_up and down: the weights of experts first .. first + count - 1,
+    [h, 2f] and [f, h] each, either a LIST of `count` arrays (one array an
+    expert: the loop over experts is then unrolled, an expert's passes a
+    code path of their own) or ONE array with a leading axis (the loop over
+    experts is then one loop whose body indexes the stack: a program
+    1/count the size; held expert e is entry e, or entry `base + e` where
+    `base`, a traced int32, says where this layer's experts begin in a
+    stack of several layers'); in both an expert nobody chose runs no pass
+    and reads no weight.  active [T] bool or None (rows that are not
+    committed work route nowhere and are not counted).  Returns (out [T, h]
+    float32, counts): out = sum over each row's chosen experts THAT ARE
+    HELD of w_e E_e(m); counts = the int32 scalars assignments, held, peak
+    (rows on the busiest held expert), touched (held experts with a row),
+    layer_steps (1 if any row is live) and skipped (live rows none of whose
+    choices is an expert: an index at or past `routed`, the count of ALL
+    experts, is a router's "no expert"; 0 where `routed` is not given).
 
     Static shapes throughout, so it runs inside the macro-step's scan and
     the prefill program: the (row, choice) pairs are sorted by held expert
@@ -82,11 +103,9 @@ def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
     nobody chose reads no weight, and no row is ever dropped whatever the
     router does."""
     first, count = held
-    t = m.shape[0]
-    top_i, w = route(m, router_w, top_k=top_k, scale=scale,
-                     normalize=normalize, scoring=scoring)
+    t, top_k = m.shape[0], chosen.shape[1]
     with jax.named_scope("moe.route"):
-        local = top_i - first
+        local = chosen - first
         mine = (local >= 0) & (local < count)
         live = jnp.ones((t,), bool) if active is None else active
         mine = mine & live[:, None]
@@ -106,7 +125,8 @@ def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
     def one_tile(e, i, out):
         # indexed INSIDE the pass: a stack is sliced only by a pass that
         # runs, as a list's array is read only by its own pass
-        w_gu, w_d = gate_up[e], down[e]
+        at_e = e if base is None else base + e
+        w_gu, w_d = gate_up[at_e], down[at_e]
         at = start[e] + i * tile + jnp.arange(tile, dtype=jnp.int32)
         ok = at < start[e] + per[e]
         at = jnp.minimum(at, n_pairs - 1)
@@ -140,7 +160,9 @@ def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
     counts = {"assignments": jnp.sum(live, dtype=jnp.int32) * top_k,
               "held": jnp.sum(per), "peak": jnp.max(per),
               "touched": jnp.sum(per > 0, dtype=jnp.int32),
-              "layer_steps": jnp.any(live).astype(jnp.int32)}
+              "layer_steps": jnp.any(live).astype(jnp.int32),
+              "skipped": jnp.int32(0) if routed is None else jnp.sum(
+                  live & jnp.all(chosen >= routed, axis=-1), dtype=jnp.int32)}
     return out, counts
 
 

@@ -352,6 +352,33 @@ class LayerStack(Layer):
         caller's jitted step (decode never differentiates), so it skips
         the ``apply`` funnel and recompute tiers entirely.
         """
+        has_extra = extra is not None
+
+        def step(layer, carry, xs):
+            out, kc, vc = body(layer, Tensor(carry), *xs)
+            if not isinstance(out, Tensor):
+                raise TypeError(
+                    "decode_scan body must return (Tensor, kc, vc); "
+                    f"got {type(out).__name__} carry")
+            return out._value, (kc, vc)
+
+        if not isinstance(h, Tensor):
+            h = Tensor(jnp.asarray(h))
+        carry, (new_k, new_v) = self.scan(
+            step, h._value,
+            (k_state, v_state, extra) if has_extra else (k_state, v_state))
+        return Tensor(carry), new_k, new_v
+
+    def scan(self, body, carry, xs=None):
+        """Scan the stack ONCE with any carry: ``body(layer, carry, xs_slice)
+        -> (carry, ys)``, `layer` the template bound to one layer's weights,
+        `carry` any pytree of raw arrays (a model whose layers hand on more
+        than the hidden state carries it here), `xs` a pytree whose leaves
+        have a leading layer axis ``[N, ...]`` and ride as per-layer state;
+        returns ``(carry, ys)``, ys stacked the same way.  Inference-only,
+        under ``no_grad`` inside the caller's jitted program, like
+        :meth:`decode_scan`, which is this with a Tensor carry and the two
+        K/V pools as xs and ys."""
         from paddle_tpu._core import autograd as core_ag
 
         self._sync_template_mode()
@@ -359,37 +386,20 @@ class LayerStack(Layer):
         slots = [self._slots[k] for k in self._stack_keys]
         state_vals = [self._stacked_tensor(k)._value
                       for k in self._stack_keys]
-        if not isinstance(h, Tensor):
-            h = Tensor(jnp.asarray(h))
-        has_extra = extra is not None
 
         def scan_body(carry, xs):
-            if has_extra:
-                slices, kc, vc, ex = xs
-            else:
-                slices, kc, vc = xs
+            slices, own = xs
             originals = [reg[short] for reg, short in slots]
             try:
                 for (reg, short), v in zip(slots, slices):
                     reg[short] = Tensor(v)
                 with core_ag.no_grad():
-                    if has_extra:
-                        out, kc, vc = body(template, Tensor(carry), kc, vc, ex)
-                    else:
-                        out, kc, vc = body(template, Tensor(carry), kc, vc)
-                if not isinstance(out, Tensor):
-                    raise TypeError(
-                        "decode_scan body must return (Tensor, kc, vc); "
-                        f"got {type(out).__name__} carry")
-                return out._value, (kc, vc)
+                    return body(template, carry, own)
             finally:
                 for (reg, short), v in zip(slots, originals):
                     reg[short] = v
 
-        xs = ((tuple(state_vals), k_state, v_state, extra) if has_extra
-              else (tuple(state_vals), k_state, v_state))
-        carry, (new_k, new_v) = jax.lax.scan(scan_body, h._value, xs)
-        return Tensor(carry), new_k, new_v
+        return jax.lax.scan(scan_body, carry, (tuple(state_vals), xs))
 
 
 def shard_stacked_params(stack: "LayerStack", mesh, place_fn, col_keys,

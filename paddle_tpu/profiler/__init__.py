@@ -71,6 +71,13 @@ SCOPE_NAMES = (
     # its expert layer is models/experts.py's, under the moe.* scopes above
     "attn.full.prefill", "attn.window.prefill", "attn.full.decode",
     "attn.window.decode", "attn.gate",
+    # models/cca_moe.py: the projections into the latent, the two causal
+    # convolutions, the q-k mean, norms and rope (cca.conv); the attention
+    # over them (the flash kernel in the prefill program, the paged write
+    # and read in the macro-step: cca.attend); the MLP router over the state
+    # carried across depth (moe.router_mlp); its experts' loop is
+    # models/experts.py's, under moe.route / moe.experts above
+    "cca.conv", "cca.attend", "moe.router_mlp",
 )
 
 _active_profiler = None  # checked by the op funnel (cheap global)

@@ -78,6 +78,9 @@ _DECODE_STATS = {
     "wk_pool_bytes": 0,
     "wv_pool_bytes": 0,
     "window_ring_blocks": 0,
+    # a STATE class's pools (state a slot: [max_batch, heads, width] a layer,
+    # no positions) under the model's own names too, and all of them here
+    "slot_state_bytes": 0,
     # sharded-serving tier: the most recent engine's PER-DEVICE pool
     # bytes (each pool leaf's committed sharding divides its global
     # bytes — ops.paged_attention.pool_device_nbytes over pool_parts)
@@ -144,6 +147,10 @@ _DECODE_STATS = {
     "moe_layer_steps": 0,
     "moe_prefill_assignments": 0,
     "moe_prefill_held_assignments": 0,
+    # rows that chose NO expert (a router with a skip choice): counted apart,
+    # and not among the assignments' held part
+    "moe_skipped": 0,
+    "moe_prefill_skipped": 0,
     # decode attention's reach (docs/DECODE.md "The decode attention"):
     # counted on the device by a model with K/V pools, the same road, once
     # a token step: the positions the step's attention read (through the
@@ -277,7 +284,9 @@ def _cache_blocks(spec, caches, start_tok, s0, bs, end=None):
     [ring_blocks, heads, bs, width]: ring block r holds the latest block j
     <= (end - 1) // bs of the sequence with j % ring_blocks == r (zeros
     where there is none), `end` the count of real positions (s0 where not
-    given; traced inside the prefill program).  The ONE shaper: eager
+    given; traced inside the prefill program).  A STATE class gets its one
+    row, [1, heads, width]: the model's forward hands back the state after
+    the last real position and nothing is shaped.  The ONE shaper: eager
     admissions call it on the host, the prefill program inside its trace."""
     n = -(-(s0 - start_tok) // bs)
     pad = start_tok + n * bs - s0
@@ -301,14 +310,18 @@ def _cache_blocks(spec, caches, start_tok, s0, bs, end=None):
         taken = jnp.take(blocks, jnp.clip(j, 0, n - 1), axis=0)
         return jnp.where((j >= 0)[:, None, None, None], taken, 0)
 
+    named = [{ps.name: t for (_c, ps), t in zip(spec.layer_pools(li), layer)}
+             for li, layer in enumerate(caches)]
     out = []
     for cls in spec.classes:
-        for p in range(len(cls.pools)):
-            if cls.window is None:
-                out.append([shape(caches[li][p]) for li in cls.layers])
+        for ps in cls.pools:
+            mine = [named[li][ps.name] for li in cls.layers]
+            if cls.slot_state:
+                out.append([getattr(t, "_value", t)[0] for t in mine])
+            elif cls.window is None:
+                out.append([shape(t) for t in mine])
             else:
-                out.append([ring(caches[li][p], cls.ring_blocks(bs))
-                            for li in cls.layers])
+                out.append([ring(t, cls.ring_blocks(bs)) for t in mine])
     return out
 
 
@@ -317,7 +330,7 @@ def _empty_caches(spec, batch=1):
     import paddle_tpu as paddle
 
     return [tuple(paddle.zeros([batch, 0, p.heads, p.width], dtype=p.dtype)
-                  for p in spec.class_of(li).pools)
+                  for _c, p in spec.layer_pools(li))
             for li in range(spec.n_layers)]
 
 
@@ -336,6 +349,18 @@ def _pour_new_blocks(pool, blocks, idx):
     zeros = jnp.zeros((n_t,) + blocks.shape[1:], blocks.dtype)
     return pa.paged_pour_blocks(
         pool, jnp.concatenate([blocks, zeros])[:n_t], idx)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _pour_stacked_blocks(pool, blocks, idx):
+    """`_pour_new_blocks` for a STACKED class's pool `[layers, blocks, ...]`:
+    `blocks[i]` is layer i's, and every layer's go to the same pages `idx` in
+    one scatter along the second axis (one dispatch a pool, not one a pool
+    and layer)."""
+    n_t = idx.shape[0]
+    new = jnp.stack(blocks).astype(pool.dtype)
+    zeros = jnp.zeros(new.shape[:1] + (n_t,) + new.shape[2:], new.dtype)
+    return pool.at[:, idx].set(jnp.concatenate([new, zeros], axis=1)[:, :n_t])
 
 
 # SLO classes for add_request(priority=): admission order is (class, submit
@@ -694,14 +719,19 @@ class GenerationEngine:
         # ring - 1 of that class's pools from here on, whatever it serves;
         # one constant [max_batch, ring] table a class (None for a paged
         # class, whose table is the requests'): on the host for the pour's
-        # page indices, on the device once for the macro-step
+        # page indices, on the device once for the macro-step.  A STATE
+        # class's pools have one row a slot, row i slot i's: the pour finds
+        # it like a ring of one, and no table goes to the device (decode's
+        # row b is slot b)
         self._ring_pages = [
-            None if c.window is None else np.arange(
-                self.max_batch * c.ring_blocks(self.block_size),
+            None if c.paged else np.arange(
+                self.max_batch * (1 if c.slot_state
+                                  else c.ring_blocks(self.block_size)),
                 dtype=np.int32).reshape(self.max_batch, -1)
             for c in spec.classes]
-        self._ring_tables = [None if t is None else jnp.asarray(t)
-                             for t in self._ring_pages]
+        self._ring_tables = [
+            None if c.window is None else jnp.asarray(t)
+            for c, t in zip(spec.classes, self._ring_pages)]
         self._free = list(range(self._num_blocks))
         self._ref = [0] * total  # per-block request refcounts (allocator)
         pc = (bool(prefix_cache) if prefix_cache is not None
@@ -828,6 +858,9 @@ class GenerationEngine:
                 pa.pool_nbytes(p) for p in pools)
         _DECODE_STATS["window_ring_blocks"] = sum(
             t.shape[1] for t in self._ring_tables if t is not None)
+        _DECODE_STATS["slot_state_bytes"] = sum(
+            _DECODE_STATS[ps.name + "_pool_bytes"]
+            for c in spec.classes if c.slot_state for ps in c.pools)
         # per-device footprint: each pool leaf's committed sharding
         # divides its bytes (== pool_bytes on single-device engines)
         _DECODE_STATS["pool_bytes_per_device"] = sum(
@@ -851,27 +884,52 @@ class GenerationEngine:
         if not self._spec.kv_pair:
             windows = [c.window for c in self._spec.classes
                        if c.window is not None]
+            state = [p.name for c in self._spec.classes if c.slot_state
+                     for p in c.pools]
             raise NotImplementedError(
                 f"GenerationEngine: {feature} cannot hold this model's "
                 f"cache pools {[p.name for p in self._spec.pools]} yet"
                 + (f" (a window class: rings of the last {windows[0]} "
                    "positions a slot, which are not pages)" if windows else "")
+                + (f" (a state class: {state} are state a slot, with no "
+                   "positions and no pages)" if state else "")
                 + "; it was built for a K/V pair (docs/DECODE.md, the model "
                 "contract)")
+
+    def _class_tables(self, block_table, ring_tables):
+        """`decode`'s tables where it takes one a class: a paged class's is
+        the block table, a window class's the slots' rings (`ring_tables`
+        holds None in every other class's place), a state class's None (its
+        pools' row b is row b's slot)."""
+        return tuple(block_table if c.paged else t
+                     for c, t in zip(self._spec.classes, ring_tables))
 
     def _alloc_pools(self, spec, total, sharding):
         """`pools[p][i]` of a cache specification, zeroed and placed: a
         paged class's pools `total` blocks each (the allocator's, plus a
-        scratch page a slot), a window class's max_batch rings."""
+        scratch page a slot), a window class's max_batch rings, a state
+        class's max_batch rows of [heads, width]."""
         from paddle_tpu.ops import paged_attention as pa
 
-        return [[self._place_pool(pa.alloc_paged_pool(
-                    total if cls.window is None
-                    else self.max_batch * cls.ring_blocks(self.block_size),
-                    ps.heads, self.block_size, ps.width,
-                    jnp.int8 if self._kv_dtype == "int8" else ps.dtype),
-                    sharding)
-                 for _ in cls.layers]
+        def blocks(cls):
+            return (total if cls.window is None
+                    else self.max_batch * cls.ring_blocks(self.block_size))
+
+        def one(cls, ps):
+            if cls.slot_state:
+                return jnp.zeros((self.max_batch, ps.heads, ps.width), ps.dtype)
+            return self._place_pool(pa.alloc_paged_pool(
+                blocks(cls), ps.heads, self.block_size, ps.width,
+                jnp.int8 if self._kv_dtype == "int8" else ps.dtype), sharding)
+
+        def stacked(cls, ps):
+            # made whole (never int8: a stacked class is not a K/V pair)
+            shape = ((self.max_batch, ps.heads, ps.width) if cls.slot_state
+                     else (blocks(cls), ps.heads, self.block_size, ps.width))
+            return jnp.zeros((len(cls.layers),) + shape, ps.dtype)
+
+        return [[stacked(cls, ps)] if cls.stacked
+                else [one(cls, ps) for _ in cls.layers]
                 for cls in spec.classes for ps in cls.pools]
 
     # ------------------------------------------------------ pool placement
@@ -962,8 +1020,7 @@ class GenerationEngine:
                 for t, v in zip(state, state_vals):
                     t._bind(v)
                 if per_class:
-                    tables = tuple(tables if t is None else t
-                                   for t in ring_tables)
+                    tables = self._class_tables(tables, ring_tables)
                 with no_grad():
                     h, _, _ = contract.decode(tokens, contract.pool_carry(pools),
                                               tables, lens, active=active)
@@ -1654,10 +1711,14 @@ class GenerationEngine:
                     logits_last = contract.logits(Tensor(last))._value[0, -1]
                 real = (jnp.arange(m_len + s_pad)
                         < m_len + n_real)[None, :, None, None]
+                # (a state class's row has no positions to zero)
                 blocks = _cache_blocks(
-                    spec, [tuple(jnp.where(real, t._value, 0) for t in layer)
-                           for layer in caches], m_len, m_len + s_pad, bs,
-                    end=m_len + n_real)
+                    spec, [tuple(t._value if c.slot_state
+                                 else jnp.where(real, t._value, 0)
+                                 for (c, _p), t in zip(spec.layer_pools(li),
+                                                       layer))
+                           for li, layer in enumerate(caches)],
+                    m_len, m_len + s_pad, bs, end=m_len + n_real)
                 return logits_last, blocks, aux
             finally:
                 for t, v in zip(state, originals):
@@ -2043,8 +2104,8 @@ class GenerationEngine:
 
     def _slot_rings(self, slot):
         """Per pool of the specification (`self._pools` order): the ring
-        pages of slot number `slot` in a window class's pools, None for a
-        paged class's."""
+        pages of slot number `slot` in a window class's pools (a state
+        class's one row), None for a paged class's."""
         return [None if t is None else t[slot]
                 for c, t in zip(self._spec.classes, self._ring_pages)
                 for _p in c.pools]
@@ -2057,13 +2118,21 @@ class GenerationEngine:
         quantized pool also resets a recycled page's stale scale.  A
         window class's blocks are its whole ring and go to `rings[p]`, the
         slot's ring pages (`_slot_rings`), never to `pages`: a ring is not
-        allocated by request."""
+        allocated by request.  A state class's block is the slot's one row,
+        poured the same way (a reused slot starts from its own prompt's
+        state).  A stacked class's pool takes all its layers' blocks in one
+        pour (`_pour_stacked_blocks`)."""
         # on the device ONCE, then shared by every pour (a numpy index would
         # be transferred again by each of the pools x layers calls)
         pages = jnp.asarray(pages, jnp.int32)
         rings = [None if r is None else jnp.asarray(r) for r in rings or ()]
+        stacked = ([c.stacked for c in self._spec.classes for _p in c.pools]
+                   if pools is self._pools else [False] * len(pools))
         for q, (per_layer, new) in enumerate(zip(pools, blocks)):
             idx = pages if not rings or rings[q] is None else rings[q]
+            if stacked[q]:                 # one array, the layers in front
+                per_layer[0] = _pour_stacked_blocks(per_layer[0], list(new), idx)
+                continue
             for li in range(len(per_layer)):
                 # placed: the pool stays committed to its head-sharded
                 # layout, so the decode executable's input shardings stay
@@ -2411,9 +2480,8 @@ class GenerationEngine:
                         # for every row: a finished or empty lane writes
                         # position 0 of a ring nobody reads until its
                         # slot's next admission pours it anew
-                        tables_eff = tuple(
-                            tables_eff if t is None else t
-                            for t in class_tables[0])
+                        tables_eff = self._class_tables(tables_eff,
+                                                        class_tables[0])
                     lens_eff = jnp.where(done, jnp.int32(1), lens_c)
                     with no_grad():
                         h, pools_c, aux = contract.decode(

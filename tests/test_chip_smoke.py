@@ -157,6 +157,39 @@ def test_window_moe_logits_phase_on_cpu_tiny(monkeypatch):
     assert (out["positions_read"], out["positions_live"]) == (5 * 12, 7 + 4 * 8)
 
 
+def test_cca_moe_logits_phase_on_cpu_tiny(monkeypatch):
+    """Rehearsal 1 of `--cca-moe-logits`: the same drive at a tiny float32
+    size (blocks of 4, prompts padded to their buckets, the state a slot many
+    steps old at the second decode boundary), where program and reference
+    agree to rounding, the references with a mechanism left out do not, the
+    convolutions' tail carried across a block boundary is the whole
+    sequence's, and dropped it is not."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                    "perfbench"))
+    from test_perfbench_cca_moe import TINY
+
+    monkeypatch.setattr(chip_smoke, "CCA_MOE_LOGIT_TOL", 1e-3)
+    monkeypatch.setattr(chip_smoke, "CCA_MOE_TIE_TOL", 1e-3)
+    monkeypatch.setattr(chip_smoke, "CCA_ROUTE_AGREEMENT_MIN", 1.0)
+    monkeypatch.setattr(chip_smoke, "CCA_CONV_TAIL_TOL", 1e-4)
+    out = chip_smoke.cca_moe_logits_phase(
+        TINY, seed=3, device=jax.devices()[0], prompt_lens=(13, 30, 61),
+        decoded=(8, 16), block_size=4)
+    assert len(out["errors"]) == 9 and max(out["errors"]) < 1e-3
+    # the controls whose mechanism every token meets read beyond the limit
+    # the program's own rows stay under (skip_zero needs a token that chose
+    # skip among those the rows see: the cell's rows have thousands)
+    for control in ("no_conv0", "no_shift", "no_carry"):
+        assert min(out[control]) > 1e-3, control
+    assert max(out["skip_zero"]) > 1e-3
+    assert out["route_agreement"] == 1.0 >= out["route_agreement_bfloat16"]
+    assert out["conv_tail_rms"] < 1e-4 < 0.05 < out["conv_tail_rms_dropped"]
+    assert (out["dense_softmax_rms_float32"] < chip_smoke.SOFTMAX_RMS_TOL)
+
+
 def test_window_softmax_probe_on_cpu():
     """Rehearsal 1 of the window read's probe at its own widths (72 / 8 heads
     x 128, window 512, rings of 5 x 128), rows below, at and past the window

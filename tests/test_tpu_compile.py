@@ -571,6 +571,61 @@ def test_the_window_models_macro_step_copies_no_pool(one_chip, mosaic):
     assert not re.findall(r"= bf16\[\d+,8,128,128\]\S* copy\(", text)
 
 
+def test_the_convolved_latent_models_programs_copy_no_pool_and_no_expert_stack(
+        one_chip, mosaic):
+    """`jit_decode_macro_step` of a `models/cca_moe.py` engine at zaya1-8b's
+    widths and cache geometry (8 / 2 heads of 128, experts of 2,048, blocks
+    of 128, a 67-page table; depth 2, 4 experts, a small vocabulary and 8
+    rows: 0.2 GB of weights on this sandbox's CPU): K and V
+    ride the layers' scan as ONE flat pool each, written and read in place
+    through the block table shifted by layer, so no pool is copied or cut
+    (held a layer, and stacked at the program's edge, the pools' second copy
+    alone would be the chip: 2 x 2.28 GB at depth 16); the experts' stack
+    ([layers x experts, 2048, 4096]) is indexed inside the pass that runs and
+    never copied either; the K/V read is `paged_decode`.  What does copy the
+    pools is `next_token_logits`' program, which must leave them as they
+    are: its temporaries are the two pools, the reason the cell runs depth
+    12."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.cca_moe import CcaMoeConfig, CcaMoeForCausalLM
+
+    paddle.seed(0)
+    rows, layers = 8, 2
+    model = CcaMoeForCausalLM(CcaMoeConfig(num_hidden_layers=layers,
+                                           vocab_size=4096, num_experts=4))
+    model.eval()
+    eng = serving.GenerationEngine(model, max_batch=rows, block_size=128,
+                                   num_blocks=rows * 67)
+    blocks = rows * 67 + rows
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    step = eng._build_step(8).lower(*described(eng._step_avals())).compile()
+    text = step.as_text()
+    assert len(re.findall(r"%paged_decode\S* = ", text)) == 1     # one body
+    assert not re.findall(rf"= bf16\[({layers},)?({blocks}|{layers * blocks}),"
+                          r"2,128,128\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[\d+,2048,(4096|2048)\]\S* copy\(", text)
+    pools = 2 * layers * blocks * 2 * 128 * 128 * 2
+    assert step.memory_analysis().temp_size_in_bytes < pools // 8
+    # the admission's program: the flash kernel, and nothing of the stack
+    # copied (8,192 positions: the cell's longest bucket)
+    fn = eng._prefill_program(8192, 0)
+    text = fn.lower(described([t._value for t in eng._state]),
+                    jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                                         sharding=one_chip),
+                    jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                    None).compile().as_text()
+    assert "flash_fwd" in text
+    assert not re.findall(r"= bf16\[\d+,2048,(4096|2048)\]\S* copy\(", text)
+
+
 def _tuned_paged_entries():
     import json
     import os
