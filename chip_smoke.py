@@ -558,15 +558,31 @@ def mla_moe_logits_phase(cfg_file, *, seed, device, prompt_lens, decoded,
     `mla_moe` family) through `_engine_logits` (the prefill program and the
     decode step through the paged latent pool against the float32
     reference), then `precision_probes`."""
+    from paddle_tpu import profiler, serving
     from perfbench import reference_mla_moe as ref
     from perfbench.families import mla_moe as fam
 
+    serving.reset_decode_stats()
+    traces = profiler.compile_stats()
     run = _engine_logits(fam, ref, cfg_file, seed=seed,
                          prompt_lens=prompt_lens, decoded=decoded,
                          block_size=block_size, label="mla_moe logits",
                          tol=MLA_MOE_LOGIT_TOL, tie_tol=MLA_MOE_TIE_TOL)
     run["rows_within"]()
     out = {"errors": run["errors"], "ties": run["ties"]}
+    # which form the engine's decode attention took, and what it read
+    st, now = serving.decode_stats(), profiler.compile_stats()
+    out["positions_read"] = st["attn_positions_read"]
+    out["positions_live"] = st["attn_positions_live"]
+    out["kernel_traces"], out["xla_traces"] = (
+        now[k] - traces[k] for k in ("paged_kernel_traces",
+                                     "paged_xla_traces"))
+    say(f"mla_moe logits: the engine's decode attention read "
+        f"{out['positions_read']} positions for {out['positions_live']} live "
+        f"(amplification "
+        f"{out['positions_read'] / max(1, out['positions_live']):.3f}); traced "
+        f"{out['kernel_traces']} times as the kernel paged_decode, "
+        f"{out['xla_traces']} as XLA's form")
     out.update(precision_probes(
         run["model"], run["weights"], run["sizes"],
         run["refs"][0][0][:len(run["prompts"][0])], seed=seed,
@@ -591,14 +607,15 @@ def _bfloat16_route(m, router_w, *, top_k, scale, normalize=True,
 
 
 def _bfloat16_softmax_attention(q, pool, tables, lens, *, rank, width):
-    """`models.mla_moe.absorbed_attention` with the scores rounded to
-    bfloat16 and the softmax computed in it: the control."""
+    """`models.mla_moe.absorbed_attention`'s XLA form with the scores
+    rounded to bfloat16 and the softmax computed in it: the control (the
+    pool may be wider than q: `pool_width`)."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops import paged_attention as pa
 
-    keys = pa.paged_gather(pool, tables)[:, 0]
+    keys = pa.paged_gather(pool, tables)[:, 0, :, :q.shape[-1]]
     score = jnp.einsum("bnr,bsr->bns", q, keys,
                        preferred_element_type=jnp.float32)
     score = score.astype(jnp.bfloat16) / jnp.bfloat16(math.sqrt(width))
@@ -617,17 +634,28 @@ def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
 
     Router: `families/mla_moe.routing_agreement` over the prompt `ids` — the
     program's `route` on the reference's own router inputs.  Softmax: the
-    program's `absorbed_attention` over a paged pool of seeded rows (one row
-    a live token, `lens` live tokens a sequence, pages in a shuffled order)
-    against `reference_mla_moe.absorbed_attention`, queries scaled so that
-    the scores spread as a trained model's do."""
+    program's `absorbed_attention`, in the form it SELECTS for the pool the
+    engine would allocate (`pool_width`: on a chip the Pallas kernel
+    `paged_decode`, each row's own pages; the line says which), over a
+    paged pool of seeded rows (one row a live token, `lens` live tokens a
+    sequence, pages in a shuffled order) against
+    `reference_mla_moe.absorbed_attention`, queries scaled so that the
+    scores spread as a trained model's do."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from paddle_tpu import profiler
     from paddle_tpu.models import mla_moe
+    from paddle_tpu.ops import paged_attention as pa
     from perfbench import reference_mla_moe as ref
     from perfbench.families import mla_moe as fam
+
+    def xla_form(q, pool, tables, lens, *, rank, width):
+        # what `absorbed_attention` ran before PR 35, and runs off a chip
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+        return pa._shared_row_xla(q, pool, tables, lens, rank,
+                                  1.0 / math.sqrt(width))
 
     cfg = model.config
     out = {}
@@ -656,12 +684,16 @@ def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
     lens = jnp.asarray(lens, jnp.int32)
     rows = np.asarray(pool[:, 0])[np.asarray(tables)].reshape(
         len(lens), per * block_size, row)
+    # as the engine holds it: the row rounded up to whole lane tiles, zeros
+    pool = jnp.pad(pool, ((0, 0),) * 3 + ((0, cfg.pool_width - row),))
+    before = profiler.compile_stats()
     for spread in (SOFTMAX_SCORE_SPREAD, 0.3):
         # unit-variance rows: a score's deviation is |q| / sqrt(width)
         q = (jax.random.normal(k_q, (len(lens), heads, row), jnp.float32)
              * spread * math.sqrt(width / row)).astype(jnp.bfloat16)
         want = np.asarray(ref.absorbed_attention(q, rows, lens, rank, width))
         for name, fn in (("float32", mla_moe.absorbed_attention),
+                         ("xla", xla_form),
                          ("bfloat16", _bfloat16_softmax_attention)):
             got = np.asarray(jax.jit(
                 lambda q, pool, tables, lens, fn=fn: fn(
@@ -672,10 +704,17 @@ def precision_probes(model, weights, sizes, ids, *, seed, block_size, lens):
         say(f"mla_moe probes: decode attention over {list(map(int, lens))} live "
             f"rows, scores spread {spread:g}: rms error "
             f"{out[f'softmax_rms_float32_spread_{spread:g}']:.5f} of the "
-            f"output's rms; with the softmax in bfloat16 "
+            f"output's rms (XLA's form "
+            f"{out[f'softmax_rms_xla_spread_{spread:g}']:.5f}); with the "
+            f"softmax in bfloat16 "
             f"{out[f'softmax_rms_bfloat16_spread_{spread:g}']:.5f}"
             + (f" (limit {SOFTMAX_RMS_TOL})" if spread == SOFTMAX_SCORE_SPREAD
                else " (the seeded weights' spread: no limit)"))
+    after = profiler.compile_stats()
+    out["softmax_form"] = ("paged_decode" if after["paged_kernel_traces"]
+                           > before["paged_kernel_traces"] else "xla")
+    say(f"mla_moe probes: the decode attention took the form "
+        f"{out['softmax_form']} over a pool {pool.shape[-1]} wide")
     s = f"{SOFTMAX_SCORE_SPREAD:g}"
     check(out[f"softmax_rms_float32_spread_{s}"] <= SOFTMAX_RMS_TOL,
           "the program's decode softmax agrees with a float32 softmax on the "
@@ -1475,6 +1514,10 @@ def main(argv=None) -> int:
         out = mla_moe_logits_phase(cfg_file, seed=args.seed, device=dev,
                                    prompt_lens=(2048, 4096, 8192),
                                    decoded=(8, 64), block_size=128)
+        check(out["softmax_form"] == "paged_decode"
+              and out["kernel_traces"] > 0 == out["xla_traces"],
+              "on the chip the decode attention, in the probe and in the "
+              "engine, is the kernel paged_decode over each row's own pages")
         check(out["route_agreement_bfloat16"] < ROUTE_AGREEMENT_MIN,
               "a bfloat16 router does NOT agree with the reference on its "
               "inputs: the comparison tells it from float32")

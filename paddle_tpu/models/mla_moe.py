@@ -26,7 +26,11 @@ width d_v (ops/flash_attention.py takes the two widths); it writes (c,
 k_rope), r + d_r values a token, to the cache.  DECODE runs the absorbed
 form against the paged latent pool: q~_i = q_nope_i W_kvb^K_i [r], score =
 q~_i.c + q_rope_i.k_rope, output (sum_s p c) W_kvb^V_i — all N query heads
-read one shared row, whose first r lanes are also the value.
+read one shared row, whose first r lanes are also the value.  On a TPU that
+read is the Pallas kernel `paged_decode` in its shared-row case: each row's
+own live pages straight from the pool, one copy a page
+(ops/paged_attention.paged_shared_row_attention; the pool's row is
+`pool_width` wide, r + d_r rounded up to whole 128-lane rows).
 
 The routed-expert layer (`routed_experts`, models/experts.py, shared with
 models/window_moe.py) is DROPLESS and is told which contiguous range of
@@ -108,6 +112,17 @@ class MlaMoeConfig:
     def latent_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
+    @property
+    def pool_width(self) -> int:
+        """A token's row as the POOL holds it: `latent_width` rounded up to
+        whole 128-lane rows (576 -> 640, the last 64 lanes zero).  Width-
+        minor, a TPU's HBM tiling pads the row to that anyway, and Mosaic
+        takes a page by DMA only where the row is whole lane tiles (a slice
+        of a 576-wide ref is refused); rounded here, the pool is one
+        `ops.paged_attention.reads_own_pages` admits as it admits a K pool
+        of one head."""
+        return -(-self.latent_width // 128) * 128
+
 
 # ------------------------------------------------------------------ layers
 
@@ -135,7 +150,7 @@ class LatentAttention(nn.Layer):
     def _project(self, n, pos):
         """n: Tensor [B, T, h]; pos [B, T].  Returns raw arrays: q_nope
         [B, T, N, d_n], q_rope [B, T, N, d_r] (rotated) and the cache row
-        [B, T, r + d_r] = (rms(c') g, rope(k_r))."""
+        [B, T, pool_width] = (rms(c') g, rope(k_r), zeros)."""
         c = self.config
         b, t = n.shape[0], n.shape[1]
         cos, sin = _rope_at(pos.reshape(-1), c.qk_rope_head_dim, c.rope_theta)
@@ -148,7 +163,8 @@ class LatentAttention(nn.Layer):
         latent = self.kv_a_layernorm(Tensor(kv_a[..., :c.kv_lora_rank]))._value
         k_rope = pa.rope_rotate_chunk(
             kv_a[:, :, None, c.kv_lora_rank:], cos, sin, at)[:, :, 0]
-        return q_nope, q_rope, jnp.concatenate([latent, k_rope], axis=-1)
+        pad = jnp.zeros((b, t, c.pool_width - c.latent_width), latent.dtype)
+        return q_nope, q_rope, jnp.concatenate([latent, k_rope, pad], axis=-1)
 
     def _w_kvb(self):
         c = self.config
@@ -160,14 +176,15 @@ class LatentAttention(nn.Layer):
     def prefill(self, n):
         """Causal self-attention over a whole prompt, per-head K/V
         materialised from the latent: (Tensor [B, S, h] before the
-        sandwich norm, cache rows [B, S, 1, r + d_r])."""
+        sandwich norm, cache rows [B, S, 1, pool_width])."""
         c = self.config
         b, s = n.shape[0], n.shape[1]
         heads = c.num_attention_heads
         with jax.named_scope("mla.prefill"):
             pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
             q_nope, q_rope, row = self._project(n, pos)
-            latent, k_rope = row[..., :c.kv_lora_rank], row[..., c.kv_lora_rank:]
+            latent = row[..., :c.kv_lora_rank]
+            k_rope = row[..., c.kv_lora_rank:c.latent_width]
             kv = self.kv_b_proj(Tensor(latent))._value.reshape(
                 b, s, heads, c.qk_nope_head_dim + c.v_head_dim)
             k = jnp.concatenate(
@@ -181,7 +198,7 @@ class LatentAttention(nn.Layer):
 
     def decode(self, n, pool, tables, lens):
         """One new token a row against the paged latent pool, absorbed
-        form.  n: Tensor [B, 1, h]; pool [num_blocks, 1, bs, r + d_r];
+        form.  n: Tensor [B, 1, h]; pool [num_blocks, 1, bs, pool_width];
         lens [B] INCLUDING this token.  Returns (Tensor [B, 1, h], pool)."""
         c = self.config
         with jax.named_scope("mla.decode"):
@@ -211,19 +228,20 @@ def _rope_at(positions, dim, theta):
 def absorbed_attention(q, pool, tables, lens, *, rank, width):
     """Decode attention over the paged latent pool, absorbed form: q [B, N,
     r + d_r] (q~ = q_nope W_kvb^K beside the rotated rope part), pool
-    [num_blocks, 1, bs, r + d_r], tables [B, W], lens [B] -> sum_s p(s) c(s),
+    [num_blocks, 1, bs, r + d_r or wider] (`pool_width`: the lanes past q's
+    are zero, and meet zeros), tables [B, W], lens [B] -> sum_s p(s) c(s),
     [B, N, r] float32 (the caller applies W_kvb^V).  All N heads read one
     shared row, whose first `rank` lanes are also the value.  Scores
     (divided by sqrt(width), the q/k head width), their maximum, exponent
     and sum are float32; the probabilities meet the rows in the rows' type
-    (bfloat16 passes of the matrix unit, float32 sums)."""
-    keys = pa.paged_gather(pool, tables)[:, 0]                  # [B, S, r+d_r]
-    score = jnp.einsum("bnr,bsr->bns", q, keys,
-                       preferred_element_type=jnp.float32) / math.sqrt(width)
-    seen = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :] < lens[:, None]
-    p = jax.nn.softmax(jnp.where(seen[:, None, :], score, -1e30), axis=-1)
-    return jnp.einsum("bns,bsr->bnr", p.astype(keys.dtype), keys[..., :rank],
-                      preferred_element_type=jnp.float32)
+    (bfloat16 passes of the matrix unit, float32 sums).  Which form runs it
+    (the Pallas kernel over each row's own pages, or XLA's gather and two
+    einsums) is `ops.paged_attention.paged_shared_row_attention`'s to say,
+    by what it sees in the pool."""
+    if pool.shape[-1] > q.shape[-1]:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+    return pa.paged_shared_row_attention(q, pool, tables, lens, rank=rank,
+                                         scale=1.0 / math.sqrt(width))
 
 
 def _causal_attention(q, k, v, block=512):
@@ -360,7 +378,8 @@ class MlaMoeForCausalLM(nn.Layer):
 
 class MlaMoeServing(ServingContract):
     """The model contract (models/contract.py): ONE pool a layer, a token's
-    row the latent and the rope key (r + d_r values), no V pool.  The
+    row the latent and the rope key (r + d_r values, rounded up to whole
+    128-lane rows: `MlaMoeConfig.pool_width`), no V pool.  The
     layers are not alike (a dense FFN ahead of the expert layers), so the
     decode step walks them unrolled and the pools are carried as a list."""
 
@@ -369,7 +388,7 @@ class MlaMoeServing(ServingContract):
         self.lm = lm
         self.max_positions = cfg.max_position_embeddings
         self.spec = CacheSpec(cfg.num_hidden_layers, (PoolSpec(
-            "latent", 1, cfg.latent_width,
+            "latent", 1, cfg.pool_width,
             "bfloat16" if cfg.dtype == "bfloat16" else "float32"),))
 
     def forward_cached(self, ids, caches, offset, n_real=None):
@@ -397,12 +416,20 @@ class MlaMoeServing(ServingContract):
             h, counts = layer.finish(h, out, active)
             new.append(pool)
             totals = add_counts(totals, counts)
-        aux = {} if totals is None else {
-            "moe_assignments": totals["assignments"],
-            "moe_held_assignments": totals["held"],
-            "moe_peak_expert_assignments": totals["peak"],
-            "moe_experts_touched": totals["touched"],
-            "moe_layer_steps": totals["layer_steps"]}
+        # what this token step's attention read and what was live, once a
+        # step (every layer reads the same pages; `absorbed_attention`'s
+        # XLA form gathers the table's whole width: no ladder)
+        pool = pools[0][0]
+        read, live = pa.attn_positions(tables, pa.pool_block_size(pool), lens,
+                                       active, pool=pool, ladder=False)
+        aux = {"attn_positions_read": read, "attn_positions_live": live}
+        if totals is not None:
+            aux.update({
+                "moe_assignments": totals["assignments"],
+                "moe_held_assignments": totals["held"],
+                "moe_peak_expert_assignments": totals["peak"],
+                "moe_experts_touched": totals["touched"],
+                "moe_layer_steps": totals["layer_steps"]})
         return model.norm(h), [new], aux
 
     def logits(self, h):

@@ -587,12 +587,13 @@ def tune_matmul_epilogue(m=4096, k=4096, n=4096, dtype="bfloat16", **kw):
 
 
 def paged_candidates(block_size, num_kv_heads, head_dim, table_width,
-                     itemsize=2):
-    """Pages a step of the paged decode kernel: powers of two whose two
-    double-buffered K and V buffers fit a quarter of VMEM."""
+                     itemsize=2, pools=2):
+    """Pages a step of the paged decode kernel: powers of two whose
+    double-buffered buffers (K and V; one pool in the shared-row case) fit
+    a quarter of VMEM."""
     out = []
     for pages in (1, 2, 4, 8, 16, 32, 64):
-        buffers = 4 * num_kv_heads * pages * block_size * lane_padded(
+        buffers = 2 * pools * num_kv_heads * pages * block_size * lane_padded(
             head_dim) * itemsize
         if pages <= table_width and buffers <= _VMEM_BUDGET // 4:
             out.append({"pages_per_step": pages})
@@ -601,11 +602,13 @@ def paged_candidates(block_size, num_kv_heads, head_dim, table_width,
 
 def tune_paged(batch=32, num_heads=16, num_kv_heads=8, head_dim=128,
                block_size=16, table_width=96, lens=(130, 512),
-               dtype="bfloat16", calls=16, **kw):
+               dtype="bfloat16", calls=16, rank=None, **kw):
     """Tune the paged decode kernel's pages a step for one page geometry
     (`ops.paged_attention.paged_key`): `batch` rows whose lengths are
-    spread evenly over `lens`, their pages scattered over the pool.  One
-    timed dispatch is `calls` dependent calls (a call is shorter than a
+    spread evenly over `lens`, their pages scattered over the pool.  With
+    `rank` the shared-row case (`paged_shared_row_attention`: ONE pool of
+    one `head_dim`-wide row a token, its first `rank` lanes the value).
+    One timed dispatch is `calls` dependent calls (a call is shorter than a
     dispatch); `ms` is one call's.  Prints the XLA form's ms on the same
     rows beside the candidates': the selection rule is read off that."""
     import jax
@@ -615,7 +618,7 @@ def tune_paged(batch=32, num_heads=16, num_kv_heads=8, head_dim=128,
     from paddle_tpu.ops import paged_attention as pa
 
     jd = jnp.dtype(dtype)
-    key = pa.paged_key(block_size, num_kv_heads, head_dim, jd)
+    key = pa.paged_key(block_size, num_kv_heads, head_dim, jd, rank)
     rng = np.random.default_rng(0)
     nb = batch * table_width + batch
     tables = jnp.asarray(rng.permutation(nb)[:batch * table_width].reshape(
@@ -624,35 +627,47 @@ def tune_paged(batch=32, num_heads=16, num_kv_heads=8, head_dim=128,
         lens[0], lens[1], batch).astype(np.int32)))
     r = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(r[0], (batch, num_heads, head_dim), jd)
-    kc, vc = (jax.random.normal(x, (nb, num_kv_heads, block_size, head_dim),
-                                jd) for x in r[1:])
+    pools = tuple(jax.random.normal(
+        x, (nb, num_kv_heads, block_size, head_dim), jd)
+        for x in (r[1:] if rank is None else r[1:2]))
     scale = 1.0 / head_dim ** 0.5
 
     def repeated(attend):
-        def run(q, kc, vc, tables, seq):
+        def run(q, *rest):
             def one(acc, _):
-                o = attend((q + acc).astype(q.dtype), kc, vc, tables, seq)
-                return o.astype(jnp.float32) * 1e-3, None
+                o = attend((q + acc).astype(q.dtype), *rest)
+                o = o.astype(jnp.float32) * 1e-3
+                if o.shape != q.shape:      # a shared row's result: `rank`
+                    o = jnp.pad(o, ((0, 0), (0, 0), (0, head_dim - rank)))
+                return o, None
             return jax.lax.scan(one, jnp.zeros(q.shape, jnp.float32), None,
                                 length=calls)[0]
         return jax.jit(run)
 
     def build(cfg):
+        if rank is not None:
+            return repeated(lambda q, pool, *a: pa._paged_decode_pallas(
+                q, pool, None, *a, scale, pages=cfg["pages_per_step"],
+                rank=rank))
         return repeated(lambda *a: pa._paged_decode_pallas(
             *a, scale, pages=cfg["pages_per_step"]))
 
-    args = (q, kc, vc, tables, seq)
+    args = (q, *pools, tables, seq)
     timing = {k: kw[k] for k in ("iters", "inner", "timer") if k in kw}
     if kw.get("verbose"):
-        xla = repeated(lambda q, *a: pa._paged_chunk_xla(
-            q[:, None], *a, scale)[:, 0])
+        if rank is not None:
+            xla = repeated(lambda *a: pa._shared_row_xla(*a, rank, scale))
+        else:
+            xla = repeated(lambda q, *a: pa._paged_chunk_xla(
+                q[:, None], *a, scale)[:, 0])
         print(f"  paged_decode XLA form: "
               f"{_time_fn(xla, args, **timing) / calls:.4f} ms")
     save = kw.pop("save", True)
     cfg, ms = tune_kernel(
         "paged_decode", key, build,
         paged_candidates(block_size, num_kv_heads, head_dim, table_width,
-                         jd.itemsize), args, save=False, **kw)
+                         jd.itemsize, pools=len(pools)), args, save=False,
+        **kw)
     # one call's time in the table, not the dispatch's
     record("paged_decode", key, cfg, ms / calls, slug=kw.get("slug"),
            save=save)
@@ -699,13 +714,16 @@ _STANDARD_SHAPES = {
         dict(m=4096, k=2048, n=8192), dict(m=4096, k=4096, n=4096),
         dict(m=8192, k=2048, n=2048),
     ],
-    # the two serving cells that read K/V pages (PERF.md section 4):
-    # laguna-s-2.1's full layers and internlm2-1.8b
+    # the serving cells that read pages (PERF.md section 4): laguna-s-2.1's
+    # full layers, internlm2-1.8b, and openpangu's latent pool (the
+    # shared-row case: 128 heads on one 640-wide row, 512 of it the value)
     "paged": [
         dict(num_heads=48, num_kv_heads=8, block_size=128, table_width=67,
              lens=(2200, 8600)),
         dict(num_heads=16, num_kv_heads=8, block_size=16, table_width=96,
              lens=(130, 512)),
+        dict(num_heads=128, num_kv_heads=1, head_dim=640, rank=512,
+             block_size=128, table_width=67, lens=(2200, 8600)),
     ],
 }
 

@@ -52,6 +52,16 @@ the default order through the step's loop and at its edge.
 `attn_positions` asks the same predicate and says what a step read and what
 was live.  Everything is shape-static, so the step jits once.
 
+A pool whose ONE row a token is key and value at once (latent attention,
+models/mla_moe.py) goes through the same kernel in its SHARED-ROW case
+(`paged_shared_row_attention`, a static `rank`): one pool, one buffer, ONE
+copy a page; the page's first `rank` lanes are the value, and the
+probabilities meet the rows in the rows' type, as that model states its
+arithmetic.  Its XLA form (`_shared_row_xla`) gathers the table's whole
+width, with no ladder.  The predicate is the same and was not loosened for
+it: the model allocates its 576-wide row 640 wide (whole lane tiles are
+what Mosaic takes a page of by DMA).
+
 A WINDOW class of a model's cache (models/contract.py: only the last W
 positions of a row are kept) lives in a per-slot RING of pool blocks and is
 read by `paged_window_attention`: the ring's pages through the slot's ring
@@ -85,6 +95,7 @@ __all__ = [
     "gathered_attention",
     "paged_decode_attention",
     "paged_chunk_attention",
+    "paged_shared_row_attention",
     "paged_window_attention",
     "ring_write_chunk",
     "window_positions",
@@ -531,21 +542,25 @@ def _ladder_index(ladder, block_size, seq_lens):
 
 
 def attn_positions(block_tables, block_size, seq_lens, active=None, *,
-                   pool=None):
-    """What one `paged_chunk_attention` call (T = 1) over these rows reads
-    and what of it is live, as two int32 scalars: (positions read, sum of
-    the `active` rows' lengths).  Read is what the path selected for
-    `pool` reads (`reads_own_pages`, the predicate `paged_chunk_attention`
-    itself asks): through the kernel each active row's own pages,
-    ceil(len / block_size) of them; in the XLA form (and with no pool
-    given) active rows x the ladder width over the longest row.  Their
-    quotient is the step's read amplification (1 but for the last page's
-    rounding through the kernel)."""
+                   pool=None, ladder=True):
+    """What one T = 1 decode attention call over these rows reads and what
+    of it is live, as two int32 scalars: (positions read, sum of the
+    `active` rows' lengths).  Read is what the path selected for `pool`
+    reads (`reads_own_pages`, the predicate the attention itself asks):
+    through the kernel each active row's own pages, ceil(len / block_size)
+    of them; in the XLA form (and with no pool given) active rows x the
+    ladder width over the longest row (`paged_chunk_attention`), or x the
+    table's whole width where the caller's XLA form has no ladder
+    (`ladder=False`: models/mla_moe.absorbed_attention).  Their quotient is
+    the step's read amplification (1 but for the last page's rounding
+    through the kernel)."""
     if active is None:
         active = jnp.ones(seq_lens.shape, bool)
     if pool is not None and reads_own_pages(pool):
         own = jnp.clip(-(-seq_lens // block_size), 1, block_tables.shape[1])
         read = jnp.sum(jnp.where(active, own, 0)) * block_size
+    elif not ladder:
+        read = jnp.sum(active) * (block_tables.shape[1] * block_size)
     else:
         ladder = page_ladder(block_tables.shape[1])
         pages = jnp.asarray(ladder, jnp.int32)[
@@ -623,7 +638,7 @@ def _take_pages(cache, block_tables):
 # The Pallas kernel: each row's OWN live pages, straight from the pool
 
 
-def _pages_per_step(block_size, num_kv_heads, head_dim, dtype):
+def _pages_per_step(block_size, num_kv_heads, head_dim, dtype, rank=None):
     """How many pages one step of the kernel's loop fetches and contracts
     together: the measured winner for this page geometry on this device
     kind (`ops/tuned/<device>.json`, kernel `paged_decode`, written by
@@ -633,30 +648,44 @@ def _pages_per_step(block_size, num_kv_heads, head_dim, dtype):
     from paddle_tpu.ops import autotune
 
     cfg = autotune.lookup("paged_decode", paged_key(
-        block_size, num_kv_heads, head_dim, dtype))
+        block_size, num_kv_heads, head_dim, dtype, rank))
     if cfg and int(cfg.get("pages_per_step", 0)) >= 1:
         return int(cfg["pages_per_step"])
     page = num_kv_heads * block_size * head_dim * jnp.dtype(dtype).itemsize
     return max(1, min(256 // block_size, (1 << 20) // page))
 
 
-def paged_key(block_size, num_kv_heads, head_dim, dtype):
-    """The tuned table's key of a page geometry."""
-    return {"block_size": int(block_size), "num_kv_heads": int(num_kv_heads),
-            "head_dim": int(head_dim), "dtype": jnp.dtype(dtype).name}
+def paged_key(block_size, num_kv_heads, head_dim, dtype, rank=None):
+    """The tuned table's key of a page geometry (`rank`: the shared-row
+    case, whose value is the row's first `rank` lanes)."""
+    key = {"block_size": int(block_size), "num_kv_heads": int(num_kv_heads),
+           "head_dim": int(head_dim), "dtype": jnp.dtype(dtype).name}
+    if rank is not None:
+        key["rank"] = int(rank)
+    return key
 
 
-def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, slot_ref, *, scale, pages,
-                         exact):
+def _paged_decode_kernel(lens_ref, tables_ref, q_ref, *refs, scale, pages,
+                         exact, rank=None):
     """One grid step = one row.  A step of the inner loop = `pages` pages
     of the row, fetched page by page from the pools in HBM into one of two
     VMEM buffers [Nkv, pages * bs, H] (the DMA of the next step, which may
     be the next ROW's first, runs under this step's products), contracted
-    for all K/V heads at once, folded into a running softmax."""
+    for all K/V heads at once, folded into a running softmax.
+
+    `rank` (static) is the SHARED-ROW case, a latent pool: there is one
+    pool and no V; a page is copied once and is key (all H lanes) and value
+    (its first `rank` lanes) at once, and the probabilities meet the rows
+    in the rows' type, as the model that owns the pool states it
+    (models/mla_moe.absorbed_attention)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if rank is None:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
+    else:
+        k_hbm, o_ref, k_buf, sems, slot_ref = refs
+        v_hbm = v_buf = None
     b, rows = pl.program_id(0), pl.num_programs(0)
     nb, nkv, bs, h = k_hbm.shape
     width = tables_ref.shape[0] // lens_ref.shape[0]
@@ -668,9 +697,10 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         return jnp.clip((lens_ref[row] + bs - 1) // bs, 1, width)
 
     def copies(row, step, slot, do):
-        """`do` (start or wait) the two copies of each live page of step
-        `step` of row `row` into buffer `slot`: a loop of the row's own
-        count, so nothing beyond it is fetched."""
+        """`do` (start or wait) the copies (K and V; one where the row is
+        both) of each live page of step `step` of row `row` into buffer
+        `slot`: a loop of the row's own count, so nothing beyond it is
+        fetched."""
         first = step * pages
 
         def one(i, _):
@@ -678,8 +708,10 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
             at = pl.ds(pl.multiple_of(i * bs, bs), bs)
             do(pltpu.make_async_copy(
                 k_hbm.at[idx], k_buf.at[slot, :, at, :], sems.at[0, slot]))
-            do(pltpu.make_async_copy(
-                v_hbm.at[idx], v_buf.at[slot, :, at, :], sems.at[1, slot]))
+            if v_hbm is not None:
+                do(pltpu.make_async_copy(
+                    v_hbm.at[idx], v_buf.at[slot, :, at, :],
+                    sems.at[1, slot]))
             return 0
 
         jax.lax.fori_loop(0, jnp.minimum(pages_of(row) - first, pages), one,
@@ -691,7 +723,8 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
             # a step's last pages may lie past the row: what the buffer
             # holds there is masked, and must be finite
             k_buf[...] = jnp.zeros_like(k_buf)
-            v_buf[...] = jnp.zeros_like(v_buf)
+            if v_buf is not None:
+                v_buf[...] = jnp.zeros_like(v_buf)
         slot_ref[0] = 0
         copies(b, 0, 0, lambda c: c.start())
 
@@ -723,39 +756,53 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        # float32 probabilities, never cut to bfloat16: the exact product
-        pv = jax.lax.dot_general(
-            p, v_buf[slot].astype(jnp.float32),
-            (((2,), (1,)), ((0,), (0,))),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+        if rank is None:
+            # float32 probabilities, never cut to bfloat16: the exact product
+            pv = jax.lax.dot_general(
+                p, v_buf[slot].astype(jnp.float32),
+                (((2,), (1,)), ((0,), (0,))),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        else:
+            # the shared row: its first `rank` lanes are the value, and the
+            # probabilities meet it in ITS type (bfloat16 passes with
+            # float32 sums; the exact product for a float32 pool)
+            v = k_buf[slot][:, :, :rank]
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                precision=(jax.lax.Precision.HIGHEST
+                           if v.dtype == jnp.float32 else None),
+                preferred_element_type=jnp.float32)
         return m_new, l_new, alpha * acc + pv
 
     init = (jnp.full((nkv, g, 1), -jnp.inf, jnp.float32),
             jnp.zeros((nkv, g, 1), jnp.float32),
-            jnp.zeros((nkv, g, h), jnp.float32))
+            jnp.zeros((nkv, g, h if rank is None else rank), jnp.float32))
     _m, l, acc = jax.lax.fori_loop(0, steps, step, init)
     slot_ref[0] = (slot0 + steps) % 2
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, key_cache, value_cache, block_tables, seq_lens,
-                         scale, pages=None):
+                         scale, pages=None, rank=None):
     """T = 1 decode attention through the kernel: q [B, N, H]; plain pools
     [num_blocks, Nkv, bs, H]; returns [B, N, H] in q's type.  `pages` a
-    step from the tuned table unless given."""
+    step from the tuned table unless given.  With `rank` the shared-row
+    case (`paged_shared_row_attention`): `value_cache` is None and the result
+    is [B, N, rank] float32."""
     _nb, nkv, bs, h = key_cache.shape
     if pages is None:
-        pages = _pages_per_step(bs, nkv, h, key_cache.dtype)
+        pages = _pages_per_step(bs, nkv, h, key_cache.dtype, rank)
     pages = max(1, min(int(pages), block_tables.shape[1]))
     return _paged_decode_call(q, key_cache, value_cache, block_tables,
                               seq_lens, scale=float(scale), pages=pages,
-                              interpret=_pl_utils.interpret())
+                              interpret=_pl_utils.interpret(), rank=rank)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pages", "interpret", "rank"))
 def _paged_decode_call(q, key_cache, value_cache, block_tables, seq_lens, *,
-                       scale, pages, interpret):
+                       scale, pages, interpret, rank=None):
     """The kernel's call, a jitted function of its own: the layers of a
     model that call it with the same shapes share ONE trace and ONE
     lowering of the kernel (unrolled, 24 layers of a dense model spent 12 s
@@ -776,34 +823,79 @@ def _paged_decode_call(q, key_cache, value_cache, block_tables, seq_lens, *,
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     buf = (2, nkv, pages * bs, h)
+    # a K/V pair: two pools, two buffers, the result in q's type; a shared
+    # row (`rank`): one of each, [.., rank] float32
+    pools = (key_cache,) if rank is not None else (key_cache, value_cache)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, pages=pages,
-                          exact=dt != jnp.bfloat16),
+                          exact=dt != jnp.bfloat16, rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((None, nkv, gp, h),
                              lambda i, *_: (i, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
+                *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
             ],
-            out_specs=pl.BlockSpec((None, nkv, gp, h),
+            out_specs=pl.BlockSpec((None, nkv, gp, h if rank is None else rank),
                                    lambda i, *_: (i, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM(buf, key_cache.dtype),
-                pltpu.VMEM(buf, value_cache.dtype),
+                *(pltpu.VMEM(buf, pool.dtype) for pool in pools),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, nkv, gp, h), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b, nkv, gp, h), q.dtype)
+                   if rank is None else
+                   jax.ShapeDtypeStruct((b, nkv, gp, rank), jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
     )(seq_lens.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
-      qg, key_cache, value_cache)
-    return out[:, :, :g].reshape(b, n, h)
+      qg, *pools)
+    return out[:, :, :g].reshape(b, n, -1)
+
+
+def paged_shared_row_attention(q, pool, block_tables, seq_lens, *, rank,
+                               scale):
+    """T = 1 decode attention over a pool whose ONE row a token is key and
+    value at once (latent attention's absorbed form, models/mla_moe.py):
+    q [B, N, H]; pool [num_blocks, 1, bs, H]; a row's first `rank` lanes
+    are the value; seq_lens [B] INCLUDING the new token.  Returns [B, N,
+    rank] float32.  Scores, maximum, exponent and sum float32; the
+    probabilities meet the rows in the rows' type (bfloat16 passes with
+    float32 sums for a bfloat16 pool).
+
+    Two forms, one arithmetic but for the order of the float32 sums, chosen
+    by `reads_own_pages(pool)` and counted by the form taken
+    (`paged_kernel_traces` / `paged_xla_traces`): the Pallas kernel
+    `paged_decode` in its shared-row case (each row's own live pages by DMA
+    from the pool, ONE copy a page, an online softmax: no gathered copy and
+    no score array in HBM), or XLA's (`_shared_row_xla`: the table's whole
+    width of every row gathered, two einsums)."""
+    from paddle_tpu._core import compile_cache
+
+    kernel = reads_own_pages(pool)
+    compile_cache.count("paged_kernel_traces" if kernel
+                        else "paged_xla_traces")
+    if kernel:
+        return _paged_decode_pallas(q, pool, None, block_tables, seq_lens,
+                                    scale, rank=int(rank))
+    return _shared_row_xla(q, pool, block_tables, seq_lens, rank, scale)
+
+
+def _shared_row_xla(q, pool, block_tables, seq_lens, rank, scale):
+    """The XLA form of `paged_shared_row_attention`: every row's whole
+    table width gathered (`paged_gather`: no ladder), scores [B, N, S]
+    float32 through HBM, probabilities x rows in the rows' type."""
+    keys = paged_gather(pool, block_tables)[:, 0]               # [B, S, H]
+    score = jnp.einsum("bnr,bsr->bns", q, keys,
+                       preferred_element_type=jnp.float32) * scale
+    seen = (jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :]
+            < seq_lens[:, None])
+    p = jax.nn.softmax(jnp.where(seen[:, None, :], score, -1e30), axis=-1)
+    return jnp.einsum("bns,bsr->bnr", p.astype(keys.dtype), keys[..., :rank],
+                      preferred_element_type=jnp.float32)
 
 
 def reads_own_pages(cache, t=1):

@@ -202,19 +202,23 @@ def test_seeded_v5e_paged_entries_are_candidates_and_measurements():
         offered = at.paged_candidates(
             int(dims["block_size"]), int(dims["num_kv_heads"]),
             int(dims["head_dim"]), table_width=64,
-            itemsize=np.dtype(dims["dtype"]).itemsize)
+            itemsize=np.dtype(dims["dtype"]).itemsize,
+            pools=1 if "rank" in dims else 2)     # a shared row: one pool
         assert entry["config"] in offered
         assert entry["ms"] > 0 and "measured" in entry["meta"]
 
 
+@pytest.mark.parametrize("rank", [None, 128], ids=["kv_pair", "shared_row"])
 @pytest.mark.parametrize("block_size", [16, 128])
 def test_tune_paged_records_one_calls_time_under_the_kernels_key(tmp_cache,
-                                                                 block_size):
+                                                                 block_size,
+                                                                 rank):
     """`tune_paged` drives the real kernel builder over its candidates (a
     fake timer here: the second candidate is the fastest) and records the
     winner under the key `_pages_per_step` builds, as ONE call's time of the
     `calls` a dispatch holds; an untuned geometry keeps the default of 256
-    positions a step."""
+    positions a step.  A shared-row pool (one pool, a `rank`: the latent
+    model's) has a key of its own beside a K/V pair's of the same page."""
     from paddle_tpu.ops import paged_attention as pa
 
     seen = []
@@ -223,13 +227,17 @@ def test_tune_paged_records_one_calls_time_under_the_kernels_key(tmp_cache,
         seen.append(1)
         return {1: 8.0, 2: 2.0}.get(len(seen), 4.0)
 
-    assert pa._pages_per_step(block_size, 2, 128, "float32") == 256 // block_size
+    nkv, width = (2, 128) if rank is None else (1, 256)
+    geometry = (block_size, nkv, width, "float32", rank)
+    assert pa._pages_per_step(*geometry) == 256 // block_size
     cfg, ms = at.tune_paged(
-        batch=2, num_heads=4, num_kv_heads=2, block_size=block_size,
-        table_width=4, lens=(5, 4 * block_size), dtype="float32", calls=4,
-        timer=timer, slug=at.device_kind_slug())
+        batch=2, num_heads=4, num_kv_heads=nkv, head_dim=width, rank=rank,
+        block_size=block_size, table_width=4, lens=(5, 4 * block_size),
+        dtype="float32", calls=4, timer=timer, slug=at.device_kind_slug())
     assert (cfg, ms) == ({"pages_per_step": 2}, 0.5) and len(seen) == 3
-    assert pa._pages_per_step(block_size, 2, 128, "float32") == 2
+    assert pa._pages_per_step(*geometry) == 2
+    if rank is not None:        # the same page as a K pool: not this entry
+        assert pa._pages_per_step(*geometry[:4]) == 256 // block_size
     raw = json.load(open(os.path.join(
         tmp_cache, at.device_kind_slug() + ".json")))["paged_decode"]
     assert [e["ms"] for e in raw.values()] == [0.5]

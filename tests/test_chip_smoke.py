@@ -124,6 +124,11 @@ def test_mla_moe_logits_phase_on_cpu_tiny(monkeypatch):
     assert out["route_agreement"] == 1.0 >= out["route_agreement_bfloat16"]
     assert (out["softmax_rms_float32_spread_4"] < chip_smoke.SOFTMAX_RMS_TOL
             < out["softmax_rms_bfloat16_spread_4"])
+    # off a chip the decode attention is XLA's form, in the engine (the
+    # table's whole width of every row) and in the probe
+    assert out["softmax_form"] == "xla" and out["kernel_traces"] == 0 < out["xla_traces"]
+    assert out["softmax_rms_xla_spread_4"] == out["softmax_rms_float32_spread_4"]
+    assert out["positions_read"] > 1.5 * out["positions_live"] > 0
 
 
 def test_window_moe_logits_phase_on_cpu_tiny(monkeypatch):

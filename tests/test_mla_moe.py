@@ -20,6 +20,7 @@ from paddle_tpu.models.contract import CacheSpec, PoolSpec
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.mla_moe import (MlaMoeForCausalLM, mla_moe_tiny,
                                        routed_experts)
+from paddle_tpu.ops import paged_attention as pa
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -98,10 +99,12 @@ def test_engine_streams_match_the_reference_forward(model):
                                       list(range(len(p) - 1, len(ids)))))
         assert toks == [int(t) for t in lg.argmax(-1)]
     st = serving.decode_stats()
-    # one pool a layer, one 24-wide row a token: no K or V pool anywhere
-    assert st["latent_pool_bytes"] == st["pool_bytes"] == 3 * 50 * 8 * 24 * 4
+    # one pool a layer, one row a token (24 values, rounded up to one whole
+    # 128-lane row: `pool_width`): no K or V pool anywhere
+    assert model.config.latent_width == 24 and model.config.pool_width == 128
+    assert st["latent_pool_bytes"] == st["pool_bytes"] == 3 * 50 * 8 * 128 * 4
     assert st["k_pool_bytes"] == st["v_pool_bytes"] == 0
-    assert eng._spec == CacheSpec(3, (PoolSpec("latent", 1, 24, "float32"),))
+    assert eng._spec == CacheSpec(3, (PoolSpec("latent", 1, 128, "float32"),))
     assert len(eng._pools) == 1 and len(eng._pools[0]) == 3
 
 
@@ -427,23 +430,189 @@ def test_router_on_the_references_inputs_tells_float32_from_bfloat16(
     np.testing.assert_allclose(np.asarray(weight.sum(-1)), 2.5, rtol=1e-5)
 
 
+@pytest.fixture
+def pallas_on():
+    """Select the Pallas path off a TPU (it then runs interpreted)."""
+    paddle.set_flags({"FLAGS_use_pallas": "true"})
+    yield
+    paddle.set_flags({"FLAGS_use_pallas": "auto"})
+
+
+# the latent pool of the probes below: a row of 96 + 8 values in a pool 128
+# wide (`pool_width`), pages of 16, a table of 4 pages a row
+RANK, ROPE, ROW, BS, TABLE = 96, 8, 128, 16, 4
+# one position; one short of a page; exactly a page; across pages; the table
+RAGGED = (1, BS - 1, BS, 2 * BS + 5, TABLE * BS)
+
+
+def _latent_pool(dtype, seed, foreign=np.nan):
+    """A latent pool whose pages lie in a shuffled order: a row's live
+    positions hold seeded values (lanes past the 104 zero, as the model
+    writes them), the rest of its last page large finite garbage, and every
+    page past a row's own `foreign` (NaN: a page read beyond a row's own
+    would put NaN into that row).  (pool, tables, lens, rows) with rows
+    [B, TABLE * BS, 104] float32, the values a reference may see: zero past
+    a row's length."""
+    rng = np.random.default_rng(seed)
+    b = len(RAGGED)
+    nb = b * TABLE + 3
+    tables = rng.permutation(nb)[:b * TABLE].astype(np.int32).reshape(b, TABLE)
+    pool = np.full((nb, 1, BS, ROW), foreign, np.float32)
+    rows = np.zeros((b, TABLE * BS, RANK + ROPE), np.float32)
+    for r, n in enumerate(RAGGED):
+        own = tables[r, :-(-n // BS)]
+        pool[own] = 300.0 * rng.standard_normal((len(own), 1, BS, ROW))
+        live = rng.standard_normal((n, RANK + ROPE)).astype(np.float32)
+        live = np.asarray(jnp.asarray(live, dtype), np.float32)
+        rows[r, :n] = live
+        for t in range(n):
+            pool[tables[r, t // BS], 0, t % BS] = np.pad(
+                live[t], (0, ROW - RANK - ROPE))
+    return (jnp.asarray(pool, dtype), jnp.asarray(tables),
+            jnp.asarray(RAGGED, jnp.int32), rows)
+
+
+def _rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean())
+
+
 @pytest.mark.parametrize("spread", [0.3, 4.0])
-def test_absorbed_attention_matches_a_float32_softmax_on_the_same_rows(spread):
-    """The decode attention alone, over a paged pool in shuffled page order,
-    against the reference's softmax on the same bfloat16 queries and rows."""
-    blocks, bs, heads, rank, rope = 12, 8, 4, 16, 8
-    lens = jnp.asarray([40, 17, 48], jnp.int32)
-    tables = jnp.asarray(np.random.default_rng(0).permutation(18).reshape(3, 6),
-                         jnp.int32)
-    k_p, k_q = jax.random.split(jax.random.key(1))
-    pool = jax.random.normal(k_p, (18, 1, bs, rank + rope)).astype(jnp.bfloat16)
-    q = (jax.random.normal(k_q, (3, heads, rank + rope)) * spread).astype(jnp.bfloat16)
-    got = mla_moe.absorbed_attention(q, pool, tables, lens, rank=rank, width=24)
-    rows = np.asarray(pool[:, 0])[np.asarray(tables)].reshape(3, 6 * bs, -1)
-    want = np.asarray(ref.absorbed_attention(q, rows, lens, rank, 24))
-    assert got.shape == (3, heads, rank) and got.dtype == jnp.float32
-    err = np.sqrt(((np.asarray(got) - want) ** 2).mean() / (want ** 2).mean())
-    assert err < 5e-3          # the probabilities meet the rows in bfloat16
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_absorbed_attention_matches_a_float32_softmax_on_the_same_rows(
+        form, dtype, spread, request):
+    """The decode attention alone, in both its forms (XLA's gather and two
+    einsums; the Pallas kernel `paged_decode`'s shared-row case,
+    interpreted here), over a paged pool in shuffled page order, ragged
+    lengths (1, one short of a page, exactly a page, the whole table) and
+    garbage past every row's length, against the reference's softmax on the
+    same queries and rows; counted by the form it took."""
+    if form == "kernel":
+        request.getfixturevalue("pallas_on")
+    # XLA's form gathers the table's whole width and multiplies what it
+    # masked by zero: its foreign pages must be finite
+    pool, tables, lens, rows = _latent_pool(
+        dtype, seed=7, foreign=np.nan if form == "kernel" else 50.0)
+    assert pa.reads_own_pages(pool) == (form == "kernel")
+    heads, width = 4, 24
+    q = (jax.random.normal(jax.random.key(1), (len(RAGGED), heads, RANK + ROPE))
+         * spread).astype(dtype)
+    before = profiler.compile_stats()
+    got = mla_moe.absorbed_attention(q, pool, tables, lens, rank=RANK,
+                                     width=width)
+    after = profiler.compile_stats()
+    assert (after["paged_kernel_traces"] - before["paged_kernel_traces"],
+            after["paged_xla_traces"] - before["paged_xla_traces"]) == (
+                (1, 0) if form == "kernel" else (0, 1))
+    want = np.asarray(ref.absorbed_attention(q, rows, lens, RANK, width))
+    assert got.shape == (len(RAGGED), heads, RANK) and got.dtype == jnp.float32
+    assert not np.isnan(np.asarray(got)).any()
+    # bfloat16: the probabilities meet the rows in bfloat16
+    assert _rel_rms(got, want) < (5e-3 if dtype == "bfloat16" else 1e-5)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_kernel_and_the_xla_form_agree_on_the_same_pool(dtype, pages):
+    """The two forms of `absorbed_attention` on one pool: for a float32 pool
+    the same products in another order of float32 sums (an online softmax,
+    at any pages a step: a tile, not a result); for a bfloat16 pool the
+    probabilities are also rounded to bfloat16 before (XLA: normalised) or
+    after (the kernel: against the running maximum) their division."""
+    pool, tables, lens, _rows = _latent_pool(dtype, seed=11, foreign=50.0)
+    q = (jax.random.normal(jax.random.key(2), (len(RAGGED), 8, RANK + ROPE))
+         * 2.0).astype(dtype)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, ROW - RANK - ROPE)))
+    scale = 1.0 / np.sqrt(24.0)
+    xla = mla_moe.absorbed_attention(q, pool, tables, lens, rank=RANK, width=24)
+    got = pa._paged_decode_pallas(q, pool, None, tables, lens, scale,
+                                  pages=pages, rank=RANK)
+    assert got.shape == xla.shape and got.dtype == jnp.float32
+    assert _rel_rms(got, xla) < (5e-3 if dtype == "bfloat16" else 2e-6)
+
+
+@pytest.mark.parametrize("case", ["row_of_whole_lanes", "row_of_576",
+                                  "chunk", "flag_off"])
+def test_which_latent_pools_read_their_own_pages(case, pallas_on):
+    """`reads_own_pages` answers for a latent pool by what it can see, as
+    for a K pool of one head: a row of whole 128-lane rows (640, what
+    `pool_width` makes of 576), pages of whole sublane tiles, one token a
+    row; a 576-wide row (Mosaic refuses a slice of it), T > 1 and
+    FLAGS_use_pallas=false keep XLA's form."""
+    cfg = mla_moe.MlaMoeConfig()
+    assert (cfg.latent_width, cfg.pool_width) == (576, 640)
+    width, t = cfg.pool_width, 1
+    if case == "row_of_576":
+        width = cfg.latent_width
+    elif case == "chunk":
+        t = 2
+    elif case == "flag_off":
+        paddle.set_flags({"FLAGS_use_pallas": "false"})
+    pool = jax.ShapeDtypeStruct((8, 1, 128, width), jnp.bfloat16)
+    assert pa.reads_own_pages(pool, t) == (case == "row_of_whole_lanes")
+
+
+@pytest.mark.parametrize("selected", ["xla", "kernel"])
+def test_latent_decode_reports_what_its_attention_read(model, selected,
+                                                       request):
+    """`MlaMoeServing.decode` hands `attn_positions_read` / `_live` back in
+    the contract's `aux`, and `decode_stats()` of a tiny latent engine sums
+    them over the token steps: XLA's form reads the table's whole width of
+    every active row, the kernel each row's own pages; the trace counters
+    say which form the engine's programs took, and the streams are the
+    same, token for token."""
+    if selected == "kernel":
+        request.getfixturevalue("pallas_on")
+    pool = jnp.zeros((12, 1, 8, model.config.pool_width), jnp.float32)
+    tables = jnp.arange(12, dtype=jnp.int32).reshape(2, 6)
+    lens = jnp.asarray([9, 30], jnp.int32)
+    _h, _pools, aux = model.serving_contract().decode(
+        jnp.zeros((2, 1), jnp.int32), [[pool] * 3], tables, lens,
+        jnp.asarray([True, True]))
+    assert int(aux["attn_positions_live"]) == 39
+    assert int(aux["attn_positions_read"]) == (
+        (2 + 4) * 8 if selected == "kernel" else 2 * 6 * 8)
+
+
+
+def test_a_tiny_latent_engine_through_the_kernel_emits_the_xla_forms_tokens(
+        model):
+    """`decode_stats()` of a tiny latent engine sums what `decode` reports
+    over the token steps, and the trace counters say which form the
+    engine's programs took: by default on the CPU XLA's, reading the
+    table's whole width of every active row; with the kernel selected each
+    row's own pages, and the same streams, token for token."""
+    block, prompts, new = 8, (5, 29), (6, 12)
+    rng = np.random.default_rng(0)
+    ids = [rng.integers(0, 256, n).astype(np.int32) for n in prompts]
+
+    def serve(flag):
+        paddle.set_flags({"FLAGS_use_pallas": flag})
+        serving.reset_decode_stats()
+        before = profiler.compile_stats()
+        eng, _firsts = _serve(model, ids, new)
+        after = profiler.compile_stats()
+        traces = tuple(after[k] - before[k] > 0 for k in (
+            "paged_kernel_traces", "paged_xla_traces"))
+        return ([eng.result(f"r{i}") for i in range(2)],
+                serving.decode_stats(), traces, eng._max_blocks_per_seq)
+
+    try:
+        want, xla, xla_traces, width = serve("auto")
+        got, st, kernel_traces, _w = serve("true")
+    finally:
+        paddle.set_flags({"FLAGS_use_pallas": "auto"})
+    assert got == want and st["tokens"] == xla["tokens"] == sum(new) - len(new)
+    assert (xla_traces, kernel_traces) == ((False, True), (True, False))
+    # the j-th later token is one token step over a row of prompt + j
+    steps = [n + j for n, k in zip(prompts, new) for j in range(1, k)]
+    assert st["attn_positions_live"] == xla["attn_positions_live"] == sum(steps)
+    assert st["attn_positions_read"] == sum(-(-n // block) * block
+                                            for n in steps)
+    assert xla["attn_positions_read"] == len(steps) * width * block
+    assert 1.0 <= st["attn_positions_read"] / st["attn_positions_live"] < 1.3
+    assert xla["attn_positions_read"] / xla["attn_positions_live"] > 3.0
 
 
 def test_paged_gather_of_a_one_row_pool_is_the_general_gather():

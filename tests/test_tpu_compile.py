@@ -539,6 +539,173 @@ def test_the_kernels_write_attend_scan_copies_no_pool(one_chip, mosaic, cell):
     assert len(_pool_copies(text, nb, nkv, bs, h)) >= 2
 
 
+# the latent cell's pool, one row a token that is key and value at once
+# (openpangu-ultra-moe-718b): (rows, query heads, the pool's row, of it the
+# value, table width, block size, pool blocks); the model's own row is 576
+# wide, `MlaMoeConfig.pool_width` rounds it up to whole 128-lane rows
+LATENT_CELL = (32, 128, 640, 512, 67, 128, 2144)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_paged_decode_kernel_compiles_at_the_latent_cells_geometry(
+        one_chip, mosaic, dtype):
+    """The kernel's shared-row case through Mosaic as
+    `models.mla_moe.absorbed_attention` selects it: 128 query heads on ONE
+    640-wide row a token (M = 128 fills the matrix unit's rows), one DMA a
+    page, the value the page's first 512 lanes, probabilities in the rows'
+    type; under the name the trace will show.  The model's own 576-wide row
+    is refused by Mosaic ("Slice shape along dimension 3 must be aligned to
+    tiling (128), but is 576": the ref is 640 wide in HBM and VMEM alike),
+    so the predicate refuses it and the pool is allocated 640 wide."""
+    from paddle_tpu.models import mla_moe
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, row, rank, w, bs, nb = LATENT_CELL
+    cfg = mla_moe.MlaMoeConfig()
+    assert (cfg.num_attention_heads, cfg.pool_width, cfg.kv_lora_rank) == (
+        n, row, rank)
+    dt = jnp.dtype(dtype)
+
+    def s(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((nb, 1, bs, row))
+    assert pa.reads_own_pages(pool)
+    assert not pa.reads_own_pages(s((nb, 1, bs, cfg.latent_width)))
+    text = jax.jit(lambda q, pool, tables, lens: mla_moe.absorbed_attention(
+        q, pool, tables, lens, rank=rank, width=192)).lower(
+            s((b, n, cfg.latent_width)), pool, s((b, w), jnp.int32),
+            s((b,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "%paged_decode" in text
+    assert "conditional(" not in text
+    assert f"f32[{b},{n},{w * bs}]" not in text         # no score array
+    assert not _pool_copies(text, nb, 1, bs, row)
+
+
+def _latent_write_attend_scan(write, attend, row, one_chip):
+    """`_write_attend_scan` for a pool that is key and value at once."""
+    b, n, _row, rank, w, bs, nb = LATENT_CELL
+
+    def steps(q, pool, new, tables, lens):
+        def one(carry, _):
+            pool, lens, acc = carry
+            pool = write(pool, new[:, None, None], tables, (lens - 1)[:, None])
+            o = attend(q + acc.astype(q.dtype), pool, tables, lens)
+            acc = acc.at[..., :rank].add(o)
+            return (pool, lens + 1, acc), None
+
+        carry, _ = jax.lax.scan(
+            one, (pool, lens, jnp.zeros(q.shape, jnp.float32)), None,
+            length=2)
+        return carry
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return jax.jit(steps, donate_argnums=(1,)).lower(
+        s((b, n, row)), s((nb, 1, bs, row)), s((b, row)),
+        s((b, w), jnp.int32), s((b,), jnp.int32)).compile().as_text()
+
+
+def test_the_latent_write_attend_scan_copies_no_pool(one_chip, mosaic):
+    """A latent decode layer's write -> attend inside a scan at the pangu
+    cell's geometry: written as rows and read by the kernel, the 640-wide
+    pool keeps the default order and NO operation of its shape is a copy,
+    in the loop or at the program's edge, and no [32, 128, 8576] float32
+    score array exists.  (One head a page: `_write_slots` keeps the same
+    order, unlike a K pool of 8 heads, so the twin that brings the copies
+    back is not the slot scatter.)  The twin is the parent's pool: the
+    model's own 576-wide row, which the predicate refuses.  XLA stores it
+    positions-minor, copies the whole pool in and out at the scan's edge
+    (PERF.md section 7 (g), closed by this) and runs XLA's form, whose
+    scores go through HBM."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    b, n, row, rank, w, bs, nb = LATENT_CELL
+
+    def attend(q, pool, tables, lens):
+        return pa.paged_shared_row_attention(q, pool, tables, lens, rank=rank,
+                                             scale=0.07)
+
+    scores = f"f32[{b},{n},{w * bs}]"
+    for write in (pa.paged_write_chunk, pa._write_slots):
+        text = _latent_write_attend_scan(write, attend, row, one_chip)
+        assert "%paged_decode" in text and scores not in text
+        assert not _pool_copies(text, nb, 1, bs, row)
+    text = _latent_write_attend_scan(pa.paged_write_chunk, attend, 576,
+                                     one_chip)
+    assert "%paged_decode" not in text and scores in text
+    assert len(_pool_copies(text, nb, 1, bs, 576)) >= 2
+
+
+def test_the_latent_models_macro_step_copies_no_pool(one_chip, mosaic):
+    """`jit_decode_macro_step` of a latent-attention engine at the pangu
+    cell's cache geometry (32 rows, 128 heads on a row of 512 + 64 values,
+    blocks of 128, a 67-page table, 2,144 + 32 blocks a pool; the other
+    widths small, one dense and one expert layer): each layer's attention is
+    `paged_decode`, no pool is copied anywhere in the program (the parent's
+    copied every pool in and out at the loop's edge), and neither the
+    gathered pages nor the float32 scores of the table's width exist."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.mla_moe import MlaMoeForCausalLM, mla_moe_tiny
+
+    b, n, row, rank, w, bs, nb = LATENT_CELL
+    paddle.seed(0)
+    model = MlaMoeForCausalLM(mla_moe_tiny(
+        hidden_size=256, num_hidden_layers=2, num_attention_heads=n,
+        q_lora_rank=64, kv_lora_rank=rank, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, max_position_embeddings=16384,
+        dtype="bfloat16", held_experts=(2, 4)))
+    model.eval()
+    eng = serving.GenerationEngine(model, max_batch=b, block_size=bs,
+                                   num_blocks=nb)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._step_avals())
+    text = eng._build_step(8).lower(*avals).compile().as_text()
+    assert len(re.findall(r"%paged_decode\S* = ", text)) == 2
+    assert f"bf16[{nb + b},1,{bs},{row}]" in text       # the pool is here
+    assert not re.findall(rf"= bf16\[\d+,1,{bs},{row}\]\S* copy\(", text)
+    assert not re.findall(rf"= bf16\[\d+,{row}\]\S* copy\(", text)
+    assert f"f32[{b},{n},{w * bs}]" not in text
+    assert f"bf16[{b},{w * bs},{row}]" not in text      # no gathered pages
+
+
+@pytest.mark.parametrize("pool,reads", [
+    *((f"{cell} K/V", True) for cell in sorted(PAGED_CELLS)),
+    ("latent row of 640", True), ("latent row of 576", False),
+    ("ring", True), ("int8", False), ("heads of 64", False),
+    ("heads of 192", False), ("float16", False), ("pages of 8 bfloat16", False),
+    ("stacked K/V", True)])
+def test_reads_own_pages_answers_by_what_it_sees(mosaic, pool, reads):
+    """The one predicate of the write, the read and the counter, over the
+    cells' pools: whole 128-lane rows, pages of whole sublane tiles, a plain
+    bfloat16 / float32 pool, one token a row.  PR 35 loosened nothing: the
+    latent pool passes because its row is allocated 640 wide; a K/V pool of
+    a head width the kernel was never compiled for does not."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    shapes = {
+        **{f"{cell} K/V": (g[6], g[2], g[5], g[3])
+           for cell, g in PAGED_CELLS.items()},
+        "latent row of 640": (2176, 1, 128, 640),
+        "latent row of 576": (2176, 1, 128, 576),
+        "ring": (160, 8, 128, 128), "int8": (64, 8, 16, 128),
+        "heads of 64": (64, 8, 16, 64), "heads of 192": (64, 1, 128, 192),
+        "float16": (64, 8, 16, 128), "pages of 8 bfloat16": (64, 8, 8, 128),
+        "stacked K/V": (12, 2176, 2, 128, 128)}
+    dtype = {"int8": jnp.int8, "float16": jnp.float16}.get(pool, jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct(shapes[pool], dtype)
+    if pool == "int8":
+        cache = pa.QuantPool(cache, jax.ShapeDtypeStruct(shapes[pool][:2],
+                                                         jnp.float32))
+    assert pa.reads_own_pages(cache) == reads
+    assert not pa.reads_own_pages(cache, 2)             # T > 1: XLA's form
+
+
 def test_the_window_models_macro_step_copies_no_pool(one_chip, mosaic):
     """`jit_decode_macro_step` of a window / full attention engine with
     laguna-s-2.1's cache geometry (48 / 72 query heads over 8 K/V heads of
@@ -649,23 +816,32 @@ def test_every_tuned_pages_a_step_is_one_the_kernel_finds_and_mosaic_accepts(
     the kernel with it; both cells' geometries have theirs."""
     from paddle_tpu.ops import paged_attention as pa
 
-    dims = dict(kv.split("=") for kv in key.split("|"))
-    bs, nkv, h = (int(dims[k]) for k in ("block_size", "num_kv_heads",
-                                         "head_dim"))
-    dt = jnp.dtype(dims["dtype"])
+    def geometry(key):
+        dims = dict(kv.split("=") for kv in key.split("|"))
+        return (*(int(dims[k]) for k in ("block_size", "num_kv_heads",
+                                         "head_dim")),
+                int(dims["rank"]) if "rank" in dims else None,
+                jnp.dtype(dims["dtype"]))
+
+    bs, nkv, h, rank, dt = geometry(key)
     assert ms > 0, "not a measurement"
-    assert pa._pages_per_step(bs, nkv, h, dt) == pages
-    cells = {(g[5], g[2], g[3]) for g in PAGED_CELLS.values()}
-    assert (bs, nkv, h) in cells
-    assert {(int(d["block_size"]), int(d["num_kv_heads"]), int(d["head_dim"]))
-            for d in (dict(kv.split("=") for kv in k.split("|"))
-                      for k, _, _ in _tuned_paged_entries())} == cells
+    assert pa._pages_per_step(bs, nkv, h, dt, rank) == pages
+    # the K/V cells' pages, and the latent cell's shared row (a `rank`)
+    cells = {(g[5], g[2], g[3], None) for g in PAGED_CELLS.values()} | {
+        (LATENT_CELL[5], 1, LATENT_CELL[2], LATENT_CELL[3])}
+    assert (bs, nkv, h, rank) in cells
+    assert {geometry(k)[:4] for k, _, _ in _tuned_paged_entries()} == cells
 
     def s(shape, dtype=dt):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     pool = s((1024, nkv, bs, h))
-    text = jax.jit(lambda *a: pa._paged_decode_pallas(*a, 0.1)).lower(
-        s((32, 2 * nkv, h)), pool, pool, s((32, 2 * pages), jnp.int32),
-        s((32,), jnp.int32)).compile().as_text()
-    assert "tpu_custom_call" in text
+    tables, lens = s((32, 2 * pages), jnp.int32), s((32,), jnp.int32)
+    if rank is None:
+        lowered = jax.jit(lambda *a: pa._paged_decode_pallas(*a, 0.1)).lower(
+            s((32, 2 * nkv, h)), pool, pool, tables, lens)
+    else:
+        lowered = jax.jit(lambda q, pool, *a: pa._paged_decode_pallas(
+            q, pool, None, *a, 0.1, rank=rank)).lower(
+                s((32, LATENT_CELL[1], h)), pool, tables, lens)
+    assert "tpu_custom_call" in lowered.compile().as_text()
