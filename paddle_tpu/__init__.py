@@ -5,6 +5,15 @@ built on JAX/XLA/Pallas/pjit rather than ported from the CUDA design.
 
 from __future__ import annotations
 
+# start-up's clock (profiler.startup_stats): the process's account of itself
+# begins here, and jax, the largest single import, is timed apart
+import time as _time
+
+_IMPORT_BEGAN = _time.perf_counter()
+import jax as _jax  # noqa: F401,E402
+
+_IMPORT_JAX_DONE = _time.perf_counter()
+
 # dtypes
 from ._core.dtype import (  # noqa: F401
     DType,
@@ -156,3 +165,6 @@ def tolist(x):
     from ._core.tensor import Tensor
 
     return x.tolist() if isinstance(x, Tensor) else Tensor(x).tolist()
+
+
+profiler.startup.imported(_IMPORT_BEGAN, _IMPORT_JAX_DONE, _time.perf_counter())

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from paddle_tpu._core import random as rng_mod
 from paddle_tpu._core.autograd import no_grad
 from paddle_tpu._core.tensor import Parameter, Tensor
-from paddle_tpu.profiler import RecordEvent
+from paddle_tpu.profiler import RecordEvent, startup
 
 __all__ = ["to_static", "TrainStep", "not_to_static", "save", "load", "ignore_module"]
 
@@ -309,7 +309,8 @@ class TrainStep:
             # GradScaler state is device tensors (amp/__init__.py) and joins
             # the state list.
             params = [p for p in self.optimizer._parameter_list if not p.stop_gradient]
-            with RecordEvent("jit.train_step.build.optimizer_state"):
+            with startup.phase("jit.train_step.build.optimizer_state",
+                               "train_optimizer_state_seconds"):
                 with _host_device():
                     self.optimizer._journaled_step(params)
                 self._state = self._collect_state()
@@ -348,16 +349,27 @@ class TrainStep:
         `.build.optimizer_state` (the accumulators, made on the host CPU
         backend) and `.build.trace` (the first call of the jitted step:
         jax traces, lowers and compiles or reads the cache there, then
-        enqueues); on every later call `jit.train_step.dispatch`."""
+        enqueues); on every later call `jit.train_step.dispatch`.  The build
+        call is also the step's `program.first_use`: it WAITS for the first
+        loss, and each of its spans adds its seconds to
+        `profiler.startup_stats()` (`train_build_seconds` and its parts)."""
         with RecordEvent("jit.train_step"):
             if self._compiled is not None:
-                return self._dispatch(batch, "jit.train_step.dispatch")
-            with RecordEvent("jit.train_step.build"):
+                return self._dispatch(batch,
+                                      RecordEvent("jit.train_step.dispatch"))
+            with startup.phase("jit.train_step.build", "train_build_seconds"):
                 self._ensure_built()
                 self._maybe_mesh_lint(batch)
-                return self._dispatch(batch, "jit.train_step.build.trace")
+                shapes = [tuple(getattr(b, "shape", ())) for b in batch]
+                with startup.first_use("jit_train_step", shapes):
+                    loss = self._dispatch(batch, startup.phase(
+                        "jit.train_step.build.trace",
+                        "train_build_trace_seconds"))
+                    jax.block_until_ready(loss._value)
+                return loss
 
     def _dispatch(self, batch, span):
+        """One call of the step under `span`, a span not yet entered."""
         batch_vals = jax.tree_util.tree_map(_unwrap, batch, is_leaf=lambda x: isinstance(x, Tensor))
         key = rng_mod.next_key()
         if self.optimizer._lr_scheduler is not None:
@@ -367,7 +379,7 @@ class TrainStep:
         # the plain path stays free of per-step flatten cost
         step_fn = (self._aot.get(self._batch_sig(batch_vals), self._compiled)
                    if self._aot else self._compiled)
-        with RecordEvent(span):
+        with span:
             new_state, loss_val = step_fn(state_vals, batch_vals, key)
         for t, v in zip(self._state, new_state):
             t._bind(v)

@@ -36,9 +36,9 @@ __all__ = [
     "ProfilerState",
     "make_scheduler",
     "export_chrome_tracing",
-    "load_profiler_result",
     "SPAN_NAMES",
     "SCOPE_NAMES",
+    "PROGRAM_NAMES",
 ]
 
 # Every span name the program emits, layer by layer (PERF.md section 3 says
@@ -49,11 +49,30 @@ SPAN_NAMES = (
     "jit.train_step", "jit.train_step.build",
     "jit.train_step.build.optimizer_state", "jit.train_step.build.trace",
     "jit.train_step.dispatch",
+    # start-up (profiler/startup.py; construction and first-use paths only):
+    # GenerationEngine.__init__ and what it allocates, and the FIRST call of
+    # a program the framework built, with `program` (a PROGRAM_NAMES entry)
+    # and `key` (what selects this build of it)
+    "serving.engine.build", "serving.engine.build.pools",
+    "serving.engine.build.state", "program.first_use",
     # admission, scheduler, cache manager: serving.GenerationEngine
     "serving.admit", "serving.admit.match", "serving.admit.prefill",
     "serving.admit.first_token", "serving.admit.pour",
     "serving.step", "serving.step.schedule", "serving.step.dispatch",
     "serving.step.sync", "serving.step.retire",
+)
+
+# The programs the framework's hot path builds, as a device trace's XLA
+# Modules line names them (PERF.md section 3): TrainStep's step, the engine's
+# macro-step, admission prefill, the two pours, the speculative pair, and
+# to_static's function.  `compile_stats()` sums the `framework_*` keys over
+# these alone.  `jit_logit_rows` (GenerationEngine.next_token_logits), which
+# only a check calls, is left out on purpose: a check inside a window is no
+# rebuild of the hot path.
+PROGRAM_NAMES = (
+    "jit_train_step", "jit_decode_macro_step", "jit_prefill_program",
+    "jit__pour_new_blocks", "jit__pour_stacked_blocks", "jit_draft_step",
+    "jit_verify_step", "jit_static_function",
 )
 
 # `jax.named_scope`s INSIDE the compiled programs (the prefill program, the
@@ -81,7 +100,6 @@ SCOPE_NAMES = (
 )
 
 _active_profiler = None  # checked by the op funnel (cheap global)
-_last_profiler = None  # most recent stopped Profiler (export_protobuf)
 
 
 class ProfilerTarget(Enum):
@@ -174,6 +192,14 @@ class RecordEvent:
         self.end()
 
 
+from paddle_tpu._core import compile_cache as _compile_cache  # noqa: E402
+from . import startup  # noqa: E402  (it needs RecordEvent)
+
+_compile_cache.keep_rows(PROGRAM_NAMES)   # never folded into `(other)`
+
+startup_stats = startup.startup_stats
+
+
 def make_scheduler(*, closed: int, ready: int, record: int, repeat: int = 0, skip_first: int = 0):
     """Reference profiler.make_scheduler: step -> ProfilerState."""
 
@@ -243,10 +269,9 @@ class Profiler:
         return self
 
     def stop(self):
-        global _active_profiler, _last_profiler
+        global _active_profiler
         self._recording = False
         _active_profiler = None
-        _last_profiler = self
         if self._tracing:
             self._tracing = False
             jax.profiler.stop_trace()
@@ -291,7 +316,8 @@ class Profiler:
                                  compile_cache_line, decode_line,
                                  dispatch_cache_line, lora_line, mesh_line,
                                  pipeline_line, protocol_line, schedule_line,
-                                 snapshot_line, summary_text, verify_line)
+                                 snapshot_line, startup_line, summary_text,
+                                 verify_line)
 
         out = summary_text(self._buffer.spans, self._step_spans,
                            sorted_by=sorted_by, op_detail=op_detail,
@@ -299,9 +325,11 @@ class Profiler:
         cache_line = dispatch_cache_line(dispatch_cache_stats())
         if cache_line:
             out = out + "\n" + cache_line
-        comp_line = compile_cache_line(compile_stats())
+        comp = compile_stats()
+        comp_line = compile_cache_line(comp)
         if comp_line:
-            out = out + "\n" + comp_line
+            out = (out + "\n" + comp_line + "\n"
+                   + startup_line(startup_stats(), comp["by_program"]))
         dec_line = decode_line(decode_stats())
         if dec_line:
             out = out + "\n" + dec_line
@@ -372,11 +400,6 @@ def export_chrome_tracing(profiler, path: str | None = None):
     return path
 
 
-def load_profiler_result(path):
-    with open(path) as f:
-        return json.load(f)
-
-
 class SortedKeys:
     """Summary-table sort keys (reference:
     python/paddle/profiler/profiler_statistic.py SortedKeys)."""
@@ -405,19 +428,7 @@ class SummaryView:
     UDFView = 8
 
 
-def export_protobuf(path=None):
-    """reference: profiler export to protobuf dump.  Despite the name (kept:
-    API surface) this writes the chrome-trace JSON of the host spans
-    (load_profiler_result reads it back) under the requested file name; the
-    protobuf of this runtime is the device trace's `.xplane.pb` under
-    `Profiler.trace_dir`."""
-    prof = _active_profiler or _last_profiler
-    if prof is None:
-        raise RuntimeError("export_protobuf: no active/finished Profiler")
-    prof.export(path or "profiler.pb")
-
-
-__all__ += ["SortedKeys", "SummaryView", "export_protobuf"]
+__all__ += ["SortedKeys", "SummaryView"]
 
 
 def dispatch_cache_stats(reset: bool = False) -> dict:
@@ -489,18 +500,29 @@ def lora_stats(reset: bool = False) -> dict:
 
 
 def compile_stats(reset: bool = False) -> dict:
-    """Trace-time / XLA-compile-time / persistent-cache counters for this
-    process (fed by jax.monitoring; see _core.compile_cache): traces,
-    trace_seconds, compiles, compile_seconds, persistent_cache_hits /
-    _misses, compile_seconds_saved, cache_dir.  A warm start (TrainStep
-    .warmup + FLAGS_compilation_cache_dir) shows hits with near-zero
-    compile_seconds; climbing compiles in steady state mean signature
-    churn is defeating jax's executable cache."""
-    from paddle_tpu._core import compile_cache
-
-    stats = compile_cache.compile_stats()
+    """The compile ledger of this process (fed by jax.monitoring; every key
+    is explained in _core.compile_cache's docstring): traces, trace_seconds;
+    lowerings, lower_seconds (no cache holds a lowering); compiles,
+    compile_seconds and its two ends cache_read_seconds (hits) and
+    compile_miss_seconds (XLA really compiled); nested_seconds;
+    persistent_cache_hits / _misses; by_program, the same by program name
+    (`jit_decode_macro_step`: the form a device trace shows); cache_dir.
+    Added here, summed over the rows of PROGRAM_NAMES alone (the programs
+    the framework's hot path builds; a reference's eager pieces or a
+    caller's own jitted function do not count): framework_compiles,
+    framework_lower_seconds, framework_compile_seconds.  A warm start
+    (TrainStep.warmup + FLAGS_compilation_cache_dir) shows hits with
+    near-zero compile_miss_seconds; climbing framework_compiles in steady
+    state mean signature churn is defeating jax's executable cache, and
+    by_program says whose."""
+    stats = _compile_cache.compile_stats()
+    rows = [stats["by_program"][n] for n in PROGRAM_NAMES
+            if n in stats["by_program"]]
+    stats["framework_compiles"] = sum(r["compiles"] for r in rows)
+    stats["framework_lower_seconds"] = sum(r["lower_seconds"] for r in rows)
+    stats["framework_compile_seconds"] = sum(r["compile_seconds"] for r in rows)
     if reset:
-        compile_cache.reset_compile_stats()
+        _compile_cache.reset_compile_stats()
     return stats
 
 
@@ -633,6 +655,7 @@ def checkpoint_stats(reset: bool = False) -> dict:
 
 
 __all__ += ["dispatch_cache_stats", "reset_dispatch_cache", "compile_stats",
+            "startup_stats",
             "decode_stats", "lora_stats", "verify_stats", "mesh_lint_stats",
             "schedule_search_stats", "checkpoint_stats", "snapshot_stats",
             "cluster_stats", "pipeline_stats", "protocol_lint_stats"]

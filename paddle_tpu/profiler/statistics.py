@@ -17,8 +17,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 __all__ = ["EventSummary", "StatisticData", "summary_text",
-           "dispatch_cache_line", "compile_cache_line", "decode_line",
-           "lora_line"]
+           "dispatch_cache_line", "compile_cache_line", "startup_line",
+           "decode_line", "lora_line"]
 
 _UNITS = {"s": 1e9, "ms": 1e6, "us": 1e3, "ns": 1.0}
 
@@ -483,20 +483,66 @@ def pipeline_line(stats: dict) -> str:
 
 
 def compile_cache_line(stats: dict) -> str:
-    """One-line rendering of the trace/compile + persistent-cache counters
-    for Profiler.summary(); empty when nothing compiled this process."""
+    """One-line rendering of the compile ledger (trace, lowering, compile,
+    the framework's own programs' share of them, and the persistent cache's
+    measured reads) for Profiler.summary(); empty when nothing compiled this
+    process."""
     if not (stats.get("compiles") or stats.get("traces")):
         return ""
     line = (
-        "XLA compile: traces=%d (%.2fs) compiles=%d (%.2fs)"
-        % (stats["traces"], stats["trace_seconds"], stats["compiles"],
-           stats["compile_seconds"])
+        "XLA compile: traces=%d (%.2fs) lowerings=%d (%.2fs) compiles=%d "
+        "(%.2fs, %.2fs of it XLA compiling)"
+        % (stats["traces"], stats["trace_seconds"], stats["lowerings"],
+           stats["lower_seconds"], stats["compiles"],
+           stats["compile_seconds"], stats["compile_miss_seconds"])
     )
+    if "framework_compiles" in stats:
+        line += (
+            "; of these the framework's own programs: compiles=%d, lowering "
+            "%.2fs, compile %.2fs"
+            % (stats["framework_compiles"], stats["framework_lower_seconds"],
+               stats["framework_compile_seconds"]))
     if stats.get("cache_dir"):
         line += (
-            "; persistent cache [%s]: hits=%d misses=%d saved=%.2fs"
+            "; persistent cache [%s]: hits=%d misses=%d read=%.2fs"
             % (stats["cache_dir"], stats["persistent_cache_hits"],
                stats["persistent_cache_misses"],
-               stats["compile_seconds_saved"])
+               stats["cache_read_seconds"])
         )
+    return line
+
+
+def startup_line(stats: dict, by_program: dict | None = None) -> str:
+    """One-line rendering of profiler.startup_stats(): seconds by phase, and
+    the three slowest rows of compile_stats()["by_program"] when given."""
+    s = stats
+    line = (
+        "Start-up: %.1fs before import paddle_tpu, then %.1fs: import %.1f "
+        "(jax %.1f), engine "
+        "build %.1f (pools %.1f, state %.1f), train build %.1f (optimizer "
+        "state %.1f, first call %.1f), first use of %d programs %.1f (a train "
+        "step's lies inside its build), compile work outside these %.1f; "
+        "accounted, each second once, %.1f + unaccounted %.1f; process-wide: "
+        "trace %.1f, lowering %.1f, XLA compile %.1f, cache read %.1f"
+        % (s["before_import_seconds"], s["elapsed_seconds"],
+           s["import_seconds"], s["import_jax_seconds"],
+           s["engine_build_seconds"], s["engine_pool_alloc_seconds"],
+           s["engine_state_alloc_seconds"], s["train_build_seconds"],
+           s["train_optimizer_state_seconds"], s["train_build_trace_seconds"],
+           s["programs_first_used"], s["program_first_use_seconds"],
+           s["compile_outside_seconds"], s["accounted_seconds"],
+           s["unaccounted_seconds"], s["trace_seconds"], s["lower_seconds"],
+           s["compile_miss_seconds"], s["cache_read_seconds"])
+    )
+
+    def cost(row):
+        return row["trace_seconds"] + row["lower_seconds"] + row["compile_seconds"]
+
+    slowest = sorted((by_program or {}).items(), key=lambda kv: -cost(kv[1]))[:3]
+    if slowest:
+        line += "; slowest programs: " + ", ".join(
+            "%s %.1fs (%d built: trace %.1f + lowering %.1f + compile %.1f)"
+            % (n, cost(r), r["lowerings"], r["trace_seconds"],
+               r["lower_seconds"], r["compile_seconds"])
+            for n, r in slowest)
     return line

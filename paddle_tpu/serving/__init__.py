@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from paddle_tpu._core import autograd as _autograd
 from paddle_tpu._core import flags as _flags
-from paddle_tpu.profiler import RecordEvent
+from paddle_tpu.profiler import RecordEvent, startup
 
 __all__ = ["GenerationEngine", "RadixPrefixCache", "decode_stats",
            "reset_decode_stats", "lora_stats", "reset_lora_stats",
@@ -588,6 +588,7 @@ class GenerationEngine:
     lists; `result(rid)` is unaffected either way (docs/DECODE.md).
     """
 
+    @startup.phase("serving.engine.build", "engine_build_seconds")
     def __init__(self, model, max_batch=4, block_size=16, num_blocks=128,
                  eos_token_id=None, mesh=None, mp_axis="mp",
                  prefill_chunk=None, draft_model=None,
@@ -729,9 +730,11 @@ class GenerationEngine:
                                   else c.ring_blocks(self.block_size)),
                 dtype=np.int32).reshape(self.max_batch, -1)
             for c in spec.classes]
-        self._ring_tables = [
-            None if c.window is None else jnp.asarray(t)
-            for c, t in zip(spec.classes, self._ring_pages)]
+        with startup.phase("serving.engine.build.state",
+                           "engine_state_alloc_seconds"):
+            self._ring_tables = jax.block_until_ready([
+                None if c.window is None else jnp.asarray(t)
+                for c, t in zip(spec.classes, self._ring_pages)])
         self._free = list(range(self._num_blocks))
         self._ref = [0] * total  # per-block request refcounts (allocator)
         pc = (bool(prefix_cache) if prefix_cache is not None
@@ -758,9 +761,11 @@ class GenerationEngine:
         # masked lanes' block tables (every page is the slot's scratch
         # page): constant, so committed to the device ONCE here — not
         # re-transferred on every dispatch
-        self._scratch_tables = jnp.asarray(np.tile(
-            np.asarray(self._scratch, np.int32)[:, None],
-            (1, self._max_blocks_per_seq)))
+        with startup.phase("serving.engine.build.state",
+                           "engine_state_alloc_seconds"):
+            self._scratch_tables = jax.block_until_ready(jnp.asarray(np.tile(
+                np.asarray(self._scratch, np.int32)[:, None],
+                (1, self._max_blocks_per_seq))))
         self._req_counter = 0
         self._queued_at: dict = {}  # rid -> perf_counter when add_request queued it
         self._state = list(model.state_dict().values())
@@ -908,7 +913,9 @@ class GenerationEngine:
         """`pools[p][i]` of a cache specification, zeroed and placed: a
         paged class's pools `total` blocks each (the allocator's, plus a
         scratch page a slot), a window class's max_batch rings, a state
-        class's max_batch rows of [heads, width]."""
+        class's max_batch rows of [heads, width].  Waited for, class by
+        class, under `serving.engine.build.pools` (a paged class) or
+        `.state` (what a slot owns for good: rings, rows)."""
         from paddle_tpu.ops import paged_attention as pa
 
         def blocks(cls):
@@ -928,9 +935,17 @@ class GenerationEngine:
                      else (blocks(cls), ps.heads, self.block_size, ps.width))
             return jnp.zeros((len(cls.layers),) + shape, ps.dtype)
 
-        return [[stacked(cls, ps)] if cls.stacked
-                else [one(cls, ps) for _ in cls.layers]
-                for cls in spec.classes for ps in cls.pools]
+        pools = []
+        for cls in spec.classes:
+            with (startup.phase("serving.engine.build.pools",
+                                "engine_pool_alloc_seconds") if cls.paged
+                  else startup.phase("serving.engine.build.state",
+                                     "engine_state_alloc_seconds")):
+                pools += jax.block_until_ready(
+                    [[stacked(cls, ps)] if cls.stacked
+                     else [one(cls, ps) for _ in cls.layers]
+                     for ps in cls.pools])
+        return pools
 
     # ------------------------------------------------------ pool placement
     @staticmethod
@@ -1736,12 +1751,17 @@ class GenerationEngine:
         suffix = prompt[:, m_len:]
         s = suffix.shape[1]
         s_pad = self._prefill_bucket(s, m_len)
-        acc["prefill_programs_built"] = int(
-            (s_pad, m_len) not in self._prefill_fns)
+        built = (s_pad, m_len) not in self._prefill_fns
+        acc["prefill_programs_built"] = int(built)
         fn = self._prefill_program(s_pad, m_len)
         ids = np.zeros((1, s_pad), np.int32)
         ids[:, :s] = suffix
-        out = fn([t._value for t in self._state], ids, np.int32(s), prefix)
+        args = ([t._value for t in self._state], ids, np.int32(s), prefix)
+        if built:
+            with startup.first_use("jit_prefill_program", (s_pad, m_len)):
+                out = jax.block_until_ready(fn(*args))
+        else:
+            out = fn(*args)
         acc["prefill_program_calls"] = acc["admit_eager_ops"] = 1
         acc["prefill_pad_tokens"] = s_pad - s
         return out
@@ -2604,16 +2624,20 @@ class GenerationEngine:
                 if D < 1:
                     raise ValueError("decode chunk widths must be >= 1")
                 if D not in self._step_fns:
-                    self._step_fns[D] = (self._build_step(D)
-                                         .lower(*self._step_avals())
-                                         .compile())
+                    with startup.first_use("jit_decode_macro_step", D):
+                        self._step_fns[D] = (self._build_step(D)
+                                             .lower(*self._step_avals())
+                                             .compile())
                 warmed.append(D)
         if prefill:
             s_pad = self._prefill_bucket(self.block_size, 0)
-            jax.block_until_ready(self._prefill_program(s_pad, 0)(
-                [t._value for t in self._state],
-                np.zeros((1, s_pad), np.int32), np.int32(self.block_size),
-                None))
+            built = (s_pad, 0) not in self._prefill_fns
+            with (startup.first_use("jit_prefill_program", (s_pad, 0))
+                  if built else contextlib.nullcontext()):
+                jax.block_until_ready(self._prefill_program(s_pad, 0)(
+                    [t._value for t in self._state],
+                    np.zeros((1, s_pad), np.int32), np.int32(self.block_size),
+                    None))
         if adopt and self._prefix is not None and self.draft_model is None \
                 and self._pack is None:
             from paddle_tpu.ops import paged_attention as pa
@@ -2856,7 +2880,15 @@ class GenerationEngine:
             return out
         t_start = time.perf_counter()
         if self.draft_model is not None:
-            out = self._spec_step()
+            if self._draft_fn is None:
+                # the tick that builds and first runs BOTH speculative
+                # programs (it ends in its own read-backs): one span, named
+                # for the pair
+                with startup.first_use("jit_draft_step+jit_verify_step",
+                                       self.num_speculative):
+                    out = self._spec_step()
+            else:
+                out = self._spec_step()
             _DECODE_STATS["tokens"] += sum(len(v) for v in out.values())
             _DECODE_STATS["macro_steps"] += 1
             _DECODE_STATS["step_seconds"] += time.perf_counter() - t_start
@@ -2868,7 +2900,8 @@ class GenerationEngine:
         with RecordEvent("serving.step.dispatch"):
             D = self._effective_chunk()
             step_fn = self._step_fns.get(D)
-            if step_fn is None:
+            built = step_fn is None
+            if built:
                 step_fn = self._step_fns[D] = self._build_step(D)
 
             B, W = self.max_batch, self._max_blocks_per_seq
@@ -2908,7 +2941,7 @@ class GenerationEngine:
                 _LORA_STATS["gather_dispatches"] += 1
             class_args = ((list(self._ring_tables),)
                           if self._spec.per_class_tables else ())
-            nxt, new_pools, aux = step_fn(
+            args = (
                 [t._value for t in self._state],
                 [list(p) for p in self._pools],
                 jnp.asarray(tokens), jnp.asarray(tables),
@@ -2917,6 +2950,12 @@ class GenerationEngine:
                 jnp.asarray(temps), jnp.asarray(keys), jnp.asarray(steps),
                 *class_args, *lora_args,
             )
+            if built:
+                with startup.first_use("jit_decode_macro_step", D):
+                    nxt, new_pools, aux = jax.block_until_ready(
+                        step_fn(*args))
+            else:
+                nxt, new_pools, aux = step_fn(*args)
         self._pools = [list(p) for p in new_pools]
         t_sync = time.perf_counter()
         with RecordEvent("serving.step.sync"):

@@ -191,8 +191,8 @@ def test_every_name_the_program_emits_is_listed(tiny_model):
     step = TrainStep(train_model, opt.AdamW(1e-3, parameters=train_model.parameters()),
                      lambda m, i, l: m(i, l)[0])
     ids = paddle.randint(0, 1024, [2, 16])
-    eng = _engine(tiny_model)
     with profiler.Profiler(timer_only=True) as p:
+        eng = _engine(tiny_model)
         for _ in range(2):
             step(ids, ids)
         for i in range(3):                      # the third one queues
@@ -204,11 +204,22 @@ def test_every_name_the_program_emits_is_listed(tiny_model):
     assert names == set(profiler.SPAN_NAMES), names ^ set(profiler.SPAN_NAMES)
     # parents follow the names' own tree, but for an admission from the queue,
     # which the scheduler causes
+    # ... and for a program's first use, which lies where the program is
+    # first called (a train step's encloses the build's own first call)
     for s in spans:
-        if s.name in ("jit.train_step", "serving.step"):
+        if s.name in ("jit.train_step", "serving.step", "serving.engine.build"):
             assert s.parent is None
         elif s.name == "serving.admit":
             assert s.parent in (None, "serving.step.schedule")
+        elif s.name == "program.first_use":
+            assert s.parent == {
+                "jit_train_step": "jit.train_step.build",
+                "jit_prefill_program": "serving.admit.prefill",
+                "jit__pour_new_blocks": "serving.admit.pour",
+                "jit_decode_macro_step": "serving.step.dispatch",
+            }[s.args["program"]], (s.args, s.parent)
+        elif s.name == "jit.train_step.build.trace":
+            assert s.parent == "program.first_use"
         else:
             assert s.parent == s.name.rsplit(".", 1)[0], (s.name, s.parent)
     admits = [s for s in spans if s.name == "serving.admit"]
