@@ -71,8 +71,9 @@ from paddle_tpu._core.tensor import Parameter, Tensor
 from paddle_tpu.models.contract import (CacheClass, CacheSpec, PoolSpec,
                                         ServingContract)
 from paddle_tpu.models.experts import add_counts, expert_loop
-from paddle_tpu.models.window_moe import (_causal_attention, _rope_at, _rotate,
-                                          rope_inv_freq)
+from paddle_tpu.models.attention import (causal_attention, rope_at_freq,
+                                         rotate)
+from paddle_tpu.models.window_moe import rope_inv_freq
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.ops import paged_attention as pa
 
@@ -271,10 +272,10 @@ class ConvAttention(nn.Layer):
             q = (unit(q) * math.sqrt(d)).astype(dt)
             k = (unit(k) * math.sqrt(d) * jnp.exp(
                 self.temperature._value.astype(f32))[:, None]).astype(dt)
-            cos, sin = _rope_at(pos.reshape(-1), self.inv_freq, 1.0)
+            cos, sin = rope_at_freq(pos.reshape(-1), self.inv_freq, 1.0)
             at = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
-            q = _rotate(q, cos, sin, at, self.rotary)
-            k = _rotate(k, cos, sin, at, self.rotary)
+            q = rotate(q, cos, sin, at, self.rotary)
+            k = rotate(k, cos, sin, at, self.rotary)
             v12 = self.v_proj(n)._value.reshape(b, t, 2, nkv // 2, d)
             v = jnp.concatenate(
                 [v12[:, :, 0], before(v12[:, :, 1], prev and prev[2])], axis=2)
@@ -289,7 +290,7 @@ class ConvAttention(nn.Layer):
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         q, k, v, state = self.project(n, None, pos)
         with jax.named_scope("cca.attend"):
-            o = _causal_attention(q, k, v)
+            o = causal_attention(q, k, v)
         last = s - 1 if n_real is None else n_real - 1
         # [B, 1, heads, width] each: z and c are one "head" of C channels
         z, c, v2 = (jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)

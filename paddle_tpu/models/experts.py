@@ -40,18 +40,26 @@ SCORINGS = {"sigmoid": jax.nn.sigmoid,
             "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
 
 
-def route(m, router_w, *, top_k, scale, normalize=True, scoring="sigmoid"):
+def route(m, router_w, *, top_k, scale, normalize=True, scoring="sigmoid",
+          bias=None):
     """The router: m [T, h], router_w [h, E] -> (chosen [T, k] int32, w [T, k]
     float32).  s = `scoring`(m W_r) over ALL E experts ("sigmoid", or
     "softmax" over the E logits), in float32 with the product at highest
     precision whatever the types handed in (on a TPU a float32 product is
     bfloat16 passes by default, and a score's eighth and ninth expert lie
     within that rounding of each other for one token in six); the k best;
-    w_e = scale * s_e / (sum of the k chosen scores)."""
+    w_e = scale * s_e / (sum of the k chosen scores).  `bias` [E] (a
+    correction bias, `noaux_tc`): the k best of s + bias are chosen, and
+    the weights are still the chosen experts' unbiased s."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(m.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        top_s, top_i = jax.lax.top_k(SCORINGS[scoring](logits), top_k)
+        if bias is None:
+            top_s, top_i = jax.lax.top_k(SCORINGS[scoring](logits), top_k)
+        else:
+            s = SCORINGS[scoring](logits)
+            _, top_i = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+            top_s = jnp.take_along_axis(s, top_i, axis=-1)
         w = top_s
         if normalize:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -60,13 +68,13 @@ def route(m, router_w, *, top_k, scale, normalize=True, scoring="sigmoid"):
 
 def routed_experts(m, router_w, gate_up, down, *, held, top_k, scale,
                    normalize=True, scoring="sigmoid", active=None,
-                   tile=EXPERT_TILE):
+                   tile=EXPERT_TILE, bias=None):
     """The held experts' part of a routed-expert layer whose router is one
-    matrix: `route` over router_w [h, E] (E: ALL experts), then
-    `expert_loop` over what it chose (w normalised over all top_k chosen,
-    wherever they live)."""
+    matrix: `route` over router_w [h, E] (E: ALL experts; `bias` its
+    correction bias, if any), then `expert_loop` over what it chose (w
+    normalised over all top_k chosen, wherever they live)."""
     top_i, w = route(m, router_w, top_k=top_k, scale=scale,
-                     normalize=normalize, scoring=scoring)
+                     normalize=normalize, scoring=scoring, bias=bias)
     return expert_loop(m, top_i, w, gate_up, down, held=held, active=active,
                        tile=tile)
 
@@ -205,11 +213,13 @@ class RoutedExperts(nn.Layer):
     expert axis (`gate_up` [count, h, 2 width], `down` [count, width, h],
     each matrix drawn as an `nn.Linear`'s is) and not as `count` `SwiGLU`s:
     `routed_experts` then runs ONE loop over experts, and the programs that
-    hold an expert layer are `count` times smaller."""
+    hold an expert layer are `count` times smaller.  `score_bias`: the
+    router's correction bias [routed] (`noaux_tc`: it moves the choice, not
+    the weights), a parameter `score_correction_bias`, zero as built."""
 
     def __init__(self, hidden, width, *, routed, held, top_k, scale,
                  normalize=True, scoring="sigmoid", shared_width=None,
-                 stacked=False):
+                 stacked=False, score_bias=False):
         super().__init__()
         if scoring not in SCORINGS:
             raise ValueError(f"scoring must be one of {sorted(SCORINGS)}")
@@ -229,6 +239,9 @@ class RoutedExperts(nn.Layer):
             self.experts = nn.LayerList([SwiGLU(hidden, width)
                                          for _ in range(count)])
         self.shared_experts = SwiGLU(hidden, shared_width or width)
+        self.score_correction_bias = (self.create_parameter(
+            [routed], default_initializer=I.Constant(0.0))
+            if score_bias else None)
 
     def expert_weights(self):
         """(gate_up, down) as `routed_experts` takes them."""
@@ -244,7 +257,9 @@ class RoutedExperts(nn.Layer):
             flat, self.gate.weight._value, *self.expert_weights(),
             held=self.held,
             top_k=self.top_k, scale=self.scale, normalize=self.normalize,
-            scoring=self.scoring, active=active)
+            scoring=self.scoring, active=active,
+            bias=(None if self.score_correction_bias is None
+                  else self.score_correction_bias._value))
         with jax.named_scope("moe.shared"):
             shared = self.shared_experts(m)
         f = routed.reshape(shape) + shared._value.astype(jnp.float32)

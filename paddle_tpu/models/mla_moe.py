@@ -42,8 +42,9 @@ without the exchange.  Nothing stands in for the absent chips.
 
 Rope pairs adjacent lanes (2i, 2i+1), as ops/paged_attention.rope_rotate_chunk
 does for every model here; no rope scaling.  The angles of the positions a
-program touches are computed inside it (`_rope_at`): a table over all 131,072
-positions would be 33 MB of constants in every compiled program.  The extra
+program touches are computed inside it (models/attention.rope_at): a table
+over all 131,072 positions would be 33 MB of constants in every compiled
+program.  The extra
 next-token-prediction module of the published model is not part of the main
 model and is not built.
 
@@ -65,6 +66,7 @@ import jax.numpy as jnp
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu._core.tensor import Tensor
+from paddle_tpu.models.attention import causal_attention, gate_heads, rope_at
 from paddle_tpu.models.contract import CacheSpec, PoolSpec, ServingContract
 # the expert layer is shared with models/window_moe.py (one implementation);
 # `route`, `routed_experts` and `EXPERT_TILE` stay importable from here
@@ -127,10 +129,24 @@ class MlaMoeConfig:
 # ------------------------------------------------------------------ layers
 
 class LatentAttention(nn.Layer):
-    def __init__(self, config: MlaMoeConfig):
+    """One layer's latent attention.  Its geometry (heads, ranks, head
+    widths, rope theta) is the `config` it is handed, so a model with two
+    geometries builds each layer from its own (models/dsa_moe.py).
+    `window` W: a query sees itself and the W - 1 positions before it, the
+    cache row lies in a window class's RING (ops/paged_attention
+    .ring_write_chunk), and decode's absorbed read runs over the ring.
+    `gate`: the headwise sigmoid gate on each head's output
+    (models/attention.gate_heads).  `lora_rescale`: c_q times sqrt(h / r_q)
+    and c times sqrt(h / r) (a config's apply_mla_qkv_lora_rescale).  The
+    defaults are the block of the module docstring."""
+
+    def __init__(self, config: MlaMoeConfig, *, window=None, gate=False,
+                 lora_rescale=False):
         super().__init__()
         c = config
         self.config = c
+        self.window = window
+        self.scope = "mla" if window is None else "mla.window"
         self.q_head = c.qk_nope_head_dim + c.qk_rope_head_dim
         self.q_a_proj = nn.Linear(c.hidden_size, c.q_lora_rank, bias_attr=False)
         self.q_a_layernorm = nn.RMSNorm(c.q_lora_rank, c.rms_norm_eps)
@@ -146,25 +162,37 @@ class LatentAttention(nn.Layer):
             bias_attr=False)
         self.o_proj = nn.Linear(c.num_attention_heads * c.v_head_dim,
                                 c.hidden_size, bias_attr=False)
+        self.gate_proj = (nn.Linear(c.hidden_size, c.num_attention_heads,
+                                    bias_attr=False) if gate else None)
+        self.rescale = ((math.sqrt(c.hidden_size / c.q_lora_rank),
+                         math.sqrt(c.hidden_size / c.kv_lora_rank))
+                        if lora_rescale else None)
 
     def _project(self, n, pos):
         """n: Tensor [B, T, h]; pos [B, T].  Returns raw arrays: q_nope
-        [B, T, N, d_n], q_rope [B, T, N, d_r] (rotated) and the cache row
-        [B, T, pool_width] = (rms(c') g, rope(k_r), zeros)."""
+        [B, T, N, d_n], q_rope [B, T, N, d_r] (rotated), the cache row
+        [B, T, pool_width] = (rms(c') g, rope(k_r), zeros) and c_q [B, T,
+        r_q], the query latent the queries are made from."""
         c = self.config
         b, t = n.shape[0], n.shape[1]
-        cos, sin = _rope_at(pos.reshape(-1), c.qk_rope_head_dim, c.rope_theta)
+        cos, sin = rope_at(pos.reshape(-1), c.qk_rope_head_dim, c.rope_theta)
         at = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(n)))._value
+        c_q = self.q_a_layernorm(self.q_a_proj(n))
+        if self.rescale is not None:
+            c_q = Tensor(_scaled(c_q._value, self.rescale[0]))
+        q = self.q_b_proj(c_q)._value
         q = q.reshape(b, t, c.num_attention_heads, self.q_head)
         q_nope, q_rope = q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
         q_rope = pa.rope_rotate_chunk(q_rope, cos, sin, at)
         kv_a = self.kv_a_proj(n)._value
         latent = self.kv_a_layernorm(Tensor(kv_a[..., :c.kv_lora_rank]))._value
+        if self.rescale is not None:
+            latent = _scaled(latent, self.rescale[1])
         k_rope = pa.rope_rotate_chunk(
             kv_a[:, :, None, c.kv_lora_rank:], cos, sin, at)[:, :, 0]
         pad = jnp.zeros((b, t, c.pool_width - c.latent_width), latent.dtype)
-        return q_nope, q_rope, jnp.concatenate([latent, k_rope, pad], axis=-1)
+        row = jnp.concatenate([latent, k_rope, pad], axis=-1)
+        return q_nope, q_rope, row, c_q._value
 
     def _w_kvb(self):
         c = self.config
@@ -173,59 +201,87 @@ class LatentAttention(nn.Layer):
             c.qk_nope_head_dim + c.v_head_dim)
         return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
-    def prefill(self, n):
-        """Causal self-attention over a whole prompt, per-head K/V
-        materialised from the latent: (Tensor [B, S, h] before the
-        sandwich norm, cache rows [B, S, 1, pool_width])."""
+    def _heads(self, q_nope, q_rope, row):
+        """Per-head q, K and V materialised from the latent (prefill): q / k
+        [B, S, N, d_n + d_r], v [B, S, N, d_v]."""
         c = self.config
-        b, s = n.shape[0], n.shape[1]
+        b, s = row.shape[0], row.shape[1]
         heads = c.num_attention_heads
-        with jax.named_scope("mla.prefill"):
+        latent = row[..., :c.kv_lora_rank]
+        k_rope = row[..., c.kv_lora_rank:c.latent_width]
+        kv = self.kv_b_proj(Tensor(latent))._value.reshape(
+            b, s, heads, c.qk_nope_head_dim + c.v_head_dim)
+        k = jnp.concatenate(
+            [kv[..., :c.qk_nope_head_dim],
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              (b, s, heads, c.qk_rope_head_dim))], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        return q, k, kv[..., c.qk_nope_head_dim:]
+
+    def _out(self, n, o):
+        """o [B, T, N, d_v] (raw) -> Tensor [B, T, h]: the gate, if any,
+        then W_o."""
+        if self.gate_proj is not None:
+            o = gate_heads(self.gate_proj, n, o)
+        return self.o_proj(Tensor(o.reshape(o.shape[0], o.shape[1], -1)))
+
+    @staticmethod
+    def _absorb(q_nope, q_rope, w_k, dtype):
+        """Decode's absorbed query [B, N, r + d_r]: q~ = q_nope W_kvb^K beside
+        the rotated rope part (w_k [r, N, d_n])."""
+        q_lat = jnp.einsum("bnd,rnd->bnr", q_nope[:, 0], w_k,
+                           preferred_element_type=jnp.float32)
+        return jnp.concatenate([q_lat.astype(dtype), q_rope[:, 0]], -1)
+
+    def _expand(self, n, o_lat, w_v, dtype):
+        """sum_s p c [B, N, r] -> Tensor [B, 1, h]: W_kvb^V (w_v [r, N,
+        d_v]), the gate, W_o."""
+        o = jnp.einsum("bnr,rnd->bnd", o_lat.astype(dtype), w_v,
+                       preferred_element_type=jnp.float32).astype(dtype)
+        if self.gate_proj is not None:
+            o = gate_heads(self.gate_proj, n, o[:, None])[:, 0]
+        return self.o_proj(Tensor(o.reshape(o.shape[0], 1, -1)))
+
+    def prefill(self, n):
+        """Causal (or windowed) self-attention over a whole prompt, per-head
+        K/V materialised from the latent: (Tensor [B, S, h] before the
+        sandwich norm, cache rows [B, S, 1, pool_width])."""
+        b, s = n.shape[0], n.shape[1]
+        with jax.named_scope(self.scope + ".prefill"):
             pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-            q_nope, q_rope, row = self._project(n, pos)
-            latent = row[..., :c.kv_lora_rank]
-            k_rope = row[..., c.kv_lora_rank:c.latent_width]
-            kv = self.kv_b_proj(Tensor(latent))._value.reshape(
-                b, s, heads, c.qk_nope_head_dim + c.v_head_dim)
-            k = jnp.concatenate(
-                [kv[..., :c.qk_nope_head_dim],
-                 jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (b, s, heads, c.qk_rope_head_dim))], axis=-1)
-            q = jnp.concatenate([q_nope, q_rope], axis=-1)
-            o = _causal_attention(q, k, kv[..., c.qk_nope_head_dim:])
-            out = self.o_proj(Tensor(o.reshape(b, s, heads * c.v_head_dim)))
+            q_nope, q_rope, row, _c_q = self._project(n, pos)
+            o = causal_attention(*self._heads(q_nope, q_rope, row),
+                                  self.window)
+            out = self._out(n, o)
         return out, row[:, :, None, :]
 
     def decode(self, n, pool, tables, lens):
-        """One new token a row against the paged latent pool, absorbed
-        form.  n: Tensor [B, 1, h]; pool [num_blocks, 1, bs, pool_width];
-        lens [B] INCLUDING this token.  Returns (Tensor [B, 1, h], pool)."""
+        """One new token a row against the latent pool, absorbed form.  n:
+        Tensor [B, 1, h]; pool [num_blocks, 1, bs, pool_width] (paged, or a
+        window class's rings; `tables` that class's); lens [B] INCLUDING
+        this token.  Returns (Tensor [B, 1, h], pool)."""
         c = self.config
-        with jax.named_scope("mla.decode"):
+        with jax.named_scope(self.scope + ".decode"):
             pos = (lens - 1)[:, None]
-            q_nope, q_rope, row = self._project(n, pos)
-            pool = pa.paged_write_chunk(pool, row[:, :, None, :], tables, pos)
+            q_nope, q_rope, row, _c_q = self._project(n, pos)
+            write = (pa.paged_write_chunk if self.window is None
+                     else pa.ring_write_chunk)
+            pool = write(pool, row[:, :, None, :], tables, pos)
             w_k, w_v = self._w_kvb()                    # [r, N, d_n], [r, N, d_v]
-            q_lat = jnp.einsum("bnd,rnd->bnr", q_nope[:, 0], w_k,
-                               preferred_element_type=jnp.float32)
-            q = jnp.concatenate([q_lat.astype(row.dtype), q_rope[:, 0]], -1)
+            q = self._absorb(q_nope, q_rope, w_k, row.dtype)
             o_lat = absorbed_attention(q, pool, tables, lens,
-                                       rank=c.kv_lora_rank, width=self.q_head)
-            o = jnp.einsum("bnr,rnd->bnd", o_lat.astype(row.dtype), w_v,
-                           preferred_element_type=jnp.float32).astype(row.dtype)
-            out = self.o_proj(Tensor(o.reshape(o.shape[0], 1, -1)))
+                                       rank=c.kv_lora_rank, width=self.q_head,
+                                       window=self.window)
+            out = self._expand(n, o_lat, w_v, row.dtype)
         return out, pool
 
 
-def _rope_at(positions, dim, theta):
-    """cos and sin [P, dim / 2] (float32) of the rope angles of the int32
-    positions [P]: rows of the table `rope_rotate_chunk` indexes."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+def _scaled(x, s):
+    """x times the constant s, in float32, back in x's type."""
+    return (x.astype(jnp.float32) * jnp.float32(s)).astype(x.dtype)
 
 
-def absorbed_attention(q, pool, tables, lens, *, rank, width):
+def absorbed_attention(q, pool, tables, lens, *, rank, width, window=None):
     """Decode attention over the paged latent pool, absorbed form: q [B, N,
     r + d_r] (q~ = q_nope W_kvb^K beside the rotated rope part), pool
     [num_blocks, 1, bs, r + d_r or wider] (`pool_width`: the lanes past q's
@@ -237,36 +293,18 @@ def absorbed_attention(q, pool, tables, lens, *, rank, width):
     (bfloat16 passes of the matrix unit, float32 sums).  Which form runs it
     (the Pallas kernel over each row's own pages, or XLA's gather and two
     einsums) is `ops.paged_attention.paged_shared_row_attention`'s to say,
-    by what it sees in the pool."""
+    by what it sees in the pool.  With a `window` W the pool is a window
+    class's rings and `tables` the rows' slots' rings: each ring's rows are
+    key and value at once, read whole through `paged_window_attention`
+    (XLA), and the result comes back in q's type."""
     if pool.shape[-1] > q.shape[-1]:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+    if window is not None:
+        return pa.paged_window_attention(
+            q[:, None], pool, pool, tables, lens, window,
+            scale=1.0 / math.sqrt(width))[:, 0, :, :rank]
     return pa.paged_shared_row_attention(q, pool, tables, lens, rank=rank,
                                          scale=1.0 / math.sqrt(width))
-
-
-def _causal_attention(q, k, v, block=512):
-    """Causal softmax(q k^T / sqrt(width)) v for q/k [B, S, N, d] and v
-    [B, S, N, d_v]: the flash kernel on a TPU; elsewhere plain XLA in query
-    blocks (never an [S, S] score matrix for more than `block` queries)."""
-    from paddle_tpu import ops as _ops
-
-    if _ops.use_pallas():
-        return _ops.flash_attention(q, k, v, causal=True)
-    s = q.shape[1]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    outs = []
-    for at in range(0, s, block):
-        qb = q[:, at:at + block]
-        upto = min(s, at + block)
-        score = jnp.einsum("bqnd,bsnd->bnqs", qb, k[:, :upto],
-                           preferred_element_type=jnp.float32) * scale
-        ok = (jnp.arange(upto)[None, :]
-              <= (at + jnp.arange(qb.shape[1]))[:, None])
-        p = jax.nn.softmax(jnp.where(ok, score, -1e30), axis=-1)
-        outs.append(jnp.einsum("bnqs,bsnd->bqnd", p.astype(v.dtype),
-                               v[:, :upto],
-                               preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=1).astype(q.dtype)
 
 
 class MlaMoeDecoderLayer(nn.Layer):

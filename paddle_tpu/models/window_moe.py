@@ -58,6 +58,8 @@ import numpy as np
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu._core.tensor import Tensor
+from paddle_tpu.models.attention import (causal_attention, gate_heads,
+                                         rope_at_freq, rotate)
 from paddle_tpu.models.contract import (CacheClass, CacheSpec, PoolSpec,
                                         ServingContract)
 from paddle_tpu.models.experts import RoutedExperts, SwiGLU, add_counts
@@ -181,54 +183,7 @@ def rope_inv_freq(params: dict, head_dim: int):
     return inv.astype(np.float32), af, rot
 
 
-def _rope_at(positions, inv_freq, attention_factor):
-    """cos and sin [P, rot / 2] (float32) of the int32 positions [P], times
-    the attention factor: rows of the table `rope_rotate_chunk` indexes."""
-    ang = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq)[None, :]
-    f = jnp.float32(attention_factor)
-    return jnp.cos(ang) * f, jnp.sin(ang) * f
-
-
-def _rotate(x, cos, sin, at, rot):
-    """x [B, T, n, d]: its first `rot` lanes rotated by the rows `at` [B, T]
-    of cos / sin, the rest untouched."""
-    if rot == x.shape[-1]:
-        return pa.rope_rotate_chunk(x, cos, sin, at)
-    return jnp.concatenate(
-        [pa.rope_rotate_chunk(x[..., :rot], cos, sin, at), x[..., rot:]], -1)
-
-
 # ----------------------------------------------------------------- attention
-
-def _causal_attention(q, k, v, window=None, block=512):
-    """Causal (and windowed) grouped attention for q [B, S, N, d] and k / v
-    [B, S, Nkv, d]: the flash kernel on a TPU; elsewhere plain XLA in query
-    blocks over the keys a block can see (never an [S, S] score matrix)."""
-    from paddle_tpu import ops as _ops
-
-    if _ops.use_pallas():
-        return _ops.flash_attention(q, k, v, causal=True, window=window)
-    b, s, n, d = q.shape
-    nkv = k.shape[2]
-    qg = q.reshape(b, s, nkv, n // nkv, d)
-    scale = 1.0 / math.sqrt(d)
-    outs = []
-    for at in range(0, s, block):
-        upto = min(s, at + block)
-        lo = 0 if window is None else max(0, at - window + 1)
-        score = jnp.einsum("bqkgd,bskd->bkgqs", qg[:, at:upto], k[:, lo:upto],
-                           preferred_element_type=jnp.float32) * scale
-        qi = (at + jnp.arange(upto - at))[:, None]
-        kj = (lo + jnp.arange(upto - lo))[None, :]
-        ok = kj <= qi
-        if window is not None:
-            ok = ok & (kj > qi - window)
-        p = jax.nn.softmax(jnp.where(ok, score, -1e30), axis=-1)
-        outs.append(jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype),
-                               v[:, lo:upto],
-                               preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=1).reshape(b, s, n, d).astype(q.dtype)
-
 
 class GatedAttention(nn.Layer):
     """One layer's attention: grouped heads, its layer type's rope and mask,
@@ -259,18 +214,16 @@ class GatedAttention(nn.Layer):
         q = qkv[..., :nq * d].reshape(b, t, nq, d)
         k = qkv[..., nq * d:(nq + nkv) * d].reshape(b, t, nkv, d)
         v = qkv[..., (nq + nkv) * d:].reshape(b, t, nkv, d)
-        cos, sin = _rope_at(pos.reshape(-1), self.inv_freq,
+        cos, sin = rope_at_freq(pos.reshape(-1), self.inv_freq,
                             self.attention_factor)
         at = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
-        return (_rotate(q, cos, sin, at, self.rotary),
-                _rotate(k, cos, sin, at, self.rotary), v)
+        return (rotate(q, cos, sin, at, self.rotary),
+                rotate(k, cos, sin, at, self.rotary), v)
 
     def _gated_out(self, n, o):
         """o [B, T, N, d] (raw) -> Tensor [B, T, h]: each head times its
-        gate (float32 sigmoid of the normed input's projection), then W_o."""
-        with jax.named_scope("attn.gate"):
-            g = jax.nn.sigmoid(self.gate_proj(n)._value.astype(jnp.float32))
-            o = (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+        gate, then W_o."""
+        o = gate_heads(self.gate_proj, n, o)
         return self.o_proj(Tensor(o.reshape(o.shape[0], o.shape[1], -1)))
 
     def prefill(self, n):
@@ -280,7 +233,7 @@ class GatedAttention(nn.Layer):
         with jax.named_scope(self.scope + ".prefill"):
             pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
             q, k, v = self._project(n, pos)
-            o = _causal_attention(q, k, v, self.window)
+            o = causal_attention(q, k, v, self.window)
         return self._gated_out(n, o), k, v
 
     def decode(self, n, k_pool, v_pool, table, lens):

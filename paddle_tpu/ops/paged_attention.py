@@ -926,6 +926,27 @@ def _shared_row_xla(q, pool, block_tables, seq_lens, rank, scale):
                       preferred_element_type=jnp.float32)
 
 
+def paged_selected_attention(q, pool, block_tables, positions, valid, *,
+                             rank, scale):
+    """T = 1 decode attention over a SELECTION of each row's positions in a
+    shared-row pool (latent attention with a learned sparse indexer,
+    models/dsa_moe.py): q [B, N, H]; pool [num_blocks, 1, bs, H], a row's
+    first `rank` lanes the value; positions [B, K] int32, the positions
+    each row attends (any order), found through `block_tables`; valid
+    [B, K] bool (a selection shorter than K: the rest are ignored).  Only
+    those K rows are taken from the pool (one gather by page table and
+    slot); scores, maximum, exponent and sum float32, the probabilities
+    meet the rows in the rows' type.  Returns [B, N, rank] float32."""
+    bs = pool_block_size(pool)
+    blocks = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+    rows = pool[blocks, 0, positions % bs]                      # [B, K, H]
+    score = jnp.einsum("bnr,bkr->bnk", q.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], score, -1e30), axis=-1)
+    return jnp.einsum("bnk,bkr->bnr", p.astype(rows.dtype), rows[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
 def reads_own_pages(cache, t=1):
     """Whether decode attention of `t` tokens a row over `cache` goes
     through the Pallas kernel (each row's own live pages, straight from

@@ -97,6 +97,14 @@ SCOPE_NAMES = (
     # carried across depth (moe.router_mlp); its experts' loop is
     # models/experts.py's, under moe.route / moe.experts above
     "cca.conv", "cca.attend", "moe.router_mlp",
+    # models/dsa_moe.py: the learned sparse indexer's scores (dsa.index) and
+    # its exact top-k with the gather of the rows it chose (dsa.select); the
+    # full layers' decode over that selection (mla.sparse.decode), the
+    # sliding layers' latent attention in its window (mla.window.*: the
+    # flash kernel's window in the prefill program, the slot's latent ring
+    # in the macro-step); the full layers' prefill runs under mla.prefill
+    "dsa.index", "dsa.select", "mla.sparse.decode", "mla.window.prefill",
+    "mla.window.decode",
 )
 
 _active_profiler = None  # checked by the op funnel (cheap global)

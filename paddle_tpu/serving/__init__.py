@@ -78,6 +78,11 @@ _DECODE_STATS = {
     "wk_pool_bytes": 0,
     "wv_pool_bytes": 0,
     "window_ring_blocks": 0,
+    # latent attention with a learned sparse indexer (models/dsa_moe.py):
+    # the full layers' indexer keys (paged, beside latent_) and the sliding
+    # layers' latent rows in a window class's rings
+    "index_pool_bytes": 0,
+    "wlatent_pool_bytes": 0,
     # a STATE class's pools (state a slot: [max_batch, heads, width] a layer,
     # no positions) under the model's own names too, and all of them here
     "slot_state_bytes": 0,
@@ -165,6 +170,16 @@ _DECODE_STATS = {
     "attn_full_positions_read": 0,
     "attn_window_positions_read": 0,
     "attn_window_positions_live": 0,
+    # a learned sparse indexer's decode (models/dsa_moe.py), once a token
+    # step: the indexer keys its scores read (the gather of a row's whole
+    # table, live or not), and the latent rows the full layers' attention
+    # read (its selection, at most index_topk a row, counted from it);
+    # a committed admission's (query, key) pairs the indexer scored over
+    # the full layers and the pairs it selected
+    "attn_indexer_positions_read": 0,
+    "attn_sparse_positions_read": 0,
+    "dsa_prefill_pairs_scored": 0,
+    "dsa_prefill_pairs_selected": 0,
 }
 
 _ADMIT_PHASES = ("match", "prefill", "first_token", "pour")

@@ -18,7 +18,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import profiler, serving
-from paddle_tpu.models import experts, window_moe
+from paddle_tpu.models import attention, experts
 from paddle_tpu.models.contract import CacheClass, CacheSpec, PoolSpec
 from paddle_tpu.models.window_moe import (FULL, SLIDING, WindowMoeForCausalLM,
                                           rope_inv_freq, window_moe_tiny)
@@ -408,9 +408,9 @@ def test_partial_rotary_leaves_the_upper_lanes_untouched():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 3, 4, 128)),
                     jnp.float32)
     pos = jnp.asarray([[5, 900, 8000], [0, 1, 77]], jnp.int32)
-    cos, sin = window_moe._rope_at(pos.reshape(-1), inv, af)
+    cos, sin = attention.rope_at_freq(pos.reshape(-1), inv, af)
     at = jnp.arange(6, dtype=jnp.int32).reshape(2, 3)
-    y = window_moe._rotate(x, cos, sin, at, rot)
+    y = attention.rotate(x, cos, sin, at, rot)
     np.testing.assert_array_equal(y[..., 64:], x[..., 64:])
     # the rotated lanes: pairs (2i, 2i+1) turned by pos * inv_i, times af
     ang = np.asarray(pos, np.float64)[..., None] * inv.astype(np.float64)

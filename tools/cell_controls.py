@@ -1,9 +1,11 @@
 """Controls that must FAIL, read through the harness's own comparison.
 
-Runs a cell of the `window_moe` or the `cca_moe` family once through
+Runs a cell of the `window_moe`, `cca_moe` or `dsa_moe` family once through
 `perfbench.run.run_cell` (drivers `serve_closed_swa_moe`, `serve_closed_cca_
-moe`), then calls that driver's OWN comparisons — the rows of logits from the
-engine as the window left it, and the routing limit — once more against
+moe`, `serve_closed_dsa_moe`), then calls that driver's OWN comparisons — the
+rows of logits from the engine as the window left it, and the routing limit
+(the dsa driver: its `agreements`, routing, the indexer's selection and each
+layer's attention on the reference's own inputs) — once more against
 references that compute something else on purpose (the controls of
 `perfbench/reference_window_moe.py` / `reference_cca_moe.py`), on the same
 engine and with the cell's own limits.  A comparison that reads `correct True`
@@ -13,8 +15,10 @@ for a control cannot tell that mechanism or precision and is too loose for it:
         --workload serve-laguna-decode-ctx8k --seed 2147487001
     chiprun --timeout 1700 -- python tools/cell_controls.py \\
         --workload serve-zaya-decode-ctx8k --seed 2147487001
+    chiprun --timeout 1700 -- python tools/cell_controls.py \\
+        --workload serve-dots3-decode-ctx8k --seed 2147487001
 
-PERF.md section 6 (PR 31, PR 34) has the readings; the cell files quote them.
+PERF.md section 6 has the readings; the cell files quote them.
 """
 
 from __future__ import annotations
@@ -45,7 +49,19 @@ CCA_CONTROLS = (("none (the reference as it is)", {}),
                 ("skip computed as zero", {"skip_zero": True}),
                 ("router in bfloat16", {"router_dtype": "bfloat16"}),
                 ("everything in bfloat16", {"dtype": "bfloat16"}))
+# the sparse-indexer / latent-window cell: its driver's own agreement
+# checks (routing, selection, each layer's attention on its decode path)
+# stand in for the routing limit alone
+DSA_CONTROLS = (("none (the reference as it is)", {}),
+                ("window ignored", {"ignore_window": True}),
+                ("gate left out", {"no_gate": True}),
+                ("every position attended", {"dense_index": True}),
+                ("correction bias left out", {"no_bias": True}),
+                ("router in bfloat16", {"router_dtype": "bfloat16"}),
+                ("indexer scores in bfloat16", {"index_dtype": "bfloat16"}))
 BY_DRIVER = {
+    "serve_closed_dsa_moe": (DSA_CONTROLS,
+                             tuple(name for name, _c in DSA_CONTROLS[1:])),
     "serve_closed_swa_moe": (CONTROLS, MUST_FAIL),
     "serve_closed_cca_moe": (CCA_CONTROLS,
                              tuple(name for name, _c in CCA_CONTROLS[1:])),
@@ -65,8 +81,10 @@ class _Controlled:
         return {**self._family.reference_sizes(cfg), **self._control}
 
 
-def run(root, workload, seed, seconds, rows=1, say=print) -> dict:
-    """{control: (correct, {check: verdict})}, after one run of the cell."""
+def run(root, workload, seed, seconds, rows=1, say=print, trace_dir=None
+        ) -> dict:
+    """{control: (correct, {check: verdict})}, after one run of the cell
+    (traced into `trace_dir` when one is given)."""
     import importlib
 
     from perfbench import run as harness
@@ -84,7 +102,8 @@ def run(root, workload, seed, seconds, rows=1, say=print) -> dict:
 
     driver.logit_rows = keeping
     try:
-        line = harness.run_cell(root, workload, seed, seconds, False)
+        line = harness.run_cell(root, workload, seed, seconds,
+                                trace_dir is not None, trace_dir=trace_dir)
     finally:
         driver.logit_rows = compare
     say(json.dumps(line))
@@ -98,11 +117,15 @@ def run(root, workload, seed, seconds, rows=1, say=print) -> dict:
         t0 = time.perf_counter()
         ctx.family = _Controlled(fam, control)
         checks = dict(compare(ctx, sv))
-        share, pairs = fam.routing_agreement(
-            fam.model, fam.reference_weights(fam.model),
-            ctx.family.reference_sizes(ctx.config), ids, ctx.reference())
-        checks[f"routing {share:.4f} of {pairs} (at least "
-               f"{ck['routing_agreement']})"] = share >= ck["routing_agreement"]
+        if hasattr(driver, "agreements"):
+            checks.update(driver.agreements(ctx))
+        else:
+            share, pairs = fam.routing_agreement(
+                fam.model, fam.reference_weights(fam.model),
+                ctx.family.reference_sizes(ctx.config), ids, ctx.reference())
+            checks[f"routing {share:.4f} of {pairs} (at least "
+                   f"{ck['routing_agreement']})"] = (
+                share >= ck["routing_agreement"])
         out[name] = (all(checks.values()), checks)
         say(f"[control] {name}: correct {out[name][0]} "
             f"({time.perf_counter() - t0:.1f} s)")
