@@ -111,6 +111,10 @@ _stats = {
     # tuned table or the rule by bytes (ops/paged_attention._pages_per_step)
     "paged_tile_tuned_traces": 0,
     "paged_tile_rule_traces": 0,
+    # ... and which form a full layer's sparse prefill attention took: the
+    # Pallas forward over a keep mask or the XLA form (models/dsa_moe.py)
+    "sparse_prefill_kernel_traces": 0,
+    "sparse_prefill_xla_traces": 0,
 }
 _by_program: dict = {}
 # what `ledger_wall_seconds` adds up; `reset_compile_stats` carries it over,

@@ -44,8 +44,9 @@ and "index", the indexer's key kI (d_I values a token).  The sliding layers
 are a WINDOW class of W positions with one pool, "wlatent": the latent row
 of their own geometry in a ring of blocks a slot.  PREFILL materialises
 per-head K/V from the latent: the sliding layers run the flash kernel with
-`window=`, the full layers a masked attention in blocks of queries whose mask
-is the indexer's selection (`sparse_attention`).  DECODE is absorbed: a full
+`window=`, the full layers each query over the indexer's selection
+(`sparse_attention`: on a TPU a Pallas flash forward over a packed keep
+mask, ops/sparse_flash.py).  DECODE is absorbed: a full
 layer scores the row's live indexer keys (every page of its table), takes
 the exact top-k (`index_select`) and reads only those latent rows
 (ops/paged_attention.paged_selected_attention: a gather by page table and
@@ -70,6 +71,7 @@ import jax.numpy as jnp
 
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
+from paddle_tpu._core import compile_cache
 from paddle_tpu._core.tensor import Tensor
 from paddle_tpu.models.contract import (CacheClass, CacheSpec, PoolSpec,
                                         ServingContract)
@@ -77,9 +79,11 @@ from paddle_tpu.models.experts import RoutedExperts, SwiGLU, add_counts
 from paddle_tpu.models.attention import rope_at, rotate
 from paddle_tpu.models.mla_moe import LatentAttention, MlaMoeConfig
 from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import sparse_flash
 
 __all__ = ["DsaMoeConfig", "DsaMoeForCausalLM", "DsaMoeModel",
-           "index_scores", "index_select", "sparse_attention", "dsa_moe_tiny",
+           "index_scores", "index_select", "select_words", "sparse_attention",
+           "sparse_attention_kernel", "sparse_attention_xla", "dsa_moe_tiny",
            "FULL", "SLIDING"]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -250,10 +254,102 @@ def sparse_attention(q, k, v, q_idx, w_idx, k_idx, top_k, block=Q_BLOCK):
     positions s <= t of the indexer's largest scores (all s <= t while
     t < k).  q / k [B, S, N, d], v [B, S, N, d_v]; the indexer's q_idx
     [B, S, H_I, d_I], w_idx [B, S, H_I], k_idx [B, S, d_I].  Queries go in
-    blocks of `block`, a lax.map over the blocks of each span of
-    max(k, block) queries, which meets only the keys up to the span's end;
-    a block's scores, selection and masked softmax are float32.  Returns
-    [B, S, N, d_v] in q's type."""
+    spans of max(k, block), whose indexer scores meet only the keys up to
+    the span's end, and each span in blocks of `block` queries (a lax.map):
+    a block's scores and selection are float32.  Returns [B, S, N, d_v] in
+    q's type.
+
+    Two forms, one selection, chosen by platform and shape and counted at
+    trace time by the form taken (`sparse_prefill_kernel_traces` /
+    `sparse_prefill_xla_traces`): on a TPU, where a head's K and V fit the
+    kernel, `sparse_attention_kernel` (the Pallas forward
+    ops/sparse_flash.py over a packed keep mask); elsewhere
+    `sparse_attention_xla`."""
+    from paddle_tpu import ops
+
+    b, s, heads, d = q.shape
+    if ops.use_pallas() and sparse_flash.fits(s, d, v.shape[-1], q.dtype):
+        compile_cache.count("sparse_prefill_kernel_traces")
+        return sparse_attention_kernel(q, k, v, q_idx, w_idx, k_idx, top_k,
+                                       block)
+    compile_cache.count("sparse_prefill_xla_traces")
+    return sparse_attention_xla(q, k, v, q_idx, w_idx, k_idx, top_k, block)
+
+
+def _total_order(x):
+    """float32 -> int32 keys in `jax.lax.top_k`'s order, a total one: -0.0
+    sorts below +0.0, where `==` calls them equal."""
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def select_words(scores, allowed, top_k):
+    """`index_select`'s selection as a packed keep mask
+    (ops/sparse_flash.pack_keep) over the last axis of scores [..., S]
+    (float32): int32 words [..., words_for(S)].  Built without a scatter:
+    the k-th largest allowed score is the row's threshold; every allowed
+    position above it is kept, and of those AT it the ones `jax.lax.top_k`
+    took, which are the lowest positions (its ties go to the lower index):
+    all up to the highest position it returned at the threshold.  Scores
+    compare in top_k's own total order (`_total_order`)."""
+    k = min(top_k, scores.shape[-1])
+    with jax.named_scope("dsa.select"):
+        masked = jnp.where(allowed, scores, -jnp.inf)
+        vals, at = jax.lax.top_k(masked, k)
+        key, ranked = _total_order(masked), _total_order(vals)
+        edge = ranked[..., -1:]
+        last = jnp.max(jnp.where(ranked == edge, at, -1), axis=-1,
+                       keepdims=True)
+        pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+        keep = allowed & ((key > edge) | ((key == edge) & (pos <= last)))
+        return sparse_flash.pack_keep(keep)
+
+
+def sparse_attention_kernel(q, k, v, q_idx, w_idx, k_idx, top_k,
+                            block=Q_BLOCK):
+    """`sparse_attention` through the Pallas kernels.  A first span whose
+    queries each see at most k keys selects nothing: it is the causal
+    `flash_attention`.  Every later query's selection is a row of packed
+    keep bits (`select_words`, a block of `block` queries at a time over
+    its span's keys), and ONE call of ops/sparse_flash.sparse_flash_forward
+    attends them all over the prompt's keys."""
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    b, s, heads, d = q.shape
+    scale = 1.0 / math.sqrt(d)
+    block = min(block, s)
+    span = -(-max(top_k, block) // block) * block
+    dense = min(s, span) if min(s, span) <= top_k else 0
+    outs = []
+    if dense:
+        outs.append(flash_attention(q[:, :dense], k[:, :dense],
+                                    v[:, :dense], causal=True, scale=scale))
+    if dense < s:
+        width = sparse_flash.words_for(s)
+        words = []
+        for lo in range(dense, s, span):
+            hi = min(s, lo + span)
+
+            def one(args, k_i=k_idx[:, :hi], hi=hi):
+                qib, wib, t0 = args
+                w = select_words(index_scores(qib, wib, k_i),
+                                 _seen(t0, block, hi)[None], top_k)
+                return jnp.pad(w, ((0, 0), (0, 0), (0, width - w.shape[-1])))
+
+            words.append(_over_blocks(one, lo, hi, block, q_idx, w_idx))
+        o = sparse_flash.sparse_flash_forward(
+            jnp.swapaxes(q[:, dense:], 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), jnp.concatenate(words, axis=1),
+            scale=scale)
+        outs.append(jnp.swapaxes(o, 1, 2))
+    return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+
+
+def sparse_attention_xla(q, k, v, q_idx, w_idx, k_idx, top_k, block=Q_BLOCK):
+    """`sparse_attention` in XLA: the form off a TPU, and the oracle of the
+    kernel's.  A block's selection is scattered into a [B, block, keys]
+    mask, and its scores over every key to its span's end, the masked
+    softmax and the product with V are float32 in HBM."""
     b, s, heads, d = q.shape
     scale = 1.0 / math.sqrt(d)
     block = min(block, s)
@@ -261,21 +357,11 @@ def sparse_attention(q, k, v, q_idx, w_idx, k_idx, top_k, block=Q_BLOCK):
     outs = []
     for lo in range(0, s, span):
         hi = min(s, lo + span)
-        keys, vals, k_i = k[:, :hi], v[:, :hi], k_idx[:, :hi]
-        n_blocks = -(-(hi - lo) // block)
-        pad = lo + n_blocks * block - hi
 
-        def cut(x, pad=pad, lo=lo, hi=hi, n_blocks=n_blocks):
-            x = x[:, lo:hi]
-            if pad:
-                x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-            return jnp.moveaxis(
-                x.reshape((b, n_blocks, block) + x.shape[2:]), 1, 0)
-
-        def one(args, keys=keys, vals=vals, k_i=k_i, hi=hi):
+        def one(args, keys=k[:, :hi], vals=v[:, :hi], k_i=k_idx[:, :hi],
+                hi=hi):
             qb, qib, wib, t0 = args
-            t = t0 + jnp.arange(block, dtype=jnp.int32)
-            seen = jnp.arange(hi, dtype=jnp.int32)[None, :] <= t[:, None]
+            seen = _seen(t0, block, hi)
             if hi > top_k:
                 at, ok = index_select(index_scores(qib, wib, k_i),
                                       seen[None], top_k)
@@ -293,11 +379,34 @@ def sparse_attention(q, k, v, q_idx, w_idx, k_idx, top_k, block=Q_BLOCK):
                               preferred_element_type=jnp.float32
                               ).astype(q.dtype)
 
-        starts = lo + block * jnp.arange(n_blocks, dtype=jnp.int32)
-        o = jax.lax.map(one, (cut(q), cut(q_idx), cut(w_idx), starts))
-        outs.append(jnp.moveaxis(o, 0, 1).reshape(
-            (b, n_blocks * block) + o.shape[3:])[:, :hi - lo])
+        outs.append(_over_blocks(one, lo, hi, block, q, q_idx, w_idx))
     return jnp.concatenate(outs, axis=1)
+
+
+def _seen(t0, block, hi):
+    """[block, hi]: query t0 + r sees key s <= t0 + r."""
+    t = t0 + jnp.arange(block, dtype=jnp.int32)
+    return jnp.arange(hi, dtype=jnp.int32)[None, :] <= t[:, None]
+
+
+def _over_blocks(one, lo, hi, block, *xs):
+    """`one((x_block, ..., t0))` over the blocks of `block` queries of
+    lo..hi (a lax.map; the last block zero-padded) of each x [B, S, ...]:
+    its results [B, hi - lo, ...]."""
+    n = -(-(hi - lo) // block)
+
+    def cut(x):
+        x = x[:, lo:hi]
+        if n * block > hi - lo:
+            x = jnp.pad(x, [(0, 0), (0, n * block - (hi - lo))]
+                        + [(0, 0)] * (x.ndim - 2))
+        return jnp.moveaxis(
+            x.reshape((x.shape[0], n, block) + x.shape[2:]), 1, 0)
+
+    starts = lo + block * jnp.arange(n, dtype=jnp.int32)
+    o = jax.lax.map(one, (*map(cut, xs), starts))
+    return jnp.moveaxis(o, 0, 1).reshape(
+        (o.shape[1], n * block) + o.shape[3:])[:, :hi - lo]
 
 
 def selected_pairs(n, top_k):

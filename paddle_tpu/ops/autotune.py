@@ -39,6 +39,7 @@ __all__ = [
     "tune_fused_norm",
     "tune_swiglu",
     "tune_paged",
+    "tune_sparse_flash",
     "device_kind_slug",
     "flash_vmem_bytes",
     "lane_padded",
@@ -674,6 +675,55 @@ def tune_paged(batch=32, num_heads=16, num_kv_heads=8, head_dim=128,
     return cfg, ms / calls
 
 
+def tune_sparse_flash(rows=6144, seq=8192, head_dim=192, v_dim=128,
+                      num_heads=128, top_k=2048, dtype="bfloat16", **kw):
+    """Tune the sparse prefill forward's tile (ops/sparse_flash.py) for the
+    last `rows` queries of a prompt of `seq` keys, each keeping the exact
+    `top_k` of its causal keys by random scores (an indexer's selection is
+    as spread: every key tile of a query tile keeps some key).  Recorded
+    under `sparse_flash.KERNEL` and `flash_key(rows, seq, ...)`.  Prints
+    the FLOPs of the tiles the kernel visits (a query's causal keys) and of
+    the selected pairs beside the candidates."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import sparse_flash as sf
+
+    jd = jnp.dtype(dtype)
+    key = flash_key(rows, seq, head_dim, jd, True, v_dim)
+    r = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(r[0], (1, num_heads, rows, head_dim), jd)
+    k = jax.random.normal(r[1], (1, num_heads, seq, head_dim), jd)
+    v = jax.random.normal(r[2], (1, num_heads, seq, v_dim), jd)
+
+    @jax.jit
+    def mask(key):
+        t = seq - rows + jnp.arange(rows, dtype=jnp.int32)[:, None]
+        seen = jnp.arange(seq, dtype=jnp.int32)[None, :] <= t
+        scores = jnp.where(seen, jax.random.uniform(key, (rows, seq)), -1.0)
+        edge = jax.lax.top_k(scores, min(top_k, seq))[0][:, -1:]
+        return sf.pack_keep(seen & (scores >= edge))[None]
+
+    words = mask(r[3])
+    if kw.get("verbose"):
+        t0 = seq - rows
+        causal = rows * t0 + rows * (rows + 1) // 2
+        chosen = sum(min(t + 1, top_k) for t in range(t0, seq))
+        per_pair = 2 * num_heads * (head_dim + v_dim)
+        print(f"  {sf.KERNEL} {rows} x {seq}: {causal * per_pair / 1e12:.3f} "
+              f"TFLOP over the causal pairs, {chosen * per_pair / 1e12:.3f} "
+              f"over the selected ({197e12 / 1e3:.0f} TFLOP/s a ms at peak)")
+    cands = [{"block_q": bq, "block_k": bk}
+             for bq in (128, 256, 512, 1024) for bk in (256, 512, 1024, 2048)
+             if sf._valid(bq, bk, seq, head_dim, v_dim, jd)]
+
+    def build(cfg):
+        return jax.jit(lambda q, k, v, w: sf.sparse_flash_forward(
+            q, k, v, w, scale=head_dim ** -0.5, **cfg))
+
+    return tune_kernel(sf.KERNEL, key, build, cands, (q, k, v, words), **kw)
+
+
 # ---------------------------------------------------------------------------
 # CLI: bounded-time sweep over the standard shape set
 
@@ -728,6 +778,9 @@ _STANDARD_SHAPES = {
         dict(num_heads=8, num_kv_heads=2, head_dim=128, block_size=128,
              table_width=67, lens=(2200, 8600)),
     ],
+    # dots3-note-prev's full layers' prefill past the first 2,048 queries
+    # (models/dsa_moe.py): the 8,192 and 4,096 prompts' selected queries
+    "sparse": [dict(rows=6144, seq=8192), dict(rows=2048, seq=4096)],
 }
 
 
@@ -737,7 +790,7 @@ def main(argv=None):
     p = argparse.ArgumentParser(description="Pallas kernel tile autotuner")
     p.add_argument("--kernel", default="all",
                    choices=["all", "flash", "norm", "swiglu", "matmul",
-                            "paged"])
+                            "paged", "sparse"])
     p.add_argument("--budget-seconds", type=float, default=300.0,
                    help="total wall budget; stops between candidates")
     p.add_argument("--dtype", default="bfloat16")
@@ -752,7 +805,7 @@ def main(argv=None):
     print(f"tuning for device kind: {slug}")
     runners = {"flash": tune_flash, "norm": tune_fused_norm,
                "swiglu": tune_swiglu, "matmul": tune_matmul_epilogue,
-               "paged": tune_paged}
+               "paged": tune_paged, "sparse": tune_sparse_flash}
     todo = [args.kernel] if args.kernel != "all" else list(runners)
     for name in todo:
         for shape in _STANDARD_SHAPES[name]:

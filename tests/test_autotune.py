@@ -172,13 +172,22 @@ def test_tune_flash_runs_each_backward_kernel_under_its_own_key(tmp_cache,
 
 
 def test_seeded_v5e_cache_is_well_formed():
-    """Every flash entry, of each of the three kernels, is a valid tile for
-    its shape in the blocks' own type, and a measurement (`ms` > 0): the
-    `"ms": 0.0` seeds of an earlier era are gone."""
+    """Every flash entry, of each of the three kernels and of the sparse
+    prefill forward, is a valid tile for its shape in the blocks' own type,
+    and a measurement (`ms` > 0): the `"ms": 0.0` seeds of an earlier era
+    are gone."""
+    from paddle_tpu.ops import sparse_flash as sf
+
     path = os.path.join(os.path.dirname(at.__file__), "tuned", "tpu_v5_lite.json")
     data = json.load(open(path))
-    assert set(data) <= {*at.FLASH_KERNELS, "paged_decode"}
+    assert set(data) <= {*at.FLASH_KERNELS, "paged_decode", sf.KERNEL}
     assert "flash_fwd" in data
+    for key, entry in data.get(sf.KERNEL, {}).items():
+        dims = dict(kv.split("=") for kv in key.split("|"))
+        assert sf._valid(entry["config"]["block_q"], entry["config"]["block_k"],
+                         int(dims["seq_k"]), int(dims["head_dim"]),
+                         int(dims["v_dim"]), dims["dtype"])
+        assert entry["ms"] > 0 and "measured" in entry["meta"]
     for kernel in set(data) & set(at.FLASH_KERNELS):
         for key, entry in data[kernel].items():
             cfg = entry["config"]

@@ -323,6 +323,37 @@ def test_flash_tile_validation_counts_the_blocks_own_type():
     assert autotune.flash_candidates(8192, 8192, 192, v_dim=128) == []
 
 
+@pytest.mark.parametrize("rows,seq", [(6144, 8192), (2048, 4096)])
+def test_the_sparse_prefill_forward_compiles_at_the_dots3_cells_shapes(
+        one_chip, mosaic, rows, seq):
+    """ops/sparse_flash.py at the dots3 cell's two selected shapes (the
+    queries past the first 2,048 of its 8,192 and 4,096 prompts; 128 heads,
+    q/k 192, v 128): the table holds a measured tile for each, the kernel
+    takes it, and Mosaic compiles the kernel with it."""
+    import json
+    import os
+
+    from paddle_tpu.ops import autotune
+    from paddle_tpu.ops import sparse_flash as sf
+
+    with open(os.path.join(os.path.dirname(autotune.__file__), "tuned",
+                           V5E_SLUG + ".json")) as f:
+        table = json.load(f)[sf.KERNEL]
+    key = autotune.flash_key(rows, seq, 192, jnp.bfloat16, True, 128)
+    entry = table[autotune._key_str(key)]
+    assert entry["ms"] > 0, "not a measurement"
+    tile = (entry["config"]["block_q"], entry["config"]["block_k"])
+    assert sf.tiles(rows, seq, 192, 128, jnp.bfloat16) == tile
+    shapes = ((1, 128, rows, 192), (1, 128, seq, 192), (1, 128, seq, 128))
+    args = [jax.ShapeDtypeStruct(x, jnp.bfloat16, sharding=one_chip)
+            for x in shapes]
+    words = jax.ShapeDtypeStruct((1, rows, sf.words_for(seq)), jnp.int32,
+                                 sharding=one_chip)
+    text = jax.jit(lambda q, k, v, w: sf.sparse_flash_forward(
+        q, k, v, w, scale=0.07)).lower(*args, words).compile().as_text()
+    assert "tpu_custom_call" in text and sf.KERNEL in text
+
+
 @pytest.mark.parametrize("rows,hidden", [(8192, 7680), (32, 7680),
                                          (8192, 1536), (8192, 512)])
 def test_fused_rms_norm_compiles_at_the_latent_models_widths(one_chip, mosaic,

@@ -1,8 +1,10 @@
 """The lowered programs of the serving engines, for comparing two trees.
 
-Lowers the decode macro-step and one prefill program of four small engines
+Lowers the decode macro-step and one prefill program of five small engines
 (dense GQA, latent attention at pangu's geometry, window / full attention at
-laguna's, convolved latent at zaya's) for a DESCRIBED v5e — no chip
+laguna's, convolved latent at zaya's, sparse / window latent attention at
+dots3's widths with a top-256 indexer, so that its 1,024-token prefill runs
+the sparse forward) for a DESCRIBED v5e — no chip
 attached, Mosaic steered on, the v5e's tile table — and writes one line a
 program: its name and the sha256 of its StableHLO text, each Mosaic kernel's
 body printed without its source locations (a kernel body carries the file
@@ -15,7 +17,7 @@ Run it once on each tree, then compare:
 
 `--tree` is the checkout whose `paddle_tpu` is imported (this one if not
 given); `--compare` exits 1 when a program differs and prints which.  A few
-minutes on a CPU for the eight programs.
+minutes on a CPU for the ten programs.
 """
 
 from __future__ import annotations
@@ -34,6 +36,7 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _engines():
     import paddle_tpu as paddle
     from paddle_tpu.models.cca_moe import CcaMoeConfig, CcaMoeForCausalLM
+    from paddle_tpu.models.dsa_moe import DsaMoeForCausalLM, dsa_moe_tiny
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
     from paddle_tpu.models.mla_moe import MlaMoeForCausalLM, mla_moe_tiny
     from paddle_tpu.models.window_moe import (WindowMoeForCausalLM,
@@ -56,6 +59,15 @@ def _engines():
             held_experts=(2, 6)))),
         ("cca", 128, 1024, lambda: CcaMoeForCausalLM(CcaMoeConfig(
             num_hidden_layers=2, vocab_size=4096, num_experts=4))),
+        ("sparse", 128, 1024, lambda: DsaMoeForCausalLM(dsa_moe_tiny(
+            hidden_size=256, num_attention_heads=128, q_lora_rank=64,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, swa_num_attention_heads=64, swa_q_lora_rank=64,
+            swa_kv_lora_rank=512, swa_qk_nope_head_dim=192,
+            swa_qk_rope_head_dim=64, swa_v_head_dim=128,
+            sliding_window_size=513, index_n_heads=64, index_head_dim=128,
+            index_topk=256, max_position_embeddings=16384, dtype="bfloat16",
+            held_experts=(2, 4)))),
     )
     for name, block_size, prompt, build in made:
         paddle.seed(0)
